@@ -22,12 +22,12 @@ and tokens/s through a slot pool with turnover (A/B at identical
 pool geometry), bytes per slot from the cache pytree, and how many
 slots each variant fits under an HBM budget.
 
-    python benchmark/bench_decode.py           # or BENCH_PLATFORM=cpu
+    python benchmark/bench_decode.py           # on the chip
     BENCH_DECODE_MODE=ssm python benchmark/bench_decode.py
-    BENCH_DECODE_SMOKE=1 ...                   # tiny shape for tests
+    BENCH_DECODE_SMOKE=1 ...                   # tiny shape
 
-One BENCH-style JSON line (bench_common fail_payload/last_known
-contract on every failure path, SIGTERM death stub armed): value =
+One BENCH-style JSON line naming the device (bench_common
+fail_payload on every failure path, SIGTERM death stub armed): value =
 the cheaper variant's tokens/s (int8 / ssm), vs_baseline = its
 throughput ratio over the baseline variant, with per-variant
 sub-objects and the bytes/step ratios the acceptance criteria read.
@@ -37,15 +37,12 @@ import os
 import sys
 import time
 
-_platform = os.environ.get("BENCH_PLATFORM")
-if _platform:
-    os.environ["JAX_PLATFORMS"] = _platform
-
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _REPO = os.path.dirname(_HERE)
 sys.path.insert(0, _REPO)
 
-from bench_common import fail_payload, install_death_stub  # noqa: E402
+from bench_common import (fail_payload, install_death_stub,  # noqa: E402
+                          require_accelerator)
 
 MODE = os.environ.get("BENCH_DECODE_MODE", "kv")
 if MODE not in ("kv", "ssm"):
@@ -172,15 +169,15 @@ def _bytes_per_slot_at(params, block_type, max_len, dtype="float32"):
                      block_type=block_type).state_bytes_per_slot()
 
 
-def _run_kv(jax):
+def _run_kv(dev):
     params = _params()
     bf16 = run_variant(params, quantize_kv=False)
     q8 = run_variant(params, quantize_kv=True)
     return {"metric": METRIC, "unit": UNIT,
-            "value": q8["tokens_s"], "live": True,
+            "value": q8["tokens_s"],
             "vs_baseline": round(q8["tokens_s"] / bf16["tokens_s"],
                                  3),
-            "device_kind": jax.devices()[0].device_kind,
+            **dev,
             "hd": DIM // HEADS, "layers": LAYERS,
             "max_len": MAXLEN, "prompt": PROMPT,
             "max_new": MAXNEW, "slots": SLOTS,
@@ -192,7 +189,7 @@ def _run_kv(jax):
                                    3)}
 
 
-def _run_ssm(jax):
+def _run_ssm(dev):
     """f32 attention vs ssm at the long-context shape: throughput,
     bytes/slot + slots-in-budget (the capacity prize), bytes
     CONSTANCY in max_len for ssm, and handoff bytes at two prompt
@@ -216,10 +213,10 @@ def _run_ssm(jax):
         "ssm": {str(p): _handoff_bytes(
             ssm_params, "ssm", p) for p in (p_short, p_long)}}
     return {"metric": METRIC, "unit": UNIT,
-            "value": ssm["tokens_s"], "live": True,
+            "value": ssm["tokens_s"],
             "vs_baseline": round(ssm["tokens_s"] / attn["tokens_s"],
                                  3),
-            "device_kind": jax.devices()[0].device_kind,
+            **dev,
             "hd": DIM // HEADS, "layers": LAYERS,
             "max_len": MAXLEN, "prompt": PROMPT,
             "max_new": MAXNEW, "slots": SLOTS,
@@ -239,13 +236,13 @@ def _run_ssm(jax):
 
 def main():
     install_death_stub(METRIC, UNIT)
-    import jax
+    dev = require_accelerator("bench_decode.py")
     try:
-        rec = _run_ssm(jax) if MODE == "ssm" else _run_kv(jax)
-        print(json.dumps(rec))
-    except Exception as e:  # noqa: BLE001 — one parseable line always
+        rec = _run_ssm(dev) if MODE == "ssm" else _run_kv(dev)
+    except Exception as e:     # one parseable line, then the traceback
         print(json.dumps(fail_payload(METRIC, UNIT, e)))
-        sys.exit(1)
+        raise
+    print(json.dumps(rec))
 
 
 if __name__ == "__main__":

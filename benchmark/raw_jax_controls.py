@@ -269,15 +269,11 @@ def main():
     ap.add_argument("--batch", type=int, default=None)
     ap.add_argument("--iters", type=int, default=20)
     ap.add_argument("--dtype", default="bfloat16")
-    ap.add_argument("--platform", default=os.environ.get(
-        "BENCH_PLATFORM", ""))
     args = ap.parse_args()
-    if args.platform:
-        os.environ["JAX_PLATFORMS"] = args.platform
     import jax
-    if args.platform:
-        jax.config.update("jax_platforms", args.platform)
     import jax.numpy as jnp
+    from bench_common import require_accelerator
+    dev = require_accelerator("raw_jax_controls.py")
 
     dtype = jnp.dtype(args.dtype)
     if args.network == "alexnet":
@@ -317,14 +313,16 @@ def main():
     xd, yd = jax.device_put(x), jax.device_put(y)
     for _ in range(2):
         params, mom, loss = step(params, mom, xd, yd, rng)
-    np.asarray(jax.device_get(loss))
+    jax.block_until_ready(loss)
     t0 = time.time()
     for _ in range(args.iters):
         params, mom, loss = step(params, mom, xd, yd, rng)
-    np.asarray(jax.device_get(loss))
+    jax.block_until_ready(loss)
     dt = (time.time() - t0) / args.iters
-    print("raw-JAX NHWC %s: %.2f ms/step, %.1f img/s (batch %d, %s)"
-          % (args.network, dt * 1e3, batch / dt, batch, args.dtype))
+    print("raw-JAX NHWC %s: %.2f ms/step, %.1f img/s (batch %d, %s) "
+          "on %s x%d"
+          % (args.network, dt * 1e3, batch / dt, batch, args.dtype,
+             dev["device_kind"], dev["device_count"]))
 
 
 if __name__ == "__main__":

@@ -124,15 +124,11 @@ def main():
     ap.add_argument("--iters", type=int, default=20)
     ap.add_argument("--image", type=int, default=224)
     ap.add_argument("--dtype", default="bfloat16")
-    ap.add_argument("--platform", default=os.environ.get(
-        "BENCH_PLATFORM", ""))
     args = ap.parse_args()
-    if args.platform:
-        os.environ["JAX_PLATFORMS"] = args.platform
     import jax
-    if args.platform:
-        jax.config.update("jax_platforms", args.platform)
     import jax.numpy as jnp
+    from bench_common import require_accelerator
+    dev = require_accelerator("raw_jax_resnet.py")
 
     dtype = jnp.dtype(args.dtype)
     params = init_params(jax.random.PRNGKey(0))
@@ -156,14 +152,16 @@ def main():
     xd, yd = jax.device_put(x), jax.device_put(y)
     for _ in range(2):
         params, mom, loss = step(params, mom, xd, yd)
-    np.asarray(jax.device_get(loss))
+    jax.block_until_ready(loss)
     t0 = time.time()
     for _ in range(args.iters):
         params, mom, loss = step(params, mom, xd, yd)
-    np.asarray(jax.device_get(loss))
+    jax.block_until_ready(loss)
     dt = (time.time() - t0) / args.iters
-    print("raw-JAX NHWC resnet50: %.2f ms/step, %.1f img/s (batch %d, %s)"
-          % (dt * 1e3, args.batch / dt, args.batch, args.dtype))
+    print("raw-JAX NHWC resnet50: %.2f ms/step, %.1f img/s (batch %d, %s) "
+          "on %s x%d"
+          % (dt * 1e3, args.batch / dt, args.batch, args.dtype,
+             dev["device_kind"], dev["device_count"]))
 
 
 if __name__ == "__main__":

@@ -23,20 +23,18 @@ if _prec != "default":
                        {"high": "bfloat16_3x", "highest": "float32"}.get(
                            _prec, _prec))
 
-# MXNET_COMPILE_CACHE: persistent XLA compilation cache so a warm
-# restart (crash-resume, elastic rejoin, repeated bench sessions) skips
-# the 20-40 s per-shape compile. Thresholds dropped to cache everything
-# — the knob is an explicit opt-in, so "cache all of it" is the intent.
-_cc = _config.get("MXNET_COMPILE_CACHE")
-if _cc:
-    for _k, _v in (("jax_compilation_cache_dir", _cc),
-                   ("jax_persistent_cache_min_compile_time_secs", 0.0),
-                   ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            _jax.config.update(_k, _v)
-        except (AttributeError, ValueError):
-            # older jax without this knob: best-effort, never fatal
-            pass
+# Persistent XLA compilation cache, so a second process on the same
+# machine skips the per-shape compiles. The directory is part of the
+# cache key, so it must not move between runs: where
+# JAX_COMPILATION_CACHE_DIR is set JAX reads it itself and nothing here
+# touches it; otherwise the cache sits at one fixed path in the checkout.
+# This is the only place the directory is set.
+import os as _os
+if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    _jax.config.update(
+        "jax_compilation_cache_dir",
+        _os.path.join(_os.path.dirname(_os.path.dirname(
+            _os.path.abspath(__file__))), ".jax_cache"))
 
 from . import telemetry
 

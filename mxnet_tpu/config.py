@@ -110,11 +110,11 @@ define("MXNET_BN_PALLAS", bool, False,
        "route 4-D NCHW training BatchNorm through the explicit-pass "
        "Pallas kernels (measured slower on v5e; experiment)")
 define("MXNET_EMBED_GRAD", str, "",
-       "Embedding backward: empty = the measured default (scatter-add; "
-       "won the staged A/B at the flagship LM shape, "
-       "bench_out/embgrad.json) | scatter | segsum = sort + "
-       "segment-sum (kept for the next TPU window's re-measure of the "
-       "traced embedding-update headroom)")
+       "Embedding backward: empty = the default (scatter-add; won the "
+       "staged A/B at the flagship LM shape on the host CPU, not "
+       "measured on the chip) | scatter | segsum = sort + "
+       "segment-sum (kept until the chip decides the traced "
+       "embedding-update headroom)")
 define("MXNET_PROFILER_AUTOSTART", bool, False,
        "start profiler collection at import")
 define("MXNET_PROFILER_MODE", bool, False,
@@ -125,10 +125,6 @@ define("MXNET_DISPATCH_AHEAD", int, 2,
        "bounded async-dispatch window for the fit hot loops: how many "
        "steps may be in flight before the loop blocks on the step K "
        "back (1 = fully synchronous stepping)")
-define("MXNET_COMPILE_CACHE", str, "",
-       "directory for JAX's persistent compilation cache — warm "
-       "restarts skip XLA recompiles (wired at package import; empty "
-       "= disabled)")
 define("MXNET_FSDP_MIN_SIZE", int, 1024,
        "SpecLayout auto-rule threshold: parameters with fewer elements "
        "than this replicate instead of sharding over the 'fsdp' mesh "

@@ -52,14 +52,22 @@ class Context:
     # -- mapping onto jax devices -------------------------------------------------
     def jax_device(self):
         """The concrete jax.Device backing this context."""
-        kind = self.device_type
-        if kind in ("cpu", "cpu_pinned"):
-            devs = jax.devices("cpu") if _has_platform("cpu") else jax.devices()
-        else:  # gpu is an alias for the accelerator on this image (TPU)
-            devs = _accelerator_devices()
-        if not devs:
-            raise RuntimeError("no devices for context %r" % (self,))
-        return devs[self.device_id % len(devs)]
+        if self.device_type in ("cpu", "cpu_pinned"):
+            # cpu ids are logical, as in the reference (any mx.cpu(k)
+            # names host memory): they wrap over the host devices
+            devs = jax.devices("cpu") if _has_platform("cpu") \
+                else jax.devices()
+            return devs[self.device_id % len(devs)]
+        # gpu is an alias for the accelerator on this image (TPU); an
+        # accelerator id names one chip, so it never wraps or falls
+        # back to the host
+        devs = [d for d in jax.devices() if d.platform != "cpu"]
+        if not 0 <= self.device_id < len(devs):
+            raise ValueError(
+                "%r: this process sees %d accelerator device(s) "
+                "(default backend %r)"
+                % (self, len(devs), jax.default_backend()))
+        return devs[self.device_id]
 
     # -- equality / hashing -------------------------------------------------------
     def __hash__(self):
@@ -95,13 +103,6 @@ def _has_platform(name):
         return bool(jax.devices(name))
     except RuntimeError:
         return False
-
-
-def _accelerator_devices():
-    """All non-CPU devices, falling back to CPU when no accelerator exists
-    (e.g. under JAX_PLATFORMS=cpu test meshes)."""
-    devs = [d for d in jax.devices() if d.platform != "cpu"]
-    return devs if devs else jax.devices()
 
 
 Context._default_ctx.value = Context("cpu", 0)

@@ -428,15 +428,16 @@ class Perplexity(EvalMetric):
         flat = label.reshape(-1).astype(jnp.int32)
         assert flat.size == pred.size // pred.shape[-1], \
             "shape mismatch: %s vs. %s" % (label.shape, pred.shape)
+        # f32 from here: a bf16-compute step hands over bf16 rows, and
+        # a bf16 sum over a 16k-token batch resolves 1/16 nat per token
         probs = pred.reshape(-1, pred.shape[-1])[
-            jnp.arange(flat.size), flat]
+            jnp.arange(flat.size), flat].astype(jnp.float32)
         count = _f32(flat.size)
         if self.ignore_label is not None:
             keep = flat != self.ignore_label
             count = keep.sum().astype(jnp.float32)
             probs = jnp.where(keep, probs, 1.0)
-        s = -jnp.log(jnp.maximum(probs, 1e-10)).sum()
-        return s.astype(jnp.float32), count
+        return -jnp.log(jnp.maximum(probs, 1e-10)).sum(), count
 
     def _finalize(self, ratio):
         return math.exp(ratio)
@@ -526,8 +527,9 @@ class _PickedNLL(EvalMetric):
     def _device_stats_one(self, label, pred):
         flat = label.reshape(-1).astype(jnp.int32)
         assert flat.shape[0] == pred.shape[0]
-        picked = pred[jnp.arange(flat.shape[0]), flat]
-        return ((-jnp.log(picked + self.eps).sum()).astype(jnp.float32),
+        picked = pred[jnp.arange(flat.shape[0]), flat] \
+            .astype(jnp.float32)       # see Perplexity: sum in f32
+        return (-jnp.log(picked + self.eps).sum(),
                 _f32(flat.shape[0]))
 
 

@@ -38,9 +38,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-
-def _interpret():
-    return jax.default_backend() != "tpu"
+from ._pallas import interpret as _interpret
 
 
 # ---------------------------------------------------------------------------

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ._compat import shard_map as _shard_map
 
 __all__ = ["moe_ffn", "dense_moe"]
 
@@ -114,7 +113,7 @@ def moe_ffn(x, gate_w, w1, w2, mesh, axis_name="expert",
         mine = back.reshape(E, cap, D)
         return _combine(mine, expert, slot, keep, gate, xl.dtype)
 
-    fn = _shard_map(local, mesh=mesh,
+    fn = jax.shard_map(local, mesh=mesh,
                     in_specs=(P(axis_name), P(), P(axis_name), P(axis_name)),
                     out_specs=P(axis_name))
     return fn(x, gate_w, w1, w2)

@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ._compat import pvary as _pvary, shard_map as _shard_map
 
 __all__ = ["pipeline_apply", "pipeline_from_symbol"]
 
@@ -62,9 +61,9 @@ def pipeline_apply(stage_fn, stage_params, microbatches, mesh,
         me = lax.axis_index(axis_name)
         mb_shape = stream.shape[1:]
         carry = jnp.zeros(mb_shape, stream.dtype)
-        carry = _pvary(carry, (axis_name,))
+        carry = lax.pcast(carry, (axis_name,), to="varying")
         outs0 = jnp.zeros((M,) + mb_shape, stream.dtype)
-        outs0 = _pvary(outs0, (axis_name,))
+        outs0 = lax.pcast(outs0, (axis_name,), to="varying")
 
         def tick(t, state):
             carry, outs = state
@@ -90,7 +89,7 @@ def pipeline_apply(stage_fn, stage_params, microbatches, mesh,
         return lax.psum(jnp.where(me == S - 1, outs, 0.0), axis_name)
 
     pspec = jax.tree.map(lambda _: P(axis_name), stage_params)
-    fn = _shard_map(local, mesh=mesh,
+    fn = jax.shard_map(local, mesh=mesh,
                     in_specs=(pspec, P()),
                     out_specs=P())
     return fn(stage_params, microbatches)

@@ -22,11 +22,17 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ._compat import pvary as _pvary, shard_map as _shard_map
 
 __all__ = ["ring_attention"]
 
 _NEG_INF = -1e30
+
+
+def _varying(x, axis_name):
+    """Mark a fresh (replicated-typed) constant device-varying over the
+    ring axis, as shard_map's type check wants of loop carries and
+    branch outputs that meet varying values."""
+    return lax.pcast(x, (axis_name,), to="varying")
 
 
 def _ring_local(q, k, v, *, axis_name, causal, scale):
@@ -62,7 +68,7 @@ def _ring_local(q, k, v, *, axis_name, causal, scale):
         def skip(_):
             # fresh constants are replicated-typed; match the kernel
             # branches' device-varying outputs for lax.switch
-            return tuple(_pvary(x, (axis_name,)) for x in (
+            return tuple(_varying(x, axis_name) for x in (
                 jnp.zeros(q3.shape, q3.dtype),
                 jnp.full((B * H, Tq), _NEG_INF, jnp.float32)))
 
@@ -85,7 +91,7 @@ def _ring_local(q, k, v, *, axis_name, causal, scale):
     lse0 = jnp.full((B * H, Tq), _NEG_INF, jnp.float32)
     # constants enter the loop carry device-varying (their updates vary
     # over the ring axis; shard_map type-checks this)
-    o0, lse0 = (_pvary(x, (axis_name,)) for x in (o0, lse0))
+    o0, lse0 = (_varying(x, axis_name) for x in (o0, lse0))
     perm = [(j, (j + 1) % n) for j in range(n)]
 
     def step(t, carry):
@@ -131,10 +137,9 @@ def _ring_local_windowed(q, k, v, *, axis_name, scale, window, n):
         w_b = jnp.exp(lse_b - lse)[..., None]
         return (o_acc * w_a + o_b.astype(jnp.float32) * w_b, lse)
 
-    o_acc = _pvary(jnp.zeros((B * H, Tq, D), jnp.float32),
-                   (axis_name,))
-    lse_acc = _pvary(jnp.full((B * H, Tq), _NEG_INF, jnp.float32),
-                     (axis_name,))
+    o_acc = _varying(jnp.zeros((B * H, Tq, D), jnp.float32), axis_name)
+    lse_acc = _varying(jnp.full((B * H, Tq), _NEG_INF, jnp.float32),
+                       axis_name)
     perm = [(j, (j + 1) % n) for j in range(n)]
     k_cur, v_cur = k, v
     for t in range(r + 1):
@@ -147,7 +152,7 @@ def _ring_local_windowed(q, k, v, *, axis_name, scale, window, n):
                 band_offset=t * Tb)
 
         def skip(_):
-            return tuple(_pvary(x, (axis_name,)) for x in (
+            return tuple(_varying(x, axis_name) for x in (
                 jnp.zeros(q3.shape, q3.dtype),
                 jnp.full((B * H, Tq), _NEG_INF, jnp.float32)))
 
@@ -185,6 +190,6 @@ def ring_attention(q, k, v, mesh, axis_name="sp", causal=False,
     else:
         body = functools.partial(_ring_local, axis_name=axis_name,
                                  causal=causal, scale=float(scale))
-    fn = _shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
                     out_specs=spec)
     return fn(q, k, v)

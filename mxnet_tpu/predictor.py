@@ -82,8 +82,10 @@ class Predictor:
         or by name (reference MXPredSetInput + MXPredForward)."""
         if kwargs:
             args = [kwargs[n] for n in self._data_names]
-        self._outputs = self._fwd(*[_as_jnp(a) for a in args])
-        return [_wrap(o) for o in self._outputs]
+        # returned from the local: engines on other threads may share
+        # this predictor, and get_output()'s slot is last-writer-wins
+        outs = self._outputs = self._fwd(*[_as_jnp(a) for a in args])
+        return [_wrap(o) for o in outs]
 
     def get_output(self, index):
         assert self._outputs is not None, "run forward() first"
@@ -184,8 +186,9 @@ class CompiledPredictor:
     def forward(self, *args, **kwargs):
         if kwargs:
             args = [kwargs[n] for n in self._data_names]
-        self._outputs = self._exported.call(*[_as_jnp(a) for a in args])
-        return [_wrap(o) for o in self._outputs]
+        outs = self._outputs = self._exported.call(
+            *[_as_jnp(a) for a in args])
+        return [_wrap(o) for o in outs]
 
     def get_output(self, index):
         assert self._outputs is not None, "run forward() first"

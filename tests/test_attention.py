@@ -191,7 +191,7 @@ class TestFlashKernel:
         kernel path itself runs (no varying operand, so no interpret
         fallback) and the out aval must declare vma=empty — omitting
         vma entirely raises under check_vma."""
-        from mxnet_tpu.parallel._compat import shard_map
+        from jax import shard_map
         mesh = Mesh(np.array(jax.devices()[:2]), ("sp",))
         q, k, v = _qkv(2, 32, 16, seed=12)
         fn = shard_map(
@@ -211,7 +211,7 @@ class TestFlashKernel:
         back replicated (psum over the extra axis), not union-varying
         (regression: the Pallas backward stamps outputs with the union
         vma; _narrow_vma reduces it to each primal's variance)."""
-        from mxnet_tpu.parallel._compat import shard_map
+        from jax import shard_map
         mesh = Mesh(np.array(jax.devices()[:2]), ("sp",))
         q, k, v = _qkv(2, 32, 16, seed=11)
 

@@ -329,5 +329,7 @@ def test_nms_pallas_iou_matches_shared_helper():
     xy = rng.rand(60, 2).astype(np.float32)
     a = np.concatenate([xy, xy + rng.rand(60, 2).astype(np.float32)], 1)
     b = a[rng.permutation(60)[:40]]
-    np.testing.assert_array_equal(np.asarray(_iou_tile(a, b)),
+    tile = _iou_tile([a[:, i:i + 1] for i in range(4)],
+                     [b.T[i:i + 1] for i in range(4)])
+    np.testing.assert_array_equal(np.asarray(tile),
                                   np.asarray(_box_iou_corner(a, b)))

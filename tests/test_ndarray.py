@@ -148,6 +148,19 @@ def test_context():
     assert b.shape == (2, 2)
 
 
+def test_accelerator_context_never_wraps_or_falls_back():
+    """mx.tpu(k) / mx.gpu(k) name one chip: an id past the device count
+    raises (it used to wrap onto chip 0, and on a chipless host to land
+    on the CPU). cpu ids stay logical, as in the reference."""
+    import jax
+    import pytest
+    chips = [d for d in jax.devices() if d.platform != "cpu"]
+    for make in (mx.tpu, mx.gpu):
+        with pytest.raises(ValueError, match="accelerator device"):
+            make(len(chips)).jax_device()
+    assert mx.cpu(len(jax.devices("cpu"))).jax_device().platform == "cpu"
+
+
 def test_broadcast_ops():
     a = nd.array(np.ones((2, 1, 3)))
     b = nd.array(np.ones((1, 4, 3)))

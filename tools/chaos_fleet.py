@@ -133,7 +133,7 @@ def _spawn_replica(args):
     proc = subprocess.Popen(cmd, stdin=subprocess.PIPE,
                             stdout=subprocess.PIPE, text=True,
                             env=env)
-    deadline = time.monotonic() + 300.0   # XLA import is the cost
+    deadline = time.monotonic() + 300.0   # package import is the cost
     remain = deadline - time.monotonic()
     if remain <= 0 or not select.select([proc.stdout], [], [],
                                         remain)[0]:
@@ -479,6 +479,8 @@ def main(argv=None):
         args.default_spec = "kill1@40"
     if args.replica:
         return _replica_child(args)
+    from bench_common import require_cpu_fleet
+    require_cpu_fleet("chaos_fleet.py")
     return _run(args)
 
 

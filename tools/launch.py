@@ -9,8 +9,13 @@ start the same command everywhere with the right env
 reference-script compat).
 
   # N local processes on one host (the dmlc_tracker 'local' mode —
-  # how the multi-process tests run without a cluster):
-  python tools/launch.py -n 4 --launcher local python train.py
+  # how the multi-process tests run without a cluster). Every worker
+  # gets the same environment, so this is for the CPU
+  # (JAX_PLATFORMS=cpu): on a host with chips each worker would claim
+  # all of them, and a chip belongs to one process. One process drives
+  # all the chips of a host (mesh= / layout=).
+  JAX_PLATFORMS=cpu python tools/launch.py -n 4 --launcher local \
+      python train.py
 
   # one process per host over ssh:
   python tools/launch.py -n 2 --launcher ssh -H hosts.txt python train.py
@@ -149,7 +154,12 @@ def main(argv=None):
                     help="parameter-server process count (dist_async; "
                          "0 = collective-only job, no servers)")
     ap.add_argument("--launcher", choices=("local", "ssh"),
-                    default="local")
+                    default="local",
+                    help="local: N processes on this host with one "
+                         "shared environment — CPU multi-process runs "
+                         "(JAX_PLATFORMS=cpu), not chips: every worker "
+                         "would claim all of them. ssh: one process "
+                         "per host, the shape a pod slice wants")
     ap.add_argument("-H", "--hostfile",
                     help="one host per line (ssh launcher)")
     ap.add_argument("--env", action="append", default=[],

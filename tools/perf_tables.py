@@ -12,8 +12,7 @@ line), groups by metric, and prints the most recent record per
 (metric, variant-ish key). Records with value=null are skipped, and so
 are A/B experiment rows (`ab_config` tag from tpu_ab_regression.sh) —
 they measure deliberately non-default configs and must never shadow
-the numbers of record, in these tables or in bench.py's last_known
-outage fallback (which shares is_experiment_row below).
+the numbers of record in these tables.
 """
 from __future__ import annotations
 
@@ -28,8 +27,7 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def is_experiment_row(rec):
     """True for A/B experiment records (tools/tpu_ab_regression.sh
     tags them ab_config) — deliberately non-default configurations
-    that must never be selected as a number of record. Shared by the
-    table renderer here and bench.py's last_known fallback."""
+    that must never be selected as a number of record."""
     return bool(rec.get("ab_config"))
 
 
