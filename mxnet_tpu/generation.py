@@ -121,8 +121,13 @@ class Generator:
         self._sym = sym
         eval_fn = _graph_eval_fn(sym, mesh=mesh)
         self._eval_fn = eval_fn
-        self._step_fn = jax.jit(
-            lambda args, aux, rng: eval_fn(args, aux, rng, False))
+
+        def generator_step(args, aux, rng):
+            # named, not a lambda: PjitFunction(generator_step) and the
+            # device's module name say which program ran
+            return eval_fn(args, aux, rng, False)
+
+        self._step_fn = jax.jit(generator_step)
         self._loop_cache = {}
 
         def _raw(name, v):

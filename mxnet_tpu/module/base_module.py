@@ -268,7 +268,9 @@ class BaseModule:
         step."""
         assert num_epoch is not None, "please specify number of epochs"
         from .. import guardrail as _guardrail
+        from .. import profiler as _profiler
         from .. import telemetry as _telemetry
+        from .. import trace as _trace
 
         skip_batches = 0
         if checkpoint_prefix and resume:
@@ -317,8 +319,6 @@ class BaseModule:
                                     skip_batches=skip_batches)
                     skip_batches = 0
                 except _guardrail.RollbackNeeded:
-                    from .. import trace as _trace
-                    _trace.unwind()   # drop the abandoned step span
                     epoch, skip_batches = self._guard_rollback(
                         checkpoint_prefix, guard)
                     train_data.reset()
@@ -326,24 +326,33 @@ class BaseModule:
                 except _guardrail.PreemptionSignal as preempted:
                     self._guard_preempt(checkpoint_prefix, epoch,
                                         preempted.nbatch)
-                for name, val in eval_metric.get_name_value():
-                    self.logger.info("Epoch[%d] Train-%s=%f", epoch,
-                                     name, val)
-                self.logger.info("Epoch[%d] Time cost=%.3f", epoch,
-                                 time.time() - tic)
+                # everything between an epoch's last step and the
+                # next epoch (or the eval pass): the device drains
+                # under the metric read, so its idle time here has
+                # this name in a trace
+                with _trace.phase("train.epoch_end", epoch=epoch):
+                    for name, val in eval_metric.get_name_value():
+                        self.logger.info("Epoch[%d] Train-%s=%f", epoch,
+                                         name, val)
+                    self.logger.info("Epoch[%d] Time cost=%.3f", epoch,
+                                     time.time() - tic)
+                    # HBM watermark: boundary-only sample, never per
+                    # step
+                    _profiler.sample_device_memory("epoch.end")
 
-                # pull trained values host-side (also re-syncs aux
-                # stats)
-                arg_now, aux_now = self.get_params()
-                self.set_params(arg_now, aux_now)
-                if checkpoint_prefix and \
-                        (epoch + 1) % checkpoint_period == 0:
-                    from ..model import save_checkpoint
-                    save_checkpoint(checkpoint_prefix, epoch + 1,
-                                    self.symbol, arg_now, aux_now)
-                    _clear_resume_sidecar(checkpoint_prefix, epoch + 1)
-                for cb in _as_list(epoch_end_callback or []):
-                    cb(epoch, self.symbol, arg_now, aux_now)
+                    # pull trained values host-side (also re-syncs aux
+                    # stats)
+                    arg_now, aux_now = self.get_params()
+                    self.set_params(arg_now, aux_now)
+                    if checkpoint_prefix and \
+                            (epoch + 1) % checkpoint_period == 0:
+                        from ..model import save_checkpoint
+                        save_checkpoint(checkpoint_prefix, epoch + 1,
+                                        self.symbol, arg_now, aux_now)
+                        _clear_resume_sidecar(checkpoint_prefix,
+                                              epoch + 1)
+                    for cb in _as_list(epoch_end_callback or []):
+                        cb(epoch, self.symbol, arg_now, aux_now)
 
                 if eval_data is not None:
                     for name, val in self.score(
@@ -442,10 +451,10 @@ class BaseModule:
         # telemetry: hoisted handle — zero cost when off; all timing
         # below is host wall-clock (no blocking syncs added, asserted
         # in tests/test_telemetry.py). The trace handle is hoisted the
-        # same way; `timed` gates the shared timestamp capture.
+        # same way: this call resolves MXNET_TRACE once, and every
+        # phase in the loop reads the module flag only.
         jr = _telemetry.journal()
-        tr = _trace.tracer()
-        timed = jr is not None or tr is not None
+        _trace.tracer()
         step_hist = _telemetry.histogram("module.step_ms") \
             if jr is not None else None
 
@@ -473,54 +482,57 @@ class BaseModule:
                     break
         pending = next(batches, None)
         nbatch = skip_batches
-        t_iter = _telemetry.now_ms() if timed else 0.0
+        t_iter = _telemetry.now_ms() if jr is not None else 0.0
         while pending is not None:
             batch = pending
-            # step span: annotated with the journal's step seq (nbatch
-            # == the record's `step`) so traces and the telemetry
-            # report cross-reference; open (not retroactive) so the
-            # kvstore's ps.op spans dispatched inside update() join it
-            ssp = _trace.start_span("train.step", loop="module",
-                                    step=nbatch, epoch=epoch) \
-                if tr is not None else None
             inject = None
             if guard is not None:
                 if guard.spec is not None or guard.shutdown is not None:
                     inject = guard.poll_faults()
                 if guard.preempt_requested():
-                    _trace.end_span(ssp, preempted=True)
                     raise _guardrail.PreemptionSignal(nbatch)
-            if monitor is not None:
-                monitor.tic()
-            ok = None
-            with _profiler.step_scope(nbatch):
-                self.forward_backward(batch)
-                if masker is not None:
-                    ok = masker(inject=inject)
-                self.update()
-            t_data = _telemetry.now_ms() if timed else 0.0
-            pending = next(batches, None)
-            if pending is not None:
-                self.prepare(pending)     # H2D of t+1 overlaps step t
-            data_ms = _telemetry.now_ms() - t_data if timed else 0.0
-            if ok is not None:
-                self.update_metric(eval_metric, batch.label, ok=ok)
-            else:
-                self.update_metric(eval_metric, batch.label)
-            if ok is not None:
-                inflight.append(ok)
-            else:
-                outs = self.get_outputs()
-                if outs and hasattr(outs[0], "wait_to_read"):
-                    inflight.append(outs[0])
-            t_win = _telemetry.now_ms() if timed else 0.0
-            while len(inflight) > ahead:
-                # the ONE allowed blocking sync per step: back-pressure
-                # on the step K back
-                drain_one()
-            if timed:
-                now_ = _telemetry.now_ms()
+            # step phase: annotated with the journal's step seq (nbatch
+            # == the record's `step`) so traces and the telemetry
+            # report cross-reference; live (not retroactive) so the
+            # kvstore's ps.op spans dispatched inside update() join
+            # it, and so a device trace carries it on its own clock
+            with _trace.phase("train.step", loop="module", step=nbatch,
+                              epoch=epoch):
+                if monitor is not None:
+                    monitor.tic()
+                ok = None
+                with _profiler.step_scope(nbatch), \
+                        _trace.phase("step.dispatch"):
+                    self.forward_backward(batch)
+                    if masker is not None:
+                        ok = masker(inject=inject)
+                    self.update()
+                t_data = _telemetry.now_ms() if jr is not None else 0.0
+                with _trace.phase("step.data_wait"):
+                    pending = next(batches, None)
+                    if pending is not None:
+                        # H2D of t+1 overlaps step t
+                        self.prepare(pending)
+                data_ms = _telemetry.now_ms() - t_data \
+                    if jr is not None else 0.0
+                if ok is not None:
+                    self.update_metric(eval_metric, batch.label, ok=ok)
+                else:
+                    self.update_metric(eval_metric, batch.label)
+                if ok is not None:
+                    inflight.append(ok)
+                else:
+                    outs = self.get_outputs()
+                    if outs and hasattr(outs[0], "wait_to_read"):
+                        inflight.append(outs[0])
+                t_win = _telemetry.now_ms() if jr is not None else 0.0
+                with _trace.phase("step.window_wait"):
+                    while len(inflight) > ahead:
+                        # the ONE allowed blocking sync per step:
+                        # back-pressure on the step K back
+                        drain_one()
                 if jr is not None:
+                    now_ = _telemetry.now_ms()
                     step_hist.observe(now_ - t_iter)
                     _telemetry.journal_step(
                         loop="module", step=nbatch, epoch=epoch,
@@ -529,15 +541,7 @@ class BaseModule:
                         window_wait_ms=round(now_ - t_win, 3),
                         samples=int(batch.data[0].shape[0])
                         if batch.data else 0)
-                if tr is not None:
-                    # wait children reconstructed from the timestamps
-                    # already taken — no extra clock reads
-                    _trace.add_span("step.data_wait", t_data,
-                                    t_data + data_ms, parent=ssp)
-                    _trace.add_span("step.window_wait", t_win, now_,
-                                    parent=ssp)
-                t_iter = now_
-            _trace.end_span(ssp)
+                    t_iter = now_
             if monitor is not None:
                 monitor.toc_print()
             if batch_end_callback is not None:
@@ -550,13 +554,12 @@ class BaseModule:
         if masker is not None:
             # drain the window so a bad tail is seen BEFORE this
             # epoch's checkpoint is published
-            while inflight:
-                drain_one()
+            with _trace.phase("train.epoch_drain"):
+                while inflight:
+                    drain_one()
         if jr is not None:
             _telemetry.journal_event("epoch.end", loop="module",
                                      epoch=epoch, steps=nbatch)
-        # HBM watermark: boundary-only sample, never per step
-        _profiler.sample_device_memory("epoch.end")
 
     # -- symbol/params accessors -------------------------------------------
     @property

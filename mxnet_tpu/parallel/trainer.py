@@ -485,10 +485,10 @@ class TrainStep:
         # wall-clock only: it adds ZERO blocking host syncs (asserted
         # against profiler.host_sync_count in tests/test_telemetry.py).
         # The trace handle (docs/observability.md §tracing) is hoisted
-        # the same way; `timed` gates the shared timestamp capture.
+        # the same way: this call resolves MXNET_TRACE once, and every
+        # phase in the loop reads the module flag only.
         jr = _telemetry.journal()
-        tr = _trace.tracer()
-        timed = jr is not None or tr is not None
+        _trace.tracer()
         step_hist = _telemetry.histogram("trainstep.step_ms") \
             if jr is not None else None
         _telemetry.journal_event("fit.start", loop="trainstep",
@@ -515,22 +515,23 @@ class TrainStep:
         with guard.shutdown_scope():
             epoch = begin_epoch
             while epoch < num_epoch:
-                train_data.reset()
-                metric.reset()
-                mstats = None
-                batches = iter(train_data)
-                if skip_batches:
-                    log.info("mid-epoch resume: skipping %d already-"
-                             "trained batches of epoch %d",
-                             skip_batches, epoch)
-                    for _ in range(skip_batches):
-                        if next(batches, None) is None:
-                            break
-                    skip_batches = 0
-                nxt = next(batches, None)
-                staged = None if nxt is None else self._stage(nxt)
+                with _trace.phase("train.epoch_begin", epoch=epoch):
+                    train_data.reset()
+                    metric.reset()
+                    mstats = None
+                    batches = iter(train_data)
+                    if skip_batches:
+                        log.info("mid-epoch resume: skipping %d "
+                                 "already-trained batches of epoch %d",
+                                 skip_batches, epoch)
+                        for _ in range(skip_batches):
+                            if next(batches, None) is None:
+                                break
+                        skip_batches = 0
+                    nxt = next(batches, None)
+                    staged = None if nxt is None else self._stage(nxt)
                 nbatch = 0
-                t_iter = _telemetry.now_ms() if timed else 0.0
+                t_iter = _telemetry.now_ms() if jr is not None else 0.0
                 try:
                     while staged is not None:
                         inject = guard.poll_faults() \
@@ -541,102 +542,106 @@ class TrainStep:
                                 checkpoint_prefix, epoch, nbatch,
                                 state, n_update, log)
                         batch, placed = staged
-                        # step span: annotated with the journal's step
+                        # step phase: annotated with the journal's step
                         # seq (n_update pre-increment == the record's
                         # `step`), so traces and the telemetry report
-                        # cross-reference. Open (not retroactive) so
-                        # any RPC spans dispatched inside join it.
-                        ssp = _trace.start_span(
-                            "train.step", loop="trainstep",
-                            step=n_update, epoch=epoch) \
-                            if tr is not None else None
-                        cur_lr = (lr_scheduler(n_update) if lr_scheduler
-                                  else lr) * guard.lr_mult
-                        step_rng = jax.random.fold_in(rng, n_update)
-                        flag = None
-                        t_disp = _telemetry.now_ms() if jr is not None \
-                            else 0.0
-                        with _profiler.step_scope(n_update):
-                            lr_arr = jnp.asarray(cur_lr, jnp.float32)
-                            if fuse:
-                                if mstats is None:
-                                    mstats = self._zero_metric_stats(
-                                        raw_step, metric, state, placed,
-                                        cur_lr, step_rng,
-                                        guarded=spec is not None)
-                                params, opt_state, aux = state
-                                if spec is not None:
+                        # cross-reference. Live (not retroactive) so
+                        # any RPC spans dispatched inside join it, and
+                        # so a device trace carries it on its own clock
+                        with _trace.phase("train.step", loop="trainstep",
+                                          step=n_update, epoch=epoch):
+                            cur_lr = (lr_scheduler(n_update)
+                                      if lr_scheduler
+                                      else lr) * guard.lr_mult
+                            step_rng = jax.random.fold_in(rng, n_update)
+                            flag = None
+                            t_disp = _telemetry.now_ms() \
+                                if jr is not None else 0.0
+                            with _profiler.step_scope(n_update), \
+                                    _trace.phase("step.dispatch"):
+                                lr_arr = jnp.asarray(cur_lr, jnp.float32)
+                                if fuse:
+                                    if mstats is None:
+                                        mstats = self._zero_metric_stats(
+                                            raw_step, metric, state,
+                                            placed, cur_lr, step_rng,
+                                            guarded=spec is not None)
+                                    params, opt_state, aux = state
+                                    if spec is not None:
+                                        (params, opt_state, aux), outs, \
+                                            mstats, flag = fused_step(
+                                                params, opt_state, aux,
+                                                placed, lr_arr, step_rng,
+                                                mstats,
+                                                jnp.asarray(inject,
+                                                            jnp.float32))
+                                    else:
+                                        (params, opt_state, aux), outs, \
+                                            mstats = fused_step(
+                                                params, opt_state, aux,
+                                                placed, lr_arr, step_rng,
+                                                mstats)
+                                    state = (params, opt_state, aux)
+                                    # the metric VIEWS the live epoch
+                                    # totals, so get() works mid-epoch
+                                    # (Speedometer) at the cost of that
+                                    # caller's one sync
+                                    metric.set_device_stats(mstats)
+                                elif spec is not None:
+                                    params, opt_state, aux = state
                                     (params, opt_state, aux), outs, \
-                                        mstats, flag = fused_step(
+                                        flag = guarded_step(
                                             params, opt_state, aux,
                                             placed, lr_arr, step_rng,
-                                            mstats,
                                             jnp.asarray(inject,
                                                         jnp.float32))
+                                    state = (params, opt_state, aux)
                                 else:
-                                    (params, opt_state, aux), outs, \
-                                        mstats = fused_step(
-                                            params, opt_state, aux,
-                                            placed, lr_arr, step_rng,
-                                            mstats)
-                                state = (params, opt_state, aux)
-                                # the metric VIEWS the live epoch
-                                # totals, so get() works mid-epoch
-                                # (Speedometer) at the cost of that
-                                # caller's one sync
-                                metric.set_device_stats(mstats)
-                            elif spec is not None:
-                                params, opt_state, aux = state
-                                (params, opt_state, aux), outs, flag = \
-                                    guarded_step(
-                                        params, opt_state, aux, placed,
-                                        lr_arr, step_rng,
-                                        jnp.asarray(inject,
-                                                    jnp.float32))
-                                state = (params, opt_state, aux)
-                            else:
-                                state, outs = self(state, placed,
-                                                   cur_lr, step_rng)
-                        n_update += 1
-                        if jr is not None and not compile_logged:
-                            # the first dispatch blocks through XLA
-                            # trace+compile; later dispatches return
-                            # async — its wall IS the compile cost
-                            compile_logged = True
-                            _telemetry.journal_event(
-                                "compile", site="TrainStep.fit",
-                                wall_ms=round(
-                                    _telemetry.now_ms() - t_disp, 3))
-                        # stage batch t+1: its H2D overlaps the step
-                        # just dispatched (async)
-                        t_data = _telemetry.now_ms() if timed else 0.0
-                        nxt = next(batches, None)
-                        staged = None if nxt is None \
-                            else self._stage(nxt)
-                        data_ms = _telemetry.now_ms() - t_data \
-                            if timed else 0.0
-                        if not fuse:
-                            # fuse=False is the host metric path
-                            # (device accumulation on this loop is
-                            # always fused)
-                            metric.update(batch.label,
-                                          [_nd_wrap(o) for o in outs])
-                        # bounded dispatch: block on the step K back so
-                        # async dispatch can't run arbitrarily ahead of
-                        # the device; the guarded item is the step's
-                        # finite flag
-                        inflight.append(flag if flag is not None
-                                        else outs[0])
-                        t_win = _telemetry.now_ms() if timed else 0.0
-                        while len(inflight) > ahead:
-                            drain_one()
-                        if timed:
-                            # boundary-to-boundary iteration wall: the
-                            # sum over an epoch is the epoch's wall, so
-                            # the report's samples/sec matches a
-                            # Speedometer-style measurement
-                            now_ = _telemetry.now_ms()
+                                    state, outs = self(state, placed,
+                                                       cur_lr, step_rng)
+                            n_update += 1
+                            if jr is not None and not compile_logged:
+                                # the first dispatch blocks through XLA
+                                # trace+compile; later dispatches return
+                                # async — its wall IS the compile cost
+                                compile_logged = True
+                                _telemetry.journal_event(
+                                    "compile", site="TrainStep.fit",
+                                    wall_ms=round(
+                                        _telemetry.now_ms() - t_disp, 3))
+                            # stage batch t+1: its H2D overlaps the step
+                            # just dispatched (async)
+                            t_data = _telemetry.now_ms() \
+                                if jr is not None else 0.0
+                            with _trace.phase("step.data_wait"):
+                                nxt = next(batches, None)
+                                staged = None if nxt is None \
+                                    else self._stage(nxt)
+                            data_ms = _telemetry.now_ms() - t_data \
+                                if jr is not None else 0.0
+                            if not fuse:
+                                # fuse=False is the host metric path
+                                # (device accumulation on this loop is
+                                # always fused)
+                                metric.update(batch.label,
+                                              [_nd_wrap(o) for o in outs])
+                            # bounded dispatch: block on the step K back
+                            # so async dispatch can't run arbitrarily
+                            # ahead of the device; the guarded item is
+                            # the step's finite flag
+                            inflight.append(flag if flag is not None
+                                            else outs[0])
+                            t_win = _telemetry.now_ms() \
+                                if jr is not None else 0.0
+                            with _trace.phase("step.window_wait"):
+                                while len(inflight) > ahead:
+                                    drain_one()
                             if jr is not None:
+                                # boundary-to-boundary iteration wall:
+                                # the sum over an epoch is the epoch's
+                                # wall, so the report's samples/sec
+                                # matches a Speedometer-style measurement
+                                now_ = _telemetry.now_ms()
                                 step_hist.observe(now_ - t_iter)
                                 _telemetry.journal_step(
                                     loop="trainstep", step=n_update - 1,
@@ -648,19 +653,7 @@ class TrainStep:
                                     samples=int(placed[
                                         self.data_names[0]].shape[0])
                                     if self.data_names else 0)
-                            if tr is not None:
-                                # wait children reconstructed from the
-                                # timestamps already taken — no extra
-                                # clock reads, no extra syncs
-                                _trace.add_span("step.data_wait",
-                                                t_data,
-                                                t_data + data_ms,
-                                                parent=ssp)
-                                _trace.add_span("step.window_wait",
-                                                t_win, now_,
-                                                parent=ssp)
-                            t_iter = now_
-                        _trace.end_span(ssp)
+                                t_iter = now_
                         if batch_end_callback:
                             batch_end_callback(_SimpleBatchEnd(
                                 epoch, nbatch, metric))
@@ -668,43 +661,50 @@ class TrainStep:
                     if spec is not None:
                         # drain the window so a bad tail is seen BEFORE
                         # this epoch's checkpoint is published
-                        while inflight:
-                            drain_one()
+                        with _trace.phase("train.epoch_drain"):
+                            while inflight:
+                                drain_one()
                 except _guardrail.RollbackNeeded:
-                    # the control-flow jump abandoned the open step
-                    # span — drop it so later spans can't mis-parent
-                    _trace.unwind()
                     state, epoch, n_update, skip_batches = \
                         self._rollback(checkpoint_prefix, guard, log)
                     state = self._ensure_scaler_state(state, spec)
                     inflight.clear()
                     continue
-                name, val = metric.get()     # the single blocking read
-                last_val = val
-                log.info("Epoch[%d] Train-%s=%f", epoch, name, val)
-                if jr is not None:
-                    # fingerprint-friendly jit-cache gauge: donated-
-                    # buffer sharding drift shows up as a second cached
-                    # executable (the step-2-recompile class of
-                    # regression tools/perf_gate.py gates on)
-                    step_fn = fused_step if fuse else (
-                        guarded_step if spec is not None
-                        else self._jit_step)
-                    cache_size = getattr(step_fn, "_cache_size", None)
-                    if cache_size is not None:
-                        _telemetry.gauge(
-                            "trainstep.jit_cache_size").set(cache_size())
-                    _telemetry.journal_event("epoch.end",
-                                             loop="trainstep",
-                                             epoch=epoch, steps=nbatch)
-                # HBM watermark: boundary-only sample, never per step
-                _profiler.sample_device_memory("epoch.end")
-                if checkpoint_prefix and \
-                        (epoch + 1) % checkpoint_period == 0:
-                    self._save_fit_checkpoint(checkpoint_prefix, epoch,
-                                              state, n_update)
-                if epoch_end_callback:
-                    epoch_end_callback(epoch, state)
+                # everything between an epoch's last step and the
+                # next epoch: the device drains under the metric read,
+                # so its idle time here has this name in a trace
+                with _trace.phase("train.epoch_end", epoch=epoch):
+                    name, val = metric.get()  # the single blocking read
+                    last_val = val
+                    log.info("Epoch[%d] Train-%s=%f", epoch, name, val)
+                    if jr is not None:
+                        # fingerprint-friendly jit-cache gauge:
+                        # donated-buffer sharding drift shows up as a
+                        # second cached executable (the step-2-recompile
+                        # class of regression tools/perf_gate.py gates
+                        # on)
+                        step_fn = fused_step if fuse else (
+                            guarded_step if spec is not None
+                            else self._jit_step)
+                        cache_size = getattr(step_fn, "_cache_size",
+                                             None)
+                        if cache_size is not None:
+                            _telemetry.gauge(
+                                "trainstep.jit_cache_size").set(
+                                    cache_size())
+                        _telemetry.journal_event("epoch.end",
+                                                 loop="trainstep",
+                                                 epoch=epoch,
+                                                 steps=nbatch)
+                    # HBM watermark: boundary-only sample, never per
+                    # step
+                    _profiler.sample_device_memory("epoch.end")
+                    if checkpoint_prefix and \
+                            (epoch + 1) % checkpoint_period == 0:
+                        self._save_fit_checkpoint(checkpoint_prefix,
+                                                  epoch, state, n_update)
+                    if epoch_end_callback:
+                        epoch_end_callback(epoch, state)
                 epoch += 1
         self.guard_report = guard.report()
         return state, last_val

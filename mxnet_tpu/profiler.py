@@ -27,6 +27,7 @@ import threading
 import time
 
 from . import telemetry as _telemetry
+from . import trace as _trace
 
 __all__ = ["profiler_set_config", "profiler_set_state", "dump_profile",
            "set_config", "set_state", "dump", "State", "record_event",
@@ -174,25 +175,23 @@ def sample_device_memory(site="boundary"):
 
 
 class scope:
-    """Context manager timing one region into the profile (and, when a
-    device trace is live, into the xplane timeline via TraceAnnotation)."""
+    """Context manager timing one region into the profile and, as the
+    host annotation ``mxnet.<name>`` (``trace.annotate``), into the
+    xplane timeline of whatever device trace is live — this module's
+    own or one started by anyone else."""
 
     def __init__(self, name, category="op"):
         self.name = name
         self.category = category
-        self._jax_ctx = None
 
     def __enter__(self):
         self._start = time.perf_counter_ns()
-        if _P._tracing:
-            import jax
-            self._jax_ctx = jax.profiler.TraceAnnotation(self.name)
-            self._jax_ctx.__enter__()
+        self._ann = _trace.annotate(self.name)
+        self._ann.__enter__()
         return self
 
     def __exit__(self, *exc):
-        if self._jax_ctx is not None:
-            self._jax_ctx.__exit__(*exc)
+        self._ann.__exit__(*exc)
         end = time.perf_counter_ns()
         record_event(self.name, self.category, self._start // 1000,
                      max((end - self._start) // 1000, 1))

@@ -378,8 +378,13 @@ class ContinuousDecoder:
                 "%r vs %r" % (sym_p.list_arguments(),
                               generator._sym.list_arguments()))
         eval_fn = _graph_eval_fn(sym_p, mesh=generator.mesh)
-        self._step_fn = jax.jit(
-            lambda args, aux, rng: eval_fn(args, aux, rng, False))
+
+        def decode_step(args, aux, rng):
+            # named, not a lambda: PjitFunction(decode_step) and the
+            # device's module name say which program ran
+            return eval_fn(args, aux, rng, False)
+
+        self._step_fn = jax.jit(decode_step)
         self._rng0 = jax.random.PRNGKey(0)
 
         self._aux = generator._fresh_aux()     # the pool caches
@@ -435,8 +440,11 @@ class ContinuousDecoder:
                     "twin: %r vs %r" % (d_sym.list_arguments(),
                                         draft._sym.list_arguments()))
             d_eval = _graph_eval_fn(d_sym, mesh=draft.mesh)
-            self._draft_step_fn = jax.jit(
-                lambda args, aux, rng: d_eval(args, aux, rng, False))
+
+            def draft_step(args, aux, rng):
+                return d_eval(args, aux, rng, False)
+
+            self._draft_step_fn = jax.jit(draft_step)
             self._daux = draft._fresh_aux()    # the draft's pool caches
             # verify rounds write up to γ speculative entries past a
             # row's live depth (on BOTH pools: the target's verify
@@ -476,6 +484,8 @@ class ContinuousDecoder:
         self._shed = 0
         self._steps = 0
         self._prefills = 0
+        self._admit_rounds = 0     # _admit calls that admitted
+        self._prefill_rows = 0     # rows of every prefill forward
         self._imported = 0
         self._resumed = 0
         self._evacuated = 0
@@ -485,10 +495,10 @@ class ContinuousDecoder:
         self._g_active = _telemetry.gauge("serve.decode.active_slots")
         # pool-measured twin of the Generator's static sizing gauge:
         # actual device-array bytes of the live cache pytree per slot.
-        # Re-published every step (the gauge is last-write-wins and
-        # any OTHER Generator construction — a speculative draft, a
-        # second model — overwrites it with ITS static figure; the
-        # live pool must win while it is serving)
+        # Re-published whenever a slot turns over (the gauge is
+        # last-write-wins and any OTHER Generator construction — a
+        # speculative draft, a second model — overwrites it with ITS
+        # static figure; the live pool must win while it is serving)
         self._kv_bytes_per_slot = sum(
             int(v.nbytes) for v in self._aux.values()) // self._B
         self._g_kv = _telemetry.gauge("serve.decode.kv_bytes_per_slot")
@@ -1098,26 +1108,25 @@ class ContinuousDecoder:
         does prefill its DRAFT cache locally) and emit the shipped
         first token. A bad blob fails THAT request's future and frees
         the slot; the loop and the other slots are untouched."""
-        t0 = _telemetry.now_ms()
-        try:
-            pos = self.import_kv_rows(slot, req.handoff["kv_blob"])
-            tok = int(req.handoff["first_token"])
-            if self._draft is not None and req.speculative:
-                self._draft_prefill_rows(slot, req.prompt)
-        except Exception as exc:          # noqa: BLE001 — the future
-            # is this sequence's one response; a scatter failure must
-            # not kill the decode loop for every other slot
-            req._fail(exc)
-            return
+        with _trace.phase("serve.decode.import", parent=req.tc,
+                          slot=slot) as ph:
+            try:
+                pos = self.import_kv_rows(slot, req.handoff["kv_blob"])
+                tok = int(req.handoff["first_token"])
+                if self._draft is not None and req.speculative:
+                    self._draft_prefill_rows(slot, req.prompt)
+            except Exception as exc:      # noqa: BLE001 — the future
+                # is this sequence's one response; a scatter failure
+                # must not kill the decode loop for every other slot
+                req._fail(exc)
+                return
+            ph.note(pos=pos)
         self._slots[slot] = req
         req.handoff = None     # the rows live on device now — holding
         #                        the host blob would double memory per
         #                        imported slot for the whole decode
         req.t_admit = _telemetry.now_ms()
         req.n_cached = pos
-        if _trace.enabled():
-            _trace.add_span("serve.decode.import", t0, req.t_admit,
-                            parent=req.tc, slot=slot, pos=pos)
         self._emit(req, tok)
         self._maybe_finish(slot, tok)
 
@@ -1128,32 +1137,30 @@ class ContinuousDecoder:
         token) and no prefill graph call. A bad blob fails THAT
         request's future; the loop and the other slots are
         untouched."""
-        t0 = _telemetry.now_ms()
-        try:
-            pos = self.import_kv_rows(slot, req.resume)
-            if self._draft is not None and req.speculative:
-                # the cache covers prompt + fed tokens (the pending
-                # last emission is not yet fed) — prefill the draft
-                # over exactly that prefix
-                self._draft_prefill_rows(
-                    slot, np.concatenate(
-                        [np.asarray(req.prompt, np.int64),
-                         np.asarray(req.emitted[:-1], np.int64)]))
-        except Exception as exc:          # noqa: BLE001 — the future
-            # is this sequence's one response; an import failure must
-            # not kill the decode loop for every other slot
-            req._fail(exc)
-            return
+        with _trace.phase("serve.decode.resume", parent=req.tc,
+                          slot=slot, emitted=len(req.emitted)) as ph:
+            try:
+                pos = self.import_kv_rows(slot, req.resume)
+                if self._draft is not None and req.speculative:
+                    # the cache covers prompt + fed tokens (the pending
+                    # last emission is not yet fed) — prefill the draft
+                    # over exactly that prefix
+                    self._draft_prefill_rows(
+                        slot, np.concatenate(
+                            [np.asarray(req.prompt, np.int64),
+                             np.asarray(req.emitted[:-1], np.int64)]))
+            except Exception as exc:      # noqa: BLE001 — the future
+                # is this sequence's one response; an import failure
+                # must not kill the decode loop for every other slot
+                req._fail(exc)
+                return
+            ph.note(pos=pos)
         self._slots[slot] = req
         req.resume = None      # the rows live on device now
         req.t_admit = _telemetry.now_ms()
         req.n_cached = pos
         self._resumed += 1
         self._c_resumed.inc()
-        if _trace.enabled():
-            _trace.add_span("serve.decode.resume", t0, req.t_admit,
-                            parent=req.tc, slot=slot, pos=pos,
-                            emitted=len(req.emitted))
 
     def _admit(self):
         """Move queued prompts into free slots. Remote-prefilled
@@ -1172,6 +1179,17 @@ class ContinuousDecoder:
                 return
             batch = [self._queue.popleft()
                      for _ in range(min(len(free), len(self._queue)))]
+        self._admit_rounds += 1
+        with _trace.phase("serve.decode.admit", n=len(batch),
+                          lengths=[len(r.prompt) for r in batch]):
+            self._admit_batch(batch, free)
+        self._publish_pool_gauges()
+
+    def _admit_batch(self, batch, free):
+        """The round's work under its ``serve.decode.admit`` phase;
+        each child phase is the boundary of one thing a later change
+        would replace (fresh pool, full-``B`` prefill, the blocking
+        logits read, the eager cache merge, first-token emission)."""
         chunk = prefill_chunk()
         by_len = {}
         waiting = []       # long prompts parked behind an active chunk
@@ -1194,8 +1212,7 @@ class ContinuousDecoder:
                     self._reserved.add(slot)
                     self._chunking = {"req": req, "slot": slot,
                                       "aux": self._gen._fresh_aux(),
-                                      "pos": 0,
-                                      "t0": _telemetry.now_ms()}
+                                      "pos": 0}
                     if self._draft is not None and req.speculative:
                         # the draft cache prefills alongside, chunk
                         # by chunk on the same widths
@@ -1211,16 +1228,23 @@ class ContinuousDecoder:
         for P, reqs in sorted(by_len.items()):
             rows = np.stack([r.prompt for r in reqs] +
                             [reqs[0].prompt] * (self._B - len(reqs)))
-            logits, pref_aux = self._gen._forward(
-                self._gen._fresh_aux(), rows.astype(np.float32), 0)
+            with _trace.phase("admit.fresh_aux"):
+                fresh = self._gen._fresh_aux()
+            with _trace.phase("admit.prefill", P=P, rows=len(reqs)):
+                logits, pref_aux = self._gen._forward(
+                    fresh, rows.astype(np.float32), 0)
+            del fresh
             self._prefills += 1
-            last = np.asarray(logits[:, -1].astype(jnp.float32))
-            idx = jnp.asarray(
-                np.array(free[:len(reqs)], np.int32))
-            self._aux = {
-                name: self._aux[name].at[idx].set(
-                    pref_aux[name][:len(reqs)])
-                for name in self._aux}
+            self._prefill_rows += self._B
+            with _trace.phase("admit.wait"):
+                last = np.asarray(logits[:, -1].astype(jnp.float32))
+            with _trace.phase("admit.merge"):
+                idx = jnp.asarray(
+                    np.array(free[:len(reqs)], np.int32))
+                self._aux = {
+                    name: self._aux[name].at[idx].set(
+                        pref_aux[name][:len(reqs)])
+                    for name in self._aux}
             if self._draft is not None and \
                     any(r.speculative for r in reqs):
                 # the draft's cache rows for this group, one shared-
@@ -1228,23 +1252,29 @@ class ContinuousDecoder:
                 # per-row propose program never sees prefill shapes) —
                 # scattered for the whole group: non-speculative rows'
                 # draft rows are unread garbage either way
-                _, d_pref = self._draft._forward(
-                    self._draft._fresh_aux(),
-                    rows.astype(np.float32), 0)
-                self._daux = {
-                    name: self._daux[name].at[idx].set(
-                        d_pref[name][:len(reqs)])
-                    for name in self._daux}
+                with _trace.phase("admit.fresh_aux", draft=1):
+                    fresh = self._draft._fresh_aux()
+                with _trace.phase("admit.prefill", P=P,
+                                  rows=len(reqs), draft=1):
+                    _, d_pref = self._draft._forward(
+                        fresh, rows.astype(np.float32), 0)
+                del fresh
+                with _trace.phase("admit.merge", draft=1):
+                    self._daux = {
+                        name: self._daux[name].at[idx].set(
+                            d_pref[name][:len(reqs)])
+                        for name in self._daux}
                 self._draft_prefills += 1
                 self._c_dprefills.inc()
-            for i, req in enumerate(reqs):
-                slot = free.pop(0)
-                self._slots[slot] = req
-                req.t_admit = _telemetry.now_ms()
-                req.n_cached = P
-                tok = req._pick(last[i])
-                self._emit(req, tok)
-                self._maybe_finish(slot, tok)
+            with _trace.phase("admit.emit"):
+                for i, req in enumerate(reqs):
+                    slot = free.pop(0)
+                    self._slots[slot] = req
+                    req.t_admit = _telemetry.now_ms()
+                    req.n_cached = P
+                    tok = req._pick(last[i])
+                    self._emit(req, tok)
+                    self._maybe_finish(slot, tok)
 
     def _emit(self, req, tok):
         """One token emission: latency metrics (TTFT on the first
@@ -1272,6 +1302,9 @@ class ContinuousDecoder:
         req = self._slots[slot]
         if (req.eos_id is not None and tok == req.eos_id) or \
                 len(req.emitted) >= req.max_new:
+            # before the waiter wakes: a caller that reads the gauges
+            # right after result() sees this turnover's values
+            self._publish_pool_gauges()
             req._finish_ok()
             now = _telemetry.now_ms()
             self._h_req.observe(now - req.t_enq)
@@ -1283,10 +1316,15 @@ class ContinuousDecoder:
                 ms=round(now - req.t_enq, 3))
             if _trace.enabled():
                 # sequence lifecycle spans, retroactive from the
-                # timestamps already taken: queue wait, then the slot
-                # occupancy from admission to the finishing emission
+                # timestamps already taken (they cross threads and
+                # steps, so they cannot be live phases): queue wait,
+                # then the slot occupancy from admission to the
+                # finishing emission. Parented to the submitter's
+                # span, else a root — never to the loop phase that
+                # happened to retire the sequence
                 ctx = _trace.add_span(
-                    "serve.decode.seq", req.t_enq, now, parent=req.tc,
+                    "serve.decode.seq", req.t_enq, now,
+                    parent=req.tc or _trace.ROOT,
                     tokens=len(req.emitted), prompt=len(req.prompt))
                 if req.t_admit is not None:
                     _trace.add_span("serve.decode.queue", req.t_enq,
@@ -1294,8 +1332,7 @@ class ContinuousDecoder:
                     _trace.add_span("serve.decode.slot", req.t_admit,
                                     now, parent=ctx, slot=slot,
                                     tokens=len(req.emitted))
-                # the decode thread holds no open span — flush the
-                # retired sequence's records as one write
+                # one write for the retired sequence's records
                 _trace.flush()
             self._slots[slot] = None
 
@@ -1308,33 +1345,50 @@ class ContinuousDecoder:
         active = [i for i, s in enumerate(self._slots) if s is not None]
         if not active:
             return
-        toks = np.zeros((self._B, 1), np.float32)
-        pos = np.zeros((self._B,), np.float32)
-        for i in active:
-            toks[i, 0] = float(self._slots[i].pending)
-            pos[i] = float(self._slots[i].n_cached)
-        args = dict(self._gen._params)
-        args["data"] = jnp.asarray(toks)
-        args["positions"] = jnp.asarray(pos[:, None])
-        args["cache_pos"] = jnp.asarray(pos)
-        outs, self._aux = self._step_fn(args, self._aux, self._rng0)
-        last = np.asarray(outs[0][:, -1].astype(jnp.float32))
-        self._steps += 1
-        self._c_steps.inc()
-        self._h_slotfill.observe(len(active))
-        self._g_active.set(len(active))
+        with _trace.phase("serve.decode.step", active=len(active)):
+            with _trace.phase("step.inputs"):
+                toks = np.zeros((self._B, 1), np.float32)
+                pos = np.zeros((self._B,), np.float32)
+                for i in active:
+                    toks[i, 0] = float(self._slots[i].pending)
+                    pos[i] = float(self._slots[i].n_cached)
+                args = dict(self._gen._params)
+                args["data"] = jnp.asarray(toks)
+                args["positions"] = jnp.asarray(pos[:, None])
+                args["cache_pos"] = jnp.asarray(pos)
+            with _trace.phase("step.dispatch"):
+                outs, self._aux = self._step_fn(args, self._aux,
+                                                self._rng0)
+            with _trace.phase("step.wait"):
+                last = np.asarray(outs[0][:, -1].astype(jnp.float32))
+            with _trace.phase("step.emit"):
+                self._steps += 1
+                self._c_steps.inc()
+                self._h_slotfill.observe(len(active))
+                self._g_active.set(len(active))
+                for i in active:
+                    req = self._slots[i]
+                    req.n_cached += 1
+                    tok = req._pick(last[i])
+                    self._emit(req, tok)
+                    self._maybe_finish(i, tok)
+
+    def _publish_pool_gauges(self):
+        """The gauges that can only change when a slot turns over,
+        published where one is admitted or freed (not per step): the
+        live pool's bytes per slot, and the compiled-program counts —
+        the target's stays 1 across slot turnover (2 with a draft:
+        the (B, 1) step plus the (B, γ+1) verify), the draft's stays
+        1; admissions must never recompile (gate-fingerprinted)."""
+        self._g_kv.set(self._kv_bytes_per_slot)   # live pool wins
         cache_size = getattr(self._step_fn, "_cache_size", None)
         if cache_size is not None:
-            # stays 1 across slot turnover — admissions must never
-            # recompile the (B, 1) step (gate-fingerprinted)
             self._g_jit.set(cache_size())
-        self._g_kv.set(self._kv_bytes_per_slot)   # live pool wins
-        for i in active:
-            req = self._slots[i]
-            req.n_cached += 1
-            tok = req._pick(last[i])
-            self._emit(req, tok)
-            self._maybe_finish(i, tok)
+        if self._draft is not None:
+            cache_size = getattr(self._draft_step_fn, "_cache_size",
+                                 None)
+            if cache_size is not None:
+                self._g_djit.set(cache_size())
 
     def _draft_forward(self, toks, pos):
         """One (B, 1) per-row-position DRAFT step: the propose half of
@@ -1348,11 +1402,6 @@ class ContinuousDecoder:
                                                self._rng0)
         self._draft_steps += 1
         self._c_dsteps.inc()
-        cache_size = getattr(self._draft_step_fn, "_cache_size", None)
-        if cache_size is not None:
-            # stays 1 across slot turnover and round count — the
-            # draft's half of the compiled-shape discipline
-            self._g_djit.set(cache_size())
         return np.asarray(outs[0][:, -1].astype(jnp.float32))
 
     def _spec_round(self):
@@ -1382,8 +1431,8 @@ class ContinuousDecoder:
         every admission pays γ headroom (``submit``'s _spec_cap
         check). Non-speculative rows ride the verify forward with
         junk tails and take only their column-0 pick — identical math
-        to :meth:`_step`."""
-        t0 = _telemetry.now_ms()
+        to :meth:`_step`. Returns the round's ``serve.spec.round``
+        phase attrs (rows, proposed, accepted)."""
         active = [i for i, s in enumerate(self._slots)
                   if s is not None]
         spec = [i for i in active if self._slots[i].speculative]
@@ -1445,12 +1494,6 @@ class ContinuousDecoder:
         self._c_srounds.inc()
         self._h_slotfill.observe(len(active))
         self._g_active.set(len(active))
-        cache_size = getattr(self._step_fn, "_cache_size", None)
-        if cache_size is not None:
-            # exactly TWO target programs — (B, 1) step + (B, γ+1)
-            # verify — across admissions and rounds (gate-pinned)
-            self._g_jit.set(cache_size())
-        self._g_kv.set(self._kv_bytes_per_slot)   # live pool wins
 
         # -- per-row acceptance walk -----------------------------------
         accepted = proposed = 0
@@ -1507,10 +1550,8 @@ class ContinuousDecoder:
                     cur[i, 0] = 0.0
                     dpos[i] = 0.0
             self._draft_forward(cur, dpos)
-        if _trace.enabled():
-            _trace.add_span("serve.spec.round", t0,
-                            _telemetry.now_ms(), rows=len(spec),
-                            proposed=proposed, accepted=accepted)
+        return {"rows": len(spec), "proposed": proposed,
+                "accepted": accepted}
 
     def _chunk_step(self):
         """Feed ONE chunk of the in-progress chunked prefill — called
@@ -1531,27 +1572,24 @@ class ContinuousDecoder:
         lo = ch["pos"]
         hi = min(lo + prefill_chunk(), P)
         rows = np.stack([req.prompt[lo:hi]] * self._B)
-        try:
-            logits, ch["aux"] = self._gen._forward(
-                ch["aux"], rows.astype(np.float32), lo)
-            if "daux" in ch:
-                _, ch["daux"] = self._draft._forward(
-                    ch["daux"], rows.astype(np.float32), lo)
-        except Exception as exc:          # noqa: BLE001 — the future
-            # is this sequence's one response; a failed chunk must not
-            # kill the decode loop for every other slot
-            self._chunking = None
-            self._reserved.discard(slot)
-            req._fail(exc)
-            return
+        with _trace.phase("serve.decode.prefill_chunk", parent=req.tc,
+                          slot=slot, lo=lo, hi=hi):
+            try:
+                logits, ch["aux"] = self._gen._forward(
+                    ch["aux"], rows.astype(np.float32), lo)
+                if "daux" in ch:
+                    _, ch["daux"] = self._draft._forward(
+                        ch["daux"], rows.astype(np.float32), lo)
+            except Exception as exc:      # noqa: BLE001 — the future
+                # is this sequence's one response; a failed chunk must
+                # not kill the decode loop for every other slot
+                self._chunking = None
+                self._reserved.discard(slot)
+                req._fail(exc)
+                return
+        self._prefill_rows += self._B
         ch["pos"] = hi
         self._c_chunks.inc()
-        if _trace.enabled():
-            _trace.add_span("serve.decode.prefill_chunk",
-                            ch.pop("t_chunk", ch["t0"]),
-                            _telemetry.now_ms(), parent=req.tc,
-                            slot=slot, lo=lo, hi=hi)
-            ch["t_chunk"] = _telemetry.now_ms()
         if hi < P:
             return
         # final chunk: merge the fully-prefilled row into the pool
@@ -1575,24 +1613,34 @@ class ContinuousDecoder:
         self._slots[slot] = req
         req.t_admit = _telemetry.now_ms()
         req.n_cached = P
+        self._publish_pool_gauges()
         tok = req._pick(last[0])
         self._emit(req, tok)
         self._maybe_finish(slot, tok)
 
+    def _nothing_to_do(self):
+        return not self._queue and \
+            not self._evac_waiters and \
+            not self._evac_flag and \
+            self._chunking is None and \
+            all(s is None for s in self._slots)
+
     def _loop(self):
+        # the hoisted handle: the one place this thread resolves
+        # MXNET_TRACE — every phase below reads the module flag only
+        _trace.tracer()
         while True:
             with self._cond:
-                while not self._queue and not self._draining and \
-                        not self._evac_waiters and \
-                        not self._evac_flag and \
-                        self._chunking is None and \
-                        all(s is None for s in self._slots):
-                    self._cond.wait(0.05)
-                if self._draining and not self._queue and \
-                        not self._evac_waiters and \
-                        not self._evac_flag and \
-                        self._chunking is None and \
-                        all(s is None for s in self._slots):
+                if self._nothing_to_do():
+                    # one phase per idle period, the drained exit
+                    # included — so every decoder that closes shows
+                    # the name at least once (a gate fingerprint must
+                    # not depend on who won the race to the queue)
+                    with _trace.phase("serve.decode.idle"):
+                        while self._nothing_to_do() and \
+                                not self._draining:
+                            self._cond.wait(0.05)
+                if self._draining and self._nothing_to_do():
                     break
             if self._evac_waiters or self._evac_flag:
                 self._do_evacuate()
@@ -1602,7 +1650,8 @@ class ContinuousDecoder:
             if self._draft is not None and any(
                     s is not None and s.speculative
                     for s in self._slots):
-                self._spec_round()
+                with _trace.phase("serve.spec.round") as ph:
+                    ph.note(**self._spec_round())
             else:
                 # draft-less pools and rounds with no speculative
                 # participant run the ordinary (B, 1) step — a
@@ -1688,6 +1737,7 @@ class ContinuousDecoder:
         if n:
             self._c_evacuated.inc(n)
         self._g_active.set(0)
+        self._publish_pool_gauges()
         _telemetry.journal_event(
             "serve.decode.evacuate", sessions=n, queued=len(queued),
             sigterm=bool(sig),
@@ -1735,6 +1785,8 @@ class ContinuousDecoder:
         return {"admitted": self._admitted, "finished": self._finished,
                 "shed": self._shed,
                 "steps": self._steps, "prefills": self._prefills,
+                "admit_rounds": self._admit_rounds,
+                "prefill_rows": self._prefill_rows,
                 "imported": self._imported, "resumed": self._resumed,
                 "evacuated": self._evacuated,
                 "deduped": self._deduped,

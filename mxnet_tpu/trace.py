@@ -18,6 +18,14 @@ Design constraints (all asserted in ``tests/test_trace.py``):
   explicit ``*.jsonl`` path) turns it on; disabled, every entry point
   is a no-op fast path (one config lookup at worst — the hot loops
   hoist even that by taking the :func:`tracer` handle once per fit).
+* **One primitive, two sinks.** :class:`phase` is the live form the
+  hot loops use: it always enters a ``jax.profiler.TraceAnnotation``
+  named ``"mxnet." + name`` (a TraceMe level check when no profiler
+  session is live), so a device trace started by ANYONE carries the
+  program's phases on the trace's own clock, and it records the JSONL
+  span under the bare name when ``MXNET_TRACE`` is on. :func:`annotate`
+  is the one place an annotation is made (``profiler.scope`` enters
+  its own through it).
 * **Zero added host syncs.** Everything here is host wall clock plus
   file appends — tracing on vs off leaves ``profiler.host_sync_count``
   identical.
@@ -48,9 +56,12 @@ import os
 import threading
 import time
 
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 from . import config as _config
 
 __all__ = ["TRACE_SCHEMA_VERSION", "TraceContext", "Span", "span",
+           "phase", "annotate", "XPLANE_PREFIX", "ROOT",
            "start_span", "end_span", "instant", "add_span",
            "current_context", "wire_context", "tracer", "enabled",
            "start_tracing", "stop_tracing", "flush", "unwind",
@@ -59,6 +70,10 @@ __all__ = ["TRACE_SCHEMA_VERSION", "TraceContext", "Span", "span",
 # bump when a spill record's required keys change; the reader
 # (tools/trace_report.py) refuses schemas it doesn't know
 TRACE_SCHEMA_VERSION = 1
+
+# xplane name = XPLANE_PREFIX + spill name (docs/observability.md): the
+# prefix is what a trace reader keys the program's own host spans on
+XPLANE_PREFIX = "mxnet."
 
 # per-thread buffered records before a forced flush (a flush also
 # happens whenever the thread's span stack empties)
@@ -110,6 +125,12 @@ class TraceContext:
 
     def __repr__(self):
         return "TraceContext(%r, %r)" % (self.trace_id, self.span_id)
+
+
+# ``parent=ROOT``: root a NEW trace even while this thread has a span
+# open — a retroactive lifecycle span that crosses steps (one served
+# sequence) must not parent to whichever loop phase happened to emit it
+ROOT = TraceContext(None, None)
 
 
 class Span:
@@ -341,12 +362,14 @@ def start_span(name, parent=None, **attrs):
     cross-thread requester in the serve engine. Default: the thread's
     current innermost span; with neither, the span roots a NEW trace
     (fresh trace_id)."""
-    if not enabled():
-        return None
+    return _open_span(name, parent, attrs) if enabled() else None
+
+
+def _open_span(name, parent, attrs):
     t = _tls()
     if parent is None and t.stack:
         parent = t.stack[-1]
-    if parent is not None:
+    if parent is not None and parent is not ROOT:
         trace_id, parent_id = parent.trace_id, parent.span_id
     else:
         trace_id, parent_id = _next_id(), None
@@ -367,6 +390,11 @@ def end_span(sp, **attrs):
         t.stack.remove(sp)      # normally the top; tolerate mis-nesting
     except ValueError:
         pass
+    if not _ENABLED:
+        # tracing stopped under an open span (a long-lived loop thread
+        # outliving stop_tracing): its record has no spill to go to and
+        # must not leak into the next one
+        return
     if attrs:
         sp.attrs = {**(sp.attrs or {}), **attrs}
     rec = _base_record("span", sp.name, sp.trace_id, sp.parent_id,
@@ -394,6 +422,52 @@ class span:
 
     def __exit__(self, *exc):
         end_span(self._sp)
+        return False
+
+
+def annotate(name, **attrs):
+    """The ONE place the program makes a host annotation for the
+    profiler's trace: a ``jax.profiler.TraceAnnotation`` named
+    ``XPLANE_PREFIX + name`` (not yet entered). With no profiler
+    session live, entering it is a TraceMe level check; ``attrs`` are
+    only encoded when one is."""
+    return _TraceAnnotation(XPLANE_PREFIX + name, **attrs)
+
+
+class phase:
+    """``with trace.phase("serve.decode.step"):`` — a LIVE phase of a
+    hot loop, written to both sinks under one name: always a host
+    annotation ``mxnet.<name>`` for whatever profiler trace is
+    running (see :func:`annotate`), and the JSONL span ``<name>``,
+    exactly as :class:`span` records it, when ``MXNET_TRACE`` is on.
+
+    Reads only the module flag — no config lookup per call. The lazy
+    ``MXNET_TRACE`` start is the hoisted :func:`tracer` call each loop
+    makes once (per fit, per decode thread). ``parent`` as in
+    :func:`start_span`; :meth:`note` adds attrs learned inside."""
+
+    __slots__ = ("_name", "_parent", "_attrs", "_ann", "_sp")
+
+    def __init__(self, name, parent=None, **attrs):
+        self._name = name
+        self._parent = parent
+        self._attrs = attrs
+
+    def __enter__(self):
+        self._ann = annotate(self._name, **self._attrs)
+        self._ann.__enter__()
+        self._sp = _open_span(self._name, self._parent, self._attrs) \
+            if _ENABLED else None
+        return self
+
+    def note(self, **attrs):
+        self._ann.set_metadata(**attrs)
+        if self._sp is not None:
+            self._sp.attrs = {**(self._sp.attrs or {}), **attrs}
+
+    def __exit__(self, *exc):
+        end_span(self._sp)
+        self._ann.__exit__(*exc)
         return False
 
 
@@ -426,7 +500,7 @@ def add_span(name, t0_ms, t1_ms, parent=None, **attrs):
     t = _tls()
     if parent is None and t.stack:
         parent = t.stack[-1]
-    if parent is not None:
+    if parent is not None and parent is not ROOT:
         trace_id, parent_id = parent.trace_id, parent.span_id
     else:
         trace_id, parent_id = _next_id(), None

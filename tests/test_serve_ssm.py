@@ -195,16 +195,19 @@ class TestHandoff:
         import time
         single = _gen(params, 1)
         p = np.arange(1, 6)
-        want = single.generate(p[None], 8, temperature=0.8, top_k=8,
+        # 16 tokens, not 8: the toy step takes a millisecond or two,
+        # so a short row could finish between the poll that sees three
+        # tokens and the evacuation (evacuate() == 0, one run in six)
+        want = single.generate(p[None], 16, temperature=0.8, top_k=8,
                                seed=7)[0]
         d1 = _gen(params, 2).serving_decoder()
         d2 = _gen(params, 2).serving_decoder()
         try:
-            fut = d1.submit(p, 8, temperature=0.8, top_k=8, seed=7)
+            fut = d1.submit(p, 16, temperature=0.8, top_k=8, seed=7)
             deadline = time.time() + 60.0
             while len(fut.emitted) < 3:
                 assert time.time() < deadline, "3 emitted tokens"
-                time.sleep(0.01)
+                time.sleep(0.001)
             assert d1.evacuate() == 1
             with pytest.raises(SessionEvacuated) as ei:
                 fut.result(10.0)
@@ -215,7 +218,7 @@ class TestHandoff:
             for name, arr in state["kv_blob"]["rows"].items():
                 assert arr.shape == (H, hd, hd)
                 assert arr.dtype == np.float32
-            got = d2.submit(p, 8, temperature=0.8, top_k=8, seed=7,
+            got = d2.submit(p, 16, temperature=0.8, top_k=8, seed=7,
                             resume=state).result(120.0)
             np.testing.assert_array_equal(got, want)
             assert d2.stats()["resumed"] == 1

@@ -417,11 +417,14 @@ def test_module_fit_spans(trace_dir):
     steps = _spans(path, "train.step")
     assert len(steps) == 3
     assert all(s["attrs"]["loop"] == "module" for s in steps)
-    # prepare()'s staging rides the step span too
+    # prepare()'s staging rides the step too: under the live
+    # step.data_wait phase, itself a child of the step span
     stages = _spans(path, "module.stage")
     assert stages
     step_ids = {s["span"] for s in steps}
-    assert any(s["parent"] in step_ids for s in stages)
+    waits = {s["span"] for s in _spans(path, "step.data_wait")
+             if s["parent"] in step_ids}
+    assert any(s["parent"] in waits for s in stages)
 
 
 def test_trace_adds_zero_host_syncs(trace_dir):
@@ -462,13 +465,13 @@ def test_guardrail_masked_step_instant(trace_dir, no_injector):
     assert marks
     assert marks[0]["attrs"]["total"] >= 1
     # a mark whose flag drained inside a step's window wait parents to
-    # that step's trace; one drained at the epoch-end flush is a root
-    # annotation (trace None) — both are valid placements
+    # that step's trace; one drained at the epoch-end flush to that
+    # flush's own phase — both are valid placements
     step_traces = {s["trace"] for s in recs
                    if s.get("kind") == "span"
-                   and s["name"] == "train.step"}
+                   and s["name"] in ("train.step", "train.epoch_drain")}
     for m in marks:
-        assert m["trace"] is None or m["trace"] in step_traces
+        assert m["trace"] in step_traces
 
 
 # ---------------------------------------------------------------------------
