@@ -368,17 +368,34 @@ class Generator:
                                          self.max_len))
         return prompt, P
 
-    def _fresh_aux(self):
-        aux = {}
-        for name in self._sym.list_auxiliary_states():
-            shape, dtype = self._aux_spec(name)
-            z = jnp.zeros(shape, dtype)
-            shard = self._scale_sharding if len(shape) == 3 \
+    def _aux_shardings(self):
+        """Placement of every decode-state aux under a mesh (scale
+        caches lack the head_dim axis), None without one — what
+        _fresh_aux allocates with and what a program that donates a
+        pool must hand back (serve/decode.py's cache merge)."""
+        if self._cache_sharding is None:
+            return None
+        return {name: self._scale_sharding
+                if len(self._aux_spec(name)[0]) == 3
                 else self._cache_sharding
-            if shard is not None:
-                z = jax.device_put(z, shard)
-            aux[name] = z
-        return aux
+                for name in self._sym.list_auxiliary_states()}
+
+    def _fresh_aux(self):
+        """A zeroed decode-state pytree: ONE compiled program for the
+        whole pytree (a dispatch per call, not an eager zeros +
+        device_put per cache array — 2 x num_layers of them)."""
+        fn = self._loop_cache.get("fresh_aux")
+        if fn is None:
+            specs = {name: self._aux_spec(name)
+                     for name in self._sym.list_auxiliary_states()}
+
+            def fresh_aux():
+                return {name: jnp.zeros(shape, dtype)
+                        for name, (shape, dtype) in specs.items()}
+
+            fn = jax.jit(fresh_aux, out_shardings=self._aux_shardings())
+            self._loop_cache["fresh_aux"] = fn
+        return fn()
 
     def _forward(self, aux, tokens, pos):
         """tokens: (B, Tnew) int array; pos: python int."""

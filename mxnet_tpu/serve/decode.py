@@ -321,6 +321,31 @@ class DecodeFuture:
         return self._value
 
 
+def _merge_program(generator):
+    """The compiled cache merge for ``generator``'s decode-state
+    pytree: rows ``src[name][i]`` land at batch positions ``slots[i]``
+    for ``i < n``, for every name at once. ONE program whatever ``n``
+    is (the row count is a traced scalar: a loop of ``n`` whole-row
+    ``dynamic_update_slice`` per cache array) and whatever the pytree
+    holds — bf16 k/v rows, int8 rows with their 3-D f32 scale caches,
+    SSM ``*_state`` blobs; it reads nothing but shapes. The pool is
+    donated, so the rows are written in place instead of into a copy
+    of every cache array; under a mesh the result keeps the
+    generator's cache placement, which donation needs."""
+    def cache_merge(pool, src, slots, n):
+        # named, not a lambda: PjitFunction(cache_merge) and the
+        # device's module name say which program ran
+        def one_row(i, pool):
+            return {name: jax.lax.dynamic_update_slice_in_dim(
+                pool[name],
+                jax.lax.dynamic_slice_in_dim(src[name], i, 1, 0),
+                slots[i], 0) for name in pool}
+        return jax.lax.fori_loop(0, n, one_row, pool)
+
+    return jax.jit(cache_merge, donate_argnums=0,
+                   out_shardings=generator._aux_shardings())
+
+
 class ContinuousDecoder:
     """Fixed-slot continuous batching over a Generator's decode state
     (KV caches for attention blocks, O(1) recurrent blobs for ssm
@@ -389,6 +414,8 @@ class ContinuousDecoder:
 
         self._aux = generator._fresh_aux()     # the pool caches
         self._import_jit = {}                  # pos -> fused scatter
+        self._merge_fn = _merge_program(generator)
+        self._dmerge_fn = None                 # the draft pool's twin
 
         # -- speculative decoding (docs/serving.md §speculative) --
         # draft=None consults MXNET_SPEC_DRAFT so subprocess replicas
@@ -446,6 +473,7 @@ class ContinuousDecoder:
 
             self._draft_step_fn = jax.jit(draft_step)
             self._daux = draft._fresh_aux()    # the draft's pool caches
+            self._dmerge_fn = _merge_program(draft)
             # verify rounds write up to γ speculative entries past a
             # row's live depth (on BOTH pools: the target's verify
             # chunk and the draft's propose steps), so every admission
@@ -486,6 +514,7 @@ class ContinuousDecoder:
         self._prefills = 0
         self._admit_rounds = 0     # _admit calls that admitted
         self._prefill_rows = 0     # rows of every prefill forward
+        self._merges = 0           # compiled cache-merge dispatches
         self._imported = 0
         self._resumed = 0
         self._evacuated = 0
@@ -1075,6 +1104,22 @@ class ContinuousDecoder:
         return [i for i, s in enumerate(self._slots)
                 if s is None and i not in self._reserved]
 
+    def _merge_rows(self, pool, src, slots, draft=False):
+        """Install the prefilled rows ``src[name][:len(slots)]`` at
+        batch positions ``slots`` of ``pool`` (the draft's, with
+        ``draft``), for every cache array in ONE compiled dispatch
+        (:func:`_merge_program`), and return the new pool. Whole rows
+        land, all ``max_len`` positions, so one program serves every
+        prompt length. ``pool`` is DONATED: the caller rebinds
+        ``self._aux`` / ``self._daux`` to the result and nothing else
+        may hold the old pytree — the loop thread is the one aux
+        mutator, as for :meth:`import_kv_rows`."""
+        padded = np.zeros((self._B,), np.int32)
+        padded[:len(slots)] = slots
+        self._merges += 1
+        fn = self._dmerge_fn if draft else self._merge_fn
+        return fn(pool, src, padded, np.int32(len(slots)))
+
     def _draft_prefill_rows(self, slot, tokens):
         """Prefill the DRAFT cache for one admitted row from raw token
         ids — the local draft leg of handoff/resume admission (the
@@ -1094,10 +1139,8 @@ class ContinuousDecoder:
             _, aux = self._draft._forward(
                 aux, rows.astype(np.float32), lo)
             lo = hi
-        idx = jnp.asarray(np.array([slot], np.int32))
-        self._daux = {
-            name: self._daux[name].at[idx].set(aux[name][:1])
-            for name in self._daux}
+        self._daux = self._merge_rows(self._daux, aux, [slot],
+                                      draft=True)
         self._draft_prefills += 1
         self._c_dprefills.inc()
 
@@ -1169,10 +1212,11 @@ class ContinuousDecoder:
         one shared-position prefill per distinct prompt length per
         round (all admitted rows start at position 0, so the
         Generator's ordinary prefill graph serves); cache rows merge
-        into the pool by a batch-axis scatter that walks the WHOLE aux
-        pytree — under quantize_kv that carries the per-token f32
-        scale caches alongside the int8 k/v rows (a merged row without
-        its scales would dequant to garbage)."""
+        into the pool by ONE compiled, donated program over the WHOLE
+        aux pytree (:meth:`_merge_rows`) — under quantize_kv that
+        carries the per-token f32 scale caches alongside the int8 k/v
+        rows (a merged row without its scales would dequant to
+        garbage)."""
         with self._lock:
             free = self._free_slots()
             if not free or not self._queue:
@@ -1189,7 +1233,7 @@ class ContinuousDecoder:
         """The round's work under its ``serve.decode.admit`` phase;
         each child phase is the boundary of one thing a later change
         would replace (fresh pool, full-``B`` prefill, the blocking
-        logits read, the eager cache merge, first-token emission)."""
+        logits read, the cache merge, first-token emission)."""
         chunk = prefill_chunk()
         by_len = {}
         waiting = []       # long prompts parked behind an active chunk
@@ -1238,13 +1282,10 @@ class ContinuousDecoder:
             self._prefill_rows += self._B
             with _trace.phase("admit.wait"):
                 last = np.asarray(logits[:, -1].astype(jnp.float32))
-            with _trace.phase("admit.merge"):
-                idx = jnp.asarray(
-                    np.array(free[:len(reqs)], np.int32))
-                self._aux = {
-                    name: self._aux[name].at[idx].set(
-                        pref_aux[name][:len(reqs)])
-                    for name in self._aux}
+            slots = free[:len(reqs)]
+            with _trace.phase("admit.merge", rows=len(reqs)):
+                self._aux = self._merge_rows(self._aux, pref_aux,
+                                             slots)
             if self._draft is not None and \
                     any(r.speculative for r in reqs):
                 # the draft's cache rows for this group, one shared-
@@ -1259,11 +1300,10 @@ class ContinuousDecoder:
                     _, d_pref = self._draft._forward(
                         fresh, rows.astype(np.float32), 0)
                 del fresh
-                with _trace.phase("admit.merge", draft=1):
-                    self._daux = {
-                        name: self._daux[name].at[idx].set(
-                            d_pref[name][:len(reqs)])
-                        for name in self._daux}
+                with _trace.phase("admit.merge", rows=len(reqs),
+                                  draft=1):
+                    self._daux = self._merge_rows(
+                        self._daux, d_pref, slots, draft=True)
                 self._draft_prefills += 1
                 self._c_dprefills.inc()
             with _trace.phase("admit.emit"):
@@ -1593,17 +1633,12 @@ class ContinuousDecoder:
         if hi < P:
             return
         # final chunk: merge the fully-prefilled row into the pool
-        # (same batch-axis scatter as the monolithic path) and emit
-        # the first token
-        idx = jnp.asarray(np.array([slot], np.int32))
-        self._aux = {
-            name: self._aux[name].at[idx].set(ch["aux"][name][:1])
-            for name in self._aux}
+        # (the monolithic path's compiled merge) and emit the first
+        # token
+        self._aux = self._merge_rows(self._aux, ch["aux"], [slot])
         if "daux" in ch:
-            self._daux = {
-                name: self._daux[name].at[idx].set(
-                    ch["daux"][name][:1])
-                for name in self._daux}
+            self._daux = self._merge_rows(self._daux, ch["daux"],
+                                          [slot], draft=True)
             self._draft_prefills += 1
             self._c_dprefills.inc()
         self._prefills += 1
@@ -1787,6 +1822,11 @@ class ContinuousDecoder:
                 "steps": self._steps, "prefills": self._prefills,
                 "admit_rounds": self._admit_rounds,
                 "prefill_rows": self._prefill_rows,
+                "merges": self._merges,
+                "merge_programs": sum(
+                    fn._cache_size() for fn in
+                    (self._merge_fn, self._dmerge_fn)
+                    if fn is not None),
                 "imported": self._imported, "resumed": self._resumed,
                 "evacuated": self._evacuated,
                 "deduped": self._deduped,

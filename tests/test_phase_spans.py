@@ -254,6 +254,18 @@ def test_admit_phase_attrs(both_sinks):
     assert {s["attrs"]["P"] for s in pre} == {4, 5, 6, 7}
 
 
+def test_merge_phase_carries_rows(both_sinks):
+    """`admit.merge` says how many prefilled rows its one compiled
+    dispatch installed: the same count as its group's `admit.prefill`."""
+    spans = [r for r in both_sinks["decode"].records
+             if r.get("kind") == "span"]
+    merges = [s for s in spans if s["name"] == "admit.merge"]
+    pre = [s for s in spans if s["name"] == "admit.prefill"]
+    assert [s["attrs"]["rows"] for s in merges] == \
+        [s["attrs"]["rows"] for s in pre]
+    assert sum(s["attrs"]["rows"] for s in merges) == 5
+
+
 # ---------------------------------------------------------------------------
 # the sinks change nothing
 # ---------------------------------------------------------------------------
@@ -318,6 +330,9 @@ def test_stats_count_admit_rounds_and_prefill_rows(lm_params):
     # every prefill forward runs all B rows, whatever it admits
     assert st["prefill_rows"] == B * st["prefills"]
     assert st["admitted"] <= st["prefill_rows"]
+    # one compiled merge dispatch per prefilled group, one program
+    assert st["merges"] == st["prefills"]
+    assert st["merge_programs"] == 1
 
 
 def test_compiled_serve_programs_have_names(lm_params):
@@ -326,6 +341,7 @@ def test_compiled_serve_programs_have_names(lm_params):
     assert pool._step_fn.__name__ == "generator_step"
     with pool.serving_decoder() as dec:
         assert dec._step_fn.__name__ == "decode_step"
+        assert dec._merge_fn.__name__ == "cache_merge"
     draft = pool.truncated_draft(num_layers=1)
     with pool.serving_decoder(draft=draft) as dec:
         assert dec._draft_step_fn.__name__ == "draft_step"

@@ -26,11 +26,13 @@ pytestmark = pytest.mark.serve
 V, L, H, DIM, T, B = 50, 2, 2, 32, 24, 3
 
 
-def _params(pos_encoding="learned", seed=0, num_kv_heads=None):
+def _params(pos_encoding="learned", seed=0, num_kv_heads=None,
+            block_type="attention"):
     sym = transformer.get_symbol(V, 12, num_layers=L, num_heads=H,
                                  dim=DIM, max_len=T,
                                  pos_encoding=pos_encoding,
-                                 num_kv_heads=num_kv_heads)
+                                 num_kv_heads=num_kv_heads,
+                                 block_type=block_type)
     step = make_train_step(sym, optimizer="sgd")
     mx.random.seed(seed)
     state = step.init_state(Xavier(), {"data": (2, 12),
@@ -48,31 +50,100 @@ def _gen(params, batch_size, **kw):
                      batch_size=batch_size, **kw)
 
 
+def _hold_admission(dec):
+    """Let a test decide how many queued prompts one admit round
+    pops: the decode loop skips admission until the queue holds
+    ``hold[0]`` requests (one shot: the round that fires resets it to
+    1). The loop is never blocked, only its ``_admit`` call skipped."""
+    hold = [1]
+    admit = dec._admit
+
+    def gated():
+        if len(dec._queue) >= hold[0]:
+            hold[0] = 1
+            admit()
+
+    dec._admit = gated
+    return hold
+
+
+def _submit_together(dec, hold, reqs, **kw):
+    """Submit ``reqs`` [(prompt, max_new)] so that ONE admit round
+    pops them all: wait until everything submitted before sits in a
+    slot or has finished and that many slots are free, then hold
+    admission until every one is queued."""
+    deadline = time.time() + 120.0
+    while True:
+        st = dec.stats()
+        if st["active"] + st["finished"] == st["admitted"] and \
+                dec._B - st["active"] >= len(reqs):
+            break
+        assert time.time() < deadline
+        time.sleep(0.002)
+    hold[0] = len(reqs)
+    return [dec.submit(p, n, **kw) for p, n in reqs]
+
+
 class TestParity:
-    def test_greedy_matches_static_generate_ragged(self, params):
-        """ACCEPTANCE: 7 ragged requests through a 3-slot pool ==
-        static per-sequence generate, token for token (eos and budget
-        endings both exercised)."""
-        pool = _gen(params, B)
+    @pytest.mark.parametrize("group,chunk", [
+        (None, None), (1, None), (3, None), (4, None),
+        (None, 3), (3, 3)],
+        ids=["free", "by1", "by3", "byB", "free-chunked",
+             "by3-chunked"])
+    def test_greedy_matches_static_generate_ragged(
+            self, params, monkeypatch, group, chunk):
+        """ACCEPTANCE: ragged requests through a slot pool == static
+        per-sequence generate, token for token (eos and budget endings
+        both exercised) — free-running through 3 slots, and through 4
+        slots with same-length prompts admitted 1, 3 and B to a round
+        (the compiled cache merge at every row count, into whichever
+        slots the ragged endings freed); likewise with prompts over
+        ``MXNET_PREFILL_CHUNK`` prefilled chunk by chunk."""
+        if chunk:
+            monkeypatch.setenv("MXNET_PREFILL_CHUNK", str(chunk))
         single = _gen(params, 1)
         rng = np.random.RandomState(3)
-        prompts = [rng.randint(0, V, (p,)) for p in
-                   (4, 6, 4, 5, 4, 6, 7)]
-        maxnew = [8, 3, 12, 5, 2, 9, 4]
+        if group is None:
+            pool = _gen(params, B)
+            prompts = [rng.randint(0, V, (p,)) for p in
+                       (4, 6, 4, 5, 4, 6, 7)]
+            maxnew = [8, 3, 12, 5, 2, 9, 4]
+        else:
+            pool = _gen(params, 4)
+            # one prompt length to a wave, ragged budgets inside it
+            lengths = [p for p in (4, 6, 3, 5, 7) for _ in range(group)]
+            prompts = [rng.randint(0, V, (p,)) for p in lengths]
+            maxnew = [int(n) for n in rng.randint(2, 13, len(prompts))]
         with pool.serving_decoder() as dec:
-            futs = [dec.submit(p, n, eos_id=0)
-                    for p, n in zip(prompts, maxnew)]
+            if group is None:
+                futs = [dec.submit(p, n, eos_id=0)
+                        for p, n in zip(prompts, maxnew)]
+            else:
+                hold = _hold_admission(dec)
+                futs = []
+                for lo in range(0, len(prompts), group):
+                    futs += _submit_together(
+                        dec, hold, list(zip(prompts, maxnew))
+                        [lo:lo + group], eos_id=0)
             got = [f.result(120.0) for f in futs]
             st = dec.stats()
         for i, (p, n) in enumerate(zip(prompts, maxnew)):
             want = single.generate(p[None], n, eos_id=0)[0]
             np.testing.assert_array_equal(got[i], want)
         # slot reuse happened: more sequences than slots were admitted
-        assert st["finished"] == len(prompts) > B
-        # the throughput property: static batching pays
-        # ceil(N/B) * max(maxnew) decode steps; continuous must beat it
-        static_steps = -(-len(prompts) // B) * max(maxnew)
-        assert st["steps"] < static_steps
+        assert st["finished"] == len(prompts) > pool.batch_size
+        # every prefill, whole or chunked, ends in ONE compiled merge
+        assert st["merges"] == st["prefills"]
+        assert st["merge_programs"] == 1
+        if group is None:
+            # the throughput property: static batching pays
+            # ceil(N/B) * max(maxnew) decode steps; continuous must
+            # beat it
+            static_steps = -(-len(prompts) // B) * max(maxnew)
+            assert st["steps"] < static_steps
+        elif not chunk:
+            # each wave was one group: one prefill, one merge
+            assert st["prefills"] == len(prompts) // group
 
     def test_sampled_matches_batch1_generate(self, params):
         """A sampled request reproduces a batch_size=1 generate with
@@ -575,3 +646,171 @@ class TestSpeculative:
         assert spec_draft() is None
         monkeypatch.setenv("MXNET_SPEC_DRAFT", "layers=2,gamma=5")
         assert spec_draft() == (2, 5)
+
+
+# ---------------------------------------------------------------------------
+# the compiled, donated cache merge and the compiled fresh pool (ISSUE 25)
+# ---------------------------------------------------------------------------
+
+MB = 4                                   # pool width of the merge cases
+KINDS = ["kv", "q8", "ssm", "draft", "sharded"]
+
+
+def _mesh_2x2():
+    import jax
+    from jax.sharding import Mesh
+    return Mesh(np.array(jax.devices()[:4]).reshape(2, 2),
+                ("data", "model"))
+
+
+@pytest.fixture(scope="module")
+def merge_decs(params):
+    """One idle decoder per kind of decode-state pytree: bf16/f32 k/v
+    rows, int8 rows with their f32 scale caches, SSM state blobs, a
+    target with a one-layer draft pool, and k/v rows sharded batch
+    over 'data' and heads over 'model'."""
+    gens = {
+        "kv": _gen(params, MB),
+        "q8": _gen(params, MB, quantize_kv=True),
+        "ssm": _gen(_params(block_type="ssm"), MB, block_type="ssm"),
+        "draft": _gen(params, MB),
+        "sharded": _gen(params, MB, mesh=_mesh_2x2()),
+    }
+    decs = {k: (_spec_dec(g) if k == "draft" else g.serving_decoder())
+            for k, g in gens.items()}
+    yield decs
+    for dec in decs.values():
+        dec.close()
+
+
+def _random_like(aux, seed):
+    """A pytree shaped, typed and placed like ``aux`` with every
+    entry distinct: a merge that moved a wrong row would show."""
+    import jax
+    rng = np.random.RandomState(seed)
+    out = {}
+    for name in sorted(aux):
+        a = aux[name]
+        if a.dtype == np.int8:
+            v = rng.randint(-127, 128, a.shape).astype(np.int8)
+        else:
+            v = rng.standard_normal(a.shape).astype(a.dtype)
+        out[name] = jax.device_put(v, a.sharding)
+    return out
+
+
+def _eager_zeros(gen):
+    """`_fresh_aux` as it was: one eager zeros (+ device_put under a
+    mesh) per aux entry — the reference the compiled one must equal."""
+    import jax
+    import jax.numpy as jnp
+    out = {}
+    for name in gen._sym.list_auxiliary_states():
+        shape, dtype = gen._aux_spec(name)
+        z = jnp.zeros(shape, dtype)
+        shard = gen._scale_sharding if len(shape) == 3 \
+            else gen._cache_sharding
+        out[name] = z if shard is None else jax.device_put(z, shard)
+    return out
+
+
+class TestCacheMerge:
+    @pytest.mark.parametrize("n", range(1, MB + 1))
+    @pytest.mark.parametrize("kind", KINDS)
+    def test_merge_rows_bit_equal_eager_scatter(self, merge_decs,
+                                                kind, n):
+        """ACCEPTANCE: for every row count and scattered, unordered
+        slots, the pool `_merge_rows` returns is bit-equal to the
+        eager per-array ``.at[idx].set`` it replaced; rows outside
+        ``slots`` are untouched; a sharded pool keeps its placement
+        (the donation's condition)."""
+        import jax.numpy as jnp
+        dec = merge_decs[kind]
+        draft = kind == "draft"
+        like = dec._daux if draft else dec._aux
+        if kind == "q8":
+            assert any(a.ndim == 3 for a in like.values())
+        if kind == "ssm":
+            assert all(k.endswith("_state") for k in like)
+        pool = _random_like(like, seed=n)
+        src = _random_like(like, seed=100 + n)
+        slots = [2, 0, 3, 1][:n]
+        idx = jnp.asarray(np.array(slots, np.int32))
+        before = {k: np.asarray(v) for k, v in pool.items()}
+        want = {k: np.asarray(pool[k].at[idx].set(src[k][:n]))
+                for k in pool}
+        placed = {k: v.sharding for k, v in pool.items()}
+        got = dec._merge_rows(pool, src, slots, draft=draft)
+        assert set(got) == set(want)
+        for k in want:
+            assert got[k].dtype == want[k].dtype
+            np.testing.assert_array_equal(np.asarray(got[k]), want[k])
+            for row in set(range(MB)) - set(slots):
+                np.testing.assert_array_equal(
+                    np.asarray(got[k])[row], before[k][row])
+            assert got[k].sharding.is_equivalent_to(placed[k],
+                                                    got[k].ndim)
+
+    @pytest.mark.parametrize("kind", KINDS)
+    def test_fresh_aux_equals_per_array_zeros(self, merge_decs, kind):
+        """The one-program `_fresh_aux` hands back what the eager walk
+        did: same names, values, dtypes and shardings."""
+        dec = merge_decs[kind]
+        gen = dec._draft if kind == "draft" else dec._gen
+        got, want = gen._fresh_aux(), _eager_zeros(gen)
+        assert set(got) == set(want)
+        for k in want:
+            assert got[k].dtype == want[k].dtype
+            assert got[k].shape == want[k].shape
+            assert not np.asarray(got[k]).any()
+            assert got[k].sharding.is_equivalent_to(
+                want[k].sharding, got[k].ndim)
+        # one compiled program, reused: a second pool is new buffers
+        again = gen._fresh_aux()
+        assert gen._loop_cache["fresh_aux"]._cache_size() == 1
+        assert all(again[k] is not got[k] for k in got)
+
+    @pytest.mark.parametrize("spec", [False, True],
+                             ids=["target", "with-draft"])
+    def test_every_group_size_twice_compiles_once(self, params, spec):
+        """ACCEPTANCE: admitting every n in 1..B a second time
+        compiles nothing — `merge_programs` constant and no
+        backend-compile event — and `merges` counts one per admitted
+        group and per pool."""
+        import jax.monitoring
+        pool = _gen(params, MB)
+        rng = np.random.RandomState(71)
+        compiles = []
+
+        def on_event(name, _secs, **_kw):
+            if name == "/jax/core/compile/backend_compile_duration":
+                compiles.append(name)
+
+        def every_size(dec, hold):
+            for n in range(1, MB + 1):
+                futs = _submit_together(
+                    dec, hold, [(rng.randint(0, V, (5,)), 3)] * n,
+                    speculative=spec)
+                for f in futs:
+                    f.result(120.0)
+
+        with (_spec_dec(pool) if spec else
+              pool.serving_decoder()) as dec:
+            hold = _hold_admission(dec)
+            every_size(dec, hold)
+            first = dec.stats()
+            jax.monitoring.register_event_duration_secs_listener(
+                on_event)
+            try:
+                every_size(dec, hold)
+            finally:
+                jax.monitoring.unregister_event_duration_listener(
+                    on_event)
+            second = dec.stats()
+        pools = 2 if spec else 1
+        assert first["merge_programs"] == pools
+        assert second["merge_programs"] == pools
+        assert compiles == []
+        assert first["prefills"] == MB          # one group per size
+        assert first["merges"] == pools * MB
+        assert second["merges"] == 2 * pools * MB
