@@ -50,11 +50,21 @@ class Generator:
         SSM layers (block_type) the state itself is O(1), but max_len
         still bounds total sequence length — it sizes the attention
         layers of a mixed stack and the learned position table.
-    block_type : "attention" (default), "ssm", or per-layer sequence
-        — SSM layers hold one (num_heads, head_dim, head_dim) f32
-        state blob per slot instead of (max_len, head_dim) KV rows
+    block_type : "attention" (default), "ssm", "mamba2", or per-layer
+        sequence — SSM layers hold one (num_heads, head_dim, head_dim)
+        f32 state blob per slot instead of (max_len, head_dim) KV rows
         (see ops/ssm.py and models/transformer.get_decode_symbol for
-        knob composition rules).
+        knob composition rules). "mamba2" layers (ops/mamba2.py), sized
+        by ``mamba2=dict(num_heads=, head_dim=, d_state=[, d_conv=,
+        chunk=])``, hold TWO blobs per slot: a (d_conv-1, conv_dim)
+        convolution window in the cache dtype and a
+        (heads, head_dim, d_state) f32 scan state.
+    norm, norm_eps, ffn, use_bias, tie_embeddings,
+    embedding_multiplier, residual_multiplier, logits_scaling,
+    attention_scale :
+        Architecture, as get_decode_symbol documents them; the
+        defaults are the OPT-style block. With ``tie_embeddings`` the
+        head is ``tok_embed_weight`` itself: one array on the device.
     batch_size : int
     dtype : optional compute dtype for params/caches (e.g. "bfloat16").
     mesh : optional jax.sharding.Mesh for multi-chip serving. Params
@@ -69,7 +79,11 @@ class Generator:
                  dtype=None, num_experts=0, mesh=None, quantize=None,
                  pos_encoding="learned", attention_window=0,
                  rolling_cache=False, num_kv_heads=None,
-                 quantize_kv=False, block_type="attention"):
+                 quantize_kv=False, block_type="attention",
+                 norm="layer", norm_eps=1e-5, ffn="relu", use_bias=True,
+                 tie_embeddings=False, embedding_multiplier=1.0,
+                 residual_multiplier=1.0, logits_scaling=1.0,
+                 attention_scale=None, mamba2=None):
         from .parallel import sharding as shd
 
         if quantize not in (None, "int8"):
@@ -101,7 +115,12 @@ class Generator:
         # compatibility refusals (speculative drafts, prefill grouping)
         self._btypes = transformer._canon_block_types(block_type,
                                                       num_layers)
-        self._has_ssm = "ssm" in self._btypes
+        mamba2 = transformer._canon_mamba2(mamba2, self._btypes)
+        # "ssm" here means RECURRENT: any layer whose state has no
+        # per-position entries (gated linear attention or Mamba-2) —
+        # what speculation cannot roll back and a padded prefill would
+        # absorb, so every refusal and split keyed on it covers both
+        self._has_ssm = bool({"ssm", "mamba2"} & set(self._btypes))
         # kept for twin-symbol builders (serve/decode.py rebuilds this
         # graph with per_row_pos=True against the SAME parameters)
         self._decode_opts = dict(
@@ -113,7 +132,13 @@ class Generator:
             pos_encoding=pos_encoding,
             attention_window=attention_window,
             rolling_cache=rolling_cache, num_kv_heads=num_kv_heads,
-            kv_quantize=quantize_kv, block_type=block_type)
+            kv_quantize=quantize_kv, block_type=block_type,
+            norm=norm, norm_eps=norm_eps, ffn=ffn, use_bias=use_bias,
+            tie_embeddings=tie_embeddings,
+            embedding_multiplier=embedding_multiplier,
+            residual_multiplier=residual_multiplier,
+            logits_scaling=logits_scaling,
+            attention_scale=attention_scale, mamba2=mamba2)
         sym = transformer.get_decode_symbol(**self._decode_opts)
         if quantize:
             arg_params = _quantize_weights(
@@ -193,6 +218,16 @@ class Generator:
         # small (no length axis) that a bf16 diet would save ~nothing
         self._state_shape = (self.batch_size, int(num_heads),
                              head_dim, head_dim)
+        # Mamba-2 layers: a convolution window in the cache dtype (it
+        # holds projection outputs as computed) and an f32 scan state
+        # (a running sum over the whole sequence: ops/mamba2.py)
+        self._conv_shape = self._scan_shape = None
+        if mamba2:
+            H, P, N = (mamba2[k] for k in ("num_heads", "head_dim",
+                                           "d_state"))
+            self._conv_shape = (self.batch_size, mamba2["d_conv"] - 1,
+                                H * P + 2 * N)
+            self._scan_shape = (self.batch_size, H, P, N)
         # quantize_kv: k/v live int8 with per-token f32 scale caches —
         # halves decode's dominant HBM stream (the cache is re-read
         # every step; each weight only once)
@@ -212,6 +247,12 @@ class Generator:
         (sizing) and _aux_row_shape (export/import) read, so the
         gauge/slot math can never drift from what is actually
         allocated."""
+        if name.endswith("_conv_state"):
+            # Mamba-2 convolution window: fixed size, served dtype
+            return self._conv_shape, jnp.dtype(self._cache_dtype)
+        if name.endswith("_scan_state"):
+            # Mamba-2 scan state: fixed size, always f32
+            return self._scan_shape, jnp.dtype(jnp.float32)
         if name.endswith("_state"):
             # SSM recurrent state: fixed-size blob, no length axis
             return self._state_shape, jnp.dtype(jnp.float32)
@@ -240,14 +281,33 @@ class Generator:
         k/v caches plus their per-token f32 scale caches under
         quantize_kv, and/or SSM state blobs) at this Generator's
         (batch_size, max_len) — computed from shapes/dtypes alone."""
-        total = 0
+        return self.batch_size * sum(self.state_bytes_by_kind().values())
+
+    @staticmethod
+    def _aux_kind(name):
+        """Which kind of decode state an aux name is, for the sizing
+        reports: "scan_state" / "conv_window" (Mamba-2), "ssm_state"
+        (gated linear attention) or "kv_rows" (k/v rows and their int8
+        scales: everything with a length axis)."""
+        if name.endswith("_scan_state"):
+            return "scan_state"
+        if name.endswith("_conv_state"):
+            return "conv_window"
+        return "ssm_state" if name.endswith("_state") else "kv_rows"
+
+    def state_bytes_by_kind(self):
+        """Bytes of decode state one slot owns, by kind of state (see
+        _aux_kind); only the kinds this model has. Sums to
+        state_bytes_per_slot()."""
+        out = {}
         for name in self._sym.list_auxiliary_states():
             shape, dtype = self._aux_spec(name)
-            n = 1
-            for d in shape:
+            n = dtype.itemsize
+            for d in shape[1:]:
                 n *= int(d)
-            total += n * dtype.itemsize
-        return total
+            kind = self._aux_kind(name)
+            out[kind] = out.get(kind, 0) + n
+        return out
 
     def state_bytes_per_slot(self):
         """Bytes of decode state ONE batch row (= one serving slot)
@@ -375,9 +435,21 @@ class Generator:
         pool must hand back (serve/decode.py's cache merge)."""
         if self._cache_sharding is None:
             return None
-        return {name: self._scale_sharding
-                if len(self._aux_spec(name)[0]) == 3
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        def place(name):
+            shape = self._aux_spec(name)[0]
+            if name.endswith(("_conv_state", "_scan_state")):
+                # Mamba-2 states: batch over 'data' only (the window
+                # axis is d_conv-1 long, x|B|C share the last one, and
+                # the mixer's heads need not divide the 'model' axis)
+                return NamedSharding(self.mesh, PartitionSpec(
+                    self._cache_sharding.spec[0],
+                    *([None] * (len(shape) - 1))))
+            return self._scale_sharding if len(shape) == 3 \
                 else self._cache_sharding
+
+        return {name: place(name)
                 for name in self._sym.list_auxiliary_states()}
 
     def _fresh_aux(self):

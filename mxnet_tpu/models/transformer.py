@@ -16,20 +16,21 @@ from __future__ import annotations
 from .. import symbol as sym
 
 
-def _fc(x, num_hidden, name, quantized=False):
+def _fc(x, num_hidden, name, quantized=False, no_bias=False):
     """FullyConnected or its weight-only-int8 twin. Same "<name>_weight"
     binding; the quantized form adds "<name>_scale" (per-out-channel)
     and keeps the f32 bias. Decode-side only — training always uses the
-    float op."""
+    float op. no_bias drops "<name>_bias" from the argument list."""
+    kw = {"no_bias": True} if no_bias else {}
     if quantized:
         return sym.contrib.QuantizedFullyConnected(
-            x, num_hidden=num_hidden, flatten=False, name=name)
+            x, num_hidden=num_hidden, flatten=False, name=name, **kw)
     return sym.FullyConnected(x, num_hidden=num_hidden, flatten=False,
-                              name=name)
+                              name=name, **kw)
 
 
 def _qkv_heads(x, num_heads, dim, prefix, quantized=False,
-               num_kv_heads=None):
+               num_kv_heads=None, no_bias=False):
     """Shared qkv projection + head split: (B, T, C) -> q (B, H, T, hd)
     and k/v (B, Hkv, T, hd). The training and decode attention blocks
     both use this so their parameter packing can never drift (a repack
@@ -45,7 +46,7 @@ def _qkv_heads(x, num_heads, dim, prefix, quantized=False,
     Hkv = int(num_kv_heads or num_heads)
     head_dim = dim // num_heads
     kv_dim = Hkv * head_dim
-    qkv = _fc(x, dim + 2 * kv_dim, prefix + "qkv", quantized)
+    qkv = _fc(x, dim + 2 * kv_dim, prefix + "qkv", quantized, no_bias)
 
     def cut(begin, end, heads):
         part = sym.slice_axis(qkv, axis=2, begin=begin, end=end)
@@ -57,12 +58,13 @@ def _qkv_heads(x, num_heads, dim, prefix, quantized=False,
             cut(dim + kv_dim, dim + 2 * kv_dim, Hkv))
 
 
-def _merge_heads_proj(att, dim, prefix, quantized=False):
+def _merge_heads_proj(att, dim, prefix, quantized=False,
+                      no_bias=False):
     """(B, H, T, hd) attention output -> (B, T, C) through the shared
     output projection."""
     att = sym.transpose(att, axes=(0, 2, 1, 3))       # (B, T, H, hd)
     att = sym.reshape(att, shape=(0, 0, -3))          # (B, T, C)
-    return _fc(att, dim, prefix + "proj", quantized)
+    return _fc(att, dim, prefix + "proj", quantized, no_bias)
 
 
 def _attention_block(x, num_heads, dim, prefix, seq_axis=None,
@@ -117,10 +119,34 @@ def _ssm_block(x, num_heads, dim, prefix):
     return _merge_heads_proj(out, dim, prefix)
 
 
-def _ffn_block(x, dim, hidden, prefix, quantized=False):
-    h = _fc(x, hidden, prefix + "fc1", quantized)
-    h = sym.Activation(h, act_type="relu")
-    return _fc(h, dim, prefix + "fc2", quantized)
+def _ffn_block(x, dim, hidden, prefix, quantized=False, kind="relu",
+               no_bias=False):
+    """The dense FFN. kind "relu": fc2(relu(fc1 x)). kind "gated_silu":
+    fc1 is twice as wide and holds [gate | up]; fc2(silu(gate) * up)
+    — same two parameter names, so both kinds bind by one rule."""
+    if kind == "gated_silu":
+        gu = _fc(x, 2 * hidden, prefix + "fc1", quantized, no_bias)
+        g = sym.slice_axis(gu, axis=2, begin=0, end=hidden)
+        u = sym.slice_axis(gu, axis=2, begin=hidden, end=2 * hidden)
+        h = sym.Activation(g, act_type="silu") * u
+    elif kind == "relu":
+        h = _fc(x, hidden, prefix + "fc1", quantized, no_bias)
+        h = sym.Activation(h, act_type="relu")
+    else:
+        raise ValueError("ffn must be 'relu' or 'gated_silu', got %r"
+                         % (kind,))
+    return _fc(h, dim, prefix + "fc2", quantized, no_bias)
+
+
+def _norm(x, name, kind="layer", eps=1e-5):
+    """The block's norm by kind: "layer" (LayerNorm, "<name>_gamma" and
+    "<name>_beta") or "rms" (RMSNorm, "<name>_gamma" alone)."""
+    if kind == "rms":
+        return sym.RMSNorm(x, eps=eps, name=name)
+    if kind != "layer":
+        raise ValueError("norm must be 'layer' or 'rms', got %r"
+                         % (kind,))
+    return sym.LayerNorm(x, eps=eps, name=name)
 
 
 def _moe_block(x, dim, hidden, num_experts, prefix, expert_axis=None,
@@ -158,9 +184,11 @@ def _check_kv_heads(num_heads, num_kv_heads):
 def _canon_block_types(block_type, num_layers):
     """Normalize block_type to a per-layer tuple.
 
-    block_type: "attention" | "ssm" for a uniform stack, or a sequence
-    of those naming each layer's kind (mixed stacks — e.g. mostly-ssm
-    with a few attention layers, the usual hybrid recipe)."""
+    block_type: "attention" | "ssm" | "mamba2" for a uniform stack, or
+    a sequence of those naming each layer's kind (mixed stacks — e.g.
+    mostly-ssm with a few attention layers, the usual hybrid recipe).
+    "mamba2" layers exist in the decode symbol only and take their
+    sizes from its `mamba2` argument."""
     if isinstance(block_type, str):
         kinds = (block_type,) * num_layers
     else:
@@ -170,10 +198,10 @@ def _canon_block_types(block_type, num_layers):
                 "block_type sequence names each layer: got %d entries "
                 "for num_layers=%d" % (len(kinds), num_layers))
     for b in kinds:
-        if b not in ("attention", "ssm"):
+        if b not in ("attention", "ssm", "mamba2"):
             raise ValueError(
-                "block_type entries must be 'attention' or 'ssm', "
-                "got %r" % (b,))
+                "block_type entries must be 'attention', 'ssm' or "
+                "'mamba2', got %r" % (b,))
     return kinds
 
 
@@ -257,16 +285,19 @@ def get_stage_symbol(num_heads=4, dim=128, ffn_hidden=None,
 def _decode_attention_block(x, num_heads, dim, prefix, max_len, pos,
                             quantized=False, rope_positions=None,
                             window=0, rolling=False,
-                            num_kv_heads=None, kv_quantize=False):
+                            num_kv_heads=None, kv_quantize=False,
+                            scale=None, no_bias=False):
     """Incremental variant of _attention_block: identical qkv/proj
     helpers (a training checkpoint binds unchanged), attention routed
     through _contrib_CachedAttention with per-layer k/v cache aux
     states ("<prefix>attn_k_cache"/"_v_cache", created by the op's
     state_inputs registration). kv_quantize routes through the int8
     variant (_contrib_CachedAttentionQ8), which adds per-token scale
-    aux states ("_k_scale"/"_v_scale")."""
+    aux states ("_k_scale"/"_v_scale"). scale: the score multiplier
+    where the model states one (default head_dim ** -0.5)."""
     q, k, v = _qkv_heads(x, num_heads, dim, prefix, quantized,
-                         num_kv_heads=num_kv_heads)
+                         num_kv_heads=num_kv_heads, no_bias=no_bias)
+    kw = {} if scale is None else {"scale": float(scale)}
     if rope_positions is not None:
         # rotate BEFORE caching: cached keys carry their absolute
         # rotation, so each step only rotates the new tokens
@@ -275,17 +306,17 @@ def _decode_attention_block(x, num_heads, dim, prefix, max_len, pos,
     if rolling:
         att = sym.contrib.RollingCachedAttention(
             q, k, v, pos=pos, max_len=max_len, window=window,
-            name=prefix + "attn")
+            name=prefix + "attn", **kw)
     elif kv_quantize:
         att = sym.contrib.CachedAttentionQ8(
             q, k, v, pos=pos, max_len=max_len, window=window,
-            name=prefix + "attn")
+            name=prefix + "attn", **kw)
     else:
         att = sym.contrib.CachedAttention(q, k, v,
                                           pos=pos, max_len=max_len,
                                           window=window,
-                                          name=prefix + "attn")
-    return _merge_heads_proj(att, dim, prefix, quantized)
+                                          name=prefix + "attn", **kw)
+    return _merge_heads_proj(att, dim, prefix, quantized, no_bias)
 
 
 def _decode_ssm_block(x, num_heads, dim, prefix, max_len, pos,
@@ -304,13 +335,68 @@ def _decode_ssm_block(x, num_heads, dim, prefix, max_len, pos,
     return _merge_heads_proj(out, dim, prefix, quantized)
 
 
+MAMBA2_SIZES = ("num_heads", "head_dim", "d_state", "d_conv", "chunk")
+
+
+def _canon_mamba2(mamba2, btypes):
+    """The Mamba-2 layers' sizes as a plain dict with every key of
+    MAMBA2_SIZES, or None when no layer is of that kind. d_conv and
+    chunk have the family's usual values as defaults; the three widths
+    have none."""
+    if "mamba2" not in btypes:
+        if mamba2:
+            raise ValueError("mamba2 sizes given but no block_type "
+                             "entry is 'mamba2'")
+        return None
+    sizes = dict({"d_conv": 4, "chunk": 256}, **dict(mamba2 or {}))
+    if set(sizes) != set(MAMBA2_SIZES) or \
+            any(int(v) < 1 for v in sizes.values()) or \
+            int(sizes["d_conv"]) < 2:
+        raise ValueError(
+            "block_type 'mamba2' needs mamba2=dict(num_heads=, "
+            "head_dim=, d_state=[, d_conv=4, chunk=256]) with "
+            "positive sizes and d_conv >= 2, got %r" % (mamba2,))
+    return {k: int(sizes[k]) for k in MAMBA2_SIZES}
+
+
+def _decode_mamba2_block(x, dim, prefix, max_len, pos, sizes,
+                         quantized=False, no_bias=False, eps=1e-5):
+    """A Mamba-2 mixer on the decode path: one input projection
+    "<prefix>in_proj" to [z | xBC | dt] (d_inner + conv_dim + heads),
+    convolution and selective scan through _contrib_Mamba2Cached with
+    two per-layer aux states ("<prefix>mamba_conv_state",
+    (B, d_conv-1, conv_dim) in the served dtype, and
+    "<prefix>mamba_scan_state", (B, heads, head_dim, d_state) f32 —
+    neither has a length axis), the gated RMS norm over all of d_inner
+    ("<prefix>mnorm_gamma", gate first), and "<prefix>out_proj". The op
+    ignores pos, so the per-row-position serving twin is this graph."""
+    H, P, N = sizes["num_heads"], sizes["head_dim"], sizes["d_state"]
+    d_inner = H * P
+    conv_dim = d_inner + 2 * N
+    zxd = _fc(x, d_inner + conv_dim + H, prefix + "in_proj", quantized,
+              no_bias)
+    z = sym.slice_axis(zxd, axis=2, begin=0, end=d_inner)
+    xbc = sym.slice_axis(zxd, axis=2, begin=d_inner,
+                         end=d_inner + conv_dim)
+    dt = sym.slice_axis(zxd, axis=2, begin=d_inner + conv_dim,
+                        end=d_inner + conv_dim + H)
+    y = sym.contrib.Mamba2Cached(xbc, dt, pos=pos, max_len=max_len,
+                                 name=prefix + "mamba", **sizes)
+    y = sym.contrib.GatedRMSNorm(y, z, eps=eps, name=prefix + "mnorm")
+    return _fc(y, dim, prefix + "out_proj", quantized, no_bias)
+
+
 def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
                       dim=128, ffn_hidden=None, num_experts=0,
                       quantized=False, compute_dtype=None,
                       pos_encoding="learned", attention_window=0,
                       rolling_cache=False, num_kv_heads=None,
                       kv_quantize=False, per_row_pos=False,
-                      block_type="attention"):
+                      block_type="attention", norm="layer",
+                      norm_eps=1e-5, ffn="relu", use_bias=True,
+                      tie_embeddings=False, embedding_multiplier=1.0,
+                      residual_multiplier=1.0, logits_scaling=1.0,
+                      attention_scale=None, mamba2=None):
     """Autoregressive-decode twin of get_symbol.
 
     Inputs: data (B, Tnew) token ids for the tokens being appended
@@ -342,6 +428,25 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
     layer (the state is already O(1) — there is no window to roll);
     per_row_pos composes freely (the SSM op ignores pos).
 
+    "mamba2" entries of block_type are Mamba-2 mixers sized by
+    `mamba2` (a dict of MAMBA2_SIZES; see _decode_mamba2_block): two
+    aux states a layer, a convolution window and a float32 scan state,
+    with the composition rules of "ssm" layers. They exist on this
+    decode path only (serving); get_symbol does not build them.
+
+    The remaining arguments are what the hybrid families' published
+    equations need beyond the block above, each with the default that
+    leaves the symbol as it was: norm "layer" | "rms" (RMSNorm has a
+    gamma and no beta) with norm_eps; ffn "relu" | "gated_silu" (fc1
+    twice as wide: [gate | up]); pos_encoding "none" (no position
+    enters anywhere, and `positions` is no input of the symbol);
+    use_bias=False drops every projection's bias; tie_embeddings
+    makes the head the token table itself (one array: no
+    lm_head_weight, no lm_head_bias); embedding_multiplier scales the
+    embedding, residual_multiplier each block's contribution before it
+    is added, logits_scaling DIVIDES the logits; attention_scale
+    replaces head_dim ** -0.5.
+
     New TPU-native capability (the 2017 reference's decode story was
     rnn.RNNCell step-wise unrolling); mxnet_tpu.generation.Generator
     drives this symbol."""
@@ -351,8 +456,13 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
                          % (dim, num_heads))
     _check_kv_heads(num_heads, num_kv_heads)
     btypes = _canon_block_types(block_type, num_layers)
-    has_ssm = "ssm" in btypes
+    mamba2 = _canon_mamba2(mamba2, btypes)
+    has_ssm = "ssm" in btypes or "mamba2" in btypes
     has_attn = "attention" in btypes
+    no_bias = not use_bias
+    if tie_embeddings and num_experts:
+        raise ValueError("tie_embeddings is not supported with "
+                         "num_experts (no model here needs both)")
     if rolling_cache and not attention_window:
         raise ValueError("rolling_cache needs attention_window > 0 "
                          "(the circular capacity covers one window)")
@@ -387,15 +497,27 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
     cache_pos = sym.Variable("cache_pos") if per_row_pos \
         else sym.Variable("cache_pos", shape=(1,))
 
+    tied = {}
+    if tie_embeddings:
+        # ONE array on the device: the table is named here and handed
+        # to both the lookup and the head (int8: with its per-row
+        # scales, which are the head's per-output-channel scales)
+        tied["weight"] = sym.Variable("tok_embed_weight")
+        if quantized:
+            tied["scale"] = sym.Variable("tok_embed_scale")
     if quantized:
         # per-row int8 token table (the largest parameter at serving)
         x = sym.contrib.QuantizedEmbedding(
             data, input_dim=vocab_size, output_dim=dim,
             dtype=compute_dtype or "float32",
-            name="tok_embed")
+            name="tok_embed", **tied)
     else:
         x = sym.Embedding(data, input_dim=vocab_size, output_dim=dim,
-                          name="tok_embed")
+                          name="tok_embed", **tied)
+    if embedding_multiplier != 1.0:
+        # in float32, rounded once: a Python scalar times a bf16 array
+        # would round the SCALAR to bf16 first (_contrib_ScaleF32)
+        x = sym.contrib.ScaleF32(x, scalar=float(embedding_multiplier))
     rope_positions = None
     if pos_encoding == "rope":
         rope_positions = positions
@@ -410,25 +532,38 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
             pos_vec = sym.take(pos_table, positions)  # (Tnew, dim)
             x = sym.broadcast_add(x,
                                   sym.expand_dims(pos_vec, axis=0))
-    else:
-        raise ValueError("pos_encoding must be 'learned' or 'rope', "
-                         "got %r" % (pos_encoding,))
+    elif pos_encoding != "none":
+        raise ValueError("pos_encoding must be 'learned', 'rope' or "
+                         "'none', got %r" % (pos_encoding,))
+
+    def residual(x, branch):
+        if residual_multiplier == 1.0:
+            return x + branch
+        return sym.contrib.AddScaledF32(
+            x, branch, scalar=float(residual_multiplier))
 
     for i in range(num_layers):
         prefix = "layer%d_" % i
-        a = sym.LayerNorm(x, name=prefix + "ln1")
+        a = _norm(x, prefix + "ln1", norm, norm_eps)
         if btypes[i] == "ssm":
-            x = x + _decode_ssm_block(a, num_heads, dim, prefix,
+            mixed = _decode_ssm_block(a, num_heads, dim, prefix,
                                       max_len, cache_pos,
                                       quantized=quantized)
+        elif btypes[i] == "mamba2":
+            mixed = _decode_mamba2_block(a, dim, prefix, max_len,
+                                         cache_pos, mamba2,
+                                         quantized=quantized,
+                                         no_bias=no_bias, eps=norm_eps)
         else:
-            x = x + _decode_attention_block(
+            mixed = _decode_attention_block(
                 a, num_heads, dim, prefix, max_len, cache_pos,
                 num_kv_heads=num_kv_heads, quantized=quantized,
                 rope_positions=rope_positions,
                 window=attention_window, rolling=rolling_cache,
-                kv_quantize=kv_quantize)
-        f = sym.LayerNorm(x, name=prefix + "ln2")
+                kv_quantize=kv_quantize, scale=attention_scale,
+                no_bias=no_bias)
+        x = residual(x, mixed)
+        f = _norm(x, prefix + "ln2", norm, norm_eps)
         # inference never capacity-drops: every token is served, so
         # the factor is raised to E (cap == token count). Training-time
         # drops mean a dropping checkpoint's decode can differ exactly
@@ -437,11 +572,22 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
         ff = _moe_block(f, dim, ffn_hidden, num_experts, prefix,
                         capacity_factor=num_experts) \
             if num_experts else _ffn_block(f, dim, ffn_hidden, prefix,
-                                           quantized=quantized)
-        x = x + ff
+                                           quantized=quantized,
+                                           kind=ffn, no_bias=no_bias)
+        x = residual(x, ff)
 
-    x = sym.LayerNorm(x, name="ln_f")
-    return _fc(x, vocab_size, "lm_head", quantized)
+    x = _norm(x, "ln_f", norm, norm_eps)
+    if tie_embeddings:
+        head = sym.contrib.QuantizedFullyConnected if quantized \
+            else sym.FullyConnected
+        logits = head(x, num_hidden=vocab_size, flatten=False,
+                      no_bias=True, name="lm_head", **tied)
+    else:
+        logits = _fc(x, vocab_size, "lm_head", quantized, no_bias)
+    if logits_scaling != 1.0:
+        logits = sym.contrib.ScaleF32(
+            logits, scalar=1.0 / float(logits_scaling))
+    return logits
 
 
 def get_symbol(vocab_size, seq_len, num_layers=2, num_heads=4, dim=128,
@@ -505,6 +651,11 @@ def get_symbol(vocab_size, seq_len, num_layers=2, num_heads=4, dim=128,
     _check_kv_heads(num_heads, num_kv_heads)
     _check_pos_encoding(pos_encoding, dim, num_heads)
     btypes = _canon_block_types(block_type, num_layers)
+    if "mamba2" in btypes:
+        raise ValueError(
+            "block_type 'mamba2' exists on the decode path only "
+            "(get_decode_symbol / Generator): the training symbol "
+            "does not build it")
     if seq_axis and "ssm" in btypes:
         raise ValueError(
             "seq_axis (ring sequence parallelism) is not supported "

@@ -533,6 +533,8 @@ def _activation(data, act_type="relu", **_):
         return jnn.softplus(data)
     if act_type == "softsign":
         return data / (1 + jnp.abs(data))
+    if act_type == "silu":
+        return jnn.silu(data)
     raise ValueError("unknown act_type %r" % act_type)
 
 

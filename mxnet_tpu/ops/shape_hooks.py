@@ -369,3 +369,36 @@ def _ssm_cached_shapes(shapes, attrs):
 
 
 set_param_shapes("_contrib_SSMCached", _ssm_cached_shapes)
+
+
+# -- Mamba2Cached (two carried states, neither with a length
+# axis) and the RMS norms ---------------------------------------------------
+
+def _mamba2_shapes(shapes, attrs):
+    """Everything is sized from xbc (B, T, conv_dim) and the attrs:
+    slots 2-6 the per-channel and per-head parameters, slot 7 the
+    convolution window, slot 8 the scan state, slot 9 the ignored pos."""
+    xbc = shapes[0]
+    if xbc is None:
+        return shapes
+    H, P, N, K = (int(attrs.get(k, 0)) for k in
+                  ("num_heads", "head_dim", "d_state", "d_conv"))
+    want = [xbc, (xbc[0], xbc[1], H), (xbc[2], K), (xbc[2],), (H,),
+            (H,), (H,), (xbc[0], K - 1, xbc[2]), (xbc[0], H, P, N),
+            (1,)]
+    return [w if s is None else s for s, w in zip(shapes, want)]
+
+
+set_param_shapes("_contrib_Mamba2Cached", _mamba2_shapes)
+
+
+def _rms_shapes(shapes, attrs):
+    data = shapes[0]
+    if data is None:
+        return shapes
+    out = [data if s is None else s for s in shapes[:-1]]
+    return out + [(data[-1],) if shapes[-1] is None else shapes[-1]]
+
+
+set_param_shapes("RMSNorm", _rms_shapes)
+set_param_shapes("_contrib_GatedRMSNorm", _rms_shapes)

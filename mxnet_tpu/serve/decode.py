@@ -532,6 +532,17 @@ class ContinuousDecoder:
             int(v.nbytes) for v in self._aux.values()) // self._B
         self._g_kv = _telemetry.gauge("serve.decode.kv_bytes_per_slot")
         self._g_kv.set(self._kv_bytes_per_slot)
+        # the same bytes by kind of state (k/v rows, SSM blob, Mamba-2
+        # scan state and convolution window), from the shapes the pool
+        # was allocated with. The kinds without a length axis get a
+        # gauge of their own beside kv_bytes_per_slot, set here once
+        # (a constant of the pool) and only where the pool has them, so
+        # a pool of k/v rows leaves the global snapshot as it was
+        self._bytes_by_kind = generator.state_bytes_by_kind()
+        for kind, n in self._bytes_by_kind.items():
+            if kind != "kv_rows":
+                _telemetry.gauge("serve.decode.%s_bytes_per_slot"
+                                 % kind).set(n)
         # one compiled (B, 1) executable across slot turnover is THE
         # property continuous batching exists for; with a speculative
         # draft the target owns exactly TWO programs — the (B, 1) step
@@ -647,14 +658,27 @@ class ContinuousDecoder:
         gen = self._gen
         bps = self._kv_bytes_per_slot
         kinds = []
-        if any(not n.endswith("_state") for n in self._aux):
+        by_kind = self._bytes_by_kind
+        dims = lambda shape: "x".join(str(d) for d in shape[1:])
+        if "kv_rows" in by_kind:
             kind = "int8 + f32 per-token scales" if gen._quantize_kv \
                 else str(jnp.dtype(gen._cache_dtype))
-            kinds.append("KV rows %s (%s)" % (
-                "x".join(str(d) for d in gen._cache_shape[1:]), kind))
-        if any(n.endswith("_state") for n in self._aux):
-            kinds.append("ssm state %s (float32, O(1) in max_len)" % (
-                "x".join(str(d) for d in gen._state_shape[1:])))
+            kinds.append("KV rows %s (%s), %d bytes" % (
+                dims(gen._cache_shape), kind, by_kind["kv_rows"]))
+        if "ssm_state" in by_kind:
+            kinds.append("ssm state %s (float32, O(1) in max_len), "
+                         "%d bytes" % (dims(gen._state_shape),
+                                       by_kind["ssm_state"]))
+        if "scan_state" in by_kind:
+            kinds.append("mamba2 scan state %s (float32, O(1) in "
+                         "max_len), %d bytes" % (
+                             dims(gen._scan_shape),
+                             by_kind["scan_state"]))
+            kinds.append("mamba2 convolution window %s (%s, O(1) in "
+                         "max_len), %d bytes" % (
+                             dims(gen._conv_shape),
+                             jnp.dtype(gen._cache_dtype),
+                             by_kind["conv_window"]))
         lines = [
             "ContinuousDecoder pool: %d slot(s), max_len=%d, "
             "%d layer(s)" % (self._B, gen.max_len,
@@ -1837,7 +1861,8 @@ class ContinuousDecoder:
                 "spec_accepted": self._spec_accepted,
                 "draft_prefills": self._draft_prefills,
                 "active": sum(s is not None for s in self._slots),
-                "queued": len(self._queue)}
+                "queued": len(self._queue),
+                "bytes_per_slot": dict(self._bytes_by_kind)}
 
     def introspect(self):
         """Live state for the ``stats`` introspection frame
