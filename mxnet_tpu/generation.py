@@ -8,9 +8,16 @@ auxiliary state through `models.transformer.get_decode_symbol`'s graph
 
 Design: two jit specializations, bucketing-style — one for the prefill
 chunk (B, P) and one for the single-token step (B, 1) — each a whole
--graph XLA program with the caches as donated-in-spirit aux arrays kept
-on device between steps. Sampling (greedy / temperature / top-k) runs
-on device too; only the chosen token ids come back to the host.
+-graph XLA program with the caches as aux arrays kept on device
+between steps. Sampling (greedy / temperature / top-k) runs on device
+too; only the chosen token ids come back to the host.
+
+What is donated where: this module's ``generator_step`` takes its
+caches UNDONATED (several loops here read ``aux`` after the call, and
+a prefill runs on a fresh pool of its own). The serving pool of
+``serve/decode.py`` is donated to every program that updates it —
+``decode_step`` / ``draft_step``, ``cache_merge`` and the import
+scatter — which write it in place; the decode loop rebinds it at once.
 """
 from __future__ import annotations
 

@@ -346,6 +346,18 @@ def _merge_program(generator):
                    out_shardings=generator._aux_shardings())
 
 
+def _step_program(step, generator):
+    """The compiled per-row step of ``generator``'s pool: ``step(args,
+    aux, rng) -> (outs, aux)`` with the pool DONATED, so each row's new
+    entries are written into the pool's own buffers instead of into a
+    copy of every cache array. The caller rebinds its pool to the
+    second result at once; the pytree it passed is deleted. Under a
+    mesh the returned pool keeps the generator's cache placement,
+    which donation needs (as in :func:`_merge_program`)."""
+    return jax.jit(step, donate_argnums=1,
+                   out_shardings=(None, generator._aux_shardings()))
+
+
 class ContinuousDecoder:
     """Fixed-slot continuous batching over a Generator's decode state
     (KV caches for attention blocks, O(1) recurrent blobs for ssm
@@ -409,10 +421,11 @@ class ContinuousDecoder:
             # device's module name say which program ran
             return eval_fn(args, aux, rng, False)
 
-        self._step_fn = jax.jit(decode_step)
+        self._step_fn = _step_program(decode_step, generator)
         self._rng0 = jax.random.PRNGKey(0)
 
         self._aux = generator._fresh_aux()     # the pool caches
+        self._alias_bytes = None               # (aliased, held), lazily
         self._import_jit = {}                  # pos -> fused scatter
         self._merge_fn = _merge_program(generator)
         self._dmerge_fn = None                 # the draft pool's twin
@@ -471,7 +484,7 @@ class ContinuousDecoder:
             def draft_step(args, aux, rng):
                 return d_eval(args, aux, rng, False)
 
-            self._draft_step_fn = jax.jit(draft_step)
+            self._draft_step_fn = _step_program(draft_step, draft)
             self._daux = draft._fresh_aux()    # the draft's pool caches
             self._dmerge_fn = _merge_program(draft)
             # verify rounds write up to γ speculative entries past a
@@ -515,6 +528,7 @@ class ContinuousDecoder:
         self._admit_rounds = 0     # _admit calls that admitted
         self._prefill_rows = 0     # rows of every prefill forward
         self._merges = 0           # compiled cache-merge dispatches
+        self._step_failures = 0    # steps that raised (_step_failed)
         self._imported = 0
         self._resumed = 0
         self._evacuated = 0
@@ -687,6 +701,15 @@ class ContinuousDecoder:
             "  kv_bytes_per_slot: %d (%.2f MiB)  pool total: %.2f MiB"
             % (bps, bps / 2 ** 20, bps * self._B / 2 ** 20),
         ]
+        aliased, held = self._step_alias_bytes()
+        if aliased is None:
+            lines.append(
+                "  step program: this backend reports no aliased bytes "
+                "(%d pool bytes on a device)" % held)
+        else:
+            lines.append(
+                "  step program writes %d of the pool's %d bytes on a "
+                "device in place (donated)" % (aliased, held))
         if hbm_budget is None:
             try:
                 stats = jax.local_devices()[0].memory_stats() or {}
@@ -706,6 +729,42 @@ class ContinuousDecoder:
                 "  no HBM budget known (backend reports no "
                 "bytes_limit) — set MXNET_DECODE_SLOTS=auto:<bytes>")
         return "\n".join(lines)
+
+    def _step_alias_bytes(self):
+        """(aliased, held): the bytes the compiled (B, 1) step program
+        updates in place of its donated pool, by XLA's own account
+        (``memory_analysis().alias_size_in_bytes`` of the
+        ``decode_step`` executable), beside the bytes the pool holds
+        on one device. Equal when every cache array is written in
+        place; fewer means the step copies what it could not alias,
+        which is logged as a warning. ``aliased`` is None where the
+        backend reports no analysis. A property of the program, so it
+        is read once; that costs one compile of the step unless the
+        compilation cache holds it."""
+        if self._alias_bytes is None:
+            def spec(a):
+                return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                            sharding=a.sharding)
+            aux = {n: spec(a) for n, a in self._aux.items()}
+            held = sum(
+                int(np.prod(a.sharding.shard_shape(a.shape)))
+                * a.dtype.itemsize for a in aux.values())
+            args = {n: spec(a) for n, a in self._gen._params.items()}
+            row = jax.ShapeDtypeStruct((self._B, 1), jnp.float32)
+            args.update(data=row, positions=row,
+                        cache_pos=jax.ShapeDtypeStruct((self._B,),
+                                                       jnp.float32))
+            analysis = self._step_fn.lower(
+                args, aux, self._rng0).compile().memory_analysis()
+            aliased = getattr(analysis, "alias_size_in_bytes", None)
+            if aliased is not None and aliased < held:
+                self._log.warning(
+                    "decode_step aliases %d of the pool's %d bytes on "
+                    "a device: XLA could not use every donated cache "
+                    "array, so each step copies the rest", aliased,
+                    held)
+            self._alias_bytes = (aliased, held)
+        return self._alias_bytes
 
     # -- admission ----------------------------------------------------------
     def _check_blob(self, blob, want_pos=None,
@@ -1134,10 +1193,14 @@ class ContinuousDecoder:
         ``draft``), for every cache array in ONE compiled dispatch
         (:func:`_merge_program`), and return the new pool. Whole rows
         land, all ``max_len`` positions, so one program serves every
-        prompt length. ``pool`` is DONATED: the caller rebinds
-        ``self._aux`` / ``self._daux`` to the result and nothing else
-        may hold the old pytree — the loop thread is the one aux
-        mutator, as for :meth:`import_kv_rows`."""
+        prompt length. ``pool`` is DONATED, as it is to the step
+        programs (:func:`_step_program`) and to the import scatter
+        (:meth:`import_kv_rows`): the caller rebinds ``self._aux`` /
+        ``self._daux`` to the result at once, and a reference kept to
+        the old pytree raises "Array has been deleted" when read. The
+        loop thread is the one aux mutator. The prefill's own pool
+        (``src``, a fresh pool run through ``generator_step``) is not
+        donated."""
         padded = np.zeros((self._B,), np.int32)
         padded[:len(slots)] = slots
         self._merges += 1
@@ -1437,6 +1500,31 @@ class ContinuousDecoder:
                     self._emit(req, tok)
                     self._maybe_finish(i, tok)
 
+    def _step_failed(self, exc):
+        """A step (or speculative round) raised. The pool it was given
+        was donated, so its buffers may be deleted, and what came back
+        may be poisoned by the same fault: no row of it can be
+        trusted. Every active sequence fails with the error, both
+        pools are built anew, and the loop goes on to admit the queue
+        into them: it never steps on the old buffers again. (A chunked
+        prefill in flight owns a pool of its own and carries on.)"""
+        self._log.error("decode step failed; failing %d active "
+                        "sequence(s) and rebuilding the pool",
+                        sum(r is not None for r in self._slots),
+                        exc_info=exc)
+        for slot, req in enumerate(self._slots):
+            if req is not None:
+                self._slots[slot] = None
+                req._fail(exc)
+        self._step_failures += 1
+        self._g_active.set(0)
+        _telemetry.journal_event("serve.decode.step_failed",
+                                 error=type(exc).__name__)
+        self._aux = self._gen._fresh_aux()
+        if self._draft is not None:
+            self._daux = self._draft._fresh_aux()
+        self._publish_pool_gauges()
+
     def _publish_pool_gauges(self):
         """The gauges that can only change when a slot turns over,
         published where one is admitted or freed (not per step): the
@@ -1706,17 +1794,21 @@ class ContinuousDecoder:
                 continue
             self._admit()
             self._chunk_step()
-            if self._draft is not None and any(
-                    s is not None and s.speculative
-                    for s in self._slots):
-                with _trace.phase("serve.spec.round") as ph:
-                    ph.note(**self._spec_round())
-            else:
-                # draft-less pools and rounds with no speculative
-                # participant run the ordinary (B, 1) step — a
-                # mixed-traffic pool flips between the two compiled
-                # target programs, never compiles a third
-                self._step()
+            try:
+                if self._draft is not None and any(
+                        s is not None and s.speculative
+                        for s in self._slots):
+                    with _trace.phase("serve.spec.round") as ph:
+                        ph.note(**self._spec_round())
+                else:
+                    # draft-less pools and rounds with no speculative
+                    # participant run the ordinary (B, 1) step — a
+                    # mixed-traffic pool flips between the two
+                    # compiled target programs, never compiles a third
+                    self._step()
+            except Exception as exc:      # noqa: BLE001 — the loop
+                # serves every later request; see _step_failed
+                self._step_failed(exc)
         self._g_active.set(0)
         _telemetry.journal_event("serve.decode.stop")
 
@@ -1847,6 +1939,7 @@ class ContinuousDecoder:
                 "admit_rounds": self._admit_rounds,
                 "prefill_rows": self._prefill_rows,
                 "merges": self._merges,
+                "step_failures": self._step_failures,
                 "merge_programs": sum(
                     fn._cache_size() for fn in
                     (self._merge_fn, self._dmerge_fn)

@@ -814,3 +814,264 @@ class TestCacheMerge:
         assert first["prefills"] == MB          # one group per size
         assert first["merges"] == pools * MB
         assert second["merges"] == 2 * pools * MB
+
+
+# -- the step programs donate the pool they update (PR 28) ----------------
+STEP_KINDS = ["kv", "q8", "gqa", "windowed", "ssm", "mamba2", "draft",
+              "sharded"]
+
+
+def _step_pool(kind, params, batch):
+    """A Generator whose pool holds one kind of decode state: bf16/f32
+    k/v rows, int8 rows with f32 scale caches, grouped k/v heads, rows
+    under a sliding-window mask (a ROLLING cache is refused at
+    construction, so this is the windowed pool that can be served),
+    SSM blobs, Mamba-2 window + scan state beside k/v rows, a pool
+    with a draft, rows sharded over a 2x2 mesh. Returns (generator,
+    vocabulary)."""
+    if kind == "mamba2":
+        from cellbench.models import granite as model
+        from cellbench.reference import granite as ref
+        import test_granite_serve as toy
+        return Generator(ref.make_params(toy.TOY, toy.SEED, "float32"),
+                         toy.V, toy.T, batch_size=batch,
+                         **model.generator_args(toy.TOY)), toy.V
+    if kind == "ssm":
+        return _gen(_params(block_type="ssm"), batch,
+                    block_type="ssm"), V
+    if kind == "gqa":
+        return _gen(_params(seed=5, num_kv_heads=1), batch,
+                    num_kv_heads=1), V
+    over = {"q8": dict(quantize_kv=True),
+            "windowed": dict(attention_window=4),
+            "sharded": dict(mesh=_mesh_2x2())}.get(kind, {})
+    return _gen(params, batch, **over), V
+
+
+def _serving(kind, gen):
+    return _spec_dec(gen) if kind == "draft" else gen.serving_decoder()
+
+
+def _without_donation(dec):
+    """Rebuild ``dec``'s step programs from their own functions with
+    no donation: the program as it was, made here and not by a switch
+    in the program. Before the first request only."""
+    import jax
+    dec._step_fn = jax.jit(dec._step_fn.__wrapped__)
+    if dec._draft_step_fn is not None:
+        dec._draft_step_fn = jax.jit(dec._draft_step_fn.__wrapped__)
+    return dec
+
+
+def _watch_pools(dec):
+    """Wrap the loop's step and speculative round: for each one that
+    ran, record whether every leaf of the pool(s) held BEFORE it is
+    deleted afterwards and every leaf of the pool(s) bound after it is
+    live."""
+    seen = []
+
+    def watched(run):
+        def inner():
+            before = dict(dec._aux), dict(dec._daux or {})
+            n, d = dec._steps, dec._draft_steps
+            out = run()
+            if dec._steps > n:
+                gone = all(a.is_deleted() for a in before[0].values())
+                if dec._draft_steps > d:
+                    gone = gone and all(a.is_deleted()
+                                        for a in before[1].values())
+                live = not any(
+                    a.is_deleted() for a in
+                    list(dec._aux.values()) +
+                    list((dec._daux or {}).values()))
+                seen.append((gone, live, before[0]))
+            return out
+        return inner
+
+    dec._step = watched(dec._step)
+    dec._spec_round = watched(dec._spec_round)
+    return seen
+
+
+def _ragged_load(dec, vocab, spec):
+    """More sequences than slots, ragged prompts and budgets, greedy
+    and sampled: slots turn over while others decode."""
+    rng = np.random.RandomState(28)
+    futs = []
+    for i, (p, n) in enumerate(zip((4, 6, 3, 5, 7, 4, 6),
+                                   (8, 3, 10, 5, 2, 9, 4))):
+        kw = dict(temperature=0.8, top_k=5, seed=i) if i % 3 == 2 \
+            else {}
+        futs.append(dec.submit(rng.randint(1, vocab, (p,)), n,
+                               speculative=spec and i % 2 == 0, **kw))
+    return [f.result(120.0) for f in futs]
+
+
+class TestDonatedStep:
+    @pytest.mark.parametrize("kind", STEP_KINDS)
+    def test_step_donates_its_pool_same_rows(self, params, kind):
+        """ACCEPTANCE: with the pool donated to `decode_step` (and
+        `draft_step`), (a) every served row is identical to the row of
+        a decoder whose step programs were compiled without donation;
+        (b) after each step the pool held before it is deleted, leaf
+        by leaf, and the pool bound after it is live; a stale
+        reference raises instead of reading old rows; (c) the step
+        stays ONE compiled program across slot turnover (two with a
+        draft: step + verify; the draft's own stays one); (d) XLA
+        could use every donated buffer: no warning."""
+        import warnings
+        draft = kind == "draft"
+        gen, vocab = _step_pool(kind, params, 3)
+        plain, _ = _step_pool(kind, params, 3)
+        with warnings.catch_warnings(record=True) as caught:
+            warnings.simplefilter("always")
+            with _serving(kind, gen) as dec:
+                seen = _watch_pools(dec)
+                got = _ragged_load(dec, vocab, draft)
+                st = dec.stats()
+                programs = dec._step_fn._cache_size()
+                drafts = dec._draft_step_fn._cache_size() \
+                    if draft else None
+                gauge = telemetry.gauge(
+                    "serve.decode.jit_cache_size").value
+            with _without_donation(_serving(kind, plain)) as ref:
+                kept = _watch_pools(ref)
+                want = _ragged_load(ref, vocab, draft)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(g, w)
+        assert st["finished"] == len(got) > gen.batch_size
+        assert st["step_failures"] == 0
+        assert seen and all(gone and live for gone, live, _ in seen)
+        with pytest.raises(RuntimeError, match="deleted"):
+            np.asarray(next(iter(seen[-1][2].values())))
+        # the twin really is undonated: its old pools stay readable
+        assert kept and not any(gone for gone, _, _ in kept)
+        assert programs == (2 if draft else 1)
+        assert drafts in (None, 1)
+        assert gauge == programs
+        assert not [w for w in caught if "onat" in str(w.message)]
+
+    @pytest.mark.parametrize("kind", KINDS)
+    def test_describe_reports_the_aliased_bytes(self, merge_decs,
+                                                kind):
+        """`describe()` states how many of the pool's bytes on one
+        device the compiled step writes in place: all of them."""
+        dec = merge_decs[kind]
+        aliased, held = dec._step_alias_bytes()
+        if aliased is None:
+            pytest.skip("this backend's memory_analysis reports no "
+                        "alias_size_in_bytes")
+        devices = 4 if kind == "sharded" else 1
+        assert held * devices == sum(int(a.nbytes)
+                                     for a in dec._aux.values())
+        assert aliased == held
+        assert "writes %d of the pool's %d bytes" % (held, held) \
+            in dec.describe(hbm_budget=1e9)
+        # a report, not a step: the pool was lowered by shape only
+        assert not any(a.is_deleted() for a in dec._aux.values())
+
+    def test_unusable_donation_is_logged(self, params, caplog):
+        """A step program that aliases less than the pool it is given
+        is reported through the decoder's logger."""
+        import logging
+        with _without_donation(
+                _gen(params, 2).serving_decoder()) as dec:
+            with caplog.at_level(logging.WARNING):
+                aliased, held = dec._step_alias_bytes()
+            if aliased is None:
+                pytest.skip("this backend's memory_analysis reports "
+                            "no alias_size_in_bytes")
+            assert aliased < held
+            assert "could not use every donated" in caplog.text
+
+    @pytest.mark.parametrize("kind", ["kv", "q8", "ssm"])
+    def test_migration_round_trips_between_steps(self, params, kind):
+        """`evacuate` (export_session on the loop thread) ->
+        `submit(resume=)` (import_kv_rows) and a prefill handoff
+        (import_kv_rows) stay bit-exact with donated steps before,
+        between and after them: nothing holds a pool across a step."""
+        from mxnet_tpu.serve import PrefillEngine
+        single, vocab = _step_pool(kind, params, 1)
+        donor, _ = _step_pool(kind, params, 2)
+        heir, _ = _step_pool(kind, params, 3)
+        rng = np.random.RandomState(7)
+        p, q, r = (rng.randint(1, vocab, (n,)) for n in (5, 4, 6))
+        opts = dict(temperature=0.8, top_k=8, seed=7)
+        with donor.serving_decoder() as d1, \
+                heir.serving_decoder() as d2:
+            other = d2.submit(q, 16)          # d2 steps all the while
+            fut = d1.submit(p, 10, **opts)
+            deadline = time.time() + 120.0
+            while len(fut.emitted) < 3:
+                assert time.time() < deadline
+                time.sleep(0.002)
+            assert d1.evacuate() == 1
+            with pytest.raises(SessionEvacuated) as ei:
+                fut.result(10.0)
+            resumed = d2.submit(p, 10, resume=ei.value.state, **opts)
+            shipped = d2.submit(
+                r, 7, handoff=PrefillEngine(single).prefill(r))
+            got = [f.result(120.0) for f in (resumed, shipped, other)]
+            st = d2.stats()
+            # the donor's pool survived its own export: it serves on
+            again = d1.submit(q, 5).result(120.0)
+        np.testing.assert_array_equal(
+            got[0], single.generate(p[None], 10, **opts)[0])
+        np.testing.assert_array_equal(
+            got[1], single.generate(r[None], 7)[0])
+        np.testing.assert_array_equal(
+            got[2], single.generate(q[None], 16)[0])
+        np.testing.assert_array_equal(
+            again, single.generate(q[None], 5)[0])
+        assert (st["resumed"], st["imported"]) == (1, 2)
+        assert st["prefills"] == 1            # `other` alone prefilled
+
+    @pytest.mark.parametrize("spec", [False, True],
+                             ids=["step", "spec-round"])
+    def test_failed_step_fails_its_rows_and_rebuilds_the_pool(
+            self, params, spec, caplog):
+        """A step that raises has consumed its donated pool. The
+        active sequences fail with the error, the pools are built
+        anew, the loop lives and never steps on the deleted buffers:
+        the next request is served, and served right."""
+        single = _gen(params, 1)
+        pool = _gen(params, 2)
+        rng = np.random.RandomState(5)
+        with (_spec_dec(pool) if spec else
+              pool.serving_decoder()) as dec:
+            real, armed = dec._step_fn, []
+
+            def failing(args, aux, rng_):
+                out = real(args, aux, rng_)
+                if armed:
+                    armed.pop()
+                    raise RuntimeError("injected step fault")
+                return out
+
+            dec._step_fn = failing
+            first = [dec.submit(rng.randint(1, V, (n,)), 12,
+                                speculative=spec) for n in (4, 6)]
+            deadline = time.time() + 120.0
+            while min(len(f.emitted) for f in first) < 2:
+                assert time.time() < deadline
+                time.sleep(0.002)
+            lost = dec._aux
+            armed.append(1)
+            for f in first:
+                with pytest.raises(RuntimeError,
+                                   match="injected step fault"):
+                    f.result(120.0)
+            prompt = rng.randint(1, V, (5,))
+            got = dec.submit(prompt, 6,
+                             speculative=spec).result(120.0)
+            st = dec.stats()
+            assert dec._thread.is_alive()
+            assert all(a.is_deleted() for a in lost.values())
+            assert not any(
+                a.is_deleted() for a in list(dec._aux.values()) +
+                list((dec._daux or {}).values()))
+        np.testing.assert_array_equal(
+            got, single.generate(prompt[None], 6)[0])
+        assert st["step_failures"] == 1
+        assert (st["finished"], st["active"]) == (1, 0)
+        assert "decode step failed" in caplog.text
