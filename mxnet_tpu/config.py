@@ -96,19 +96,6 @@ define("MXNET_NATIVE_RECORDIO", bool, True,
 define("MXNET_NATIVE_IMAGE", bool, True,
        "use the native C++ batched image decode+crop+resize pipeline "
        "when the augment list allows it")
-define("MXNET_POOL_DENSE_BWD", bool, False,
-       "max-pool backward as kh*kw dense passes instead of "
-       "SelectAndScatter (measured 10-12x slower on v5e; experiment)")
-define("MXNET_BN_IMPL", str, "",
-       "training BatchNorm impl: empty = two-pass autodiff (default) "
-       "| onepass = r4 closed-form custom_vjp core (experiment)")
-define("MXNET_BN_STATS", str, "",
-       "training BN statistics: empty = VPU reduce (default) | dot = "
-       "MXU contractions | auto = dot only at the measured winning "
-       "shape class (both lose whole-model on v5e; experiments)")
-define("MXNET_BN_PALLAS", bool, False,
-       "route 4-D NCHW training BatchNorm through the explicit-pass "
-       "Pallas kernels (measured slower on v5e; experiment)")
 define("MXNET_EMBED_GRAD", str, "",
        "Embedding backward: empty = the default (scatter-add; won the "
        "staged A/B at the flagship LM shape on the host CPU, not "

@@ -15,9 +15,6 @@ TPU-native notes:
 """
 from __future__ import annotations
 
-import functools
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -181,22 +178,6 @@ def _pooling(data, kernel=(), pool_type="max", stride=(), pad=(),
     if pool_type == "max":
         init = -jnp.inf if jnp.issubdtype(data.dtype, jnp.floating) else \
             jnp.iinfo(data.dtype).min
-        if nd == 2 and jnp.issubdtype(data.dtype, jnp.floating) and \
-                os.environ.get("MXNET_POOL_DENSE_BWD", "0") == "1":
-            # OFF by default: measured on a real v5e chip, the kh*kw
-            # dense formulation below is 10-12x SLOWER than XLA's
-            # SelectAndScatter autodiff at conv-net pool shapes (38 ms
-            # vs 3.6 ms fwd+bwd at the ResNet stem, bench_out/
-            # pool_micro.jsonl) — each of the 2*kh*kw passes streams
-            # the full padded tensor from HBM, swamping whatever the
-            # scatter serialization costs. Kept behind the env knob
-            # for its tie-SPLITTING subgradient (ties share dy/count;
-            # SelectAndScatter picks one winner) and as the A/B
-            # harness for benchmark/bench_pool.py. Reverse-mode only
-            # (custom_vjp): the default path is also what jvp users
-            # get.
-            return _max_pool2d_dense_bwd(data, kernel, stride,
-                                         padding[2:])
         return lax.reduce_window(data, init, lax.max, window, strides,
                                  padding)
     if pool_type == "avg":
@@ -211,175 +192,10 @@ def _pooling(data, kernel=(), pool_type="max", stride=(), pad=(),
     raise ValueError("unknown pool_type %r" % pool_type)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
-def _max_pool2d_dense_bwd(x, kernel, stride, pad2):
-    """2-D max pooling whose BACKWARD avoids SelectAndScatter.
-
-    Forward: the normal reduce_window max. Backward: for each kernel
-    offset (a, b), the strided slice of the (-inf padded) input that
-    fed the windows is compared against the pooled output; matches
-    route dy there via an interior-padded (dilated) dense pad — kh*kw
-    fully-vectorized passes instead of XLA's serialized scatter.
-
-    Subgradient choice: a window with TIED maxima SPLITS dy equally
-    among them (dy/count each) — magnitude-preserving, so tie-heavy
-    data (integer-grid pixels!) trains like the one-winner
-    SelectAndScatter; off ties the two are gradient-identical. The
-    reference's mshadow x==y routing gave every tie the FULL dy,
-    which measurably inflates gradients on quantized inputs (caught
-    by the real-digits convergence gate)."""
-    return _max_pool2d_fwd_impl(x, kernel, stride, pad2)
-
-
-def _max_pool2d_fwd_impl(x, kernel, stride, pad2):
-    window = (1, 1) + tuple(kernel)
-    strides = (1, 1) + tuple(stride)
-    padding = ((0, 0), (0, 0)) + tuple(pad2)
-    return lax.reduce_window(x, -jnp.inf, lax.max, window, strides,
-                             padding)
-
-
-def _max_pool2d_fwd(x, kernel, stride, pad2):
-    y = _max_pool2d_fwd_impl(x, kernel, stride, pad2)
-    return y, (x, y)
-
-
-def _max_pool2d_bwd(kernel, stride, pad2, res, dy):
-    x, y = res
-    (kh, kw), (sh, sw) = kernel, stride
-    (pt, pb), (pl, pr) = pad2
-    OH, OW = y.shape[2], y.shape[3]
-    xp = jnp.pad(x.astype(jnp.float32),
-                 ((0, 0), (0, 0), (pt, pb), (pl, pr)),
-                 constant_values=-jnp.inf)
-    yf = y.astype(jnp.float32)
-    HP, WP = xp.shape[2], xp.shape[3]
-
-    def window_views():
-        for a in range(kh):
-            for b in range(kw):
-                # windows' (a, b) elements, aligned with the output
-                yield a, b, lax.slice(
-                    xp, (0, 0, a, b),
-                    (xp.shape[0], xp.shape[1],
-                     a + sh * (OH - 1) + 1, b + sw * (OW - 1) + 1),
-                    (1, 1, sh, sw))
-
-    # pass 1: per-window tie count (== 1 off ties)
-    count = jnp.zeros_like(yf)
-    for _a, _b, x_ab in window_views():
-        count = count + (x_ab == yf).astype(jnp.float32)
-    share = dy.astype(jnp.float32) / count
-    # pass 2: route dy/count to every maximum — dilate by the stride
-    # and place at the offset: a pure pad, no scatter
-    dxp = jnp.zeros_like(xp)
-    for a, b, x_ab in window_views():
-        contrib = jnp.where(x_ab == yf, share, 0.0)
-        dxp = dxp + lax.pad(
-            contrib, jnp.float32(0),
-            ((0, 0, 0), (0, 0, 0),
-             (a, HP - a - (sh * (OH - 1) + 1), sh - 1),
-             (b, WP - b - (sw * (OW - 1) + 1), sw - 1)))
-    dx = dxp[:, :, pt:HP - pb, pl:WP - pr]
-    return (dx.astype(x.dtype),)
-
-
-_max_pool2d_dense_bwd.defvjp(_max_pool2d_fwd, _max_pool2d_bwd)
-
-
 # ---------------------------------------------------------------------------
 # BatchNorm — reference batch_norm-inl.h; aux moving stats are state.
 # fn returns (out[, mean, var], new_moving_mean, new_moving_var)
 # ---------------------------------------------------------------------------
-
-def _bn_train_core(data, g, beta, eps, red, bshape):
-    """Training-mode BN with ONE-PASS statistics and a closed-form
-    backward — the HBM-traffic-minimal formulation (this op was
-    measured at ~18% of the ResNet-50 step, docs/mfu_analysis.md):
-
-    forward: shifted sums sum(x-c) and sum((x-c)^2) are SIBLING
-    reductions over the same bf16 input (XLA fuses them into one loop
-    with f32 accumulators; jnp.var's E[(x-mean)^2] would chain two
-    dependent passes), then one read+write apply pass — 2 reads +
-    1 write total. The per-channel shift c (the first sample's channel
-    mean — a 1/N-of-the-data reduction, then an in-pass broadcast
-    subtract) removes the catastrophic cancellation of the naive
-    E[x^2]-E[x]^2 form when |mean| >> std: variance is
-    translation-invariant, and with c drawn from the batch itself the
-    shifted mean is O(std), giving two-pass-grade accuracy at
-    one-pass HBM cost (advisor r4).
-
-    backward: the textbook closed form
-        dx = (g*inv/m) * (m*dy - sum(dy) - xhat*sum(dy*xhat))
-    needs only the sibling pair sum(dy), sum(dy*xhat) (one pass over
-    dy,x) plus the dx pass — autodiff of the two-pass forward chains
-    dvar/dmean passes on top. The mean/var outputs' own cotangents
-    (nonzero when a graph differentiates through output_mean_var)
-    enter via d mean/dx = 1/m and d var/dx = 2(x-mean)/m, fused into
-    the same dx pass; training graphs pass zeros there and XLA folds
-    the terms away.
-
-    Returns (y, mean, var); callers thread moving stats outside (the
-    custom_vjp boundary must not capture them)."""
-
-    @jax.custom_vjp
-    def f(x, g, b):
-        y, mean, var, _inv = fwd_impl(x, g, b)
-        return y, mean, var
-
-    def fwd_impl(x, g, b):
-        m = 1
-        for i in red:
-            m *= x.shape[i]
-        xf = x.astype(jnp.float32)
-        # per-channel shift: the FIRST SAMPLE's channel mean — a
-        # reduction over 1/N of the data, so near-free next to the two
-        # main sums, but robust where a single anchor pixel is not
-        # (e.g. a zero-padded corner in a large-mean channel would
-        # reintroduce the very cancellation the shift removes)
-        x0 = lax.index_in_dim(xf, 0, red[0], keepdims=True)
-        cb = lax.stop_gradient(
-            jnp.mean(x0, axis=red, keepdims=True))   # bshape
-        c = cb.reshape(-1)                           # (C,)
-        s1 = jnp.sum(xf - cb, axis=red)
-        s2 = jnp.sum(jnp.square(xf - cb), axis=red)
-        mean_s = s1 / m
-        mean = c + mean_s
-        var = jnp.maximum(s2 / m - jnp.square(mean_s), 0.0)
-        inv = lax.rsqrt(var + eps)
-        y = ((xf - mean.reshape(bshape))
-             * (inv.reshape(bshape)
-                * g.reshape(bshape).astype(jnp.float32))
-             + b.reshape(bshape).astype(jnp.float32)).astype(x.dtype)
-        return y, mean, var, inv
-
-    def fwd(x, g, b):
-        y, mean, var, inv = fwd_impl(x, g, b)
-        return (y, mean, var), (x, g, mean, inv)
-
-    def bwd(res, cts):
-        dy = cts[0].astype(jnp.float32)
-        dmean = cts[1].astype(jnp.float32)
-        dvar = cts[2].astype(jnp.float32)
-        x, g, mean, inv = res
-        m = 1
-        for i in red:
-            m *= x.shape[i]
-        xc = x.astype(jnp.float32) - mean.reshape(bshape)
-        db = jnp.sum(dy, axis=red)                     # sibling pair:
-        dgx = jnp.sum(dy * xc, axis=red) * inv         # one pass
-        k = (g.astype(jnp.float32) * inv) / m
-        dx = (k.reshape(bshape)
-              * (m * dy - db.reshape(bshape)
-                 - xc * (inv * dgx).reshape(bshape))
-              # mean/var output cotangents (zero in training graphs)
-              + (dmean / m).reshape(bshape)
-              + (2.0 / m) * xc * dvar.reshape(bshape)).astype(x.dtype)
-        return dx, dgx.astype(g.dtype), db.astype(beta.dtype)
-
-    f.defvjp(fwd, bwd)
-    return f(data, g, beta)
-
 
 @register("BatchNorm", arg_names=("data", "gamma", "beta", "moving_mean",
                                   "moving_var"),
@@ -400,76 +216,21 @@ def _batch_norm(data, gamma, beta, moving_mean, moving_var, eps=1e-3,
     # discipline: bf16 activations, f32 batch stats), output back in the
     # input dtype so downstream convs see one dtype
     if is_train and not use_global_stats:
-        # fix_gamma: g is ones_like(gamma), so no gradient reaches
-        # gamma through the core (ones_like is a constant), matching
-        # the reference's zeroed fixed-gamma grad
-        import os as _os
-        if _os.environ.get("MXNET_BN_PALLAS") == "1" and \
-                data.ndim == 4 and axis == 1:
-            # below-XLA experiment: explicit-pass Pallas kernels
-            # (ops/bn_pallas.py) — same math, guaranteed 2-read
-            # forward / 2-read backward structure
-            from .bn_pallas import bn_train_pallas
-            out, mean, var = bn_train_pallas(data, g, beta,
-                                             float(eps))
-        elif _os.environ.get("MXNET_BN_IMPL") == "onepass":
-            # the r4 one-pass/closed-form custom_vjp rewrite — kept as
-            # an experiment, NOT the default: measured on a real v5e
-            # it is never faster than the plain autodiff form below
-            # and falls off a cliff at the ResNet stem shape (1831 ms
-            # vs 3.1 ms fwd+bwd at (128,64,112,112), bench_out/
-            # bn_micro.jsonl) — the custom_vjp boundary blocks the
-            # surrounding fusion the "one pass" was meant to buy
-            out, mean, var = _bn_train_core(data, g, beta, float(eps),
-                                            red, bshape)
-        else:
-            # default: plain two-pass statistics, autodiff backward —
-            # no custom_vjp boundary, so XLA fuses BN into the
-            # neighboring convs' epilogues freely. On-chip microbench
-            # and whole-model A/B both prefer this over the one-pass
-            # rewrite (bench_out/{bn_micro,ab_regression}.jsonl).
-            #
-            # MXNET_BN_STATS=dot|auto: statistics as MXU contractions
-            # (sum_nx x = ones-vector einsum, sum_nx x^2 = self inner
-            # product; bf16 x bf16 products are exact in the f32
-            # accumulator). The live micro A/B
-            # (bench_out/bn_stats_micro.jsonl) shows the VPU reduce
-            # wins at early-net shapes but LOSES at deep-stage shapes
-            # (C large, HW small: 1.8x at 1024x14^2) — 'auto' applies
-            # the contraction only there (C >= 2*H*W). One-pass
-            # E[x^2]-E[x]^2 in f32: fine for post-conv activations,
-            # degrades when |mean|/std > ~3e3 (the two-pass default
-            # has no such limit).
-            stats = _os.environ.get("MXNET_BN_STATS", "")
-            dot_ok = (stats in ("dot", "auto") and data.ndim == 4
-                      and axis == 1)
-            if dot_ok and stats == "auto":
-                # gate to the one measured crossover class (the
-                # 1024x14^2 row of bn_stats_micro.jsonl, 1.8x): big C
-                # with a not-tiny spatial extent. 2048x7^2 also has
-                # C >= 2*HW but measured 0.94x, hence the HW floor.
-                hw = data.shape[2] * data.shape[3]
-                dot_ok = data.shape[1] >= 2 * hw and hw >= 128
-            xf = data.astype(jnp.float32)
-            if dot_ok:
-                N, C, H, W = data.shape
-                m = N * H * W
-                x3 = data.reshape(N, C, H * W)
-                ones = jnp.ones((N, H * W), data.dtype)
-                s1 = jnp.einsum("ncx,nx->c", x3, ones,
-                                preferred_element_type=jnp.float32)
-                s2 = jnp.einsum("ncx,ncx->c", x3, x3,
-                                preferred_element_type=jnp.float32)
-                mean = s1 / m
-                var = jnp.maximum(s2 / m - jnp.square(mean), 0.0)
-            else:
-                mean = jnp.mean(xf, axis=red)
-                var = jnp.var(xf, axis=red)
-            inv = lax.rsqrt(var.reshape(bshape) + eps)
-            out = ((xf - mean.reshape(bshape)) * inv
-                   * g.reshape(bshape).astype(jnp.float32)
-                   + beta.reshape(bshape).astype(jnp.float32)
-                   ).astype(data.dtype)
+        # Plain mean/var with autodiff backward: no custom_vjp boundary,
+        # so XLA fuses the statistics into the neighbouring convolutions.
+        # The rewrites tried against it (one-pass closed-form vjp, einsum
+        # statistics always and shape-gated, Pallas; and a dense max-pool
+        # backward) all lost on the chip: bench_out/ab_regression.jsonl.
+        # fix_gamma: g is ones_like(gamma), a constant, so gamma's
+        # gradient is zero as in the reference.
+        xf = data.astype(jnp.float32)
+        mean = jnp.mean(xf, axis=red)
+        var = jnp.var(xf, axis=red)
+        inv = lax.rsqrt(var.reshape(bshape) + eps)
+        out = ((xf - mean.reshape(bshape)) * inv
+               * g.reshape(bshape).astype(jnp.float32)
+               + beta.reshape(bshape).astype(jnp.float32)
+               ).astype(data.dtype)
         new_mm = moving_mean * momentum + mean * (1 - momentum)
         new_mv = moving_var * momentum + var * (1 - momentum)
         use_mean, use_var = mean, var

@@ -122,9 +122,9 @@ def test_perf_tables_renders_from_committed_captures():
 
 
 def test_perf_tables_excludes_ab_experiment_rows(tmp_path):
-    """A/B rows (tools/tpu_ab_regression.sh tags ab_config) measure
-    deliberately non-default configs; a newer experiment row must
-    never shadow the headline capture."""
+    """A/B rows (tagged ab_config, as bench_out/ab_regression.jsonl's
+    are) measure deliberately non-default configs; a newer experiment
+    row must never shadow the headline capture."""
     import json
     pt = _load_perf_tables()
     rec = {"metric": "resnet50_train_throughput", "unit": "img/s",

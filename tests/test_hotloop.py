@@ -381,26 +381,3 @@ def test_compile_cache_dir_default_is_fixed_in_checkout(cache_dirs):
     _, seen = cache_dirs
     assert seen[1] == os.path.join(_REPO, ".jax_cache")
     assert seen[2] == seen[1]
-
-
-# ---------------------------------------------------------------------------
-# bench_bn env hygiene (satellite)
-# ---------------------------------------------------------------------------
-
-def test_bench_bn_does_not_leak_bn_impl_env():
-    import jax.numpy as jnp
-    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmark"))
-    import bench_bn
-    prev = os.environ.pop("MXNET_BN_IMPL", None)
-    try:
-        x = jnp.ones((2, 3, 4, 4), jnp.float32)
-        bench_bn.framework_bn(x, jnp.ones(3), jnp.zeros(3))
-        assert "MXNET_BN_IMPL" not in os.environ
-        os.environ["MXNET_BN_IMPL"] = "sentinel"
-        bench_bn.framework_bn(x, jnp.ones(3), jnp.zeros(3))
-        assert os.environ["MXNET_BN_IMPL"] == "sentinel"
-    finally:
-        os.environ.pop("MXNET_BN_IMPL", None)
-        if prev is not None:
-            os.environ["MXNET_BN_IMPL"] = prev

@@ -3,8 +3,8 @@ docs/perf_gates.md): fingerprint extraction, the --bless round trip,
 and — the load-bearing part — that each class of injected regression
 (an extra per-step host sync, a steady-state recompile, a missing
 trace span, a vanished counter) FAILS the gate with a diagnostic
-naming the PR-won property it protects, while seeded ±25% time jitter
-does NOT flap the noise-tolerant time bounds."""
+naming the PR-won property it protects. The gate counts and does not
+time: a fingerprint carries no `times`."""
 import copy
 import importlib.util
 import json
@@ -50,7 +50,7 @@ def _synthetic_records():
         {"v": 1, "kind": "step", "step": 2, "wall_ms": 12.0,
          "samples": 24},
         {"v": 1, "kind": "event", "event": "gate.probe",
-         "fields": {"max_step_syncs_steady": 1, "elapsed_ms": 150.0}},
+         "fields": {"max_step_syncs_steady": 1}},
         {"v": 1, "kind": "snapshot", "metrics": {
             "host_syncs": {"type": "counter", "value": 4},
             "ps.retries": {"type": "counter", "value": 2},
@@ -76,7 +76,7 @@ def _fingerprint(pg, scenario="trainstep"):
 
 
 def _baseline(pg, fp):
-    return {"scenario": fp["scenario"], "time_ratio": 3.0,
+    return {"scenario": fp["scenario"],
             "fingerprint": copy.deepcopy(fp)}
 
 
@@ -94,13 +94,6 @@ def test_fingerprint_extraction_and_self_compare(pg):
     # gauge values normalize to int so baselines read cleanly
     assert fp["counts"]["gauges"]["trainstep.jit_cache_size"] == 1
     assert fp["counts"]["probe"]["max_step_syncs_steady"] == 1
-    # probe *_ms fields route to the ratio-compared times, not counts
-    assert fp["times"]["elapsed_ms"] == 150.0
-    assert "elapsed_ms" not in fp["counts"]["probe"]
-    # steady-state p50 excludes the compile-flagged step (nearest-rank
-    # with banker's rounding: index round(0.5) == 0 -> 10.0, the
-    # telemetry_report._quantile convention)
-    assert fp["times"]["step_ms_p50"] == 10.0
     assert fp["trace"]["spans"] == ["step.window_wait", "train.step"]
     assert fp["trace"]["edges"] == [
         "train.step>guardrail.masked_step",
@@ -180,33 +173,27 @@ def test_new_untracked_field_asks_for_rebless(pg):
 
 
 # ---------------------------------------------------------------------------
-# time bounds: ±25% seeded jitter never flaps, big regressions fail
+# the gate does not time; a baseline from when it did is refused
 # ---------------------------------------------------------------------------
 
-def test_time_jitter_tolerated_but_blowup_fails(pg):
-    import random
+def test_no_times_and_old_schema_baseline_refused(pg):
     fp = _fingerprint(pg)
-    base = _baseline(pg, fp)
-    rng = random.Random(12345)
-    for _ in range(20):                       # seeded ±25% jitter
-        live = copy.deepcopy(fp)
-        jitter = 1.0 + rng.uniform(-0.25, 0.25)
-        live["times"] = {k: v * jitter for k, v in fp["times"].items()}
-        assert pg.compare(base, live) == [], \
-            "time gate flapped at %.2fx" % jitter
-    live = copy.deepcopy(fp)
-    live["times"]["step_ms_p50"] = fp["times"]["step_ms_p50"] * 4.0
-    fails = pg.compare(base, live)
-    assert fails and "times.step_ms_p50" in fails[0].format()
-    assert "ratio" in fails[0].format()
-    # --no-time escape hatch
-    assert pg.compare(base, live, check_times=False) == []
-    # env override widens the tolerance
-    os.environ["MXNET_GATE_TIME_RATIO"] = "10"
-    try:
-        assert pg.compare(base, live) == []
-    finally:
-        del os.environ["MXNET_GATE_TIME_RATIO"]
+    assert set(fp) == {"gate_schema", "scenario", "counts", "trace"}
+    # the steps' wall_ms, whatever they read, are not in the fingerprint
+    journal, trace = _synthetic_records()
+    for r in journal:
+        if r["kind"] == "step":
+            r["wall_ms"] *= 40.0
+    assert pg.extract_fingerprint("trainstep", journal, trace) == fp
+    # a schema-1 baseline (it carried `times` and a `time_ratio`) is
+    # refused whole by the schema check, not compared on what is left
+    old = _baseline(pg, fp)
+    old["time_ratio"] = 3.0
+    old["fingerprint"]["gate_schema"] = 1
+    old["fingerprint"]["times"] = {"step_ms_p50": 10.0}
+    fails = pg.compare(old, fp)
+    assert [f.path for f in fails] == ["gate_schema"]
+    assert (fails[0].baseline, fails[0].live) == (1, pg.GATE_SCHEMA)
 
 
 # ---------------------------------------------------------------------------
@@ -223,8 +210,9 @@ def test_committed_baselines_parse_and_cover_scenarios(pg):
         fp = base["fingerprint"]
         assert fp["gate_schema"] == pg.GATE_SCHEMA
         assert fp["scenario"] == name
-        for key in ("counts", "trace", "times"):
-            assert key in fp, (name, key)
+        assert set(fp) == {"gate_schema", "scenario", "counts",
+                           "trace"}, name
+        assert "time_ratio" not in base, name
         assert fp["counts"]["journal_schema"] == 1
         # a baseline must compare clean against itself
         assert pg.compare(base, fp) == []
@@ -250,7 +238,7 @@ def test_trainstep_scenario_bless_and_recheck_deterministic(
         pg, tmp_path):
     """Acceptance: run the trainstep scenario twice back-to-back on
     CPU; --bless from run 1, compare run 2 — every count/shape field
-    identical (times go through the ratio gate)."""
+    identical."""
     fp1, err = pg.run_scenario("trainstep", str(tmp_path / "r1"))
     assert err is None, err
     path = pg.bless("trainstep", fp1, str(tmp_path / "bl"))
@@ -274,12 +262,11 @@ def test_trainstep_scenario_bless_and_recheck_deterministic(
 
 @pytest.mark.slow
 def test_full_gate_all_scenarios_bless_then_pass(pg, tmp_path):
-    """All six scenarios, blessed then re-checked (times skipped —
-    absolute walls belong to the blessing machine)."""
+    """Every scenario, blessed then re-checked."""
     rc = pg.main(["--bless", "--baselines", str(tmp_path / "bl"),
                   "--keep", str(tmp_path / "runs1")])
     assert rc == 0
-    rc = pg.main(["--baselines", str(tmp_path / "bl"), "--no-time",
+    rc = pg.main(["--baselines", str(tmp_path / "bl"),
                   "--keep", str(tmp_path / "runs2")])
     assert rc == 0
 

@@ -16,12 +16,10 @@ trace spill those PRs built into ONE enforcement surface:
   per-step blocking-host-sync counts, compile-event counts and which
   step carries them, the jit-cache size across donated steps, the
   trace-span vocabulary/nesting shape, the journal schema version,
-  key counter values (ps.retries, guardrail.masked_steps, serve.shed)
-  and noise-tolerant CPU step-time figures;
+  key counter values (ps.retries, guardrail.masked_steps, serve.shed).
+  No times: speed is measured on the chip, by ``cellbench``;
 * the fingerprint is compared against the committed baseline in
-  ``perf_baselines/<scenario>.json`` — EXACT match for every count and
-  shape field, a ratio tolerance (default 3x, env
-  ``MXNET_GATE_TIME_RATIO``) for wall-clock times;
+  ``perf_baselines/<scenario>.json`` — EXACT match for every field;
 * a failure prints which field diverged AND which PR-won property that
   field protects, so a gate failure reads as "you reintroduced a
   per-step host sync", not as a JSON diff.
@@ -30,7 +28,6 @@ trace spill those PRs built into ONE enforcement surface:
     python tools/perf_gate.py --scenario trainstep,gspmd
     python tools/perf_gate.py --bless            # regenerate baselines
     python tools/perf_gate.py --keep /tmp/gate   # keep run artifacts
-    python tools/perf_gate.py --no-time          # skip the time bounds
 
 ``tools/perf_gate.sh`` runs this gate plus every smoke-lint and marker
 test subset — the one builder entrypoint. Count/shape fields are
@@ -48,8 +45,7 @@ _SELF = os.path.abspath(__file__)
 _REPO = os.path.dirname(os.path.dirname(_SELF))
 sys.path.insert(0, _REPO)
 
-GATE_SCHEMA = 1
-DEFAULT_TIME_RATIO = 3.0
+GATE_SCHEMA = 2
 BASELINE_DIR = os.path.join(_REPO, "perf_baselines")
 
 
@@ -58,9 +54,9 @@ BASELINE_DIR = os.path.join(_REPO, "perf_baselines")
 # ---------------------------------------------------------------------------
 # Every workload must be CPU-deterministic: fixed seeds, fixed fault
 # specs, sequential request submission where concurrency would make
-# event counts racy. Each emits a `gate.probe` journal event carrying
-# the in-process measurements a journal record can't (host-sync deltas);
-# everything else is read back from the journal + trace spill.
+# event counts racy. The fit scenarios emit a `gate.probe` journal event
+# carrying the in-process measurements a journal record can't (host-sync
+# deltas); everything else is read back from the journal + trace spill.
 
 def _mlp(classes=2, hidden=32):
     import mxnet_tpu as mx
@@ -168,11 +164,9 @@ def _scn_ps_faults():
     import numpy as np
 
     import mxnet_tpu as mx
-    from mxnet_tpu import telemetry
     from mxnet_tpu.parallel.ps_async import AsyncPSClient, AsyncPSServer
     from mxnet_tpu.parallel.resilience import (FaultInjector,
                                                install_fault_injector)
-    t0 = telemetry.now_ms()
     srv = AsyncPSServer(host="127.0.0.1", port=0, num_workers=1)
     threading.Thread(target=srv.serve_forever, daemon=True).start()
     c = AsyncPSClient(host="127.0.0.1", port=srv.port)
@@ -191,9 +185,6 @@ def _scn_ps_faults():
     srv.stop()
     assert inj.fired == [("send", 3, "disconnect"),
                          ("recv", 6, "drop")], inj.fired
-    telemetry.journal_event("gate.probe",
-                            ps_elapsed_ms=round(
-                                telemetry.now_ms() - t0, 3))
 
 
 def _serve_predictor(feat=8, classes=4):
@@ -224,9 +215,7 @@ def _scn_serve():
     count is exact."""
     import numpy as np
 
-    from mxnet_tpu import telemetry
     from mxnet_tpu.serve import Overloaded, ServeEngine
-    t0 = telemetry.now_ms()
     pred = _serve_predictor()
     x = np.zeros((1, 8), np.float32)
     with ServeEngine(pred, buckets=(1, 2, 4), max_wait_ms=0.0,
@@ -243,9 +232,6 @@ def _scn_serve():
                 eng.submit(x)
             except Overloaded:
                 pass
-    telemetry.journal_event("gate.probe",
-                            serve_elapsed_ms=round(
-                                telemetry.now_ms() - t0, 3))
 
 
 def _scn_router():
@@ -258,9 +244,7 @@ def _scn_router():
     all deterministic."""
     import numpy as np
 
-    from mxnet_tpu import telemetry
     from mxnet_tpu.serve import ServeEngine, ServeRouter, ServeServer
-    t0 = telemetry.now_ms()
     pred = _serve_predictor()
     x = np.zeros((1, 8), np.float32)
 
@@ -291,9 +275,6 @@ def _scn_router():
     router.close()
     for closer in (s1, live["s"], e1, live["e"]):
         closer.close()
-    telemetry.journal_event("gate.probe",
-                            router_elapsed_ms=round(
-                                telemetry.now_ms() - t0, 3))
 
 
 def _decode_workload(quantize_kv, block_type="attention"):
@@ -304,12 +285,10 @@ def _decode_workload(quantize_kv, block_type="attention"):
     import numpy as np
 
     import mxnet_tpu as mx
-    from mxnet_tpu import telemetry
     from mxnet_tpu.generation import Generator
     from mxnet_tpu.initializer import Xavier
     from mxnet_tpu.models import transformer
     from mxnet_tpu.parallel import make_train_step
-    t0 = telemetry.now_ms()
     V, L, H, DIM, T = 50, 2, 2, 32, 24
     sym = transformer.get_symbol(V, 12, num_layers=L, num_heads=H,
                                  dim=DIM, max_len=T,
@@ -326,9 +305,6 @@ def _decode_workload(quantize_kv, block_type="attention"):
         for length, max_new in ((4, 5), (6, 3), (3, 4)):
             dec.submit(np.arange(length), max_new,
                        eos_id=None).result(300.0)
-    telemetry.journal_event("gate.probe",
-                            decode_elapsed_ms=round(
-                                telemetry.now_ms() - t0, 3))
 
 
 def _scn_disagg():
@@ -342,7 +318,6 @@ def _scn_disagg():
     import numpy as np
 
     import mxnet_tpu as mx
-    from mxnet_tpu import telemetry
     from mxnet_tpu.generation import Generator
     from mxnet_tpu.initializer import Xavier
     from mxnet_tpu.models import transformer
@@ -351,7 +326,6 @@ def _scn_disagg():
                                                install_fault_injector)
     from mxnet_tpu.serve import (ContinuousDecoder, PrefillEngine,
                                  ServeRouter, ServeServer)
-    t0 = telemetry.now_ms()
     V, L, H, DIM, T = 50, 2, 2, 32, 24
     sym = transformer.get_symbol(V, 12, num_layers=L, num_heads=H,
                                  dim=DIM, max_len=T,
@@ -385,9 +359,6 @@ def _scn_disagg():
     router.close()
     for closer in (s1, s2, dec, pre):
         closer.close()
-    telemetry.journal_event("gate.probe",
-                            disagg_elapsed_ms=round(
-                                telemetry.now_ms() - t0, 3))
 
 
 def _scn_failover():
@@ -417,7 +388,6 @@ def _scn_failover():
     from mxnet_tpu.parallel.resilience import (FaultInjector,
                                                install_fault_injector)
     from mxnet_tpu.serve import ContinuousDecoder, ServeRouter, ServeServer
-    t0 = telemetry.now_ms()
     V, L, H, DIM, T = 50, 2, 2, 32, 24
     sym = transformer.get_symbol(V, 12, num_layers=L, num_heads=H,
                                  dim=DIM, max_len=T,
@@ -487,9 +457,6 @@ def _scn_failover():
     router.close()
     for closer in (s0, s1, d0, d1):
         closer.close()
-    telemetry.journal_event("gate.probe",
-                            failover_elapsed_ms=round(
-                                telemetry.now_ms() - t0, 3))
 
 
 def _scn_decode():
@@ -537,7 +504,6 @@ def _scn_streaming():
     from mxnet_tpu.parallel import make_train_step
     from mxnet_tpu.serve import ContinuousDecoder, ServeServer
     from mxnet_tpu.serve.net import ServeClient
-    t0 = telemetry.now_ms()
     V, L, H, DIM, T = 50, 2, 2, 32, 24
     sym = transformer.get_symbol(V, 12, num_layers=L, num_heads=H,
                                  dim=DIM, max_len=T)
@@ -583,9 +549,6 @@ def _scn_streaming():
     assert cval("serve.decode.prefill_chunks") == 4
     srv.close()
     dec.close()
-    telemetry.journal_event("gate.probe",
-                            streaming_elapsed_ms=round(
-                                telemetry.now_ms() - t0, 3))
 
 
 def _scn_spec_decode():
@@ -609,7 +572,6 @@ def _scn_spec_decode():
     from mxnet_tpu.parallel import make_train_step
     from mxnet_tpu.serve import ContinuousDecoder, ServeServer
     from mxnet_tpu.serve.net import ServeClient
-    t0 = telemetry.now_ms()
     V, L, H, DIM, T = 50, 2, 2, 32, 24
     sym = transformer.get_symbol(V, 12, num_layers=L, num_heads=H,
                                  dim=DIM, max_len=T)
@@ -653,9 +615,6 @@ def _scn_spec_decode():
     assert gval("serve.spec.draft_jit_cache_size") == 1
     srv.close()
     dec.close()
-    telemetry.journal_event("gate.probe",
-                            spec_decode_elapsed_ms=round(
-                                telemetry.now_ms() - t0, 3))
 
 
 def _scn_controller():
@@ -669,10 +628,8 @@ def _scn_controller():
     exact."""
     import numpy as np
 
-    from mxnet_tpu import telemetry
     from mxnet_tpu.serve import (FleetController, ServeEngine,
                                  ServeRouter, ServeServer)
-    t0 = telemetry.now_ms()
     pred = _serve_predictor()
     x = np.zeros((1, 8), np.float32)
 
@@ -739,9 +696,6 @@ def _scn_controller():
     for eng, srv in list(cells.values()):
         srv.close()
         eng.close()
-    telemetry.journal_event("gate.probe",
-                            controller_elapsed_ms=round(
-                                telemetry.now_ms() - t0, 3))
 
 
 # which PR-won property each gauge protects is resolved through
@@ -1017,9 +971,6 @@ _PROPERTY_NOTES = (
      "PR 10 tracing: the span vocabulary / nesting shape of this "
      "path (a span that disappears or re-parents breaks trace "
      "consumers and usually marks deleted instrumentation)"),
-    ("times.",
-     "noise-tolerant CPU time bound (ratio tolerance, not exact — "
-     "see --no-time / MXNET_GATE_TIME_RATIO)"),
 )
 
 
@@ -1050,12 +1001,12 @@ def _intish(v):
 
 
 def extract_fingerprint(scenario, journal_records, trace_records):
-    """The gate fingerprint: counts/shapes (exact-compared) + times
-    (ratio-compared) from one scenario run's journal and trace spill."""
+    """The gate fingerprint: counts and trace shape (exact-compared)
+    from one scenario run's journal and trace spill."""
     from mxnet_tpu.trace import span_shape
 
     cfg = SCENARIOS[scenario]
-    counts, times = {}, {}
+    counts = {}
     run_start = next((r for r in journal_records
                       if r.get("kind") == "run_start"), None)
     counts["journal_schema"] = (run_start or {}).get("schema")
@@ -1071,11 +1022,7 @@ def extract_fingerprint(scenario, journal_records, trace_records):
         ev = r.get("event", "?")
         events[ev] = events.get(ev, 0) + 1
         if ev == "gate.probe":
-            for k, v in (r.get("fields") or {}).items():
-                if k.endswith("_ms"):
-                    times[k] = v
-                else:
-                    probe[k] = v
+            probe.update(r.get("fields") or {})
     counts["compile_events"] = events.get("compile", 0)
     counts["events"] = {k: v for k, v in sorted(events.items())
                         if k not in cfg["noisy_events"]}
@@ -1093,15 +1040,8 @@ def extract_fingerprint(scenario, journal_records, trace_records):
         # exact value are both deterministic, so keep it exact
         counts["gauges"][g] = _intish(val) if val is not None else None
 
-    steady = sorted(float(s.get("wall_ms", 0.0)) for s in steps
-                    if not s.get("compile"))
-    if steady:
-        times["step_ms_p50"] = round(
-            steady[int(round(0.5 * (len(steady) - 1)))], 3)
-
     return {"gate_schema": GATE_SCHEMA, "scenario": scenario,
-            "counts": counts, "trace": span_shape(trace_records),
-            "times": times}
+            "counts": counts, "trace": span_shape(trace_records)}
 
 
 # ---------------------------------------------------------------------------
@@ -1142,19 +1082,9 @@ def _cmp_tree(path, base, live, fails):
         fails.append(Failure(path, base, live))
 
 
-def time_ratio_for(baseline, override=None):
-    if override is not None:
-        return float(override)
-    env = os.environ.get("MXNET_GATE_TIME_RATIO")
-    if env:
-        return float(env)
-    return float(baseline.get("time_ratio") or DEFAULT_TIME_RATIO)
-
-
-def compare(baseline, live, time_ratio=None, check_times=True):
+def compare(baseline, live):
     """Baseline record (the perf_baselines/*.json dict) vs a live
-    fingerprint -> list of Failure. Counts and trace shape are exact;
-    times fail only beyond `time_ratio` x baseline."""
+    fingerprint -> list of Failure. Every field is exact."""
     fails = []
     bfp = baseline["fingerprint"]
     if bfp.get("gate_schema") != live.get("gate_schema"):
@@ -1163,20 +1093,6 @@ def compare(baseline, live, time_ratio=None, check_times=True):
         return fails
     _cmp_tree("counts", bfp.get("counts"), live.get("counts"), fails)
     _cmp_tree("trace", bfp.get("trace"), live.get("trace"), fails)
-    if check_times:
-        ratio = time_ratio_for(baseline, time_ratio)
-        for k, bv in sorted((bfp.get("times") or {}).items()):
-            lv = (live.get("times") or {}).get(k)
-            if lv is None:
-                # a vanished time field means the probe/step records
-                # that produced it stopped being emitted — deleted
-                # instrumentation, not noise
-                fails.append(Failure("times." + k, bv, None,
-                                     "missing from live run"))
-            elif bv and float(lv) > float(bv) * ratio:
-                fails.append(Failure(
-                    "times." + k, bv, lv,
-                    "exceeds %.2gx ratio tolerance" % ratio))
     return fails
 
 
@@ -1257,7 +1173,6 @@ def bless(name, fingerprint, baselines=None):
     os.makedirs(os.path.dirname(path), exist_ok=True)
     rec = {"scenario": name,
            "description": SCENARIOS[name]["desc"],
-           "time_ratio": DEFAULT_TIME_RATIO,
            "bless_cmd": "python tools/perf_gate.py --bless "
                         "--scenario " + name,
            "fingerprint": fingerprint}
@@ -1275,12 +1190,9 @@ def _child_main(name):
     gate failure, not a traceback."""
     fn = SCENARIOS[name]["fn"]
     from mxnet_tpu import telemetry, trace
-    t0 = telemetry.now_ms()
     telemetry.start_journal()
     trace.start_tracing()
     fn()
-    telemetry.journal_event(
-        "gate.probe", elapsed_ms=round(telemetry.now_ms() - t0, 3))
     trace.stop_tracing()
     telemetry.close_journal()
 
@@ -1297,10 +1209,6 @@ def main(argv=None):
                    help="baseline dir (default perf_baselines/)")
     p.add_argument("--keep", default=None, metavar="DIR",
                    help="keep per-scenario journals/traces under DIR")
-    p.add_argument("--no-time", action="store_true",
-                   help="skip the wall-clock ratio checks")
-    p.add_argument("--time-ratio", type=float, default=None,
-                   help="override the time ratio tolerance")
     p.add_argument("--json", action="store_true",
                    help="emit a machine-readable result")
     p.add_argument("--run-scenario", default=None,
@@ -1348,8 +1256,7 @@ def main(argv=None):
             print("  %-10s ERROR no readable baseline (%s) — run "
                   "--bless and commit it" % (name, e))
             continue
-        fails = compare(base, fp, time_ratio=args.time_ratio,
-                        check_times=not args.no_time)
+        fails = compare(base, fp)
         if fails:
             failed = True
             results[name] = {"status": "fail",

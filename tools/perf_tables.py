@@ -10,9 +10,10 @@ session is one command:
 Reads every *.json / *.jsonl under bench_out/ (one JSON object per
 line), groups by metric, and prints the most recent record per
 (metric, variant-ish key). Records with value=null are skipped, and so
-are A/B experiment rows (`ab_config` tag from tpu_ab_regression.sh) —
-they measure deliberately non-default configs and must never shadow
-the numbers of record in these tables.
+are A/B experiment rows (`ab_config` tag, as in the 2026-08-01
+bench_out/ab_regression.jsonl, each row one since-deleted variant of
+BatchNorm or Pooling) — they measure deliberately non-default configs
+and must never shadow the numbers of record in these tables.
 """
 from __future__ import annotations
 
@@ -25,9 +26,10 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def is_experiment_row(rec):
-    """True for A/B experiment records (tools/tpu_ab_regression.sh
-    tags them ab_config) — deliberately non-default configurations
-    that must never be selected as a number of record."""
+    """True for A/B experiment records (tagged ab_config, as the rows
+    of bench_out/ab_regression.jsonl are) — deliberately non-default
+    configurations that must never be selected as a number of
+    record."""
     return bool(rec.get("ab_config"))
 
 

@@ -156,12 +156,6 @@ capa "$LCTX" lctx:49152w4096chunk env BENCH_ITERS=3 \
     --remat
 [ -s "$LCTX" ] && mv "$LCTX" "$OUT/longcontext.jsonl" || rm -f "$LCTX"
 
-echo "== 3d0. BatchNorm one-pass vs two-pass microbench =="
-cap "$OUT/bn_micro.jsonl" bn_micro python benchmark/bench_bn.py
-
-echo "== 3d1. max-pool dense backward vs SelectAndScatter =="
-cap "$OUT/pool_micro.jsonl" pool_micro python benchmark/bench_pool.py
-
 echo "== 3d2. embedding-grad formulation (scatter vs segsum vs matmul) =="
 # BENCH_EMBGRAD_MODEL=1 adds the whole-model A/B (two bench.py runs):
 # the round-5 lesson is that micro wins routinely lose at model level,
