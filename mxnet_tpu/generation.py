@@ -177,21 +177,21 @@ class Generator:
         wanted = set(sym.list_arguments())
         self._params = {k: _raw(k, v) for k, v in arg_params.items()
                         if k in wanted}
-        # cache placement: batch over 'data', heads over 'model'
+        # cache placement: batch over 'data', heads over 'model' —
+        # a row holds its kv heads side by side, so splitting the row
+        # axis (Hkv*hd, or Hkv for the int8 scales) splits the heads
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
-            spec = [None, None, None, None]
+            spec = [None, None, None]
             if "data" in mesh.axis_names and \
                     batch_size % mesh.shape["data"] == 0:
                 spec[0] = "data"
             if "model" in mesh.axis_names and \
                     kv_heads % mesh.shape["model"] == 0:
-                spec[1] = "model"
+                spec[2] = "model"
             self._cache_sharding = NamedSharding(mesh, P(*spec))
-            self._scale_sharding = NamedSharding(mesh, P(*spec[:3]))
         else:
             self._cache_sharding = None
-            self._scale_sharding = None
         missing = wanted - set(self._params) - {
             "data", "positions", "cache_pos"}
         if missing:
@@ -215,9 +215,12 @@ class Generator:
         cache_dtype = jnp.dtype(dtype) if dtype else next(
             v.dtype for v in self._params.values()
             if jnp.issubdtype(v.dtype, jnp.floating))
-        # GQA: caches hold only the kv heads (the memory win)
-        self._cache_shape = (self.batch_size, kv_heads, self.max_len,
-                             head_dim)
+        # GQA: caches hold only the kv heads (the memory win), one
+        # token's heads side by side in one row (ops/attention.py::
+        # cached_attention); the int8 scales are a value a head
+        self._kv_heads = kv_heads
+        self._cache_shape = (self.batch_size, self.max_len,
+                             kv_heads * head_dim)
         self._cache_dtype = cache_dtype
         # SSM layers: one (B, H, hd, hd) recurrent-state blob each,
         # ALWAYS f32 regardless of compute dtype — the bit-identical-
@@ -265,23 +268,45 @@ class Generator:
             return self._state_shape, jnp.dtype(jnp.float32)
         if name.endswith(("_k_scale", "_v_scale")):
             # per-token dequant scales for the int8 caches
-            return self._cache_shape[:3], jnp.dtype(jnp.float32)
+            return (self._cache_shape[:2] + (self._kv_heads,),
+                    jnp.dtype(jnp.float32))
         if self._quantize_kv:
             return self._cache_shape, jnp.dtype(jnp.int8)
         return self._cache_shape, jnp.dtype(self._cache_dtype)
 
     def _aux_row_shape(self, name, pos):
         """Shape of ONE batch row's exported state for aux ``name`` at
-        sequence position ``pos``: length-indexed caches ship their
-        ``[:, :pos]`` prefix; SSM state blobs have no length axis and
-        ship whole (the O(1)-handoff property — blob bytes constant in
-        prompt length). Shared by export_kv_rows and the serving
-        side's import validation so the two ends of a handoff can
-        never disagree."""
+        sequence position ``pos``, ON THE WIRE: length-indexed caches
+        ship their first ``pos`` tokens head-major, ``(Hkv, pos, hd)``
+        (the int8 scales ``(Hkv, pos)``), which is blob format ``"v":
+        1`` whatever layout the device keeps (:meth:`_wire_rows`); SSM
+        state blobs have no length axis and ship whole (the
+        O(1)-handoff property — blob bytes constant in prompt length).
+        Shared by export_kv_rows and the serving side's import
+        validation so the two ends of a handoff can never disagree."""
         shape, _ = self._aux_spec(name)
         if name.endswith("_state"):
             return shape[1:]
-        return (shape[1], pos) + shape[3:]
+        if name.endswith(("_k_scale", "_v_scale")):
+            return (self._kv_heads, pos)
+        return (self._kv_heads, pos, shape[2] // self._kv_heads)
+
+    def _wire_rows(self, name, rows, to_wire):
+        """One sequence's state between the device's layout and the
+        wire's (see _aux_row_shape): a length-indexed cache's
+        ``(pos, Hkv*hd)`` token rows become ``(Hkv, pos, hd)`` and
+        back (the scales' ``(pos, Hkv)`` become ``(Hkv, pos)``); state
+        blobs pass as they are. The one transpose of a handoff: it
+        sits in the export program and in the import scatter."""
+        if name.endswith("_state"):
+            return rows
+        if to_wire:
+            return jnp.moveaxis(
+                rows.reshape(rows.shape[0], self._kv_heads, -1), 0, 1
+            ).reshape(self._aux_row_shape(name, rows.shape[0]))
+        pos = rows.shape[1]
+        return jnp.moveaxis(rows.reshape(self._kv_heads, pos, -1),
+                            0, 1).reshape(pos, -1)
 
     def kv_cache_bytes(self):
         """Total bytes of the decode-state aux pytree (every layer's
@@ -335,9 +360,10 @@ class Generator:
         ``aux``: a state pytree this Generator produced (typically the
         prefill output); ``row``: which batch row to export; ``pos``:
         how many tokens of state that row holds. Length-indexed caches
-        contribute their ``[row, :, :pos, ...]`` prefix — the int8 k/v
-        rows AND their per-token f32 scale rows under ``quantize_kv``,
-        or the bf16/f32 rows otherwise; SSM state blobs contribute
+        contribute their ``[row, :pos]`` prefix, head-major on the
+        wire (:meth:`_aux_row_shape`) — the int8 k/v rows AND their
+        per-token f32 scale rows under ``quantize_kv``, or the
+        bf16/f32 rows otherwise; SSM state blobs contribute
         ``[row]`` WHOLE (no length axis — the blob's bytes are
         constant in ``pos``, which is what makes an SSM handoff O(1)
         on the wire). Everything ships as numpy with the device dtype
@@ -375,10 +401,10 @@ class Generator:
         if fn is None:
             def _one(a, r, n):
                 # SSM state blobs have no length axis: ship whole
-                # (slicing [:, :pos] would cut the HEAD axis)
                 full = jax.lax.dynamic_index_in_dim(
                     a[n], r, axis=0, keepdims=False)
-                return full if n.endswith("_state") else full[:, :pos]
+                return full if n.endswith("_state") else \
+                    self._wire_rows(n, full[:pos], True)
             fn = jax.jit(lambda a, r: {n: _one(a, r, n) for n in a})
             self._loop_cache[("export", pos)] = fn
         host = jax.device_get(fn(aux, jnp.int32(row)))
@@ -436,8 +462,8 @@ class Generator:
         return prompt, P
 
     def _aux_shardings(self):
-        """Placement of every decode-state aux under a mesh (scale
-        caches lack the head_dim axis), None without one — what
+        """Placement of every decode-state aux under a mesh (the int8
+        scales split like the rows they scale), None without one — what
         _fresh_aux allocates with and what a program that donates a
         pool must hand back (serve/decode.py's cache merge)."""
         if self._cache_sharding is None:
@@ -453,8 +479,7 @@ class Generator:
                 return NamedSharding(self.mesh, PartitionSpec(
                     self._cache_sharding.spec[0],
                     *([None] * (len(shape) - 1))))
-            return self._scale_sharding if len(shape) == 3 \
-                else self._cache_sharding
+            return self._cache_sharding
 
         return {name: place(name)
                 for name in self._sym.list_auxiliary_states()}

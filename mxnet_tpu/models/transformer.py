@@ -404,9 +404,10 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
     (Tnew,) absolute position ids, cache_pos (1,) = tokens already in
     the caches. Output: logits (B, Tnew, vocab) — no loss head.
     Parameter names match get_symbol exactly; the KV caches are
-    auxiliary states shaped (B, Hkv, max_len, head_dim) where Hkv =
-    num_kv_heads or num_heads (grouped-query attention stores only the
-    kv heads — the cache memory/bandwidth win).
+    auxiliary states shaped (B, max_len, Hkv*head_dim) — a token's kv
+    heads side by side in one row — where Hkv = num_kv_heads or
+    num_heads (grouped-query attention stores only the kv heads — the
+    cache memory/bandwidth win).
 
     per_row_pos=True builds the CONTINUOUS-BATCHING variant: positions
     becomes (B, Tnew) and cache_pos (B,) — every batch row decodes at
@@ -419,7 +420,7 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
     remains shared-position only.
 
     block_type: "attention" (default), "ssm", or a per-layer sequence
-    (mixed stacks). SSM layers replace the (B, H, max_len, hd) KV-row
+    (mixed stacks). SSM layers replace the (B, max_len, H*hd) KV-row
     caches with one (B, H, hd, hd) f32 recurrent-state aux per layer
     ("layerN_ssm_state") — O(1) decode memory in sequence length.
     Knob composition: kv_quantize and attention_window apply to the

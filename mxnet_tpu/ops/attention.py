@@ -24,6 +24,7 @@ numerics are identical everywhere; ops/_pallas.py makes that choice.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -605,15 +606,213 @@ def flash_attention(query, key, value, scale=None, causal=False,
     return out
 
 
+def _token_rows(x):
+    """(B, Hkv, Tn, ...) projections -> (B, Tn, Hkv * ...) cache rows:
+    one token's heads side by side, as the caches hold them."""
+    B, Hkv, Tn = x.shape[:3]
+    return jnp.moveaxis(x, 1, 2).reshape(B, Tn, -1)
+
+
+def _check_heads(query, k_cache):
+    """(Hkv, G): the kv heads a (B, C, Hkv*D) cache holds for this
+    query's head size, and the query heads that share each."""
+    H, D = query.shape[1], query.shape[3]
+    Hkv, rest = divmod(k_cache.shape[2], D)
+    if rest or not Hkv or H % Hkv:
+        raise ValueError(
+            "query heads (%d x %d) must be a multiple of the cache's "
+            "kv heads (row width %d / head size) — grouped-query "
+            "attention groups q heads over kv heads"
+            % (H, D, k_cache.shape[2]))
+    return Hkv, H // Hkv
+
+
+# rows of one product above which _attend takes a kv head at a time:
+# the MXU's pass is 128 rows deep
+_MXU_ROWS = 128
+
+
+def _attend(query, k_cache, v_cache, valid, scale, k_scale=None,
+            v_scale=None):
+    """softmax(q k^T * scale, over the ``valid`` columns) v, read from
+    caches that lie token-contiguous: (B, C, Hkv*D), one token's heads
+    side by side. query: (B, H, Tn, D); valid: (1 or B, Tn, C) bool;
+    k_scale/v_scale: (B, C, Hkv) f32 where the caches are int8 rows
+    (the scales multiply scores and probabilities, so the int8 bytes
+    are what HBM moves). Returns (B, H, Tn, D).
+
+    THE one reader every cached-attention variant goes through. Both
+    products are matrix products that contract the cache's own axes,
+    over the lanes of ``g`` kv heads at a time: scores against a query
+    that is block-diagonal inside its group (head h's D values in its
+    own kv head's lanes, zeros in the others'), values as
+    (g*G*Tn, C) @ (C, g*D) of which each head keeps its own block.
+    GQA: a kv head's G query heads sit in its group, so each cache
+    head is still read once for all of them.
+
+    How many heads make a group is read from the shapes. A decode step
+    (few rows: g*G*Tn within one 128-row pass of the MXU) takes the
+    heads of one 128-lane tile together (g = 2 at D = 64, 1 at D =
+    128; all of them where a row is narrower than a tile), views the
+    cache as (B, C, J, g*D) and batches both products over (B, J): the
+    zeros ride in a pass the MXU makes anyway, XLA reads the cache
+    where it lies, and the compiled step holds no loop. A prefill
+    chunk (more rows) takes one kv head at a time, in a ``lax.map``
+    over the heads that slices that head's lanes out of the cache
+    where it lies: the zeros would double its arithmetic, a head's
+    (B, G*Tn, C) scores are small enough to stay in fast memory
+    between the two products, and the body compiles once (unrolled
+    over 32 heads x 24 layers the prefill program compiled for 58 s a
+    prompt length instead of 11). Measured on a v5e (my chip runs, PR
+    30; us a layer, write + attend, (8, 1536, 32 x 64) bf16): one
+    token 158 batched against 401 a head at a time (228 before,
+    head-major); 1 024 tokens 2 877 mapped and 3 706 unrolled a head
+    at a time against 14 420 batched (6 116 before). An einsum over a
+    (B, C, Hkv, D) view instead makes the TPU compiler copy each whole
+    cache into a head-major layout and back, every call (662 and
+    6 602)."""
+    B, H, Tn, D = query.shape
+    C, F = k_cache.shape[1:]
+    Hkv, G = _check_heads(query, k_cache)
+    W = D * 128 // math.gcd(D, 128)          # lcm: whole lane tiles
+    if F % W:
+        W = F
+    batched = W // D * G * Tn <= _MXU_ROWS
+    if not batched:
+        W = D
+    g, J = W // D, F // W
+    # own[m, j]: query head m of a group reads the group's kv head j
+    own = jnp.repeat(jnp.eye(g, dtype=bool), G, axis=0)[:, None, :, None]
+
+    def products(q, k, v, ks, vs):
+        """q (B, j, g*G, Tn, D) over k, v (B, C, j, W) [and their
+        scales (B, C, j, g)]: (B, j, g*G, Tn, D) for the j groups
+        given."""
+        j = q.shape[1]
+        if ks is not None:
+            # a row of the block-diagonal query meets one kv head only,
+            # so that head's scales multiply the small scores and
+            # probabilities, never a dequantized copy of the cache
+            def of_rows(sc):        # (B, C, j, g) -> (B, j, g*G, 1, C)
+                return jnp.repeat(jnp.moveaxis(sc, 1, 3), G,
+                                  axis=2)[:, :, :, None]
+            k, v = k.astype(jnp.float32), v.astype(jnp.float32)
+        qbd = jnp.where(own, q[..., None, :], 0).reshape(
+            B, j, g * G * Tn, W)
+        s = jnp.einsum("bjmw,bcjw->bjmc", qbd, k,
+                       precision=jax.lax.Precision.DEFAULT,
+                       preferred_element_type=jnp.float32) * scale
+        s = s.reshape(B, j, g * G, Tn, C)
+        if ks is not None:
+            s = s * of_rows(ks)
+        p = jax.nn.softmax(jnp.where(valid[:, None, None], s, _NEG_INF),
+                           axis=-1).astype(v.dtype)
+        if ks is not None:
+            p = p * of_rows(vs)
+        o = jnp.einsum("bjmc,bcjw->bjmw",
+                       p.reshape(B, j, g * G * Tn, C), v,
+                       precision=jax.lax.Precision.DEFAULT)
+        return jnp.where(own, o.reshape(B, j, g * G, Tn, g, D),
+                         0).sum(axis=4)
+
+    q = query.reshape(B, J, g * G, Tn, D)
+
+    def lanes(take):
+        # each cache (and the int8 caches' scales) through ``take``
+        return [None if c is None else take(c, w)
+                for c, w in ((k_cache, W), (v_cache, W),
+                             (k_scale, g), (v_scale, g))]
+
+    if batched:
+        o = products(q, *lanes(lambda c, w: c.reshape(B, C, J, w)))
+    else:
+        def group(x):
+            j, qj = x
+            return products(qj[:, None], *lanes(
+                lambda c, w: jax.lax.dynamic_slice_in_dim(
+                    c, j * w, w, axis=2)[:, :, None]))[:, 0]
+
+        o = jnp.moveaxis(jax.lax.map(
+            group, (jnp.arange(J), jnp.moveaxis(q, 1, 0))), 0, 1)
+    return o.reshape(B, H, Tn, D)
+
+
+def _causal(pos, Tn, C, window):
+    """(1 or B, Tn, C) mask: new row r of batch row b, which sits at
+    pos[b] + r, attends cache column c iff c <= pos[b] + r and, under
+    a window, pos[b] + r - c < window. pos: () or (B,) int."""
+    at = jnp.reshape(pos, (-1, 1, 1)) + jnp.arange(Tn)[None, :, None]
+    cols = jnp.arange(C)[None, None, :]
+    valid = cols <= at
+    if window:
+        valid = valid & (at - cols < window)
+    return valid
+
+
+def _check_capacity(op, pos, Tn, C):
+    """Raise where ``pos`` is concrete and a row would overrun."""
+    if not isinstance(pos, jax.core.Tracer):
+        import numpy as _np
+        worst = int(_np.asarray(pos).max())
+        if worst + Tn > C:
+            raise ValueError(
+                "%s overrun: pos (%d) + Tnew (%d) exceeds cache "
+                "capacity Tmax=%d — dynamic_update_slice would clamp "
+                "and silently corrupt the cache" % (op, worst, Tn, C))
+
+
+def _row_pos(pos, B):
+    """pos as int32: () for one shared position, (B,) per row."""
+    pos = jnp.asarray(pos)
+    if pos.ndim >= 1 and pos.size > 1:
+        if pos.size != B:
+            raise ValueError(
+                "per-row pos must have one entry per batch row: got "
+                "%r for batch %d" % (pos.shape, B))
+        return jnp.reshape(pos, (B,)).astype(jnp.int32)
+    return jnp.reshape(pos, ()).astype(jnp.int32)
+
+
+def _write_rows(cache, new, pos):
+    """``cache[b, pos[b]:pos[b]+Tn] = new[b]`` for a (B, C, ...) cache
+    and (B, Tn, ...) rows. A token is one contiguous row of the cache
+    (at (8, 1536, 32 x 64) bf16: 4 KB, 16 adjacent lane tiles), so a
+    write touches only that row: the 384 writes of an OPT-1.3B decode
+    step take 0.39 ms of its 6.95, where a (B, Hkv, C, hd) cache (the
+    TPU makes C the lane axis: a token is one lane column in 128
+    tiles) paid 2.88 of 10.05 (my chip runs, PR 30).
+
+    pos (): one ``dynamic_update_slice`` for all rows. pos (B,): one a
+    row at a STATIC row index, each on the result of the last — not a
+    ``vmap`` of one: a batched ``dynamic_update_slice`` is a scatter,
+    which the TPU compiler expands into a ``while`` over the rows that
+    carries the whole cache array through fast memory and back (3.9 +
+    2.7 ms of a 12.4 ms decode step over 48 arrays; my chip run, PR
+    28). Either way the donated buffer is updated where it lies, and a
+    start past ``C - Tn`` clamps as ``dynamic_update_slice`` does."""
+    tail = (0,) * (cache.ndim - 2)
+    if pos.ndim == 0:
+        return jax.lax.dynamic_update_slice(cache, new, (0, pos) + tail)
+    for b in range(cache.shape[0]):
+        cache = jax.lax.dynamic_update_slice(
+            cache, new[b:b + 1], (b, pos[b]) + tail)
+    return cache
+
+
 def cached_attention(query, key, value, k_cache, v_cache, pos,
                      scale=None, window=0):
     """Incremental-decode attention over a KV cache.
 
     query/key/value: (B, H, Tnew, hd) — projections of the Tnew tokens
-    being appended (Tnew = prompt length at prefill, 1 per step after).
-    k_cache/v_cache: (B, H, Tmax, hd) rolling caches. pos: (1,) int —
-    number of tokens already cached; the new keys land at
-    [pos, pos+Tnew) and query row r may attend cache columns <= pos+r.
+    being appended (Tnew = prompt length at prefill, 1 per step after;
+    key/value carry Hkv <= H heads under grouped-query attention).
+    k_cache/v_cache: (B, Tmax, Hkv*hd) — TOKEN-CONTIGUOUS: one token's
+    kv heads side by side in one row, which is how the TPU stores it
+    too (the row axis is the lane axis), so appending a token writes
+    one contiguous row (:func:`_write_rows`) and both products read
+    the cache where it lies (:func:`_attend`). pos: (1,) int — number
+    of tokens already cached; the new keys land at [pos, pos+Tnew) and
+    query row r may attend cache columns <= pos+r.
 
     CAPACITY CONTRACT: pos + Tnew must be <= Tmax. Past it,
     dynamic_update_slice CLAMPS the start index rather than raising, so
@@ -629,126 +828,25 @@ def cached_attention(query, key, value, k_cache, v_cache, pos,
     own offset and its causal window masks against its own position,
     which is what lets a serving slot pool hold sequences at different
     decode depths in ONE compiled step (mxnet_tpu/serve/decode.py).
-    A (1,) pos keeps the shared-position fast path bit-for-bit.
 
     Decode is bandwidth-bound (one (Tnew, Tmax) strip per head), so
     this is a plain jnp composition — XLA fuses the mask+softmax; the
     MXU-dense training path stays with the Pallas flash kernel.
     Returns (out, new_k_cache, new_v_cache)."""
     B, H, Tn, D = query.shape
-    Hkv = k_cache.shape[1]
-    if H % Hkv:
-        raise ValueError(
-            "query heads (%d) must be a multiple of cache kv heads "
-            "(%d) — grouped-query attention groups q heads over kv "
-            "heads" % (H, Hkv))
-    G = H // Hkv
+    _check_heads(query, k_cache)
+    C = k_cache.shape[1]
     if scale is None:
         scale = D ** -0.5
-    pos = jnp.asarray(pos)
-    if pos.ndim >= 1 and pos.size > 1:
-        if pos.size != B:
-            raise ValueError(
-                "per-row pos must have one entry per batch row: got "
-                "%r for batch %d" % (pos.shape, B))
-        return _cached_attention_per_row(
-            query, key, value, k_cache, v_cache,
-            jnp.reshape(pos, (B,)), float(scale), int(window or 0))
-    p0 = jnp.reshape(pos, ()).astype(jnp.int32)
-    if not isinstance(p0, jax.core.Tracer) and \
-            int(p0) + Tn > k_cache.shape[2]:
-        raise ValueError(
-            "cached_attention overrun: pos (%d) + Tnew (%d) exceeds "
-            "cache capacity Tmax=%d — dynamic_update_slice would clamp "
-            "and silently corrupt the cache"
-            % (int(p0), Tn, k_cache.shape[2]))
-    k_cache = jax.lax.dynamic_update_slice(
-        k_cache, key.astype(k_cache.dtype), (0, 0, p0, 0))
-    v_cache = jax.lax.dynamic_update_slice(
-        v_cache, value.astype(v_cache.dtype), (0, 0, p0, 0))
-    # grouped einsum: q reshaped (B, Hkv, G, Tn, D) against the
-    # (B, Hkv, Tmax, D) cache — each cache head is READ ONCE for its
-    # whole q-head group (the GQA decode-bandwidth win; a repeat would
-    # materialize G copies)
-    qg = query.reshape(B, Hkv, G, Tn, D)
-    s = jnp.einsum("bhgqd,bhkd->bhgqk", qg, k_cache,
-                   precision=jax.lax.Precision.DEFAULT,
-                   preferred_element_type=jnp.float32) * scale
-    cols = jnp.arange(k_cache.shape[2])[None, :]
-    rows = jnp.arange(Tn)[:, None]
-    valid = cols <= p0 + rows
-    if window:
-        valid = valid & (p0 + rows - cols < window)
-    s = jnp.where(valid, s, _NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhgqk,bhkd->bhgqd", p.astype(v_cache.dtype),
-                     v_cache,
-                     precision=jax.lax.Precision.DEFAULT)
-    return (out.reshape(B, H, Tn, D).astype(query.dtype),
-            k_cache, v_cache)
-
-
-def _write_rows(cache, new, pb):
-    """``cache[b, :, pb[b]:pb[b]+Tn] = new[b]`` for every batch row b
-    of a (B, Hkv, C, ...) cache: one ``dynamic_update_slice`` a row at
-    a STATIC row index, each on the result of the last.
-
-    Not a ``vmap`` of one: a batched ``dynamic_update_slice`` is a
-    scatter, which the TPU compiler expands into a ``while`` over the
-    rows that carries the whole cache array. Even with the array
-    donated it moves most arrays into fast memory for that loop and
-    writes them back whole: over 48 arrays of (8, 32, 1536, 64) bf16
-    the loops take 3.9 ms and the write-backs 2.7 ms of a 12.4 ms
-    decode step, where these static-row writes take 2.9 ms of 10.0
-    and update the donated buffer where it lies (my chip run, PR 28).
-    Same values either way: both clamp a start past ``C - Tn`` as
-    ``dynamic_update_slice`` does."""
-    tail = (0,) * (cache.ndim - 3)
-    for b in range(cache.shape[0]):
-        cache = jax.lax.dynamic_update_slice(
-            cache, new[b:b + 1], (b, 0, pb[b]) + tail)
-    return cache
-
-
-def _cached_attention_per_row(query, key, value, k_cache, v_cache, pb,
-                              scale, window):
-    """cached_attention's per-row-position core: pb (B,) int — row b's
-    new tokens land at [pb[b], pb[b]+Tn) and mask against pb[b]
-    (:func:`_write_rows`: in place when the caller donates the
-    caches); same capacity contract as the scalar path, enforced per
-    row."""
-    B, H, Tn, D = query.shape
-    Hkv = k_cache.shape[1]
-    G = H // Hkv
-    C = k_cache.shape[2]
-    pb = pb.astype(jnp.int32)
-    if not isinstance(pb, jax.core.Tracer):
-        import numpy as _np
-        worst = int(_np.asarray(pb).max())
-        if worst + Tn > C:
-            raise ValueError(
-                "cached_attention overrun: row pos (%d) + Tnew (%d) "
-                "exceeds cache capacity Tmax=%d" % (worst, Tn, C))
-
-    k_cache = _write_rows(k_cache, key.astype(k_cache.dtype), pb)
-    v_cache = _write_rows(v_cache, value.astype(v_cache.dtype), pb)
-    qg = query.reshape(B, Hkv, G, Tn, D)
-    s = jnp.einsum("bhgqd,bhkd->bhgqk", qg, k_cache,
-                   precision=jax.lax.Precision.DEFAULT,
-                   preferred_element_type=jnp.float32) * scale
-    cols = jnp.arange(C)[None, None, :]            # (1, 1, C)
-    rows = jnp.arange(Tn)[None, :, None]           # (1, Tn, 1)
-    prow = pb[:, None, None]                       # (B, 1, 1)
-    valid = cols <= prow + rows                    # (B, Tn, C)
-    if window:
-        valid = valid & (prow + rows - cols < window)
-    s = jnp.where(valid[:, None, None], s, _NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhgqk,bhkd->bhgqd", p.astype(v_cache.dtype),
-                     v_cache,
-                     precision=jax.lax.Precision.DEFAULT)
-    return (out.reshape(B, H, Tn, D).astype(query.dtype),
-            k_cache, v_cache)
+    pos = _row_pos(pos, B)
+    _check_capacity("cached_attention", pos, Tn, C)
+    k_cache = _write_rows(k_cache,
+                          _token_rows(key).astype(k_cache.dtype), pos)
+    v_cache = _write_rows(v_cache,
+                          _token_rows(value).astype(v_cache.dtype), pos)
+    out = _attend(query, k_cache, v_cache,
+                  _causal(pos, Tn, C, int(window or 0)), float(scale))
+    return out.astype(query.dtype), k_cache, v_cache
 
 
 def rope(x, positions, base=10000.0):
@@ -793,7 +891,8 @@ def rolling_cached_attention(query, key, value, k_cache, v_cache, pos,
                              window, scale=None):
     """Sliding-window decode attention over a CIRCULAR cache.
 
-    Caches have fixed capacity C = k_cache.shape[2]; position p lives
+    Caches are (B, C, Hkv*hd), token-contiguous like
+    cached_attention's, with fixed capacity C; position p lives
     in slot p % C, so memory stays O(C) however long generation runs
     (pair with RoPE — a learned position table would still bound
     absolute positions). Correctness needs C >= window + Tnew - 1:
@@ -807,34 +906,23 @@ def rolling_cached_attention(query, key, value, k_cache, v_cache, pos,
     congruent to s. Valid for query row r iff 0 <= p_s <= p0+r and
     p0+r - p_s < window."""
     B, H, Tn, D = query.shape
-    Hkv = k_cache.shape[1]
-    if H % Hkv:
-        raise ValueError(
-            "query heads (%d) must be a multiple of cache kv heads "
-            "(%d)" % (H, Hkv))
-    G = H // Hkv
-    C = k_cache.shape[2]
+    _check_heads(query, k_cache)
+    C = k_cache.shape[1]
     if scale is None:
         scale = D ** -0.5
     p0 = jnp.reshape(pos, ()).astype(jnp.int32)
     slots = (p0 + jnp.arange(Tn)) % C
-    k_cache = k_cache.at[:, :, slots].set(key.astype(k_cache.dtype))
-    v_cache = v_cache.at[:, :, slots].set(value.astype(v_cache.dtype))
-    qg = query.reshape(B, Hkv, G, Tn, D)    # GQA: see cached_attention
-    s = jnp.einsum("bhgqd,bhkd->bhgqk", qg, k_cache,
-                   precision=jax.lax.Precision.DEFAULT,
-                   preferred_element_type=jnp.float32) * scale
+    k_cache = k_cache.at[:, slots].set(
+        _token_rows(key).astype(k_cache.dtype))
+    v_cache = v_cache.at[:, slots].set(
+        _token_rows(value).astype(v_cache.dtype))
     pos_end = p0 + Tn - 1
     slot_ids = jnp.arange(C)[None, :]
     p_s = pos_end - ((pos_end - slot_ids) % C)      # (1, C)
     rows = p0 + jnp.arange(Tn)[:, None]             # (Tn, 1)
     valid = (p_s >= 0) & (p_s <= rows) & (rows - p_s < window)
-    s = jnp.where(valid, s, _NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhgqk,bhkd->bhgqd", p.astype(v_cache.dtype),
-                     v_cache, precision=jax.lax.Precision.DEFAULT)
-    return (out.reshape(B, H, Tn, D).astype(query.dtype),
-            k_cache, v_cache)
+    out = _attend(query, k_cache, v_cache, valid[None], float(scale))
+    return out.astype(query.dtype), k_cache, v_cache
 
 
 @register("_contrib_RollingCachedAttention",
@@ -864,8 +952,8 @@ def _rolling_cached_attention_op(query, key, value, k_cache, v_cache,
           defaults={"scale": None, "max_len": 0, "window": 0})
 def _cached_attention_op(query, key, value, k_cache, v_cache, pos,
                          scale=None, window=0, **_):
-    """(B, H, Tnew, hd) decode attention; k_cache/v_cache are aux
-    states updated in place (the executor threads them like BN moving
+    """(B, H, Tnew, hd) decode attention; k_cache/v_cache
+    ((B, max_len, Hkv*hd)) are aux states updated in place (the executor threads them like BN moving
     stats — but unconditionally, since appending to the cache is the
     op's purpose at inference)."""
     return cached_attention(query, key, value, k_cache, v_cache, pos,
@@ -890,129 +978,46 @@ def cached_attention_q8(query, key, value, k_cache, v_cache, k_scale,
     prompts the CACHE dominates decode HBM traffic, and it is read
     every step while each weight is read once).
 
-    k_cache/v_cache: (B, Hkv, Tmax, hd) int8. k_scale/v_scale:
-    (B, Hkv, Tmax) f32 per-token-per-head absmax/127 scales — written
-    once when the token's k/v enters the cache, so quantization is
-    independent of later reads (a token's cache entry never changes).
-    Dequantize happens tile-wise inside the einsum's operand read (an
-    int8→f32 convert + scale multiply XLA fuses into the matmul loop),
-    so HBM moves ~half the bytes of the bf16 cache (+1.6% for scales
-    at hd=128). Scales clamp at 1e-8: an all-zero k/v row stores
-    zeros, not NaNs.
+    k_cache/v_cache: (B, Tmax, Hkv*hd) int8, token-contiguous like
+    cached_attention's. k_scale/v_scale: (B, Tmax, Hkv) f32
+    per-token-per-head absmax/127 scales — written once when the
+    token's k/v enters the cache, so quantization is independent of
+    later reads (a token's cache entry never changes). No dequantized
+    cache is ever built: the int8→f32 convert sits in the products'
+    operand reads and the scales multiply the scores and the
+    probabilities (:func:`_attend`), so HBM moves ~half the bytes of
+    the bf16 cache (+1.6% for scales at hd=128). Scales clamp at 1e-8:
+    an all-zero k/v row stores zeros, not NaNs.
 
     PER-ROW POSITIONS (continuous batching): like cached_attention,
     pos may be (B,) — row b's new int8 rows AND its f32 scale rows
-    land at pb[b], and its causal/window mask reads against pb[b].
-    This is what lets the serving slot pool run int8 caches: one
-    compiled (B, 1) step whatever depths the slots sit at
-    (mxnet_tpu/serve/decode.py). A (1,) pos keeps the shared-position
-    path below bit-for-bit.
+    land at pos[b] (:func:`_write_rows`, for all four caches), and its
+    causal/window mask reads against pos[b]. This is what lets the
+    serving slot pool run int8 caches: one compiled (B, 1) step
+    whatever depths the slots sit at (mxnet_tpu/serve/decode.py).
+    Quantization is _q8_quantize whatever pos is, so the stored cache
+    entry for a row is independent of which path wrote it.
 
     Same capacity contract and GQA grouping as cached_attention.
     Returns (out, k_cache, v_cache, k_scale, v_scale)."""
     B, H, Tn, D = query.shape
-    Hkv = k_cache.shape[1]
-    if H % Hkv:
-        raise ValueError(
-            "query heads (%d) must be a multiple of cache kv heads "
-            "(%d)" % (H, Hkv))
-    G = H // Hkv
+    Hkv, _ = _check_heads(query, k_cache)
+    C = k_cache.shape[1]
     if scale is None:
         scale = D ** -0.5
-    pos = jnp.asarray(pos)
-    if pos.ndim >= 1 and pos.size > 1:
-        if pos.size != B:
-            raise ValueError(
-                "per-row pos must have one entry per batch row: got "
-                "%r for batch %d" % (pos.shape, B))
-        return _cached_attention_q8_per_row(
-            query, key, value, k_cache, v_cache, k_scale, v_scale,
-            jnp.reshape(pos, (B,)), float(scale), int(window or 0))
-    p0 = jnp.reshape(pos, ()).astype(jnp.int32)
-    if not isinstance(p0, jax.core.Tracer) and \
-            int(p0) + Tn > k_cache.shape[2]:
-        raise ValueError(
-            "cached_attention_q8 overrun: pos (%d) + Tnew (%d) "
-            "exceeds cache capacity Tmax=%d"
-            % (int(p0), Tn, k_cache.shape[2]))
-
-    kq, ks = _q8_quantize(key)
-    vq, vs = _q8_quantize(value)
-    k_cache = jax.lax.dynamic_update_slice(k_cache, kq, (0, 0, p0, 0))
-    v_cache = jax.lax.dynamic_update_slice(v_cache, vq, (0, 0, p0, 0))
-    k_scale = jax.lax.dynamic_update_slice(k_scale, ks, (0, 0, p0))
-    v_scale = jax.lax.dynamic_update_slice(v_scale, vs, (0, 0, p0))
-
-    # dequantized views — producers XLA fuses into the einsum reads
-    kf = k_cache.astype(jnp.float32) * k_scale[..., None]
-    vf = v_cache.astype(jnp.float32) * v_scale[..., None]
-    qg = query.reshape(B, Hkv, G, Tn, D)
-    s = jnp.einsum("bhgqd,bhkd->bhgqk", qg.astype(jnp.float32), kf,
-                   precision=jax.lax.Precision.DEFAULT,
-                   preferred_element_type=jnp.float32) * scale
-    cols = jnp.arange(k_cache.shape[2])[None, :]
-    rows = jnp.arange(Tn)[:, None]
-    valid = cols <= p0 + rows
-    if window:
-        valid = valid & (p0 + rows - cols < window)
-    s = jnp.where(valid, s, _NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhgqk,bhkd->bhgqd", p, vf,
-                     precision=jax.lax.Precision.DEFAULT)
-    return (out.reshape(B, H, Tn, D).astype(query.dtype),
-            k_cache, v_cache, k_scale, v_scale)
-
-
-def _cached_attention_q8_per_row(query, key, value, k_cache, v_cache,
-                                 k_scale, v_scale, pb, scale, window):
-    """cached_attention_q8's per-row-position core: the int8 k/v rows
-    AND their per-token f32 scale rows land at each row's own offset
-    (:func:`_write_rows`, for all four caches), and each row masks
-    against its own position. Quantization
-    is _q8_quantize, the exact shared-path rule, so the stored cache
-    entry for a row is independent of which path wrote it. Same
-    capacity contract as the scalar path, enforced per row."""
-    B, H, Tn, D = query.shape
-    Hkv = k_cache.shape[1]
-    G = H // Hkv
-    C = k_cache.shape[2]
-    pb = pb.astype(jnp.int32)
-    if not isinstance(pb, jax.core.Tracer):
-        import numpy as _np
-        worst = int(_np.asarray(pb).max())
-        if worst + Tn > C:
-            raise ValueError(
-                "cached_attention_q8 overrun: row pos (%d) + Tnew "
-                "(%d) exceeds cache capacity Tmax=%d" % (worst, Tn, C))
-
+    pos = _row_pos(pos, B)
+    _check_capacity("cached_attention_q8", pos, Tn, C)
     kq, ks = _q8_quantize(key)       # (B, Hkv, Tn, D), (B, Hkv, Tn)
     vq, vs = _q8_quantize(value)
+    k_cache = _write_rows(k_cache, _token_rows(kq), pos)
+    v_cache = _write_rows(v_cache, _token_rows(vq), pos)
+    k_scale = _write_rows(k_scale, _token_rows(ks), pos)
+    v_scale = _write_rows(v_scale, _token_rows(vs), pos)
 
-    k_cache = _write_rows(k_cache, kq, pb)
-    v_cache = _write_rows(v_cache, vq, pb)
-    k_scale = _write_rows(k_scale, ks, pb)
-    v_scale = _write_rows(v_scale, vs, pb)
-
-    # dequantized views — producers XLA fuses into the einsum reads,
-    # same formulation as the shared-position path
-    kf = k_cache.astype(jnp.float32) * k_scale[..., None]
-    vf = v_cache.astype(jnp.float32) * v_scale[..., None]
-    qg = query.reshape(B, Hkv, G, Tn, D)
-    s = jnp.einsum("bhgqd,bhkd->bhgqk", qg.astype(jnp.float32), kf,
-                   precision=jax.lax.Precision.DEFAULT,
-                   preferred_element_type=jnp.float32) * scale
-    cols = jnp.arange(C)[None, None, :]            # (1, 1, C)
-    rows = jnp.arange(Tn)[None, :, None]           # (1, Tn, 1)
-    prow = pb[:, None, None]                       # (B, 1, 1)
-    valid = cols <= prow + rows                    # (B, Tn, C)
-    if window:
-        valid = valid & (prow + rows - cols < window)
-    s = jnp.where(valid[:, None, None], s, _NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhgqk,bhkd->bhgqd", p, vf,
-                     precision=jax.lax.Precision.DEFAULT)
-    return (out.reshape(B, H, Tn, D).astype(query.dtype),
-            k_cache, v_cache, k_scale, v_scale)
+    out = _attend(query.astype(jnp.float32), k_cache, v_cache,
+                  _causal(pos, Tn, C, int(window or 0)), float(scale),
+                  k_scale, v_scale)
+    return out.astype(query.dtype), k_cache, v_cache, k_scale, v_scale
 
 
 @register("_contrib_CachedAttentionQ8",

@@ -262,11 +262,12 @@ def _cached_attention_shapes(shapes, attrs):
     out = list(shapes)
     tmax = int(attrs.get("max_len", 0))
     if q is not None and tmax:
-        # cache head count follows the KEY projection, not the query —
-        # under grouped-query attention Hkv < H and the cache stores
-        # only the kv heads
+        # token-contiguous rows (ops/attention.py::cached_attention):
+        # (B, max_len, Hkv*hd). The head count follows the KEY
+        # projection, not the query — under grouped-query attention
+        # Hkv < H and the cache stores only the kv heads
         heads = k[1] if k is not None else q[1]
-        cache = (q[0], heads, tmax, q[3])
+        cache = (q[0], tmax, heads * q[3])
         if len(out) > 3 and out[3] is None:
             out[3] = cache
         if len(out) > 4 and out[4] is None:
@@ -325,7 +326,7 @@ set_param_shapes("_contrib_RollingCachedAttention",
 
 def _cached_attention_q8_shapes(shapes, attrs):
     """Int8 variant: slots 3/4 are the int8 caches, 5/6 the per-token
-    (B, Hkv, Tmax) scale caches, 7 the pos scalar. NOTE on dtypes:
+    (B, Tmax, Hkv) scale caches, 7 the pos scalar. NOTE on dtypes:
     infer_type's same-dtype propagation cannot express the int8/f32
     aux split — Generator._fresh_aux (the supported allocator for this
     op) creates them by suffix; Executor-bound users must supply aux
@@ -336,13 +337,12 @@ def _cached_attention_q8_shapes(shapes, attrs):
     tmax = int(attrs.get("max_len", 0))
     if q is not None and tmax:
         heads = k[1] if k is not None else q[1]
-        cache = (q[0], heads, tmax, q[3])
         for i in (3, 4):
             if len(out) > i and out[i] is None:
-                out[i] = cache
+                out[i] = (q[0], tmax, heads * q[3])
         for i in (5, 6):
             if len(out) > i and out[i] is None:
-                out[i] = cache[:3]
+                out[i] = (q[0], tmax, heads)
     if len(out) > 7 and out[7] is None:
         out[7] = (1,)
     return out

@@ -710,6 +710,15 @@ class ContinuousDecoder:
             lines.append(
                 "  step program writes %d of the pool's %d bytes on a "
                 "device in place (donated)" % (aliased, held))
+        rows = [a.sharding.shard_shape(a.shape) + (a.dtype.itemsize,)
+                for n, a in self._aux.items()
+                if gen._aux_kind(n) == "kv_rows"]
+        if rows:
+            # (B, C, Hkv*hd) token rows: what one step writes, and how
+            lines.append(
+                "  a token is %d contiguous bytes a row; %d row writes "
+                "a step" % (max(r[2] * r[3] for r in rows),
+                            sum(r[0] for r in rows)))
         if hbm_budget is None:
             try:
                 stats = jax.local_devices()[0].memory_stats() or {}
@@ -834,6 +843,8 @@ class ContinuousDecoder:
             def scatter(aux, rows, slot_):
                 out = dict(aux)
                 for name, r in rows.items():
+                    # the wire is head-major; the pool token-contiguous
+                    r = self._gen._wire_rows(name, r, False)
                     start = (slot_,) + (0,) * (r.ndim)
                     out[name] = jax.lax.dynamic_update_slice(
                         aux[name], r[None], start)

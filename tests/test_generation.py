@@ -43,7 +43,7 @@ class TestCachedAttentionOp:
         q = jnp.asarray(rng.randn(1, 2, Tmax, hd), jnp.float32)
         k = jnp.asarray(rng.randn(1, 2, Tmax, hd), jnp.float32)
         v = jnp.asarray(rng.randn(1, 2, Tmax, hd), jnp.float32)
-        kc = jnp.zeros((1, 2, Tmax, hd), jnp.float32)
+        kc = jnp.zeros((1, Tmax, 2 * hd), jnp.float32)
         vc = jnp.zeros_like(kc)
         outs = []
         for t in range(Tmax):
@@ -66,7 +66,7 @@ class TestCachedAttentionOp:
         Tmax, hd, P = 8, 8, 5
         mk = lambda: jnp.asarray(rng.randn(1, 1, Tmax, hd), jnp.float32)
         q, k, v = mk(), mk(), mk()
-        kc = jnp.zeros((1, 1, Tmax, hd), jnp.float32)
+        kc = jnp.zeros((1, Tmax, hd), jnp.float32)
         vc = jnp.zeros_like(kc)
         o_chunk, kc1, vc1 = cached_attention(
             q[:, :, :P], k[:, :, :P], v[:, :, :P], kc, vc,
@@ -116,7 +116,7 @@ class TestTeacherForcingConsistency:
         dec = transformer.get_decode_symbol(V, T, num_layers=L,
                                             num_heads=H, dim=DIM)
         dfn = _graph_eval_fn(dec)
-        aux = {n: jnp.zeros((B, H, T, DIM // H), jnp.float32)
+        aux = {n: jnp.zeros((B, T, DIM), jnp.float32)
                for n in dec.list_auxiliary_states()}
         P = 4
         logits_inc = []
@@ -496,7 +496,7 @@ class TestMoEDecode:
                                             num_heads=H, dim=DIM,
                                             num_experts=E)
         dfn = _graph_eval_fn(dec)
-        aux = {n: jnp.zeros((B, H, T, DIM // H), jnp.float32)
+        aux = {n: jnp.zeros((B, T, DIM), jnp.float32)
                for n in dec.list_auxiliary_states()}
         logits = []
         for t in range(T):
@@ -640,7 +640,7 @@ class TestGenerator:
                         num_heads=4, dim=DIM, batch_size=B,
                         num_kv_heads=2)
         hd = DIM // 4
-        assert gen._cache_shape == (B, 2, T, hd)
+        assert gen._cache_shape == (B, T, 2 * hd)
 
         rng = np.random.RandomState(0)
         toks = rng.randint(0, V, (B, T))
@@ -745,7 +745,7 @@ class TestGenerator:
                         num_heads=4, dim=DIM, num_kv_heads=2,
                         batch_size=B, pos_encoding="rope",
                         attention_window=8, rolling_cache=True)
-        assert gen._cache_shape == (B, 2, 12, DIM // 4)
+        assert gen._cache_shape == (B, 12, 2 * (DIM // 4))
         out = gen.generate(np.array([[1, 2, 3], [4, 5, 6]]),
                            max_new_tokens=20)   # past plain capacity
         assert out.shape == (B, 23)
@@ -1007,11 +1007,11 @@ class TestQuantizedKVCache:
         q = jnp.asarray(rng.randn(1, 2, Tmax, hd), jnp.float32)
         k = jnp.asarray(rng.randn(1, 2, Tmax, hd), jnp.float32)
         v = jnp.asarray(rng.randn(1, 2, Tmax, hd), jnp.float32)
-        kc = jnp.zeros((1, 2, Tmax, hd), jnp.int8)
+        kc = jnp.zeros((1, Tmax, 2 * hd), jnp.int8)
         vc = jnp.zeros_like(kc)
-        ks = jnp.zeros((1, 2, Tmax), jnp.float32)
+        ks = jnp.zeros((1, Tmax, 2), jnp.float32)
         vs = jnp.zeros_like(ks)
-        kcf = jnp.zeros((1, 2, Tmax, hd), jnp.float32)
+        kcf = jnp.zeros((1, Tmax, 2 * hd), jnp.float32)
         vcf = jnp.zeros_like(kcf)
         for t in range(Tmax):
             o8, kc, vc, ks, vs = cached_attention_q8(
@@ -1026,7 +1026,7 @@ class TestQuantizedKVCache:
                                        rtol=0.05, atol=0.02)
         # the caches really are int8 + per-token scales
         assert kc.dtype == jnp.int8 and vs.dtype == jnp.float32
-        assert float(jnp.abs(ks[0, :, :Tmax]).min()) > 0
+        assert float(jnp.abs(ks[0, :Tmax]).min()) > 0
 
     def test_q8_generator_close_and_aux_dtypes(self):
         _, params = _trained_params()

@@ -244,7 +244,10 @@ def test_export_import_bit_preserves_all_three_kinds(params):
         for name, arr in rows.items():
             got = np.asarray(dec._aux[name])[2]
             if not name.endswith("_state"):
-                got = got[:, :9]
+                # the pool keeps (C, Hkv*hd) token rows; the wire is
+                # head-major
+                got = got[:9].reshape(9, arr.shape[0], -1).swapaxes(
+                    0, 1).reshape(arr.shape)
             np.testing.assert_array_equal(got, arr)
         bad = dict(blob, rows=dict(rows, layer0_mamba_conv_state=rows[
             "layer0_mamba_conv_state"][:2]))

@@ -255,12 +255,128 @@ class TestContract:
                 dec.submit(np.arange(4), 2, top_k=3)
 
 
+def _token_major(c):
+    """A cache as every tree before PR 30 held it — (B, Hkv, C, hd),
+    its int8 scales (B, Hkv, C) — in the layout the device keeps now:
+    (B, C, Hkv*hd) and (B, C, Hkv), a token's heads side by side."""
+    c = np.asarray(c)
+    return np.moveaxis(c, 1, 2).reshape(c.shape[0], c.shape[2], -1)
+
+
+def _old_layout_reference(q, k, v, kc, vc, pos, window=0, rolling=False,
+                          scales=None):
+    """float32 cached attention in the OLD indexing, caches
+    (B, Hkv, C, hd): row b's Tn new tokens land at pos[b] + r (slot
+    (pos + r) % C when rolling), query row r attends what the causal
+    and window masks allow, each kv head serving its G query heads.
+    ``scales`` (ks, vs), each (B, Hkv, C): int8 caches, absmax/127 a
+    token a head. Returns (out, kc, vc[, ks, vs])."""
+    import jax
+    import jax.numpy as jnp
+    B_, H_, Tn, D_ = q.shape
+    Hkv, C = kc.shape[1], kc.shape[2]
+    pos = np.broadcast_to(np.asarray(pos, np.int64).reshape(-1), (B_,))
+    if scales is not None:
+        def quantize(x):
+            s = jnp.maximum(jnp.max(jnp.abs(x), axis=-1), 1e-8) / 127.0
+            return jnp.round(x / s[..., None]).astype(jnp.int8), s
+        (k, ksn), (v, vsn) = quantize(k), quantize(v)
+        ks, vs = scales
+    at = pos[:, None] + np.arange(Tn)[None]              # (B, Tn)
+    slot = at % C if rolling else at
+    for b in range(B_):
+        kc = kc.at[b, :, slot[b]].set(jnp.moveaxis(k[b], 0, 1))
+        vc = vc.at[b, :, slot[b]].set(jnp.moveaxis(v[b], 0, 1))
+        if scales is not None:
+            ks = ks.at[b, :, slot[b]].set(ksn[b].T)
+            vs = vs.at[b, :, slot[b]].set(vsn[b].T)
+    kf, vf = kc.astype(jnp.float32), vc.astype(jnp.float32)
+    if scales is not None:
+        kf, vf = kf * ks[..., None], vf * vs[..., None]
+    cols = np.arange(C)[None, None]
+    if rolling:
+        end = at[:, -1:, None]
+        held = end - ((end - cols) % C)      # newest position a slot has
+        valid = (held >= 0) & (held <= at[..., None]) & \
+            (at[..., None] - held < window)
+    else:
+        valid = cols <= at[..., None]
+        if window:
+            valid = valid & (at[..., None] - cols < window)
+    qg = q.reshape(B_, Hkv, H_ // Hkv, Tn, D_)
+    sc = jnp.einsum("bhgqd,bhkd->bhgqk", qg, kf,
+                    precision=jax.lax.Precision.HIGHEST) * D_ ** -0.5
+    sc = jnp.where(jnp.asarray(valid)[:, None, None], sc, -1e30)
+    out = jnp.einsum("bhgqk,bhkd->bhgqd", jax.nn.softmax(sc, axis=-1),
+                     vf, precision=jax.lax.Precision.HIGHEST)
+    out = out.reshape(B_, H_, Tn, D_)
+    return (out, kc, vc) + (() if scales is None else (ks, vs))
+
+
+@pytest.mark.parametrize("tn", [1, 5], ids=["Tn1", "TnP"])
+@pytest.mark.parametrize("variant", ["scalar", "per_row", "rolling",
+                                     "q8_scalar", "q8_per_row"])
+def test_cached_attention_reads_token_rows_like_the_old_layout(variant,
+                                                               tn):
+    """The five cached-attention variants over (B, C, Hkv*hd) token
+    rows against a float32 reference written in the old
+    (B, Hkv, C, hd) indexing: GQA (4 query heads on 2 kv heads), a
+    window mask, rows at unequal depths with one ending on the
+    cache's last row (C - 1: the latest start that does not clamp),
+    one decode token and a chunk, int8 rows with their scales. The
+    caches must be the reference's own, re-laid: bit for bit."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import attention as att
+    rng = np.random.RandomState(43)
+    B_, H_, Hkv, D_, C, window = 3, 4, 2, 8, 16, 6
+    q = jnp.asarray(rng.randn(B_, H_, tn, D_), jnp.float32)
+    k = jnp.asarray(rng.randn(B_, Hkv, tn, D_), jnp.float32)
+    v = jnp.asarray(rng.randn(B_, Hkv, tn, D_), jnp.float32)
+    q8 = variant.startswith("q8")
+    if q8:
+        kc, vc = (jnp.asarray(rng.randint(-127, 128, (B_, Hkv, C, D_)),
+                              jnp.int8) for _ in range(2))
+        scales = tuple(jnp.asarray(rng.rand(B_, Hkv, C) + 0.01,
+                                   jnp.float32) for _ in range(2))
+    else:
+        kc, vc = (jnp.asarray(rng.randn(B_, Hkv, C, D_), jnp.float32)
+                  for _ in range(2))
+        scales = None
+    if variant.endswith("per_row"):
+        pos = np.array([2, C - tn, 9 - tn])      # unequal; one at the end
+    elif variant == "rolling":
+        pos = np.array([C + 3])                  # wrapped once already
+    else:
+        pos = np.array([C - tn])
+    want = _old_layout_reference(q, k, v, kc, vc, pos, window=window,
+                                 rolling=variant == "rolling",
+                                 scales=scales)
+    caches = [jnp.asarray(_token_major(c))
+              for c in (kc, vc) + (scales or ())]
+    posf = jnp.asarray(pos, jnp.float32)
+    if variant == "rolling":
+        got = att.rolling_cached_attention(q, k, v, *caches, posf,
+                                           window)
+    elif q8:
+        got = att.cached_attention_q8(q, k, v, *caches, posf,
+                                      window=window)
+    else:
+        got = att.cached_attention(q, k, v, *caches, posf,
+                                   window=window)
+    assert len(got) == len(want)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                               rtol=2e-5, atol=2e-6)
+    for g, w in zip(got[1:], want[1:]):
+        assert g.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(g), _token_major(w))
+
+
 def _q8_shared_reference(q, k, v, kc, vc, ks, vs, p0, scale=None,
                          window=0):
     """Pinned copy of the pre-per-row shared-position
-    cached_attention_q8 math. The (1,)-pos path of the live op must
-    stay BITWISE equal to this forever — the per-row dispatch may
-    never reroute or perturb the shared fast path."""
+    cached_attention_q8 math, in the (B, Hkv, C, hd) indexing of its
+    day. What the live op STORES must stay bitwise equal to this
+    forever, whatever layout it stores it in."""
     import jax
     import jax.numpy as jnp
     B_, H_, Tn, D_ = q.shape
@@ -369,18 +485,16 @@ class TestQuantizedKV:
             P = len(prompt)
             for name in aux:
                 want = np.asarray(ref[name])[0]
+                np.testing.assert_array_equal(
+                    aux[name][slot, :P], want[:P])
                 if name.endswith(("_k_scale", "_v_scale")):
-                    np.testing.assert_array_equal(
-                        aux[name][slot, :, :P], want[:, :P])
-                    assert (aux[name][slot, :, :P] > 0).all()
-                else:
-                    np.testing.assert_array_equal(
-                        aux[name][slot, :, :P], want[:, :P])
+                    assert (aux[name][slot, :P] > 0).all()
 
     def test_q8_shared_pos_bitwise_vs_pinned_reference(self):
-        """(1,)-pos cached_attention_q8 is bitwise the pre-per-row
-        implementation; a (B,) pos with equal entries agrees with it
-        up to einsum association order."""
+        """cached_attention_q8 stores bitwise what the pre-per-row
+        implementation stored, under a (1,) pos and under a (B,) pos
+        with equal entries, and its output agrees with it up to the
+        products' association order."""
         import jax.numpy as jnp
         from mxnet_tpu.ops.attention import cached_attention_q8
         rng = np.random.RandomState(37)
@@ -395,18 +509,19 @@ class TestQuantizedKV:
         ks = jnp.asarray(rng.rand(B_, Hkv, C) + 0.01, jnp.float32)
         vs = jnp.asarray(rng.rand(B_, Hkv, C) + 0.01, jnp.float32)
         p0 = 5
-        got = cached_attention_q8(
-            q, k, v, kc, vc, ks, vs, jnp.full((1,), p0, jnp.float32))
+        rows = [jnp.asarray(_token_major(c)) for c in (kc, vc, ks, vs)]
         ref = _q8_shared_reference(q, k, v, kc, vc, ks, vs, p0)
-        for g, r in zip(got, ref):
-            np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
-        per_row = cached_attention_q8(
-            q, k, v, kc, vc, ks, vs,
-            jnp.full((B_,), p0, jnp.float32))
-        for g, r in zip(per_row, ref):
+        for pos in (jnp.full((1,), p0, jnp.float32),
+                    jnp.full((B_,), p0, jnp.float32)):
+            got = cached_attention_q8(q, k, v, *rows, pos)
+            # the products group heads by lane tile now: the same
+            # sums in another order
             np.testing.assert_allclose(
-                np.asarray(g, np.float32), np.asarray(r, np.float32),
+                np.asarray(got[0]), np.asarray(ref[0]),
                 rtol=1e-6, atol=1e-6)
+            for g, r in zip(got[1:], ref[1:]):
+                np.testing.assert_array_equal(np.asarray(g),
+                                              _token_major(r))
 
     def test_per_row_q8_capacity_check(self):
         import jax.numpy as jnp
@@ -415,8 +530,8 @@ class TestQuantizedKV:
         B_, Hkv, Tn, D_, C = 2, 2, 2, 8, 8
         q = jnp.asarray(rng.randn(B_, Hkv, Tn, D_), jnp.float32)
         k = v = q
-        kc = vc = jnp.zeros((B_, Hkv, C, D_), jnp.int8)
-        ks = vs = jnp.zeros((B_, Hkv, C), jnp.float32)
+        kc = vc = jnp.zeros((B_, C, Hkv * D_), jnp.int8)
+        ks = vs = jnp.zeros((B_, C, Hkv), jnp.float32)
         with pytest.raises(ValueError, match="overrun"):
             cached_attention_q8(q, k, v, kc, vc, ks, vs,
                                 jnp.asarray([0.0, 7.0]))
@@ -708,8 +823,7 @@ def _eager_zeros(gen):
     for name in gen._sym.list_auxiliary_states():
         shape, dtype = gen._aux_spec(name)
         z = jnp.zeros(shape, dtype)
-        shard = gen._scale_sharding if len(shape) == 3 \
-            else gen._cache_sharding
+        shard = None if gen.mesh is None else gen._aux_shardings()[name]
         out[name] = z if shard is None else jax.device_put(z, shard)
     return out
 
@@ -965,8 +1079,16 @@ class TestDonatedStep:
         assert held * devices == sum(int(a.nbytes)
                                      for a in dec._aux.values())
         assert aliased == held
-        assert "writes %d of the pool's %d bytes" % (held, held) \
-            in dec.describe(hbm_budget=1e9)
+        text = dec.describe(hbm_budget=1e9)
+        assert "writes %d of the pool's %d bytes" % (held, held) in text
+        # and what a step writes of them: one contiguous row a token
+        # for each length-indexed cache array and slot on a device
+        rows = [a.sharding.shard_shape(a.shape) + (a.dtype.itemsize,)
+                for n, a in dec._aux.items() if not n.endswith("_state")]
+        said = "a token is %d contiguous bytes a row; %d row writes a " \
+            "step" % (max([r[2] * r[3] for r in rows], default=0),
+                      sum(r[0] for r in rows))
+        assert (said in text) if rows else ("contiguous" not in text)
         # a report, not a step: the pool was lowered by shape only
         assert not any(a.is_deleted() for a in dec._aux.values())
 

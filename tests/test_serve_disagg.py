@@ -50,6 +50,16 @@ def _gen(params, batch_size, **kw):
                      batch_size=batch_size, **kw)
 
 
+def _wire(rows, heads):
+    """One sequence's (pos, Hkv*hd) token rows (or (pos, Hkv) int8
+    scales) as a "v": 1 blob ships them: head-major, (Hkv, pos, hd)
+    (or (Hkv, pos)) — what the device kept before PR 30, and what the
+    wire still carries."""
+    pos = rows.shape[0]
+    out = rows.reshape(pos, heads, -1).swapaxes(0, 1)
+    return out.reshape(heads, pos) if rows.shape[1] == heads else out
+
+
 def _ragged(rng, n=4):
     # two DISTINCT prompt lengths only: ragged coverage without a
     # fresh XLA prefill specialization per sequence (tier-1 rides the
@@ -134,7 +144,48 @@ class TestHandoffRoundTrip:
         blob = gen.export_kv_rows(aux, 0, 6)
         for name, arr in blob["rows"].items():
             np.testing.assert_array_equal(
-                arr, np.asarray(aux[name][0, :, :6]))
+                arr, _wire(np.asarray(aux[name][0, :6]), arr.shape[0]))
+
+    @pytest.mark.parametrize("genkw", [
+        {}, {"dtype": "bfloat16"},
+        {"quantize_kv": True, "num_kv_heads": 1}],
+        ids=["f32", "bf16", "int8_gqa"])
+    def test_blob_in_the_parents_row_shape_imports_bit_exact(self, genkw):
+        """Handoff across trees: a "v": 1 blob built BY HAND in the
+        row shape every earlier tree exports — (Hkv, pos, hd) a cache,
+        (Hkv, pos) the int8 scales — lands in the token-contiguous
+        pool bit for bit, token t of head h at lanes [h*hd, (h+1)*hd)
+        of row t, and an export of that slot is the blob again."""
+        import jax.numpy as jnp
+        kv = genkw.get("num_kv_heads", H)
+        gen = _gen(_params(seed=5, num_kv_heads=genkw.get(
+            "num_kv_heads")), B, **genkw)
+        rng = np.random.RandomState(17)
+        pos, hd, rows = 7, DIM // H, {}
+        for name in gen._sym.list_auxiliary_states():
+            dtype = gen._aux_spec(name)[1]
+            if name.endswith(("_k_scale", "_v_scale")):
+                rows[name] = (rng.rand(kv, pos) + 0.01).astype(dtype)
+            elif dtype == jnp.int8:
+                rows[name] = rng.randint(-127, 128, (kv, pos, hd)
+                                         ).astype(np.int8)
+            else:
+                rows[name] = np.asarray(jnp.asarray(
+                    rng.randn(kv, pos, hd), dtype))
+        blob = {"v": 1, "pos": pos, "rows": rows}
+        with gen.serving_decoder() as dec:
+            assert dec.import_kv_rows(1, blob) == pos
+            pool = {n: np.asarray(a) for n, a in dec._aux.items()}
+            back = gen.export_kv_rows(dec._aux, 1, pos)
+        for name, arr in rows.items():
+            assert pool[name].shape[:2] == (B, T)       # token rows
+            for h in range(kv):
+                got = pool[name][1, :pos].reshape(pos, kv, -1)[:, h]
+                np.testing.assert_array_equal(
+                    got.reshape(arr[h].shape), arr[h])
+            assert back["rows"][name].dtype == arr.dtype
+            np.testing.assert_array_equal(back["rows"][name], arr)
+        assert back["v"] == 1 and back["pos"] == pos
 
     def test_int8_blob_smaller_than_f32(self, params):
         """int8 rows + f32 per-token scales undercut the float blob

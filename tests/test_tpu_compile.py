@@ -43,39 +43,95 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
-def test_decode_step_writes_the_pool_in_place_on_v5e(one_chip,
-                                                     no_compile_cache):
-    """The serving step at `opt-1.3b.serve_saturated`'s cache shape (8
-    slots x 32 heads x 1 536 positions x 64, bf16; two layers, narrow
-    FFN and vocabulary to keep it a few seconds): compiled for a v5e,
-    the donated program aliases every byte of the pool, and the
-    per-row write is not the scatter the TPU compiler expands into a
-    `while` that carries, and writes back, each whole cache array."""
+def _entry_results(compiled):
+    """(op, dtype, dims, minor_to_major) of every instruction of the
+    compiled module's ENTRY computation whose result is an array."""
+    import re
+    text = compiled.as_text()
+    out = []
+    for line in text[text.index("\nENTRY"):].splitlines():
+        m = re.match(r"\s+(?:ROOT )?%\S+ = (\w+)\[([\d,]*)\]"
+                     r"(?:\{([\d,]*)[^}]*\})? ([\w-]+)\(", line)
+        if m and m.group(2):
+            out.append((m.group(4), m.group(1),
+                        tuple(int(d) for d in m.group(2).split(",")),
+                        tuple(int(d) for d in (m.group(3) or "").split(",")
+                              if d)))
+    return out
+
+
+# the two serve cells' pools: slots, positions, query heads, kv heads
+# (x 64), and the longest prompt a prefill takes
+POOLS = {"opt-1.3b.serve_saturated": (8, 1536, 32, 32, 1024),
+         "granite-4.0-h-micro.attention": (16, 768, 32, 8, 256)}
+
+
+@pytest.mark.parametrize("pool", sorted(POOLS))
+def test_serve_programs_keep_a_token_contiguous_on_v5e(
+        one_chip, no_compile_cache, pool):
+    """The serving programs at a cell's cache shape (bf16, two layers,
+    narrow FFN and vocabulary to keep it a few seconds), compiled for
+    a v5e. `decode_step`: the donated program aliases every byte of
+    the pool; each cache array lies token-contiguous (its minor-most
+    axis is the row of Hkv*64 values); the per-row write is not the
+    scatter the TPU compiler expands into a `while` that carries, and
+    writes back, each whole cache array; and nothing copies or
+    transposes a whole cache array on the way to the two products.
+    `generator_step` at the longest prompt: no cache-shaped transpose,
+    and no temporary the size of the scores of all heads at once."""
     from cellbench.reference import opt as ref
-    cfg = {"hidden_size": 2048, "num_attention_heads": 32,
+    slots, length, heads, kv_heads, prompt = POOLS[pool]
+    row = kv_heads * 64
+    cfg = {"hidden_size": 2048, "num_attention_heads": heads,
            "ffn_dim": 2048, "vocab_size": 1024, "num_hidden_layers": 2,
-           "max_position_embeddings": 1536}
-    slots = 8
-    gen = Generator(ref.make_params(cfg, 1, "bfloat16"), 1024, 1536,
-                    num_layers=2, num_heads=32, dim=2048,
-                    ffn_hidden=2048, batch_size=slots, dtype="bfloat16")
+           "max_position_embeddings": length}
+    params = {n: a[:2048 + 2 * row] if "_qkv_" in n else a
+              for n, a in ref.make_params(cfg, 1, "bfloat16").items()}
+    gen = Generator(params, 1024, length, num_layers=2, num_heads=heads,
+                    num_kv_heads=kv_heads, dim=2048, ffn_hidden=2048,
+                    batch_size=slots, dtype="bfloat16")
 
     def spec(a):
         return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
 
-    with gen.serving_decoder() as dec:
+    def inputs(data, positions, cache_pos):
         args = {n: spec(a) for n, a in gen._params.items()}
-        args["data"] = args["positions"] = jax.ShapeDtypeStruct(
-            (slots, 1), jnp.float32, sharding=one_chip)
-        args["cache_pos"] = jax.ShapeDtypeStruct(
-            (slots,), jnp.float32, sharding=one_chip)
+        for name, shape in (("data", data), ("positions", positions),
+                            ("cache_pos", cache_pos)):
+            args[name] = jax.ShapeDtypeStruct(shape, jnp.float32,
+                                              sharding=one_chip)
+        return args
+
+    with gen.serving_decoder() as dec:
         aux = {n: spec(a) for n, a in dec._aux.items()}
-        compiled = dec._step_fn.lower(args, aux,
-                                      spec(dec._rng0)).compile()
-    held = sum(int(np.prod(a.shape)) * a.dtype.itemsize
-               for a in aux.values())
-    assert held == 4 * slots * 32 * 1536 * 64 * 2
-    assert compiled.memory_analysis().alias_size_in_bytes == held
-    text = compiled.as_text()
+        step = dec._step_fn.lower(
+            inputs((slots, 1), (slots, 1), (slots,)), aux,
+            spec(dec._rng0)).compile()
+        prefill = gen._step_fn.lower(
+            inputs((slots, prompt), (prompt,), (1,)), aux,
+            spec(dec._rng0)).compile()
+    cache = (slots, length, row)
+    assert {a.shape for a in aux.values()} == {cache}
+    held = 4 * int(np.prod(cache)) * 2
+    assert step.memory_analysis().alias_size_in_bytes == held
+    text = step.as_text()
     assert " while(" not in text
     assert text.count(" dynamic-update-slice(") >= 4 * slots
+    whole = int(np.prod(cache))
+    results = _entry_results(step)
+    params_seen = [r for r in results
+                   if r[0] == "parameter" and r[2] == cache]
+    assert len(params_seen) == 4
+    for op, _dtype, dims, minor_to_major in results:
+        if dims == cache:
+            # a token's row is the lane axis wherever the array goes
+            assert minor_to_major[0] == 2, (op, minor_to_major)
+        if int(np.prod(dims)) >= whole:
+            assert op not in ("copy", "transpose"), (op, dims)
+    # nothing cache-sized beside the pool: written where it lies
+    assert step.memory_analysis().temp_size_in_bytes < whole * 2
+    for op, _dtype, dims, _layout in _entry_results(prefill):
+        if int(np.prod(dims)) >= whole:
+            assert op != "transpose", (op, dims)
+    scores = slots * heads * prompt * length * 4
+    assert prefill.memory_analysis().temp_size_in_bytes < scores // 2
