@@ -30,7 +30,79 @@ from . import telemetry as _telemetry
 from .executor import _graph_eval_fn
 from .models import transformer
 
-__all__ = ["Generator", "kv_blob_nbytes", "replay_key"]
+__all__ = ["Generator", "kv_blob_nbytes", "replay_key",
+           "canon_diffusion", "unmask_choice", "block_picks"]
+
+REMASKING = ("sequential", "low_confidence_static",
+             "low_confidence_dynamic")
+
+
+def canon_diffusion(diffusion):
+    """``Generator(diffusion=...)`` as a plain dict with every key:
+    ``block_length`` L and ``mask_id`` (required), ``steps`` T a block
+    (default L: one token a forward; L must be a multiple of it),
+    ``remasking`` (one of REMASKING, default "low_confidence_static"),
+    ``threshold`` (0.9; "low_confidence_dynamic" reads it). None stays
+    None."""
+    if diffusion is None:
+        return None
+    d = dict(diffusion)
+    unknown = set(d) - {"block_length", "mask_id", "steps", "remasking",
+                        "threshold"}
+    if unknown or "block_length" not in d or "mask_id" not in d:
+        raise ValueError(
+            "diffusion=dict(block_length=, mask_id=[, steps=, "
+            "remasking=, threshold=]), got %r" % (diffusion,))
+    L = int(d["block_length"])
+    T = int(d.get("steps") or L)
+    rule = d.get("remasking", "low_confidence_static")
+    if L < 1 or T < 1 or L % T:
+        raise ValueError("diffusion: block_length (%d) must be a "
+                         "positive multiple of steps (%d)" % (L, T))
+    if rule not in REMASKING:
+        raise ValueError("diffusion: remasking must be one of %r, got "
+                         "%r" % (REMASKING, rule))
+    return {"block_length": L, "mask_id": int(d["mask_id"]), "steps": T,
+            "remasking": rule,
+            "threshold": float(d.get("threshold", 0.9))}
+
+
+def block_picks(logits):
+    """What a denoising forward hands back in place of its logits:
+    each position's best id (int32) and that id's probability,
+    ``max softmax`` in float32 — on the device, so a step returns
+    2 x (B, L) numbers and not (B, L, V)."""
+    lf = logits.astype(jnp.float32)
+    top = lf.max(axis=-1)
+    conf = 1.0 / jnp.exp(lf - top[..., None]).sum(axis=-1)
+    return jnp.argmax(lf, axis=-1).astype(jnp.int32), conf
+
+
+def unmask_choice(masked, conf, diffusion):
+    """The positions of ONE block to unmask after a denoising forward:
+    masked (L,) bool (at least one True), conf (L,) float -> (L,) bool,
+    a subset of ``masked``. "sequential": the leftmost L / T masked
+    positions. "low_confidence_static": the L / T of highest
+    confidence (a tie goes to the left). "low_confidence_dynamic":
+    every one whose confidence is over the threshold, and the most
+    confident one where none is. Fewer remain than L / T: all of
+    them. An unmasked position is never masked again."""
+    masked = np.asarray(masked, bool)
+    where = np.flatnonzero(masked)
+    per_step = diffusion["block_length"] // diffusion["steps"]
+    rule = diffusion["remasking"]
+    conf = np.asarray(conf, np.float64)[where]
+    if rule == "sequential":
+        take = where[:per_step]
+    elif rule == "low_confidence_static":
+        take = where[np.argsort(-conf, kind="stable")[:per_step]]
+    else:
+        take = where[conf > diffusion["threshold"]]
+        if not len(take):
+            take = where[[np.argmax(conf)]]
+    out = np.zeros_like(masked)
+    out[take] = True
+    return out
 
 
 def kv_blob_nbytes(blob):
@@ -79,6 +151,25 @@ class Generator:
         Megatron column-parallel weights over a 'model' axis, experts
         over 'expert'), KV caches shard heads over 'model' and batch
         over 'data'; GSPMD inserts the collectives.
+    num_experts, experts_per_token, expert_hidden, norm_topk_prob,
+    head_dim, qk_norm, rope_base :
+        Architecture, as get_decode_symbol documents them: routed
+        expert layers (top-k, nothing dropped), a head size apart from
+        dim / num_heads, per-head RMS norm of q and k, the rotary base.
+    diffusion : optional dict — generation by diffusion over blocks.
+        ``dict(block_length=L, mask_id=, steps=T, remasking=,
+        threshold=)`` (:func:`canon_diffusion`). Attention takes the
+        block mask (position i sees j iff floor(j/L) <= floor(i/L)),
+        for the prompt too. :meth:`generate` then prefills the
+        prompt's whole blocks and fills one block of L positions at a
+        time: the block starts as the prompt's remainder followed by
+        ``mask_id``; each denoising forward over it predicts every
+        masked position's OWN token (no shift) and unmasks some by the
+        ``remasking`` rule (:func:`unmask_choice`); when no mask is
+        left one commit forward over the clean block stores its keys
+        and values — T + 1 forwards a block, the commit apart — and
+        the next block begins. Greedy only; the sampling, beam,
+        speculative and scoring entry points refuse it.
     """
 
     def __init__(self, arg_params, vocab_size, max_len, num_layers=2,
@@ -90,7 +181,10 @@ class Generator:
                  norm="layer", norm_eps=1e-5, ffn="relu", use_bias=True,
                  tie_embeddings=False, embedding_multiplier=1.0,
                  residual_multiplier=1.0, logits_scaling=1.0,
-                 attention_scale=None, mamba2=None):
+                 attention_scale=None, mamba2=None,
+                 experts_per_token=1, expert_hidden=None,
+                 norm_topk_prob=False, head_dim=None, qk_norm=False,
+                 rope_base=None, diffusion=None):
         from .parallel import sharding as shd
 
         if quantize not in (None, "int8"):
@@ -115,8 +209,14 @@ class Generator:
         self.mesh = mesh
         self._window = int(attention_window or 0)
         self._rolling = bool(rolling_cache)
-        head_dim = dim // num_heads
+        head_dim = int(head_dim or dim // num_heads)
         kv_heads = int(num_kv_heads or num_heads)
+        self._diffusion = canon_diffusion(diffusion)
+        if self._diffusion and (rolling_cache or quantize_kv or
+                                attention_window):
+            raise ValueError("diffusion is built for the plain cache "
+                             "(no rolling_cache, quantize_kv or "
+                             "attention_window)")
         # block_type validation happens in get_decode_symbol below;
         # the flags steer slot-state accounting and the serving-layer
         # compatibility refusals (speculative drafts, prefill grouping)
@@ -145,7 +245,12 @@ class Generator:
             embedding_multiplier=embedding_multiplier,
             residual_multiplier=residual_multiplier,
             logits_scaling=logits_scaling,
-            attention_scale=attention_scale, mamba2=mamba2)
+            attention_scale=attention_scale, mamba2=mamba2,
+            experts_per_token=experts_per_token,
+            expert_hidden=expert_hidden, norm_topk_prob=norm_topk_prob,
+            head_dim=head_dim, qk_norm=qk_norm, rope_base=rope_base,
+            attention_block=self._diffusion["block_length"]
+            if self._diffusion else 0)
         sym = transformer.get_decode_symbol(**self._decode_opts)
         if quantize:
             arg_params = _quantize_weights(
@@ -161,6 +266,15 @@ class Generator:
 
         self._step_fn = jax.jit(generator_step)
         self._loop_cache = {}
+
+        def block_prefill(args, aux, rng):
+            # a diffusion prefill reads no logits (the first tokens
+            # come from the first block's denoising forward): only the
+            # caches come back, so the final norm and the head, dead
+            # code here, are never computed
+            return eval_fn(args, aux, rng, False)[1]
+
+        self._prefill_fn = jax.jit(block_prefill)
 
         def _raw(name, v):
             arr = jnp.asarray(getattr(v, "_data", v))
@@ -454,12 +568,29 @@ class Generator:
                     "learned positions cap total length at the table "
                     "(%d rows); use pos_encoding='rope' for unbounded "
                     "rolling generation" % self._pos_rows)
-        elif P + max_new_tokens > self.max_len:
+        elif self.block_span(P, max_new_tokens) > self.max_len:
             raise ValueError(
                 "prompt (%d) + max_new_tokens (%d) exceeds the cache "
                 "capacity max_len=%d" % (P, max_new_tokens,
                                          self.max_len))
         return prompt, P
+
+    def block_span(self, P, max_new_tokens):
+        """Positions a request touches: P + max_new_tokens, rounded up
+        to whole blocks under ``diffusion`` (the last block is run
+        whole, though the row keeps max_new_tokens of it)."""
+        total = int(P) + int(max_new_tokens)
+        if self._diffusion:
+            L = self._diffusion["block_length"]
+            total = -(-total // L) * L
+        return total
+
+    def _refuse_diffusion(self, what):
+        if self._diffusion:
+            raise ValueError(
+                "%s is not supported with diffusion= (generation by "
+                "diffusion fills whole blocks, greedily, through "
+                "generate() or the serving decoder)" % what)
 
     def _aux_shardings(self):
         """Placement of every decode-state aux under a mesh (the int8
@@ -524,6 +655,7 @@ class Generator:
         over the sequence, via one prefill pass. tokens: (B, Tseq) with
         Tseq <= max_len; returns (B,) float64. The serving-side eval
         utility (perplexity = exp(-ll / (Tseq - 1)))."""
+        self._refuse_diffusion("log_likelihood")
         tokens = np.asarray(tokens)
         if tokens.ndim != 2 or tokens.shape[0] != self.batch_size:
             raise ValueError("tokens must be (batch_size, T), got %r"
@@ -558,6 +690,7 @@ class Generator:
         eos_id: a beam that emits eos is frozen (only eos continues it,
         at no score change); search stops early when every beam of
         every row is frozen."""
+        self._refuse_diffusion("beam_search")
         prompt, P = self._check_prompt(prompt, max_new_tokens)
         B, W, V = self.batch_size, int(beam_size), self.vocab_size
         if W < 1:
@@ -646,6 +779,7 @@ class Generator:
         distinct
         (prompt_len, max_new_tokens, beam_size, eos_id) compiles once.
         Returns (B, P + n) ids."""
+        self._refuse_diffusion("beam_search_on_device")
         prompt, P = self._check_prompt(prompt, max_new_tokens)
         B, W = self.batch_size, int(beam_size)
         if W < 1:
@@ -801,6 +935,7 @@ class Generator:
         (the accepted length each round is the minimum across rows) —
         the serving decoder's per-slot rounds lift that restriction;
         B=1 is the classic setting here."""
+        self._refuse_diffusion("speculative decoding")
         if draft.vocab_size != self.vocab_size or \
                 draft.batch_size != self.batch_size:
             raise ValueError("draft must share vocab_size/batch_size "
@@ -915,6 +1050,7 @@ class Generator:
         ``batch_size``/``max_len`` default to this model's (the
         serving decoder wants the same slot-pool shape; give the draft
         a larger max_len only if you need extra lookahead headroom)."""
+        self._refuse_diffusion("truncated_draft")
         o = self._decode_opts
         if o["quantized"]:
             raise ValueError(
@@ -966,6 +1102,7 @@ class Generator:
         `lookahead` and emissions are clamped to the remaining budget,
         so both caches need headroom — max_len >= P + max_new_tokens +
         lookahead on target AND draft (validated here)."""
+        self._refuse_diffusion("speculative decoding")
         if draft.vocab_size != self.vocab_size or \
                 draft.batch_size != self.batch_size:
             raise ValueError("draft must share vocab_size/batch_size "
@@ -1154,6 +1291,7 @@ class Generator:
         same tokens, different tail). Each distinct
         (prompt_len, max_new_tokens, temperature, top_k, top_p,
         eos_id) tuple compiles once."""
+        self._refuse_diffusion("generate_on_device")
         self._check_sampling(temperature, top_k, top_p)
         prompt, P = self._check_prompt(prompt, max_new_tokens)
         if int(max_new_tokens) == 0:
@@ -1265,9 +1403,58 @@ class Generator:
         from .serve.decode import ContinuousDecoder
         return ContinuousDecoder(self, **kwargs)
 
+    def _prefill(self, aux, tokens):
+        """The caches after ``tokens`` (B, P0) from position 0, and
+        nothing else: a diffusion prefill reads no logits."""
+        args = dict(self._params)
+        args["data"] = jnp.asarray(tokens, jnp.float32)
+        args["positions"] = jnp.arange(tokens.shape[1],
+                                       dtype=jnp.float32)
+        args["cache_pos"] = jnp.zeros((1,), jnp.float32)
+        return self._prefill_fn(args, aux, jax.random.PRNGKey(0))
+
+    def _generate_blocks(self, prompt, P, n, on_token, on_block_logits):
+        """generate() under ``diffusion``: T + 1 forwards a block (T
+        denoising, one commit; the last block needs no commit). Rows
+        share their positions, so one row may ride a forward it has
+        no mask left for while another still denoises."""
+        d = self._diffusion
+        L, mask_id = d["block_length"], d["mask_id"]
+        B = self.batch_size
+        P0 = P // L * L
+        aux = self._fresh_aux()
+        if P0:
+            aux = self._prefill(aux, prompt[:, :P0])
+        out = np.concatenate(
+            [prompt.astype(np.int64),
+             np.full((B, self.block_span(P, n) - P), mask_id, np.int64)],
+            axis=1)
+        known = np.zeros(out.shape, bool)
+        known[:, :P] = True
+        for pos in range(P0, out.shape[1], L):
+            ids, masked = out[:, pos:pos + L], ~known[:, pos:pos + L]
+            while masked.any():
+                logits, _ = self._forward(aux, ids, pos)
+                best, conf = (np.asarray(a) for a in
+                              block_picks(logits))
+                if on_block_logits is not None:
+                    on_block_logits(pos, ids.copy(), masked.copy(),
+                                    np.asarray(logits, np.float32))
+                for b in range(B):
+                    if masked[b].any():
+                        take = unmask_choice(masked[b], conf[b], d)
+                        ids[b, take] = best[b, take]
+                        masked[b, take] = False
+            if on_token is not None:
+                for p in range(max(pos, P), min(pos + L, P + n)):
+                    on_token(out[:, p].copy())
+            if pos + L < out.shape[1]:
+                _, aux = self._forward(aux, ids, pos)     # commit
+        return out[:, :P + n]
+
     def generate(self, prompt, max_new_tokens, temperature=0.0,
                  top_k=None, top_p=None, eos_id=None, seed=0,
-                 on_token=None):
+                 on_token=None, on_block_logits=None):
         """Greedy (temperature 0) or sampled continuation.
 
         prompt: (B, P) int token ids. Returns (B, P + n) ids as numpy
@@ -1276,9 +1463,29 @@ class Generator:
         with each round's (B,) numpy token array as soon as it is
         picked — the local twin of the serve path's streamed frames
         (the returned rows are exactly the concatenation the callback
-        saw, so callers can cross-check stream against one-shot)."""
+        saw, so callers can cross-check stream against one-shot).
+
+        Under ``diffusion`` the continuation is filled a block at a
+        time (see the class docstring): always (B, P + max_new_tokens)
+        ids, greedy; ``on_token`` sees each position's (B,) ids, in
+        order, as each block completes; ``on_block_logits(pos, ids,
+        masked, logits)`` sees every denoising forward: the block's
+        first position, its (B, L) input ids (``mask_id`` where
+        masked), the (B, L) mask and the float32 (B, L, V) logits."""
         self._check_sampling(temperature, top_k, top_p)
         prompt, P = self._check_prompt(prompt, max_new_tokens)
+        if self._diffusion:
+            if (temperature and float(temperature) > 0) or \
+                    eos_id is not None:
+                raise ValueError("diffusion generate() is greedy and "
+                                 "of fixed length: no temperature, no "
+                                 "eos_id")
+            if int(max_new_tokens) == 0:
+                return np.asarray(prompt, np.int64)
+            return self._generate_blocks(prompt, P, int(max_new_tokens),
+                                         on_token, on_block_logits)
+        if on_block_logits is not None:
+            raise ValueError("on_block_logits needs diffusion=")
         key = jax.random.PRNGKey(seed)
         aux = self._fresh_aux()
         logits, aux = self._forward(aux, prompt, 0)

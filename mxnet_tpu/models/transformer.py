@@ -30,7 +30,8 @@ def _fc(x, num_hidden, name, quantized=False, no_bias=False):
 
 
 def _qkv_heads(x, num_heads, dim, prefix, quantized=False,
-               num_kv_heads=None, no_bias=False):
+               num_kv_heads=None, no_bias=False, head_dim=None,
+               qk_norm_eps=None):
     """Shared qkv projection + head split: (B, T, C) -> q (B, H, T, hd)
     and k/v (B, Hkv, T, hd). The training and decode attention blocks
     both use this so their parameter packing can never drift (a repack
@@ -42,20 +43,30 @@ def _qkv_heads(x, num_heads, dim, prefix, quantized=False,
     stores only Hkv heads — the modern serving memory/bandwidth
     saver. The packing layout [q | k | v] along the output dim equals
     the historical fused-3C layout when Hkv == H, so existing
-    checkpoints bind unchanged."""
+    checkpoints bind unchanged.
+
+    head_dim: a head size the model states apart from dim / num_heads
+    (the q block is then H*hd wide, not dim). qk_norm_eps: when given,
+    every head of q and of k is RMS-normalised over its own hd
+    channels with one learned gain each ("<prefix>q_norm_gamma",
+    "<prefix>k_norm_gamma", (hd,)), before any rotation."""
     Hkv = int(num_kv_heads or num_heads)
-    head_dim = dim // num_heads
+    head_dim = int(head_dim or dim // num_heads)
+    q_dim = num_heads * head_dim
     kv_dim = Hkv * head_dim
-    qkv = _fc(x, dim + 2 * kv_dim, prefix + "qkv", quantized, no_bias)
+    qkv = _fc(x, q_dim + 2 * kv_dim, prefix + "qkv", quantized, no_bias)
 
     def cut(begin, end, heads):
         part = sym.slice_axis(qkv, axis=2, begin=begin, end=end)
         part = sym.reshape(part, shape=(0, 0, heads, head_dim))
         return sym.transpose(part, axes=(0, 2, 1, 3))  # (B, H, T, hd)
 
-    return (cut(0, dim, num_heads),
-            cut(dim, dim + kv_dim, Hkv),
-            cut(dim + kv_dim, dim + 2 * kv_dim, Hkv))
+    q = cut(0, q_dim, num_heads)
+    k = cut(q_dim, q_dim + kv_dim, Hkv)
+    if qk_norm_eps is not None:
+        q = sym.RMSNorm(q, eps=qk_norm_eps, name=prefix + "q_norm")
+        k = sym.RMSNorm(k, eps=qk_norm_eps, name=prefix + "k_norm")
+    return q, k, cut(q_dim + kv_dim, q_dim + 2 * kv_dim, Hkv)
 
 
 def _merge_heads_proj(att, dim, prefix, quantized=False,
@@ -174,6 +185,31 @@ def _moe_block(x, dim, hidden, num_experts, prefix, expert_axis=None,
                               name=prefix + "moe")
 
 
+def _routed_block(x, dim, hidden, num_experts, prefix, top_k=1,
+                  kind="relu", renormalize=False):
+    """The expert layer as it is served (_contrib_RoutedExperts): the
+    top_k experts by float32 softmax score, every routed (token,
+    expert) pair computed and nothing dropped. Binds the parameter
+    names of _moe_block, so a Switch checkpoint (top_k 1, "relu")
+    decodes through it; kind "gated_silu" makes experts_w1 twice as
+    wide, [gate | up], by the rule of _ffn_block's fc1. Returns (y,
+    stats): the layer's output and its (3,) int32 counts."""
+    if kind not in ("relu", "gated_silu"):
+        raise ValueError("ffn must be 'relu' or 'gated_silu', got %r"
+                         % (kind,))
+    wide = 2 * hidden if kind == "gated_silu" else hidden
+    gate = sym.Variable(prefix + "gate_weight", shape=(dim, num_experts))
+    w1 = sym.Variable(prefix + "experts_w1_weight",
+                      shape=(num_experts, dim, wide))
+    w2 = sym.Variable(prefix + "experts_w2_weight",
+                      shape=(num_experts, hidden, dim))
+    out = sym.contrib.RoutedExperts(x, gate, w1, w2, top_k=int(top_k),
+                                    act=kind,
+                                    renormalize=bool(renormalize),
+                                    name=prefix + "moe")
+    return out[0], out[1]
+
+
 def _check_kv_heads(num_heads, num_kv_heads):
     if num_kv_heads and num_heads % int(num_kv_heads):
         raise ValueError(
@@ -286,7 +322,8 @@ def _decode_attention_block(x, num_heads, dim, prefix, max_len, pos,
                             quantized=False, rope_positions=None,
                             window=0, rolling=False,
                             num_kv_heads=None, kv_quantize=False,
-                            scale=None, no_bias=False):
+                            scale=None, no_bias=False, head_dim=None,
+                            qk_norm_eps=None, rope_base=None, block=0):
     """Incremental variant of _attention_block: identical qkv/proj
     helpers (a training checkpoint binds unchanged), attention routed
     through _contrib_CachedAttention with per-layer k/v cache aux
@@ -294,15 +331,25 @@ def _decode_attention_block(x, num_heads, dim, prefix, max_len, pos,
     state_inputs registration). kv_quantize routes through the int8
     variant (_contrib_CachedAttentionQ8), which adds per-token scale
     aux states ("_k_scale"/"_v_scale"). scale: the score multiplier
-    where the model states one (default head_dim ** -0.5)."""
+    where the model states one (default head_dim ** -0.5). head_dim,
+    qk_norm_eps: see _qkv_heads. rope_base: the rotation's base where
+    it is not 10000. block: the block mask of _contrib_CachedAttention
+    (position i sees position j iff j's block is not after i's)."""
     q, k, v = _qkv_heads(x, num_heads, dim, prefix, quantized,
-                         num_kv_heads=num_kv_heads, no_bias=no_bias)
+                         num_kv_heads=num_kv_heads, no_bias=no_bias,
+                         head_dim=head_dim, qk_norm_eps=qk_norm_eps)
     kw = {} if scale is None else {"scale": float(scale)}
+    if block:
+        if rolling or kv_quantize:
+            raise ValueError("attention_block is built for the plain "
+                             "cache only (no rolling, no int8 cache)")
+        kw["block"] = int(block)
     if rope_positions is not None:
         # rotate BEFORE caching: cached keys carry their absolute
         # rotation, so each step only rotates the new tokens
-        q = sym.contrib.RoPE(q, rope_positions)
-        k = sym.contrib.RoPE(k, rope_positions)
+        rkw = {} if rope_base is None else {"base": float(rope_base)}
+        q = sym.contrib.RoPE(q, rope_positions, **rkw)
+        k = sym.contrib.RoPE(k, rope_positions, **rkw)
     if rolling:
         att = sym.contrib.RollingCachedAttention(
             q, k, v, pos=pos, max_len=max_len, window=window,
@@ -396,7 +443,11 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
                       norm_eps=1e-5, ffn="relu", use_bias=True,
                       tie_embeddings=False, embedding_multiplier=1.0,
                       residual_multiplier=1.0, logits_scaling=1.0,
-                      attention_scale=None, mamba2=None):
+                      attention_scale=None, mamba2=None,
+                      experts_per_token=1, expert_hidden=None,
+                      norm_topk_prob=False, head_dim=None,
+                      qk_norm=False, rope_base=None, attention_block=0,
+                      moe_stats=False):
     """Autoregressive-decode twin of get_symbol.
 
     Inputs: data (B, Tnew) token ids for the tokens being appended
@@ -448,13 +499,34 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
     is added, logits_scaling DIVIDES the logits; attention_scale
     replaces head_dim ** -0.5.
 
+    num_experts > 0 makes every layer's FFN a routed expert layer
+    (_contrib_RoutedExperts, parallel/moe.py::routed_experts): the
+    experts_per_token largest float32 softmax scores (divided by
+    their sum under norm_topk_prob), every routed pair computed,
+    nothing dropped; experts of width expert_hidden (default
+    ffn_hidden) and of kind `ffn`. The defaults (top-1, "relu", the
+    score itself as the weight) serve a Switch checkpoint trained
+    through get_symbol. moe_stats=True adds a second output, (layers,
+    3) int32: each layer's pairs computed, distinct experts hit and
+    largest expert batch. head_dim: a head size other than dim /
+    num_heads (the q block and the out-projection's input are then
+    num_heads * head_dim wide; the cache rows Hkv * head_dim).
+    qk_norm: each head of q and k RMS-normalised with a learned gain,
+    before the rotation. rope_base: the rotary base where it is not
+    10000. attention_block=L (> 0): the BLOCK mask in place of the
+    causal one — position i sees position j iff floor(j / L) <=
+    floor(i / L): causal across blocks of L, both ways inside one —
+    for prefill and step alike.
+
     New TPU-native capability (the 2017 reference's decode story was
     rnn.RNNCell step-wise unrolling); mxnet_tpu.generation.Generator
     drives this symbol."""
     ffn_hidden = ffn_hidden or 4 * dim
-    if dim % num_heads:
+    if not head_dim and dim % num_heads:
         raise ValueError("dim (%d) must be divisible by num_heads (%d)"
                          % (dim, num_heads))
+    if moe_stats and not num_experts:
+        raise ValueError("moe_stats needs num_experts > 0")
     _check_kv_heads(num_heads, num_kv_heads)
     btypes = _canon_block_types(block_type, num_layers)
     mamba2 = _canon_mamba2(mamba2, btypes)
@@ -543,6 +615,7 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
         return sym.contrib.AddScaledF32(
             x, branch, scalar=float(residual_multiplier))
 
+    layer_stats = []
     for i in range(num_layers):
         prefix = "layer%d_" % i
         a = _norm(x, prefix + "ln1", norm, norm_eps)
@@ -562,19 +635,27 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
                 rope_positions=rope_positions,
                 window=attention_window, rolling=rolling_cache,
                 kv_quantize=kv_quantize, scale=attention_scale,
-                no_bias=no_bias)
+                no_bias=no_bias, head_dim=head_dim,
+                qk_norm_eps=norm_eps if qk_norm else None,
+                rope_base=rope_base, block=attention_block)
         x = residual(x, mixed)
         f = _norm(x, prefix + "ln2", norm, norm_eps)
-        # inference never capacity-drops: every token is served, so
-        # the factor is raised to E (cap == token count). Training-time
-        # drops mean a dropping checkpoint's decode can differ exactly
-        # where training zeroed a token's FFN. (MoE expert weights stay
-        # float — quantized= covers the dense projections.)
-        ff = _moe_block(f, dim, ffn_hidden, num_experts, prefix,
-                        capacity_factor=num_experts) \
-            if num_experts else _ffn_block(f, dim, ffn_hidden, prefix,
-                                           quantized=quantized,
-                                           kind=ffn, no_bias=no_bias)
+        if num_experts:
+            # inference never capacity-drops: every token is served,
+            # and only the routed pairs are computed. Training-time
+            # drops mean a dropping checkpoint's decode can differ
+            # exactly where training zeroed a token's FFN. (Expert
+            # weights stay float — quantized= covers the dense
+            # projections.)
+            ff, stats = _routed_block(
+                f, dim, expert_hidden or ffn_hidden, num_experts,
+                prefix, top_k=experts_per_token, kind=ffn,
+                renormalize=norm_topk_prob)
+            layer_stats.append(stats)
+        else:
+            ff = _ffn_block(f, dim, ffn_hidden, prefix,
+                            quantized=quantized, kind=ffn,
+                            no_bias=no_bias)
         x = residual(x, ff)
 
     x = _norm(x, "ln_f", norm, norm_eps)
@@ -588,6 +669,9 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
     if logits_scaling != 1.0:
         logits = sym.contrib.ScaleF32(
             logits, scalar=1.0 / float(logits_scaling))
+    if moe_stats:
+        return sym.Group([logits, sym.stack(
+            *layer_stats, axis=0, num_args=len(layer_stats))])
     return logits
 
 

@@ -737,12 +737,22 @@ def _attend(query, k_cache, v_cache, valid, scale, k_scale=None,
     return o.reshape(B, H, Tn, D)
 
 
-def _causal(pos, Tn, C, window):
+def _causal(pos, Tn, C, window, block=0):
     """(1 or B, Tn, C) mask: new row r of batch row b, which sits at
     pos[b] + r, attends cache column c iff c <= pos[b] + r and, under
-    a window, pos[b] + r - c < window. pos: () or (B,) int."""
+    a window, pos[b] + r - c < window. pos: () or (B,) int.
+
+    block=L (> 0) is the BLOCK mask instead: the row at position i
+    attends column j iff floor(j / L) <= floor(i / L) — causal across
+    blocks of L positions, both ways inside one, so the new rows of
+    one block see each other whatever their order."""
     at = jnp.reshape(pos, (-1, 1, 1)) + jnp.arange(Tn)[None, :, None]
     cols = jnp.arange(C)[None, None, :]
+    if block:
+        if window:
+            raise ValueError("the block mask takes no window")
+        with jax.named_scope("attn.block"):
+            return cols // block <= at // block
     valid = cols <= at
     if window:
         valid = valid & (at - cols < window)
@@ -800,7 +810,7 @@ def _write_rows(cache, new, pos):
 
 
 def cached_attention(query, key, value, k_cache, v_cache, pos,
-                     scale=None, window=0):
+                     scale=None, window=0, block=0):
     """Incremental-decode attention over a KV cache.
 
     query/key/value: (B, H, Tnew, hd) — projections of the Tnew tokens
@@ -832,6 +842,18 @@ def cached_attention(query, key, value, k_cache, v_cache, pos,
     Decode is bandwidth-bound (one (Tnew, Tmax) strip per head), so
     this is a plain jnp composition — XLA fuses the mask+softmax; the
     MXU-dense training path stays with the Pallas flash kernel.
+
+    BLOCK MASK (block=L > 0; generation by diffusion over blocks):
+    row i attends column j iff floor(j / L) <= floor(i / L)
+    (:func:`_causal`). The Tnew new rows are written first, as ever,
+    so the rows of one block see each other both ways. A forward that
+    must leave the cache as it was (a denoising pass over a block that
+    is not final) is this same call with the caller keeping ``pos``
+    where it was: its rows land past the cached prefix, where the next
+    forward at the same ``pos`` overwrites them before any row can
+    attend them — the rule that makes a rejected speculative entry
+    harmless. The forward that keeps its rows is the one after which
+    the caller advances ``pos``.
     Returns (out, new_k_cache, new_v_cache)."""
     B, H, Tn, D = query.shape
     _check_heads(query, k_cache)
@@ -845,7 +867,8 @@ def cached_attention(query, key, value, k_cache, v_cache, pos,
     v_cache = _write_rows(v_cache,
                           _token_rows(value).astype(v_cache.dtype), pos)
     out = _attend(query, k_cache, v_cache,
-                  _causal(pos, Tn, C, int(window or 0)), float(scale))
+                  _causal(pos, Tn, C, int(window or 0), int(block or 0)),
+                  float(scale))
     return out.astype(query.dtype), k_cache, v_cache
 
 
@@ -949,15 +972,17 @@ def _rolling_cached_attention_op(query, key, value, k_cache, v_cache,
                      "pos"),
           state_inputs=(3, 4), nondiff_inputs=(5,),
           differentiable=False,
-          defaults={"scale": None, "max_len": 0, "window": 0})
+          defaults={"scale": None, "max_len": 0, "window": 0,
+                    "block": 0})
 def _cached_attention_op(query, key, value, k_cache, v_cache, pos,
-                         scale=None, window=0, **_):
+                         scale=None, window=0, block=0, **_):
     """(B, H, Tnew, hd) decode attention; k_cache/v_cache
     ((B, max_len, Hkv*hd)) are aux states updated in place (the executor threads them like BN moving
     stats — but unconditionally, since appending to the cache is the
     op's purpose at inference)."""
     return cached_attention(query, key, value, k_cache, v_cache, pos,
-                            scale=scale, window=int(window or 0))
+                            scale=scale, window=int(window or 0),
+                            block=int(block or 0))
 
 
 def _q8_quantize(x):

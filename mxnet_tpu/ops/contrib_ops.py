@@ -212,3 +212,28 @@ def _moe_ffn_op(data, gate_weight, expert_w1, expert_w2,
     out = dense_moe(x, gate_weight, expert_w1, expert_w2,
                     capacity_factor=float(capacity_factor))
     return out.astype(data.dtype).reshape(orig_shape)
+
+
+@register("_contrib_RoutedExperts",
+          arg_names=("data", "gate_weight", "expert_w1", "expert_w2"),
+          differentiable=False, num_visible=2,
+          defaults={"top_k": 1, "act": "relu", "renormalize": False})
+def _routed_experts_op(data, gate_weight, expert_w1, expert_w2, top_k=1,
+                       act="relu", renormalize=False, **_):
+    """Top-k mixture-of-experts FFN as it is served: float32 softmax
+    routing, every routed (token, expert) pair computed by a grouped
+    product over the ragged per-expert batches, nothing dropped under
+    any imbalance (parallel/moe.py::routed_experts; contrast
+    _contrib_MoEFFN, the capacity-buffer training form).
+
+    data (B, T, D) or (N, D); gate_weight (D, E); expert_w1 (E, D, H)
+    for act "relu", (E, D, 2H) = [gate | up] for "gated_silu";
+    expert_w2 (E, H, D). Outputs: y, shaped like data, and stats (3,)
+    int32 = pairs computed, distinct experts hit, largest expert
+    batch. Inference-only."""
+    from ..parallel.moe import routed_experts
+    y, stats = routed_experts(
+        data.reshape(-1, data.shape[-1]), gate_weight, expert_w1,
+        expert_w2, top_k=int(top_k), act=str(act),
+        renormalize=bool(renormalize))
+    return y.reshape(data.shape), stats

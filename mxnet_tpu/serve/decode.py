@@ -79,6 +79,29 @@ draft-less replica admits the same request down the ordinary (B, 1)
 path with identical output. Every emission funnels through
 :meth:`_emit` one token at a time, so TTFT/inter-token metrics,
 streamed frames and mid-stream failover cursors work unchanged.
+
+Generation by diffusion over blocks (``Generator(diffusion=...)``):
+the pool's one compiled program is then ``block_step``, (B, L) ids in
+at each row's own block start, and a step no longer yields one token a
+row. A row carries its block's ids and which of its L positions are
+still masked (by position: a prompt or an answer may hold the mask
+id); a row with a mask left rides the step as a DENOISING forward —
+the step returns each position's best id and its confidence, picked on
+the device, and the row unmasks some by the generator's rule — and a
+row with none left rides it as the COMMIT forward of its clean block,
+after which its cache depth advances by L and its next block opens.
+Both are the same forward: a denoising forward's rows land past the
+row's cached depth and the next forward at that depth overwrites them
+(T + 1 forwards a block, the commit apart; fusing the commit with the
+next block's first step is the next step). A step emits 0 to L tokens
+a row, each as soon as it and all before it are unmasked, one at a
+time through :meth:`_emit`. The step after is dispatched BEFORE a
+step's tokens are emitted (its inputs need only the rows' new block
+states), so the device runs while the host emits, finishes and
+admits; a row admitted meanwhile joins the step after, and a finished
+row rides no further forward. Admission prefills the prompt's whole
+blocks and picks nothing. Greedy only; drafts, chunked prefill,
+handoff, resume and session export refuse such a generator.
 """
 from __future__ import annotations
 
@@ -96,7 +119,8 @@ from .. import config as _config
 from .. import telemetry as _telemetry
 from .. import trace as _trace
 from ..executor import _graph_eval_fn
-from ..generation import _pick_token, replay_key
+from ..generation import (_pick_token, block_picks, replay_key,
+                          unmask_choice)
 from ..models import transformer
 from .engine import (EngineClosed, Overloaded, RequestTimeout,
                      SessionEvacuated)
@@ -199,8 +223,8 @@ class DecodeFuture:
     __slots__ = ("prompt", "max_new", "eos_id", "temperature", "top_k",
                  "top_p", "seed", "_key", "t_enq", "t_admit", "t_last",
                  "tc", "emitted", "pending", "n_cached", "handoff",
-                 "resume", "speculative", "_ev", "_value", "_exc",
-                 "_slock", "_sinks")
+                 "resume", "speculative", "blk_ids", "blk_masked",
+                 "_ev", "_value", "_exc", "_slock", "_sinks")
 
     def __init__(self, prompt, max_new, eos_id, temperature, top_k,
                  top_p, seed, handoff=None, speculative=False):
@@ -232,6 +256,9 @@ class DecodeFuture:
         self.emitted = []
         self.pending = None                # sampled but not yet fed
         self.n_cached = 0
+        # diffusion rows: the open block's L ids (the mask id where
+        # still masked) and which of its positions are masked
+        self.blk_ids = self.blk_masked = None
         self._ev = threading.Event()
         self._value = None
         self._exc = None
@@ -406,6 +433,15 @@ class ContinuousDecoder:
         # same parameter names, so the generator's own (placed, maybe
         # quantized) param dict binds unchanged
         opts = dict(generator._decode_opts, per_row_pos=True)
+        self._diff = getattr(generator, "_diffusion", None)
+        if self._diff:
+            if draft is not None or spec_draft() is not None:
+                raise ValueError(
+                    "speculative drafts are not supported with a "
+                    "diffusion generator (a block step already yields "
+                    "several tokens a forward)")
+            # the block step reports what its expert layers did
+            opts["moe_stats"] = bool(opts["num_experts"])
         sym_p = transformer.get_decode_symbol(**opts)
         if sym_p.list_arguments() != generator._sym.list_arguments():
             # checkpoint-binding contract: both variants must bind the
@@ -421,8 +457,30 @@ class ContinuousDecoder:
             # device's module name say which program ran
             return eval_fn(args, aux, rng, False)
 
-        self._step_fn = _step_program(decode_step, generator)
+        def block_step(args, aux, rng):
+            # the diffusion pool's one program: (B, L) ids in; each
+            # position's best id and its confidence out, picked on the
+            # device (the float32 logits of 16 x 4 positions over a
+            # 152k vocabulary are 39 MB a step), the logits themselves
+            # left on the device for whoever asks (on_block_logits),
+            # and the expert layers' counts
+            outs, aux = eval_fn(args, aux, rng, False)
+            best, conf = block_picks(outs[0])
+            stats = outs[1] if len(outs) > 1 else \
+                jnp.zeros((0, 3), jnp.int32)
+            return (best, conf, outs[0], stats), aux
+
+        self._step_fn = _step_program(
+            block_step if self._diff else decode_step, generator)
         self._rng0 = jax.random.PRNGKey(0)
+        # set by whoever wants the logits behind served tokens: called
+        # on the decode thread for every denoising forward of every
+        # active row as fn(request, block_start, ids (L,), masked (L,),
+        # logits (L, V) float32); costs a device-to-host copy a step
+        self.on_block_logits = None
+        # a diffusion pool's step dispatched ahead of the host's work on
+        # the step before: (rows, commits, outputs), see _block_step
+        self._inflight = None
 
         self._aux = generator._fresh_aux()     # the pool caches
         self._alias_bytes = None               # (aliased, held), lazily
@@ -529,6 +587,17 @@ class ContinuousDecoder:
         self._prefill_rows = 0     # rows of every prefill forward
         self._merges = 0           # compiled cache-merge dispatches
         self._step_failures = 0    # steps that raised (_step_failed)
+        # diffusion pools, in rows x forwards (a step runs one forward
+        # for every active row): all forwards, those that were commits
+        # (each stored one block's rows), positions unmasked; and what
+        # the expert layers report from the device, summed over layers
+        # and steps (idle rows' pairs included: they are computed)
+        self._forwards = 0
+        self._commit_forwards = 0
+        self._tokens_unmasked = 0
+        self._moe_assignments = 0
+        self._moe_experts_hit = 0
+        self._moe_max_load = 0.0   # largest expert batch over the mean
         self._imported = 0
         self._resumed = 0
         self._evacuated = 0
@@ -578,6 +647,10 @@ class ContinuousDecoder:
         # emitted token (from enqueue) and the gap between consecutive
         # emissions of one sequence — what streaming users actually
         # feel; tools/telemetry_report.py renders the quantiles
+        # (a step that emits several tokens of one sequence — a
+        # speculative round, a block step — observes one step-long gap
+        # for the first of them and a gap near zero for each of the
+        # rest: the histogram is of tokens, not of steps)
         self._h_ttft = _telemetry.histogram("serve.ttft_ms")
         self._h_itl = _telemetry.histogram("serve.inter_token_ms")
         self._c_streams = _telemetry.counter("serve.decode.streams")
@@ -886,6 +959,11 @@ class ContinuousDecoder:
         req = self._slots[slot]
         if req is None:
             raise ValueError("slot %d holds no active sequence" % slot)
+        if self._diff:
+            raise ValueError(
+                "export_session is not supported with a diffusion "
+                "generator (a row's open block is not part of the "
+                "portable session state)")
         blob = self._gen.export_kv_rows(self._aux, slot, req.n_cached)
         return {"v": 1,
                 "prompt": np.asarray(req.prompt, np.int64),
@@ -948,6 +1026,16 @@ class ContinuousDecoder:
         P, n = int(prompt.shape[0]), int(max_new_tokens)
         if P < 1:
             raise ValueError("empty prompt")
+        if self._diff:
+            chunk = prefill_chunk()
+            if handoff is not None or resume is not None or \
+                    (temperature and float(temperature) > 0) or \
+                    (chunk and P > chunk):
+                raise ValueError(
+                    "a diffusion generator serves greedy requests "
+                    "prefilled here in one piece: no handoff, resume, "
+                    "temperature or chunked prefill "
+                    "(MXNET_PREFILL_CHUNK)")
         if handoff is not None and resume is not None:
             raise ValueError(
                 "handoff and resume are mutually exclusive — a "
@@ -1020,7 +1108,7 @@ class ContinuousDecoder:
                 resume["kv_blob"], P + len(emitted) - 1,
                 why="a migrated session's rows must cover prompt + "
                     "fed tokens")
-        if P + n > self._gen.max_len:
+        if self._gen.block_span(P, n) > self._gen.max_len:
             raise ValueError(
                 "prompt (%d) + max_new_tokens (%d) exceeds the cache "
                 "capacity max_len=%d" % (P, n, self._gen.max_len))
@@ -1363,11 +1451,19 @@ class ContinuousDecoder:
                 else:
                     waiting.append(req)
                 continue
-            by_len.setdefault(len(req.prompt), []).append(req)
+            P = len(req.prompt)
+            if self._diff:
+                # whole blocks are prefilled; the remainder opens the
+                # first block
+                P -= P % self._diff["block_length"]
+            by_len.setdefault(P, []).append(req)
         if waiting:
             with self._lock:
                 self._queue.extendleft(reversed(waiting))
         for P, reqs in sorted(by_len.items()):
+            if self._diff:
+                self._admit_blocks(P, reqs, free)
+                continue
             rows = np.stack([r.prompt for r in reqs] +
                             [reqs[0].prompt] * (self._B - len(reqs)))
             with _trace.phase("admit.fresh_aux"):
@@ -1413,6 +1509,44 @@ class ContinuousDecoder:
                     tok = req._pick(last[i])
                     self._emit(req, tok)
                     self._maybe_finish(slot, tok)
+
+    def _admit_blocks(self, P0, reqs, free):
+        """A diffusion round's group: prompts whose whole blocks are
+        the same ``P0`` positions. One shared-position prefill of
+        those under the block mask, with no logits read (the first
+        tokens come from the first block's denoising forward), the
+        merge, and each row's first block opened on the prompt's
+        remainder. A prompt shorter than a block prefills nothing."""
+        if P0:
+            rows = np.stack([r.prompt[:P0] for r in reqs] +
+                            [reqs[0].prompt[:P0]] *
+                            (self._B - len(reqs)))
+            with _trace.phase("admit.fresh_aux"):
+                fresh = self._gen._fresh_aux()
+            with _trace.phase("admit.prefill", P=P0, rows=len(reqs)):
+                pref_aux = self._gen._prefill(fresh, rows)
+            del fresh
+            self._prefills += 1
+            self._prefill_rows += self._B
+            with _trace.phase("admit.merge", rows=len(reqs)):
+                self._aux = self._merge_rows(self._aux, pref_aux,
+                                             free[:len(reqs)])
+        with _trace.phase("admit.emit"):
+            for req in reqs:
+                self._slots[free.pop(0)] = req
+                req.t_admit = _telemetry.now_ms()
+                req.n_cached = P0
+                self._open_block(req)
+
+    def _open_block(self, req):
+        """The row's next block, at its cache depth: the prompt's
+        remainder (the first block only) and the mask id elsewhere."""
+        d = self._diff
+        known = req.prompt[req.n_cached:req.n_cached + d["block_length"]]
+        req.blk_ids = np.full((d["block_length"],), d["mask_id"],
+                              np.int64)
+        req.blk_ids[:len(known)] = known
+        req.blk_masked = np.arange(d["block_length"]) >= len(known)
 
     def _emit(self, req, tok):
         """One token emission: latency metrics (TTFT on the first
@@ -1480,6 +1614,8 @@ class ContinuousDecoder:
         next; inactive slots feed a dummy token at position 0 (their
         cache rows are garbage until the next admission overwrites
         them wholesale)."""
+        if self._diff:
+            return self._block_step()
         active = [i for i, s in enumerate(self._slots) if s is not None]
         if not active:
             return
@@ -1511,6 +1647,119 @@ class ContinuousDecoder:
                     self._emit(req, tok)
                     self._maybe_finish(i, tok)
 
+    def _dispatch_block(self, rows):
+        """Build and dispatch, without waiting, one (B, L) forward for
+        ``rows`` ((slot, request) pairs): each at its own depth over
+        its open block; the other slots feed zeros at position 0.
+        What is in flight is kept for :meth:`_block_step` to read."""
+        L = self._diff["block_length"]
+        with _trace.phase("step.inputs"):
+            toks = np.zeros((self._B, L), np.float32)
+            pos = np.zeros((self._B,), np.float32)
+            for i, req in rows:
+                toks[i] = req.blk_ids
+                pos[i] = float(req.n_cached)
+            commits = {i for i, req in rows if not req.blk_masked.any()}
+            args = dict(self._gen._params)
+            args["data"] = jnp.asarray(toks)
+            args["positions"] = jnp.asarray(
+                pos[:, None] + np.arange(L, dtype=np.float32))
+            args["cache_pos"] = jnp.asarray(pos)
+        with _trace.phase("step.dispatch"):
+            outs, self._aux = self._step_fn(args, self._aux, self._rng0)
+        self._inflight = (rows, commits, outs)
+
+    def _block_step(self):
+        """One (B, L) step of a diffusion pool (see the module
+        docstring): every active row runs one forward over its open
+        block at its own depth — a denoising forward while the block
+        holds a mask, the commit forward once it holds none. The phase
+        carries ``forward`` ("denoise" | "commit" where every row ran
+        the same kind, else "mixed") and ``unmasked``.
+
+        The NEXT step is dispatched before this one's tokens are
+        emitted: its inputs need only the rows' new block states, so
+        the device runs it while the host emits, finishes and admits.
+        A call therefore reads the step left in flight by the call
+        before it (or dispatches one, after an idle period); a row
+        admitted meanwhile joins the step after."""
+        if self._inflight is None:
+            rows = [(i, s) for i, s in enumerate(self._slots)
+                    if s is not None]
+            if not rows:
+                return
+        d = self._diff
+        L = d["block_length"]
+        with _trace.phase("serve.decode.step") as ph:
+            if self._inflight is None:
+                self._dispatch_block(rows)
+            (rows, commits, (best, conf, logits, stats)), \
+                self._inflight = self._inflight, None
+            with _trace.phase("step.wait"):
+                best, conf, stats = jax.device_get((best, conf, stats))
+            with _trace.phase("step.emit"):
+                self._steps += 1
+                self._c_steps.inc()
+                self._h_slotfill.observe(len(rows))
+                self._g_active.set(len(rows))
+                self._forwards += len(rows)
+                self._commit_forwards += len(commits)
+                if len(stats):
+                    self._moe_assignments += int(stats[:, 0].sum())
+                    self._moe_experts_hit += int(stats[:, 1].sum())
+                    experts = self._gen._decode_opts["num_experts"]
+                    self._moe_max_load = max(
+                        self._moe_max_load, float(
+                            (stats[:, 2] * experts / stats[:, 0]).max()))
+                hook = self.on_block_logits
+                if hook is not None and len(commits) < len(rows):
+                    logits = np.asarray(logits.astype(jnp.float32))
+                unmasked = 0
+                out, done = [], set()
+                for i, req in rows:
+                    if self._slots[i] is not req:
+                        continue          # failed or evacuated meanwhile
+                    if i in commits:
+                        req.n_cached += L
+                        self._open_block(req)
+                        continue
+                    if hook is not None:
+                        hook(req, req.n_cached, req.blk_ids.copy(),
+                             req.blk_masked.copy(), logits[i])
+                    take = unmask_choice(req.blk_masked, conf[i], d)
+                    req.blk_ids[take] = best[i, take]
+                    req.blk_masked[take] = False
+                    unmasked += int(take.sum())
+                    # in order: a token streams once it and all before
+                    # it are unmasked; the row ends by the rule of
+                    # _maybe_finish
+                    toks = []
+                    sent = len(req.prompt) + len(req.emitted) - \
+                        req.n_cached
+                    while sent + len(toks) < L and \
+                            not req.blk_masked[sent + len(toks)]:
+                        toks.append(int(req.blk_ids[sent + len(toks)]))
+                        if toks[-1] == req.eos_id or len(req.emitted) + \
+                                len(toks) >= req.max_new:
+                            done.add(i)
+                            break
+                    out.append((i, req, toks))
+                self._tokens_unmasked += unmasked
+                ph.note(active=len(rows), unmasked=unmasked,
+                        forward="commit" if len(commits) == len(rows)
+                        else "mixed" if commits else "denoise")
+            try:
+                ahead = [(i, s) for i, s in enumerate(self._slots)
+                         if s is not None and i not in done]
+                if ahead:
+                    self._dispatch_block(ahead)
+            finally:
+                with _trace.phase("step.emit"):
+                    for i, req, toks in out:
+                        for tok in toks:
+                            self._emit(req, tok)
+                            self._maybe_finish(i, tok)
+
     def _step_failed(self, exc):
         """A step (or speculative round) raised. The pool it was given
         was donated, so its buffers may be deleted, and what came back
@@ -1528,6 +1777,7 @@ class ContinuousDecoder:
                 self._slots[slot] = None
                 req._fail(exc)
         self._step_failures += 1
+        self._inflight = None
         self._g_active.set(0)
         _telemetry.journal_event("serve.decode.step_failed",
                                  error=type(exc).__name__)
@@ -1951,6 +2201,15 @@ class ContinuousDecoder:
                 "prefill_rows": self._prefill_rows,
                 "merges": self._merges,
                 "step_failures": self._step_failures,
+                "forwards": self._forwards,
+                "commit_forwards": self._commit_forwards,
+                # one block a commit forward, until a commit is fused
+                # with the next block's first step
+                "blocks_committed": self._commit_forwards,
+                "tokens_unmasked": self._tokens_unmasked,
+                "moe_assignments": self._moe_assignments,
+                "moe_experts_hit": self._moe_experts_hit,
+                "moe_max_load": self._moe_max_load,
                 "merge_programs": sum(
                     fn._cache_size() for fn in
                     (self._merge_fn, self._dmerge_fn)

@@ -1,0 +1,425 @@
+"""Routed experts as deployed (top-k of float32 softmax scores, nothing
+dropped, only the routed pairs computed) and generation by diffusion
+over blocks (the block mask, a step that yields 0 to L tokens a row),
+through the op, `Generator.generate` and `ContinuousDecoder`, against
+the benchmark's plain float32 reference at toy widths with seeded
+weights."""
+import json
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from cellbench.models import sdar as model
+from cellbench.reference import sdar as ref
+from mxnet_tpu.generation import (REMASKING, Generator, canon_diffusion,
+                                  unmask_choice)
+from mxnet_tpu.ops.attention import cached_attention
+from mxnet_tpu.parallel.moe import dense_moe, routed_experts
+
+pytestmark = pytest.mark.serve
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+V, T, SEED, L, MASK = 97, 48, 11, 4, 96
+with open(os.path.join(ROOT, "cellbench", "configs",
+                       "sdar-30b-a3b-chat.json")) as _f:
+    TOY = json.load(_f)
+TOY.update(hidden_size=32, num_attention_heads=4, num_key_value_heads=2,
+           head_dim=16, num_experts=8, num_experts_per_tok=2,
+           moe_intermediate_size=16, vocab_size=V, num_hidden_layers=2,
+           max_position_embeddings=64, initializer_range=0.3,
+           compute_dtype="float32")
+TOY["assumed"] = dict(TOY["assumed"], mask_token_id=MASK)
+
+
+@pytest.fixture(scope="module")
+def params():
+    return ref.make_params(TOY, SEED, "float32")
+
+
+def _gen(params, batch_size, steps=2, rule="sequential", **over):
+    args = model.generator_args(
+        TOY, {"denoising_steps": steps, "remasking": rule})
+    args["diffusion"].update(over.pop("diffusion", {}))
+    return Generator(params, V, T, batch_size=batch_size,
+                     **dict(args, **over))
+
+
+def _prompts(lengths, seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, MASK, n, dtype=np.int64) for n in lengths]
+
+
+# -- the expert layer --------------------------------------------------------
+
+def _expert_inputs(E, k, seed=0, N=24, D=32, H=16, act="gated_silu"):
+    rng = np.random.default_rng(seed)
+    f32 = lambda *s: jnp.asarray(rng.normal(size=s) * 0.3, jnp.float32)
+    wide = 2 * H if act == "gated_silu" else H
+    return f32(N, D) / 0.3, f32(D, E), f32(E, D, wide), f32(E, H, D)
+
+
+def _reference_experts(x, g, w1, w2, k):
+    s = dict(top_k=k, renorm=True, expert_ffn=w2.shape[1])
+    with jax.default_matmul_precision("highest"):
+        return ref._experts(x, {"gate_weight": g, "experts_w1_weight": w1,
+                                "experts_w2_weight": w2}, s)
+
+
+@pytest.mark.parametrize("k,E", [(1, 8), (2, 8), (8, 16)])
+def test_routed_experts_match_the_reference(k, E):
+    x, g, w1, w2 = _expert_inputs(E, k)
+    y, stats = routed_experts(x, g, w1, w2, top_k=k, act="gated_silu",
+                              renormalize=True)
+    np.testing.assert_allclose(y, _reference_experts(x, g, w1, w2, k),
+                               rtol=2e-5, atol=2e-5)
+    pairs, hit, largest = np.asarray(stats)
+    assert pairs == 24 * k and 1 <= hit <= E
+    assert largest >= -(-pairs // E)       # the mean, at least
+
+
+def test_every_token_to_one_expert_and_nothing_is_dropped():
+    """A router that sends all 24 tokens to expert 3: one ragged batch
+    of 24 rows, seven empty ones, every token served (a Switch capacity
+    of ceil(24 * 1.25 / 8) = 4 would have zeroed 20 of them)."""
+    x, g, w1, w2 = _expert_inputs(8, 1)
+    g = jnp.zeros_like(g).at[:, 3].set(jnp.sign(x.mean(0)) * 4.0)
+    x = jnp.abs(x) * jnp.sign(x.mean(0))       # every score of 3 wins
+    y, stats = routed_experts(x, g, w1, w2, top_k=1, act="gated_silu",
+                              renormalize=True)
+    assert np.asarray(stats).tolist() == [24, 1, 24]
+    f = w2.shape[1]
+    gu = x @ w1[3]
+    want = (jax.nn.silu(gu[:, :f]) * gu[:, f:]) @ w2[3]
+    np.testing.assert_allclose(y, want, rtol=2e-5, atol=2e-5)
+    assert float(jnp.abs(y).min(axis=1).max()) > 0   # no zeroed row
+
+
+def test_a_tie_in_the_router_goes_to_the_lower_expert():
+    x, g, w1, w2 = _expert_inputs(8, 2)
+    g = g.at[:, 5].set(g[:, 2])                # experts 2 and 5 tie
+    y, _ = routed_experts(x, g, w1, w2, top_k=2, act="gated_silu",
+                          renormalize=True)
+    np.testing.assert_allclose(y, _reference_experts(x, g, w1, w2, 2),
+                               rtol=2e-5, atol=2e-5)
+    # and the tie did decide something: with expert 5 nudged ahead the
+    # answer is another
+    y5, _ = routed_experts(x, g.at[:, 5].add(g[:, 2] * 1e-3), w1, w2,
+                           top_k=2, act="gated_silu", renormalize=True)
+    assert float(jnp.abs(y5 - y).max()) > 1e-3
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_a_switch_layer_served_by_routed_pairs_is_what_it_was(seed):
+    """top-1, ReLU, the score itself as the weight: the capacity-buffer
+    form with the capacity raised to every token (what the decode
+    symbol built before) and the routed pairs give one answer."""
+    x, g, w1, w2 = _expert_inputs(8, 1, seed=seed, act="relu")
+    old = dense_moe(x, g, w1, w2, capacity_factor=8)
+    new, _ = routed_experts(x, g, w1, w2, top_k=1, act="relu")
+    np.testing.assert_allclose(new, old, rtol=2e-5, atol=2e-5)
+
+
+# -- the block mask ----------------------------------------------------------
+
+@pytest.mark.parametrize("per_row", [False, True])
+def test_block_mask_against_dense_masked_attention(per_row):
+    """The L new rows see each other both ways and every block before
+    theirs, at a shared position (a prefill of three blocks) and at
+    per-row block starts (a step)."""
+    rng = np.random.default_rng(3)
+    B, H, Hkv, D, C = 2, 4, 2, 8, 16
+    Tn = L if per_row else 3 * L
+    f32 = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
+    q, k, v = f32(B, H, Tn, D), f32(B, Hkv, Tn, D), f32(B, Hkv, Tn, D)
+    kc, vc = f32(B, C, Hkv * D), f32(B, C, Hkv * D)
+    pos = np.array([4, 8]) if per_row else np.array([0])
+    out, kc2, vc2 = cached_attention(q, k, v, kc, vc, jnp.asarray(pos),
+                                     block=L)
+    for b in range(B):
+        p = int(pos[b if per_row else 0])
+        keys = np.array(kc[b]).reshape(C, Hkv, D)
+        vals = np.array(vc[b]).reshape(C, Hkv, D)
+        keys[p:p + Tn] = np.moveaxis(np.asarray(k[b]), 0, 1)
+        vals[p:p + Tn] = np.moveaxis(np.asarray(v[b]), 0, 1)
+        np.testing.assert_array_equal(
+            np.asarray(kc2[b]).reshape(C, Hkv, D), keys)
+        for h in range(H):
+            s = np.asarray(q[b, h]) @ keys[:, h // 2].T / np.sqrt(D)
+            at = p + np.arange(Tn)
+            sees = np.arange(C)[None, :] // L <= at[:, None] // L
+            s = np.where(sees, s, -np.inf)
+            w = np.exp(s - s.max(-1, keepdims=True))
+            want = (w / w.sum(-1, keepdims=True)) @ vals[:, h // 2]
+            np.testing.assert_allclose(out[b, h], want, rtol=2e-5,
+                                       atol=2e-5)
+
+
+def test_block_mask_takes_no_window():
+    z = jnp.zeros((1, 2, L, 8))
+    c = jnp.zeros((1, 8, 16))
+    with pytest.raises(ValueError, match="no window"):
+        cached_attention(z, z, z, c, c, jnp.asarray([0]), window=4,
+                         block=L)
+
+
+# -- the unmasking rules -----------------------------------------------------
+
+def test_unmask_choice_by_rule():
+    d = lambda rule, steps: canon_diffusion(dict(
+        block_length=4, mask_id=0, steps=steps, remasking=rule,
+        threshold=0.9))
+    masked = np.array([False, True, True, True])
+    conf = np.array([0.99, 0.2, 0.95, 0.95])
+    pick = lambda rule, steps: np.flatnonzero(
+        unmask_choice(masked, conf, d(rule, steps))).tolist()
+    assert pick("sequential", 2) == [1, 2]
+    assert pick("sequential", 1) == [1, 2, 3]
+    assert pick("low_confidence_static", 4) == [2]      # tie: the left
+    assert pick("low_confidence_static", 2) == [2, 3]
+    assert pick("low_confidence_dynamic", 4) == [2, 3]  # all over 0.9
+    low = np.array([0.99, 0.2, 0.5, 0.4])
+    assert np.flatnonzero(unmask_choice(
+        masked, low, d("low_confidence_dynamic", 4))).tolist() == [2]
+    with pytest.raises(ValueError, match="multiple of steps"):
+        d("sequential", 3)
+    with pytest.raises(ValueError, match="remasking"):
+        d("random", 2)
+
+
+# -- Generator.generate against the reference --------------------------------
+
+def _state_logits(clean, start, noisy):
+    """The reference's logits for one noisy block at `start` beside the
+    clean tokens before it: the sampler's definition without a cache."""
+    toks = np.concatenate([clean[:start], noisy])
+    pos = np.concatenate([np.arange(start), start + np.arange(L)])
+    state = np.concatenate([np.zeros(start, int), np.ones(L, int)])
+    return np.asarray(ref.logits_at(
+        TOY, SEED, toks, pos, pos // L, state,
+        np.arange(start, start + L), "float32"))
+
+
+@pytest.mark.parametrize("steps", [1, 2, 4])
+@pytest.mark.parametrize("rule", REMASKING)
+def test_generate_against_the_reference(params, rule, steps):
+    """Every denoising forward's logits are the reference's for that
+    very state (the clean blocks before it, the noisy block itself),
+    every unmasked token is the reference's best there, positions are
+    unmasked by the rule, and the row holds exactly max_new tokens."""
+    gen = _gen(params, 2, steps, rule)
+    prompt = np.stack(_prompts([6, 6], seed=steps))
+    seen, streamed = [], []
+    out = gen.generate(prompt, 7, on_block_logits=lambda *a: seen.append(a),
+                       on_token=streamed.append)
+    assert out.shape == (2, 13)
+    np.testing.assert_array_equal(out[:, :6], prompt)
+    np.testing.assert_array_equal(np.stack(streamed, 1), out[:, 6:])
+    d = gen._diffusion
+    final = np.concatenate([out, np.zeros((2, 3), np.int64)], 1)
+    forwards = {}
+    for start, ids, masked, logits in seen:
+        forwards[start] = forwards.get(start, 0) + 1
+        for b in range(2):
+            if not masked[b].any():
+                continue
+            assert (ids[b][masked[b]] == MASK).all()
+            want = _state_logits(final[b], start, ids[b])
+            np.testing.assert_allclose(logits[b], want, rtol=2e-4,
+                                       atol=2e-4)
+            best = want.argmax(-1)
+            prob = np.exp(want - want.max(-1, keepdims=True))
+            conf = prob.max(-1) / prob.sum(-1)
+            take = unmask_choice(masked[b], conf, d)
+            # what this forward unmasked is what the row holds there
+            sel = take & (start + np.arange(L) < out.shape[1])
+            np.testing.assert_array_equal(
+                final[b, start:start + L][sel], best[sel])
+    if rule != "low_confidence_dynamic":
+        # T forwards a block that starts all masked; the first block
+        # holds two prompt tokens
+        assert forwards[8] == steps and forwards[12] == steps
+        assert forwards[4] == -(-2 // (L // steps))
+
+
+def test_sequential_rows_replay_from_their_tokens_alone(params):
+    """`plan_row` lays a served row out from its tokens alone, and the
+    reference then puts every served token first."""
+    gen = _gen(params, 1, 2)
+    for p, n in ((6, 7), (5, 2), (8, 5), (7, 9)):
+        row = gen.generate(np.stack(_prompts([p], seed=p)), n)[0]
+        want = next(ref.served_logits(TOY, SEED, [(p, row)], 2,
+                                      dtype="float32"))
+        assert want.shape == (n, V)
+        np.testing.assert_array_equal(want.argmax(-1), row[p:])
+
+
+# -- the serving decoder -----------------------------------------------------
+
+CASES = [(r, n) for r in range(L) for n in (2, 5)]
+
+
+@pytest.fixture(scope="module")
+def served(params):
+    """Eight requests, every remainder of the prompt against a block
+    with max_new 2 and 5, through a pool of two slots: six of them are
+    admitted while others are mid-block. Beside each, the one-shot
+    row of a batch-1 generator."""
+    one = _gen(params, 1, 2)
+    dec = _gen(params, 2, 2).serving_decoder()
+    prompts = _prompts([8 + r for r, _ in CASES], seed=5)
+    streams = [[] for _ in CASES]
+    try:
+        futs = [dec.submit(p, n) for p, (_, n) in zip(prompts, CASES)]
+        for f, s in zip(futs, streams):
+            f.subscribe(s.append)
+        rows = [np.asarray(f.result(timeout=120)) for f in futs]
+        stats = dec.stats()
+    finally:
+        dec.close(30)
+    want = [one.generate(p[None], n)[0]
+            for p, (_, n) in zip(prompts, CASES)]
+    return rows, want, streams, stats
+
+
+@pytest.mark.parametrize("case", range(len(CASES)),
+                         ids=["rem%d_new%d" % c for c in CASES])
+def test_decoder_rows_equal_the_one_shot_rows(served, case):
+    rows, want, streams, _ = served
+    r, n = CASES[case]
+    assert rows[case].shape == (8 + r + n,)
+    np.testing.assert_array_equal(rows[case], want[case])
+    # streamed in order, each token once, then the sentinel
+    assert streams[case] == list(want[case][8 + r:]) + [None]
+
+
+def test_decoder_counts_forwards_blocks_and_expert_pairs(served):
+    _, _, _, st = served
+    assert st["steps"] >= 1 and st["prefills"] >= 1
+    assert st["forwards"] >= st["steps"]
+    assert st["commit_forwards"] == st["blocks_committed"]
+    # a finished row's last block is never committed
+    assert st["blocks_committed"] < st["forwards"]
+    assert st["tokens_unmasked"] >= sum(n for _, n in CASES)
+    layers, experts, k = 2, 8, 2
+    assert st["moe_assignments"] == st["steps"] * layers * 2 * L * k
+    assert 0 < st["moe_experts_hit"] <= st["steps"] * layers * experts
+    assert 1.0 <= st["moe_max_load"] <= experts
+
+
+def test_next_step_is_in_flight_while_tokens_are_emitted(params):
+    """The step after is dispatched before a step's tokens go out: the
+    decode thread has a step in flight at every token but those of the row's
+    last step, and a finished row rides no further forward (two blocks
+    at two steps a block: 2 + commit + 2)."""
+    dec = _gen(params, 2, 2).serving_decoder()
+    seen, emit = [], dec._emit
+
+    def spy(req, tok):                         # on the decode thread
+        seen.append(dec._inflight is not None)
+        emit(req, tok)
+
+    dec._emit = spy
+    try:
+        dec.submit(_prompts([8], seed=3)[0], 8).result(timeout=60)
+        st = dec.stats()
+    finally:
+        dec.close(30)
+    assert seen == [True] * 6 + [False] * 2
+    assert (st["forwards"], st["commit_forwards"]) == (5, 1)
+
+
+def test_masked_is_a_matter_of_position_not_of_the_id(params):
+    """A head of zeros makes every logit tie, so every served token is
+    id 0; with id 0 as the mask id too, a prompt and an answer hold it,
+    and the row still finishes with exactly max_new tokens."""
+    tied = dict(params, lm_head_weight=jnp.zeros_like(
+        params["lm_head_weight"]))
+    over = {"diffusion": {"mask_id": 0}}
+    prompt = np.array([3, 0, 0, 5, 0, 7], np.int64)
+    want = _gen(tied, 1, 2, **over).generate(prompt[None], 7)[0]
+    np.testing.assert_array_equal(want, np.concatenate(
+        [prompt, np.zeros(7, np.int64)]))
+    dec = _gen(tied, 2, 2, **over).serving_decoder()
+    try:
+        row = dec.submit(prompt, 7).result(timeout=60)
+    finally:
+        dec.close(30)
+    np.testing.assert_array_equal(row, want)
+
+
+def test_logits_hook_reads_what_tokens_were_picked_from(params):
+    dec = _gen(params, 2, 2).serving_decoder()
+    try:
+        prompts = _prompts([6, 9], seed=9)
+        rows, logits = model.served_logits(dec, prompts, 5)
+    finally:
+        dec.close(30)
+    want = list(ref.served_logits(
+        TOY, SEED, [(len(p), r) for p, r in zip(prompts, rows)], 2,
+        dtype="float32"))
+    for got, exp, p, row in zip(logits, want, prompts, rows):
+        np.testing.assert_allclose(got, exp, rtol=2e-4, atol=2e-4)
+        np.testing.assert_array_equal(got.argmax(-1), row[len(p):])
+
+
+# -- what refuses a diffusion generator --------------------------------------
+
+@pytest.mark.parametrize("call", [
+    lambda g, p: g.generate_on_device(p, 4),
+    lambda g, p: g.beam_search(p, 4),
+    lambda g, p: g.beam_search_on_device(p, 4),
+    lambda g, p: g.log_likelihood(p),
+    lambda g, p: g.truncated_draft(1),
+    lambda g, p: g.generate_speculative(g, p, 4),
+    lambda g, p: g.generate(p, 4, temperature=0.7),
+], ids=["on_device", "beam", "beam_on_device", "log_likelihood",
+        "truncated_draft", "speculative", "temperature"])
+def test_entry_points_that_refuse_diffusion(params, call):
+    gen = _gen(params, 1, 2)
+    with pytest.raises(ValueError, match="diffusion"):
+        call(gen, np.stack(_prompts([6])))
+
+
+def test_decoder_refuses_drafts_chunks_and_sampling(params, monkeypatch):
+    gen = _gen(params, 1, 2)
+    with pytest.raises(ValueError, match="drafts"):
+        gen.serving_decoder(draft=gen)
+    dec = gen.serving_decoder()
+    try:
+        with pytest.raises(ValueError, match="greedy"):
+            dec.submit(_prompts([6])[0], 4, temperature=0.5)
+        monkeypatch.setenv("MXNET_PREFILL_CHUNK", "4")
+        with pytest.raises(ValueError, match="chunked prefill"):
+            dec.submit(_prompts([6])[0], 4)
+        monkeypatch.delenv("MXNET_PREFILL_CHUNK")
+        # the last block is run whole: 41 + 6 tokens end in block 44-47
+        dec.submit(_prompts([41])[0], 6).result(timeout=60)
+        with pytest.raises(ValueError, match="capacity"):
+            dec.submit(_prompts([42])[0], 7)
+    finally:
+        dec.close(30)
+
+
+def test_defaults_leave_the_symbol_as_it_was():
+    """Each new argument's default builds the symbol of before: the
+    same arguments, the same states, no second output."""
+    from mxnet_tpu.models import transformer
+    base = transformer.get_decode_symbol(V, T, num_layers=2, num_heads=4,
+                                         dim=32)
+    same = transformer.get_decode_symbol(
+        V, T, num_layers=2, num_heads=4, dim=32, experts_per_token=1,
+        expert_hidden=None, norm_topk_prob=False, head_dim=None,
+        qk_norm=False, rope_base=None, attention_block=0,
+        moe_stats=False)
+    assert base.list_arguments() == same.list_arguments()
+    assert base.list_auxiliary_states() == same.list_auxiliary_states()
+    assert len(base.list_outputs()) == len(same.list_outputs()) == 1
+    ops = lambda sym: [(n["op"], n.get("attrs", n.get("param")))
+                       for n in json.loads(sym.tojson())["nodes"]]
+    assert ops(base) == ops(same)
+    with pytest.raises(ValueError, match="moe_stats"):
+        transformer.get_decode_symbol(V, T, moe_stats=True)

@@ -135,3 +135,33 @@ def test_serve_programs_keep_a_token_contiguous_on_v5e(
             assert op != "transpose", (op, dims)
     scores = slots * heads * prompt * length * 4
     assert prefill.memory_analysis().temp_size_in_bytes < scores // 2
+
+
+@pytest.mark.parametrize("tokens", [16 * 4, 16 * 60])
+def test_expert_layer_compiles_for_v5e_without_a_dense_buffer(
+        one_chip, no_compile_cache, monkeypatch, tokens):
+    """One routed expert layer at the SDAR cell's widths (2048 -> 128
+    experts of 768, top 8, bf16), a block step's 64 positions and the
+    shortest prefill's 960: Mosaic takes the grouped product's tiles at
+    these shapes, the two products are the kernel and not a loop XLA
+    wrote, and nothing the size of (experts, tokens, width) exists."""
+    from mxnet_tpu.ops import _pallas
+    from mxnet_tpu.parallel.moe import routed_experts
+    # a compile for a described chip still sees the CPU backend
+    monkeypatch.setattr(_pallas, "interpret", lambda: False)
+    D, E, H, K = 2048, 128, 768, 8
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    layer = jax.jit(lambda x, g, w1, w2: routed_experts(
+        x, g, w1, w2, top_k=K, act="gated_silu", renormalize=True))
+    compiled = layer.lower(spec(tokens, D), spec(D, E),
+                           spec(E, D, 2 * H), spec(E, H, D)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
+    # the sorted pairs' rows and outputs, a few times over: far under
+    # one row of width D for every (expert, token)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 8 * tokens * K * (D + 2 * H) * 2
+    assert temp < E * tokens * D * 2 // 4
