@@ -168,8 +168,11 @@ class Generator:
         ``remasking`` rule (:func:`unmask_choice`); when no mask is
         left one commit forward over the clean block stores its keys
         and values — T + 1 forwards a block, the commit apart — and
-        the next block begins. Greedy only; the sampling, beam,
-        speculative and scoring entry points refuse it.
+        the next block begins (the plain path: the serving decoder's
+        step carries the clean block beside the next one, T forwards a
+        block, and is held to these rows token for token). Greedy
+        only; the sampling, beam, speculative and scoring entry points
+        refuse it.
     """
 
     def __init__(self, arg_params, vocab_size, max_len, num_layers=2,

@@ -447,7 +447,7 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
                       experts_per_token=1, expert_hidden=None,
                       norm_topk_prob=False, head_dim=None,
                       qk_norm=False, rope_base=None, attention_block=0,
-                      moe_stats=False):
+                      moe_stats=False, head_rows=0):
     """Autoregressive-decode twin of get_symbol.
 
     Inputs: data (B, Tnew) token ids for the tokens being appended
@@ -516,7 +516,11 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
     10000. attention_block=L (> 0): the BLOCK mask in place of the
     causal one — position i sees position j iff floor(j / L) <=
     floor(i / L): causal across blocks of L, both ways inside one —
-    for prefill and step alike.
+    for prefill and step alike. head_rows=R (> 0; per_row_pos): one
+    more input, head_pos (B,), and the final norm and the head read
+    only positions head_pos[b] .. head_pos[b] + R - 1 of row b, so the
+    logits are (B, R, vocab) however many positions the layers ran
+    (every position still writes its cache rows).
 
     New TPU-native capability (the 2017 reference's decode story was
     rnn.RNNCell step-wise unrolling); mxnet_tpu.generation.Generator
@@ -527,6 +531,9 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
                          % (dim, num_heads))
     if moe_stats and not num_experts:
         raise ValueError("moe_stats needs num_experts > 0")
+    if head_rows and not per_row_pos:
+        raise ValueError("head_rows needs per_row_pos (head_pos is "
+                         "one offset a row)")
     _check_kv_heads(num_heads, num_kv_heads)
     btypes = _canon_block_types(block_type, num_layers)
     mamba2 = _canon_mamba2(mamba2, btypes)
@@ -658,6 +665,9 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
                             no_bias=no_bias)
         x = residual(x, ff)
 
+    if head_rows:
+        x = sym.contrib.RowsAt(x, sym.Variable("head_pos"),
+                               rows=int(head_rows))
     x = _norm(x, "ln_f", norm, norm_eps)
     if tie_embeddings:
         head = sym.contrib.QuantizedFullyConnected if quantized \

@@ -165,6 +165,20 @@ def _add_scaled_f32(lhs, rhs, scalar=1.0, **_):
         .astype(lhs.dtype)
 
 
+@register("_contrib_RowsAt", arg_names=("data", "start"),
+          nondiff_inputs=(1,), defaults={"rows": 1})
+def _rows_at(data, start, rows=1, **_):
+    """``data[b, start[b]:start[b] + rows]`` for every batch row:
+    (B, T, ...) and (B,) -> (B, rows, ...). Which of a forward's
+    positions go on (a diffusion pool's step runs 2L positions a row
+    and its head reads the L of the open block, wherever a row has
+    them: models/transformer.py ``head_rows``). ``start[b] + rows``
+    must not pass T: a gather clamps where a slice would raise."""
+    idx = start.astype(jnp.int32)[:, None] + jnp.arange(int(rows))
+    idx = idx.reshape(idx.shape + (1,) * (data.ndim - 2))
+    return jnp.take_along_axis(data, idx, axis=1)
+
+
 @register("_contrib_MoEFFN",
           arg_names=("data", "gate_weight", "expert_w1", "expert_w2"),
           aliases=("_contrib_moe_ffn",),

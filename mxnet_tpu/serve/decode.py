@@ -81,27 +81,38 @@ path with identical output. Every emission funnels through
 streamed frames and mid-stream failover cursors work unchanged.
 
 Generation by diffusion over blocks (``Generator(diffusion=...)``):
-the pool's one compiled program is then ``block_step``, (B, L) ids in
-at each row's own block start, and a step no longer yields one token a
-row. A row carries its block's ids and which of its L positions are
-still masked (by position: a prompt or an answer may hold the mask
-id); a row with a mask left rides the step as a DENOISING forward —
-the step returns each position's best id and its confidence, picked on
-the device, and the row unmasks some by the generator's rule — and a
-row with none left rides it as the COMMIT forward of its clean block,
-after which its cache depth advances by L and its next block opens.
-Both are the same forward: a denoising forward's rows land past the
-row's cached depth and the next forward at that depth overwrites them
-(T + 1 forwards a block, the commit apart; fusing the commit with the
-next block's first step is the next step). A step emits 0 to L tokens
-a row, each as soon as it and all before it are unmasked, one at a
-time through :meth:`_emit`. The step after is dispatched BEFORE a
-step's tokens are emitted (its inputs need only the rows' new block
-states), so the device runs while the host emits, finishes and
-admits; a row admitted meanwhile joins the step after, and a finished
-row rides no further forward. Admission prefills the prompt's whole
-blocks and picks nothing. Greedy only; drafts, chunked prefill,
-handoff, resume and session export refuse such a generator.
+the pool's one compiled program is then ``block_step``, (B, 2L) ids
+in, and a step no longer yields one token a row. A row carries its
+OPEN block's ids and which of its L positions are still masked (by
+position: a prompt or an answer may hold the mask id), and the ids of
+the CLEAN block before it. Every forward of a row is a denoising
+forward of its open block — the step returns each of its positions'
+best id and its confidence, picked on the device, and the row unmasks
+some by the generator's rule — over 2L positions: the clean block at
+its own depth, then the open block at depth + L. Under the block mask
+the clean block is blind to the open one, so the rows it writes are
+its final key/value rows: a block's COMMIT rides the next block's
+first denoising forward (a FUSED forward, after which the row's cached
+depth advances by L), and a block costs T forwards, not T + 1. A later
+forward of the same open block carries the same clean block at the
+same depth again (the same program over the same inputs writes the
+same rows); the open block's own rows land past the cached depth,
+where the next forward overwrites them. So no forward writes past the
+end of a row's open block, and a row of exactly ``max_len`` positions
+is served. What a new row brings as its clean block is its prompt's
+last whole block (admission prefills the whole blocks before that one
+and picks nothing); only a prompt shorter than a block has none, and
+its first block rides in the first L positions with L ignored ones
+after it. The final norm and the head read the open block's L
+positions alone, wherever a row has them (``head_pos``), so the logits
+stay (B, L, V). A step emits 0 to L tokens a row, each as soon as it
+and all before it are unmasked, one at a time through :meth:`_emit`.
+The step after is dispatched BEFORE a step's tokens are emitted (its
+inputs need only the rows' new block states), so the device runs while
+the host emits, finishes and admits; a row admitted meanwhile joins
+the step after, a finished row rides no further forward, and its last
+block is never stored. Greedy only; drafts, chunked prefill, handoff,
+resume and session export refuse such a generator.
 """
 from __future__ import annotations
 
@@ -224,6 +235,7 @@ class DecodeFuture:
                  "top_p", "seed", "_key", "t_enq", "t_admit", "t_last",
                  "tc", "emitted", "pending", "n_cached", "handoff",
                  "resume", "speculative", "blk_ids", "blk_masked",
+                 "blk_start", "blk_prev",
                  "_ev", "_value", "_exc", "_slock", "_sinks")
 
     def __init__(self, prompt, max_new, eos_id, temperature, top_k,
@@ -257,8 +269,12 @@ class DecodeFuture:
         self.pending = None                # sampled but not yet fed
         self.n_cached = 0
         # diffusion rows: the open block's L ids (the mask id where
-        # still masked) and which of its positions are masked
-        self.blk_ids = self.blk_masked = None
+        # still masked), which of its positions are masked, where it
+        # starts, and the L ids of the clean block before it (None
+        # where there is none); n_cached reaches blk_start once a
+        # forward has stored that block
+        self.blk_ids = self.blk_masked = self.blk_prev = None
+        self.blk_start = 0
         self._ev = threading.Event()
         self._value = None
         self._exc = None
@@ -440,10 +456,18 @@ class ContinuousDecoder:
                     "speculative drafts are not supported with a "
                     "diffusion generator (a block step already yields "
                     "several tokens a forward)")
-            # the block step reports what its expert layers did
+            if generator.max_len < 2 * self._diff["block_length"]:
+                raise ValueError(
+                    "a diffusion pool's step runs two blocks a row: "
+                    "max_len (%d) must hold 2 x block_length (%d)"
+                    % (generator.max_len, self._diff["block_length"]))
+            # the block step reports what its expert layers did, and
+            # its head reads the open block's positions alone
             opts["moe_stats"] = bool(opts["num_experts"])
+            opts["head_rows"] = self._diff["block_length"]
         sym_p = transformer.get_decode_symbol(**opts)
-        if sym_p.list_arguments() != generator._sym.list_arguments():
+        if [a for a in sym_p.list_arguments() if a != "head_pos"] != \
+                generator._sym.list_arguments():
             # checkpoint-binding contract: both variants must bind the
             # same parameter names (a bare assert would vanish under -O)
             raise ValueError(
@@ -458,12 +482,12 @@ class ContinuousDecoder:
             return eval_fn(args, aux, rng, False)
 
         def block_step(args, aux, rng):
-            # the diffusion pool's one program: (B, L) ids in; each
-            # position's best id and its confidence out, picked on the
-            # device (the float32 logits of 16 x 4 positions over a
-            # 152k vocabulary are 39 MB a step), the logits themselves
-            # left on the device for whoever asks (on_block_logits),
-            # and the expert layers' counts
+            # the diffusion pool's one program: (B, 2L) ids in; each
+            # position of the open blocks' best id and its confidence
+            # out, picked on the device (the float32 logits of 16 x 4
+            # positions over a 152k vocabulary are 39 MB a step), the
+            # logits themselves left on the device for whoever asks
+            # (on_block_logits), and the expert layers' counts
             outs, aux = eval_fn(args, aux, rng, False)
             best, conf = block_picks(outs[0])
             stats = outs[1] if len(outs) > 1 else \
@@ -479,7 +503,7 @@ class ContinuousDecoder:
         # logits (L, V) float32); costs a device-to-host copy a step
         self.on_block_logits = None
         # a diffusion pool's step dispatched ahead of the host's work on
-        # the step before: (rows, commits, outputs), see _block_step
+        # the step before: (rows, fused, outputs), see _block_step
         self._inflight = None
 
         self._aux = generator._fresh_aux()     # the pool caches
@@ -588,12 +612,13 @@ class ContinuousDecoder:
         self._merges = 0           # compiled cache-merge dispatches
         self._step_failures = 0    # steps that raised (_step_failed)
         # diffusion pools, in rows x forwards (a step runs one forward
-        # for every active row): all forwards, those that were commits
-        # (each stored one block's rows), positions unmasked; and what
-        # the expert layers report from the device, summed over layers
-        # and steps (idle rows' pairs included: they are computed)
+        # for every active row): all forwards, those that also stored
+        # the clean block before the one they denoised, positions
+        # unmasked; and what the expert layers report from the device,
+        # summed over layers and steps (idle rows' pairs included:
+        # they are computed)
         self._forwards = 0
-        self._commit_forwards = 0
+        self._fused_commits = 0
         self._tokens_unmasked = 0
         self._moe_assignments = 0
         self._moe_experts_hit = 0
@@ -1453,9 +1478,11 @@ class ContinuousDecoder:
                 continue
             P = len(req.prompt)
             if self._diff:
-                # whole blocks are prefilled; the remainder opens the
-                # first block
-                P -= P % self._diff["block_length"]
+                # the whole blocks but the last are prefilled: that one
+                # is stored by the row's first forward, which denoises
+                # the block the remainder opens
+                L = self._diff["block_length"]
+                P = max(P // L * L - L, 0)
             by_len.setdefault(P, []).append(req)
         if waiting:
             with self._lock:
@@ -1511,12 +1538,14 @@ class ContinuousDecoder:
                     self._maybe_finish(slot, tok)
 
     def _admit_blocks(self, P0, reqs, free):
-        """A diffusion round's group: prompts whose whole blocks are
-        the same ``P0`` positions. One shared-position prefill of
-        those under the block mask, with no logits read (the first
-        tokens come from the first block's denoising forward), the
+        """A diffusion round's group: prompts whose whole blocks but
+        the last are the same ``P0`` positions. One shared-position
+        prefill of those under the block mask, with no logits read
+        (the first tokens come from the first block's denoising
+        forward, which also stores the prompt's last whole block), the
         merge, and each row's first block opened on the prompt's
-        remainder. A prompt shorter than a block prefills nothing."""
+        remainder. A prompt shorter than two blocks prefills
+        nothing."""
         if P0:
             rows = np.stack([r.prompt[:P0] for r in reqs] +
                             [reqs[0].prompt[:P0]] *
@@ -1539,14 +1568,23 @@ class ContinuousDecoder:
                 self._open_block(req)
 
     def _open_block(self, req):
-        """The row's next block, at its cache depth: the prompt's
-        remainder (the first block only) and the mask id elsewhere."""
+        """The row's next block: the prompt's remainder (the first
+        block only) and the mask id elsewhere. The block before it is
+        the clean block its next forward stores: the one the row
+        leaves or, for a new row, its prompt's last whole block (none
+        where the prompt is shorter than a block)."""
         d = self._diff
-        known = req.prompt[req.n_cached:req.n_cached + d["block_length"]]
-        req.blk_ids = np.full((d["block_length"],), d["mask_id"],
-                              np.int64)
+        L = d["block_length"]
+        if req.blk_ids is None:
+            start = len(req.prompt) // L * L
+            req.blk_prev = req.prompt[start - L:start] if start else None
+        else:
+            start, req.blk_prev = req.blk_start + L, req.blk_ids
+        req.blk_start = start
+        known = req.prompt[start:start + L]
+        req.blk_ids = np.full((L,), d["mask_id"], np.int64)
         req.blk_ids[:len(known)] = known
-        req.blk_masked = np.arange(d["block_length"]) >= len(known)
+        req.blk_masked = np.arange(L) >= len(known)
 
     def _emit(self, req, tok):
         """One token emission: latency metrics (TTFT on the first
@@ -1648,34 +1686,46 @@ class ContinuousDecoder:
                     self._maybe_finish(i, tok)
 
     def _dispatch_block(self, rows):
-        """Build and dispatch, without waiting, one (B, L) forward for
-        ``rows`` ((slot, request) pairs): each at its own depth over
-        its open block; the other slots feed zeros at position 0.
-        What is in flight is kept for :meth:`_block_step` to read."""
+        """Build and dispatch, without waiting, one (B, 2L) forward
+        for ``rows`` ((slot, request) pairs): each row's clean block at
+        its own depth and its open block after it, the head on the
+        open block; a row with no block before its open one feeds that
+        one first, and the other slots feed zeros at position 0. What
+        is in flight is kept for :meth:`_block_step` to read: the rows
+        and which of them the forward is FUSED for (it stores a clean
+        block no forward has stored yet)."""
         L = self._diff["block_length"]
         with _trace.phase("step.inputs"):
-            toks = np.zeros((self._B, L), np.float32)
+            toks = np.zeros((self._B, 2 * L), np.float32)
             pos = np.zeros((self._B,), np.float32)
+            head = np.zeros((self._B,), np.float32)
             for i, req in rows:
-                toks[i] = req.blk_ids
-                pos[i] = float(req.n_cached)
-            commits = {i for i, req in rows if not req.blk_masked.any()}
+                if req.blk_prev is None:
+                    toks[i, :L] = req.blk_ids
+                    pos[i] = float(req.blk_start)
+                else:
+                    toks[i, :L], toks[i, L:] = req.blk_prev, req.blk_ids
+                    pos[i] = float(req.blk_start - L)
+                    head[i] = float(L)
+            fused = {i for i, req in rows if req.n_cached < req.blk_start}
             args = dict(self._gen._params)
             args["data"] = jnp.asarray(toks)
             args["positions"] = jnp.asarray(
-                pos[:, None] + np.arange(L, dtype=np.float32))
+                pos[:, None] + np.arange(2 * L, dtype=np.float32))
             args["cache_pos"] = jnp.asarray(pos)
+            args["head_pos"] = jnp.asarray(head)
         with _trace.phase("step.dispatch"):
             outs, self._aux = self._step_fn(args, self._aux, self._rng0)
-        self._inflight = (rows, commits, outs)
+        self._inflight = (rows, fused, outs)
 
     def _block_step(self):
-        """One (B, L) step of a diffusion pool (see the module
-        docstring): every active row runs one forward over its open
-        block at its own depth — a denoising forward while the block
-        holds a mask, the commit forward once it holds none. The phase
-        carries ``forward`` ("denoise" | "commit" where every row ran
-        the same kind, else "mixed") and ``unmasked``.
+        """One (B, 2L) step of a diffusion pool (see the module
+        docstring): every active row runs one denoising forward of its
+        open block, which for a row whose clean block no forward has
+        stored yet is the fused forward that stores it. The phase
+        carries ``forward`` ("denoise" | "fused" where every row ran
+        the same kind, else "mixed"), ``unmasked`` and ``fused`` (the
+        blocks the step stored).
 
         The NEXT step is dispatched before this one's tokens are
         emitted: its inputs need only the rows' new block states, so
@@ -1693,7 +1743,7 @@ class ContinuousDecoder:
         with _trace.phase("serve.decode.step") as ph:
             if self._inflight is None:
                 self._dispatch_block(rows)
-            (rows, commits, (best, conf, logits, stats)), \
+            (rows, fused, (best, conf, logits, stats)), \
                 self._inflight = self._inflight, None
             with _trace.phase("step.wait"):
                 best, conf, stats = jax.device_get((best, conf, stats))
@@ -1703,7 +1753,7 @@ class ContinuousDecoder:
                 self._h_slotfill.observe(len(rows))
                 self._g_active.set(len(rows))
                 self._forwards += len(rows)
-                self._commit_forwards += len(commits)
+                self._fused_commits += len(fused)
                 if len(stats):
                     self._moe_assignments += int(stats[:, 0].sum())
                     self._moe_experts_hit += int(stats[:, 1].sum())
@@ -1712,19 +1762,17 @@ class ContinuousDecoder:
                         self._moe_max_load, float(
                             (stats[:, 2] * experts / stats[:, 0]).max()))
                 hook = self.on_block_logits
-                if hook is not None and len(commits) < len(rows):
+                if hook is not None:
                     logits = np.asarray(logits.astype(jnp.float32))
                 unmasked = 0
                 out, done = [], set()
                 for i, req in rows:
                     if self._slots[i] is not req:
                         continue          # failed or evacuated meanwhile
-                    if i in commits:
-                        req.n_cached += L
-                        self._open_block(req)
-                        continue
+                    if i in fused:
+                        req.n_cached = req.blk_start
                     if hook is not None:
-                        hook(req, req.n_cached, req.blk_ids.copy(),
+                        hook(req, req.blk_start, req.blk_ids.copy(),
                              req.blk_masked.copy(), logits[i])
                     take = unmask_choice(req.blk_masked, conf[i], d)
                     req.blk_ids[take] = best[i, take]
@@ -1735,7 +1783,7 @@ class ContinuousDecoder:
                     # _maybe_finish
                     toks = []
                     sent = len(req.prompt) + len(req.emitted) - \
-                        req.n_cached
+                        req.blk_start
                     while sent + len(toks) < L and \
                             not req.blk_masked[sent + len(toks)]:
                         toks.append(int(req.blk_ids[sent + len(toks)]))
@@ -1744,10 +1792,13 @@ class ContinuousDecoder:
                             done.add(i)
                             break
                     out.append((i, req, toks))
+                    if i not in done and not req.blk_masked.any():
+                        self._open_block(req)
                 self._tokens_unmasked += unmasked
                 ph.note(active=len(rows), unmasked=unmasked,
-                        forward="commit" if len(commits) == len(rows)
-                        else "mixed" if commits else "denoise")
+                        fused=len(fused),
+                        forward="fused" if len(fused) == len(rows)
+                        else "mixed" if fused else "denoise")
             try:
                 ahead = [(i, s) for i, s in enumerate(self._slots)
                          if s is not None and i not in done]
@@ -2202,10 +2253,13 @@ class ContinuousDecoder:
                 "merges": self._merges,
                 "step_failures": self._step_failures,
                 "forwards": self._forwards,
-                "commit_forwards": self._commit_forwards,
-                # one block a commit forward, until a commit is fused
-                # with the next block's first step
-                "blocks_committed": self._commit_forwards,
+                # blocks stored, each by the first denoising forward
+                # of the block after it; no forward of this pool is a
+                # commit and nothing else (the key stays for its
+                # readers)
+                "commit_forwards": 0,
+                "fused_commits": self._fused_commits,
+                "blocks_committed": self._fused_commits,
                 "tokens_unmasked": self._tokens_unmasked,
                 "moe_assignments": self._moe_assignments,
                 "moe_experts_hit": self._moe_experts_hit,

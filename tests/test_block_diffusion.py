@@ -300,12 +300,20 @@ def test_decoder_counts_forwards_blocks_and_expert_pairs(served):
     _, _, _, st = served
     assert st["steps"] >= 1 and st["prefills"] >= 1
     assert st["forwards"] >= st["steps"]
-    assert st["commit_forwards"] == st["blocks_committed"]
-    # a finished row's last block is never committed
+    # every block was stored by a forward that also denoised the block
+    # after it; no forward was a commit and nothing else
+    assert st["commit_forwards"] == 0
+    assert st["fused_commits"] + st["commit_forwards"] == \
+        st["blocks_committed"]
+    # each request's last whole prompt block and every answered block
+    # but its last (a finished row's last block is never stored)
+    assert st["blocks_committed"] == sum(
+        -(-(8 + r + n) // L) - 2 for r, n in CASES)
     assert st["blocks_committed"] < st["forwards"]
     assert st["tokens_unmasked"] >= sum(n for _, n in CASES)
+    # a step runs 2L positions a row: the clean block and the open one
     layers, experts, k = 2, 8, 2
-    assert st["moe_assignments"] == st["steps"] * layers * 2 * L * k
+    assert st["moe_assignments"] == st["steps"] * layers * 2 * 2 * L * k
     assert 0 < st["moe_experts_hit"] <= st["steps"] * layers * experts
     assert 1.0 <= st["moe_max_load"] <= experts
 
@@ -314,7 +322,8 @@ def test_next_step_is_in_flight_while_tokens_are_emitted(params):
     """The step after is dispatched before a step's tokens go out: the
     decode thread has a step in flight at every token but those of the row's
     last step, and a finished row rides no further forward (two blocks
-    at two steps a block: 2 + commit + 2)."""
+    at two steps a block: 2 + 2, the first of each pair storing the
+    block before: the prompt's last, then the first answered)."""
     dec = _gen(params, 2, 2).serving_decoder()
     seen, emit = [], dec._emit
 
@@ -329,7 +338,213 @@ def test_next_step_is_in_flight_while_tokens_are_emitted(params):
     finally:
         dec.close(30)
     assert seen == [True] * 6 + [False] * 2
-    assert (st["forwards"], st["commit_forwards"]) == (5, 1)
+    assert (st["forwards"], st["commit_forwards"], st["fused_commits"],
+            st["blocks_committed"]) == (4, 0, 2, 2)
+
+
+# -- the fused step: a block's commit rides the next block's first forward ---
+
+@pytest.fixture(scope="module", params=[
+    (rule, steps) for rule in REMASKING for steps in (1, 2, 4)],
+    ids=lambda p: "%s-%d" % p)
+def fused(request, params):
+    """Four requests, one of each remainder of the prompt against a
+    block, nine tokens each, through a fused pool of two slots, beside
+    the one-shot T + 1 rows of a batch-1 generator: one pool for the
+    schedule's four cases."""
+    rule, steps = request.param
+    prompts = _prompts([8 + r for r in range(L)], seed=steps)
+    dec = _gen(params, 2, steps, rule).serving_decoder()
+    try:
+        futs = [dec.submit(p, 9) for p in prompts]
+        rows = [np.asarray(f.result(timeout=120)) for f in futs]
+        st = dec.stats()
+    finally:
+        dec.close(30)
+    one = _gen(params, 1, steps, rule)
+    return (rule, steps, rows,
+            [one.generate(p[None], 9)[0] for p in prompts], st)
+
+
+@pytest.mark.parametrize("rem", range(L))
+def test_fused_pool_rows_equal_the_one_shot_rows(fused, rem):
+    rule, steps, rows, want, st = fused
+    np.testing.assert_array_equal(rows[rem], want[rem])
+    assert st["commit_forwards"] == 0
+    assert st["fused_commits"] == st["blocks_committed"] > 0
+    if rule != "low_confidence_dynamic" and steps == 1:
+        # one forward a block: every forward is fused
+        assert st["forwards"] == st["fused_commits"]
+
+
+def _watch_writes(dec):
+    """Check every step of `dec` as it runs: slot b's cache rows change
+    inside [cache_pos[b], cache_pos[b] + 2L) and nowhere else (a start
+    past capacity would clamp and land lower), and a row that rides a
+    step with its clean block already stored rewrites that block's
+    rows with the very values they hold. Returns the list the steps'
+    (cache_pos, rewrites checked) go to."""
+    real, seen = dec._step_fn, []
+
+    def checked(args, aux, rng):
+        before = {k: np.asarray(v) for k, v in aux.items()}
+        pos = np.asarray(args["cache_pos"]).astype(int)
+        head = np.asarray(args["head_pos"]).astype(int)
+        stored = {i: r.n_cached for i, r in enumerate(dec._slots)
+                  if r is not None}
+        outs, new = real(args, aux, rng)
+        again = 0
+        for k, was in before.items():
+            now = np.asarray(new[k])
+            for b in range(len(pos)):
+                lo, hi = pos[b], pos[b] + 2 * L
+                assert hi <= now.shape[1]
+                np.testing.assert_array_equal(now[b, :lo], was[b, :lo])
+                np.testing.assert_array_equal(now[b, hi:], was[b, hi:])
+                if head[b] and stored.get(b, 0) > lo:
+                    np.testing.assert_array_equal(now[b, lo:lo + L],
+                                                  was[b, lo:lo + L])
+                    again += 1
+        seen.append((pos.copy(), again))
+        return outs, new
+
+    dec._step_fn = checked
+    return seen
+
+
+def test_a_row_of_exactly_max_len_is_served_and_nothing_is_clamped(
+        params):
+    """prompt + max_new == max_len: the last block's forwards write up
+    to the last position and not past it, beside a second row whose
+    stored rows stay as they were."""
+    prompts = _prompts([41, 9], seed=4)
+    dec = _gen(params, 2, 2).serving_decoder()
+    seen = _watch_writes(dec)
+    try:
+        futs = [dec.submit(prompts[0], 7), dec.submit(prompts[1], 30)]
+        rows = [np.asarray(f.result(timeout=120)) for f in futs]
+    finally:
+        dec.close(30)
+    assert len(rows[0]) == T
+    assert max(int(pos.max()) for pos, _ in seen) == T - 2 * L
+    assert sum(again for _, again in seen) > 0
+    one = _gen(params, 1, 2)
+    for p, n, row in zip(prompts, (7, 30), rows):
+        np.testing.assert_array_equal(row, one.generate(p[None], n)[0])
+    with pytest.raises(ValueError, match="2 x block_length"):
+        Generator(params, V, L + 2, batch_size=1,
+                  **model.generator_args(TOY, {
+                      "denoising_steps": 2, "remasking": "sequential"})
+                  ).serving_decoder()
+
+
+@pytest.mark.parametrize("p,n", [(1, 7), (3, 1), (2, 2), (3, 9)])
+def test_a_prompt_shorter_than_a_block(params, p, n):
+    """No prefill and no block before the first: that block rides in
+    the step's first L positions (head_pos 0), the rest as ever."""
+    prompt = _prompts([p], seed=p + n)[0]
+    dec = _gen(params, 2, 2).serving_decoder()
+    seen = _watch_writes(dec)
+    try:
+        row = dec.submit(prompt, n).result(timeout=60)
+        st = dec.stats()
+    finally:
+        dec.close(30)
+    np.testing.assert_array_equal(
+        row, _gen(params, 1, 2).generate(prompt[None], n)[0])
+    assert st["prefills"] == 0 and seen
+    # the first block is stored by the second block's first forward
+    assert st["blocks_committed"] == -(-(p + n) // L) - 1
+
+
+def test_a_row_admitted_while_another_is_mid_block(params):
+    """The second request is submitted from inside the first one's
+    first emission, so it is admitted with that row half unmasked and
+    rides its first (fused) forward beside the other's second."""
+    prompts = _prompts([9, 10], seed=8)
+    dec = _gen(params, 2, 2).serving_decoder()
+    emit, admit = dec._emit, dec._admit_blocks
+    futs, found = [], []
+
+    def spy(req, tok):
+        if not futs:
+            futs.append(dec.submit(prompts[1], 6))
+        emit(req, tok)
+
+    def admitting(P0, reqs, free):
+        found.extend((r.blk_masked.sum(), r.n_cached, r.blk_start)
+                     for r in dec._slots if r is not None)
+        admit(P0, reqs, free)
+
+    dec._emit, dec._admit_blocks = spy, admitting
+    try:
+        first = dec.submit(prompts[0], 6)
+        rows = [first.result(timeout=60), futs[0].result(timeout=60)]
+    finally:
+        dec.close(30)
+    # the first row: two of its three masks gone, its prompt block
+    # stored
+    assert [tuple(int(x) for x in f) for f in found] == [(1, 8, 8)]
+    one = _gen(params, 1, 2)
+    for p, row in zip(prompts, rows):
+        np.testing.assert_array_equal(row, one.generate(p[None], 6)[0])
+
+
+def test_a_fused_step_in_flight_when_a_step_fails(params):
+    """The step dispatched ahead raises: every active row fails with
+    the error, nothing stays in flight, the pool is built anew and the
+    next request is served from it, exactly."""
+    prompts = _prompts([8, 10, 9], seed=6)
+    dec = _gen(params, 2, 2).serving_decoder()
+    real, calls = dec._step_fn, []
+
+    def failing(args, aux, rng):
+        calls.append(1)
+        if len(calls) > 1 and None not in dec._slots and \
+                "raised" not in calls:
+            # a step dispatched ahead, both rows in it
+            calls.append("raised")
+            raise RuntimeError("injected step fault")
+        return real(args, aux, rng)
+
+    dec._step_fn = failing
+    try:
+        first = [dec.submit(p, 8) for p in prompts[:2]]
+        for f in first:
+            with pytest.raises(RuntimeError, match="injected"):
+                f.result(timeout=60)
+        assert dec._inflight is None
+        row = dec.submit(prompts[2], 8).result(timeout=60)
+        st = dec.stats()
+    finally:
+        dec.close(30)
+    assert st["step_failures"] == 1
+    np.testing.assert_array_equal(
+        row, _gen(params, 1, 2).generate(prompts[2][None], 8)[0])
+
+
+def test_logits_hook_on_a_fused_step(params):
+    """`on_block_logits` gets the OPEN block's start and (L, V) logits,
+    the reference's for that state, on the forwards that also store
+    the block before (the first of each block here) as on the others."""
+    prompt = _prompts([6], seed=2)[0]
+    dec = _gen(params, 2, 2).serving_decoder()
+    seen = []
+    dec.on_block_logits = lambda req, start, ids, masked, logits: \
+        seen.append((start, req.n_cached, ids, masked, logits))
+    try:
+        row = dec.submit(prompt, 9).result(timeout=60)
+    finally:
+        dec.close(30)
+    final = np.concatenate([row, np.zeros(L, np.int64)])
+    assert [(s, c) for s, c, *_ in seen] == [
+        (4, 4), (8, 8), (8, 8), (12, 12), (12, 12)]
+    for start, _, ids, masked, logits in seen:
+        assert logits.shape == (L, V) and masked.any()
+        assert (ids[masked] == MASK).all()
+        np.testing.assert_allclose(
+            logits, _state_logits(final, start, ids), rtol=2e-4,
+            atol=2e-4)
 
 
 def test_masked_is_a_matter_of_position_not_of_the_id(params):
@@ -414,7 +629,7 @@ def test_defaults_leave_the_symbol_as_it_was():
         V, T, num_layers=2, num_heads=4, dim=32, experts_per_token=1,
         expert_hidden=None, norm_topk_prob=False, head_dim=None,
         qk_norm=False, rope_base=None, attention_block=0,
-        moe_stats=False)
+        moe_stats=False, head_rows=0)
     assert base.list_arguments() == same.list_arguments()
     assert base.list_auxiliary_states() == same.list_auxiliary_states()
     assert len(base.list_outputs()) == len(same.list_outputs()) == 1
@@ -423,3 +638,40 @@ def test_defaults_leave_the_symbol_as_it_was():
     assert ops(base) == ops(same)
     with pytest.raises(ValueError, match="moe_stats"):
         transformer.get_decode_symbol(V, T, moe_stats=True)
+    with pytest.raises(ValueError, match="per_row_pos"):
+        transformer.get_decode_symbol(V, T, head_rows=L)
+
+
+def test_head_rows_reads_each_rows_own_positions():
+    """The head of a per-row symbol built with head_rows=L reads L
+    positions from head_pos[b] on: the logits the whole forward gives
+    there, whatever the offset of a row."""
+    from mxnet_tpu.executor import _graph_eval_fn
+    from mxnet_tpu.models import transformer
+    opts = dict(num_layers=1, num_heads=2, dim=16, pos_encoding="rope",
+                per_row_pos=True)
+    whole = transformer.get_decode_symbol(V, T, **opts)
+    part = transformer.get_decode_symbol(V, T, head_rows=L, **opts)
+    assert [a for a in part.list_arguments() if a != "head_pos"] == \
+        whole.list_arguments()
+    rng = np.random.default_rng(0)
+    shapes, _, aux_shapes = whole.infer_shape(
+        data=(3, 2 * L), positions=(3, 2 * L), cache_pos=(3,))
+    args = {k: jnp.asarray(rng.normal(size=v) * 0.3, jnp.float32)
+            for k, v in zip(whole.list_arguments(), shapes)}
+    args["data"] = jnp.asarray(rng.integers(0, V, (3, 2 * L)),
+                               jnp.float32)
+    args["cache_pos"] = jnp.asarray([0.0, 4.0, 8.0])
+    args["positions"] = args["cache_pos"][:, None] + jnp.arange(2.0 * L)
+    aux = lambda: {k: jnp.zeros(v, jnp.float32) for k, v in zip(
+        whole.list_auxiliary_states(), aux_shapes)}
+    key = jax.random.PRNGKey(0)
+    full, _ = _graph_eval_fn(whole)(args, aux(), key, False)
+    head = [0, L, 2]
+    got, _ = _graph_eval_fn(part)(
+        dict(args, head_pos=jnp.asarray(head, jnp.float32)), aux(), key,
+        False)
+    assert got[0].shape == (3, L, V)
+    for b, h in enumerate(head):
+        np.testing.assert_allclose(got[0][b], full[0][b, h:h + L],
+                                   rtol=1e-5, atol=1e-5)

@@ -137,12 +137,12 @@ def test_serve_programs_keep_a_token_contiguous_on_v5e(
     assert prefill.memory_analysis().temp_size_in_bytes < scores // 2
 
 
-@pytest.mark.parametrize("tokens", [16 * 4, 16 * 60])
+@pytest.mark.parametrize("tokens", [16 * 4, 16 * 8, 16 * 60])
 def test_expert_layer_compiles_for_v5e_without_a_dense_buffer(
         one_chip, no_compile_cache, monkeypatch, tokens):
     """One routed expert layer at the SDAR cell's widths (2048 -> 128
-    experts of 768, top 8, bf16), a block step's 64 positions and the
-    shortest prefill's 960: Mosaic takes the grouped product's tiles at
+    experts of 768, top 8, bf16), a block's 64 positions, the pool's
+    step of two blocks a row (128) and the shortest prefill's 960: Mosaic takes the grouped product's tiles at
     these shapes, the two products are the kernel and not a loop XLA
     wrote, and nothing the size of (experts, tokens, width) exists."""
     from mxnet_tpu.ops import _pallas
