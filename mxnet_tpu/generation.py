@@ -156,6 +156,19 @@ class Generator:
         Architecture, as get_decode_symbol documents them: routed
         expert layers (top-k, nothing dropped), a head size apart from
         dim / num_heads, per-head RMS norm of q and k, the rotary base.
+    layer_kinds : optional per-layer sequence spelling the stack one
+        sublayer a layer ("attention" | "ssm" | "mamba2" | "experts" |
+        "mlp"; get_decode_symbol); ``num_layers`` is then its length.
+        A layer of the last two kinds owns no decode state: a slot's
+        bytes count the layers that hold some.
+    expert_scoring, routed_scaling_factor, expert_latent,
+    shared_expert_hidden, experts_held :
+        The expert layers' routing ("softmax" | "sigmoid" with a
+        choosing bias, kept in float32), the weights' scale, latent
+        experts between one down- and one up-projection, a shared
+        expert, and the chip's share ``(first, count)`` of the
+        ``num_experts`` routed over — as get_decode_symbol documents
+        them.
     diffusion : optional dict — generation by diffusion over blocks.
         ``dict(block_length=L, mask_id=, steps=T, remasking=,
         threshold=)`` (:func:`canon_diffusion`). Attention takes the
@@ -187,7 +200,10 @@ class Generator:
                  attention_scale=None, mamba2=None,
                  experts_per_token=1, expert_hidden=None,
                  norm_topk_prob=False, head_dim=None, qk_norm=False,
-                 rope_base=None, diffusion=None):
+                 rope_base=None, diffusion=None, layer_kinds=None,
+                 expert_scoring="softmax", routed_scaling_factor=1.0,
+                 expert_latent=0, shared_expert_hidden=0,
+                 experts_held=None):
         from .parallel import sharding as shd
 
         if quantize not in (None, "int8"):
@@ -223,8 +239,13 @@ class Generator:
         # block_type validation happens in get_decode_symbol below;
         # the flags steer slot-state accounting and the serving-layer
         # compatibility refusals (speculative drafts, prefill grouping)
-        self._btypes = transformer._canon_block_types(block_type,
-                                                      num_layers)
+        if layer_kinds is not None:
+            layer_kinds = tuple(layer_kinds)
+            num_layers = self.num_layers = len(layer_kinds)
+            self._btypes = transformer._mixer_kinds(layer_kinds)
+        else:
+            self._btypes = transformer._canon_block_types(block_type,
+                                                          num_layers)
         mamba2 = transformer._canon_mamba2(mamba2, self._btypes)
         # "ssm" here means RECURRENT: any layer whose state has no
         # per-position entries (gated linear attention or Mamba-2) —
@@ -253,7 +274,12 @@ class Generator:
             expert_hidden=expert_hidden, norm_topk_prob=norm_topk_prob,
             head_dim=head_dim, qk_norm=qk_norm, rope_base=rope_base,
             attention_block=self._diffusion["block_length"]
-            if self._diffusion else 0)
+            if self._diffusion else 0, layer_kinds=layer_kinds,
+            expert_scoring=expert_scoring,
+            routed_scaling_factor=routed_scaling_factor,
+            expert_latent=expert_latent,
+            shared_expert_hidden=shared_expert_hidden,
+            experts_held=experts_held)
         sym = transformer.get_decode_symbol(**self._decode_opts)
         if quantize:
             arg_params = _quantize_weights(
@@ -282,9 +308,11 @@ class Generator:
         def _raw(name, v):
             arr = jnp.asarray(getattr(v, "_data", v))
             # int8 weights and their f32 scales keep their dtypes (the
-            # whole point of quantize= is the int8 HBM footprint)
+            # whole point of quantize= is the int8 HBM footprint), and
+            # so does a router's score-correction bias: it decides
+            # between near-tied float32 scores
             if dtype and jnp.issubdtype(arr.dtype, jnp.floating) and \
-                    not name.endswith("_scale"):
+                    not name.endswith(("_scale", "_score_bias")):
                 arr = arr.astype(dtype)
             if mesh is not None:
                 arr = jax.device_put(
@@ -353,7 +381,7 @@ class Generator:
             H, P, N = (mamba2[k] for k in ("num_heads", "head_dim",
                                            "d_state"))
             self._conv_shape = (self.batch_size, mamba2["d_conv"] - 1,
-                                H * P + 2 * N)
+                                H * P + 2 * mamba2["n_groups"] * N)
             self._scan_shape = (self.batch_size, H, P, N)
         # quantize_kv: k/v live int8 with per-token f32 scale caches —
         # halves decode's dominant HBM stream (the cache is re-read
@@ -1064,6 +1092,10 @@ class Generator:
             raise ValueError("truncated_draft is not supported with "
                              "rolling caches (speculative decoding "
                              "rejects rolling models outright)")
+        if o["layer_kinds"] is not None:
+            raise ValueError(
+                "truncated_draft is not supported with layer_kinds (a "
+                "draft of the first sublayers is not a model)")
         if self._has_ssm:
             raise ValueError(
                 "truncated_draft is not supported with ssm blocks "

@@ -132,20 +132,23 @@ def _ssm_block(x, num_heads, dim, prefix):
 
 def _ffn_block(x, dim, hidden, prefix, quantized=False, kind="relu",
                no_bias=False):
-    """The dense FFN. kind "relu": fc2(relu(fc1 x)). kind "gated_silu":
-    fc1 is twice as wide and holds [gate | up]; fc2(silu(gate) * up)
-    — same two parameter names, so both kinds bind by one rule."""
+    """The dense FFN. kind "relu": fc2(relu(fc1 x)); "relu2": the
+    ReLU squared. kind "gated_silu": fc1 is twice as wide and holds
+    [gate | up]; fc2(silu(gate) * up) — same two parameter names, so
+    all kinds bind by one rule."""
     if kind == "gated_silu":
         gu = _fc(x, 2 * hidden, prefix + "fc1", quantized, no_bias)
         g = sym.slice_axis(gu, axis=2, begin=0, end=hidden)
         u = sym.slice_axis(gu, axis=2, begin=hidden, end=2 * hidden)
         h = sym.Activation(g, act_type="silu") * u
-    elif kind == "relu":
+    elif kind in ("relu", "relu2"):
         h = _fc(x, hidden, prefix + "fc1", quantized, no_bias)
         h = sym.Activation(h, act_type="relu")
+        if kind == "relu2":
+            h = sym.square(h)
     else:
-        raise ValueError("ffn must be 'relu' or 'gated_silu', got %r"
-                         % (kind,))
+        raise ValueError("ffn must be 'relu', 'relu2' or 'gated_silu', "
+                         "got %r" % (kind,))
     return _fc(h, dim, prefix + "fc2", quantized, no_bias)
 
 
@@ -186,27 +189,59 @@ def _moe_block(x, dim, hidden, num_experts, prefix, expert_axis=None,
 
 
 def _routed_block(x, dim, hidden, num_experts, prefix, top_k=1,
-                  kind="relu", renormalize=False):
+                  kind="relu", renormalize=False, scoring="softmax",
+                  scale=1.0, held=None, latent=0, shared_hidden=0):
     """The expert layer as it is served (_contrib_RoutedExperts): the
-    top_k experts by float32 softmax score, every routed (token,
-    expert) pair computed and nothing dropped. Binds the parameter
-    names of _moe_block, so a Switch checkpoint (top_k 1, "relu")
-    decodes through it; kind "gated_silu" makes experts_w1 twice as
-    wide, [gate | up], by the rule of _ffn_block's fc1. Returns (y,
-    stats): the layer's output and its (3,) int32 counts."""
-    if kind not in ("relu", "gated_silu"):
-        raise ValueError("ffn must be 'relu' or 'gated_silu', got %r"
-                         % (kind,))
+    top_k experts by float32 score, every routed (token, expert) pair
+    computed and nothing dropped. Binds the parameter names of
+    _moe_block, so a Switch checkpoint (top_k 1, "relu") decodes
+    through it; kind "gated_silu" makes experts_w1 twice as wide,
+    [gate | up], by the rule of _ffn_block's fc1. scoring "sigmoid"
+    adds "<prefix>gate_score_bias" (E,), which chooses and does not
+    weigh; scale multiplies the weights. held=(first, count): the
+    experts this chip holds of the num_experts routed over (the
+    expert arrays have `count` rows). latent=Z: the experts live in Z
+    channels between "<prefix>latent_down_weight" (dim, Z) and
+    "<prefix>latent_up_weight" (Z, dim). shared_hidden=Hs: a shared
+    expert "<prefix>shared_w1_weight" (dim, Hs), "<prefix>
+    shared_w2_weight" (Hs, dim), added whole. Returns (y, stats): the
+    layer's output and its int32 counts."""
+    if kind not in ("relu", "relu2", "gated_silu"):
+        raise ValueError("ffn must be 'relu', 'relu2' or 'gated_silu', "
+                         "got %r" % (kind,))
+    first, count = held or (0, num_experts)
+    inner = int(latent) or dim
     wide = 2 * hidden if kind == "gated_silu" else hidden
     gate = sym.Variable(prefix + "gate_weight", shape=(dim, num_experts))
     w1 = sym.Variable(prefix + "experts_w1_weight",
-                      shape=(num_experts, dim, wide))
+                      shape=(count, inner, wide))
     w2 = sym.Variable(prefix + "experts_w2_weight",
-                      shape=(num_experts, hidden, dim))
-    out = sym.contrib.RoutedExperts(x, gate, w1, w2, top_k=int(top_k),
-                                    act=kind,
+                      shape=(count, hidden, inner))
+    more, attrs = [], {}
+    if scoring != "softmax":
+        attrs["scoring"] = scoring
+        more.append(sym.Variable(prefix + "gate_score_bias",
+                                 shape=(num_experts,)))
+    if scale != 1.0:
+        attrs["scale"] = float(scale)
+    if first:
+        attrs["first_expert"] = int(first)
+    if latent:
+        attrs["latent"] = True
+        more += [sym.Variable(prefix + "latent_down_weight",
+                              shape=(dim, inner)),
+                 sym.Variable(prefix + "latent_up_weight",
+                              shape=(inner, dim))]
+    if shared_hidden:
+        attrs["shared"] = True
+        more += [sym.Variable(prefix + "shared_w1_weight",
+                              shape=(dim, int(shared_hidden))),
+                 sym.Variable(prefix + "shared_w2_weight",
+                              shape=(int(shared_hidden), dim))]
+    out = sym.contrib.RoutedExperts(x, gate, w1, w2, *more,
+                                    top_k=int(top_k), act=kind,
                                     renormalize=bool(renormalize),
-                                    name=prefix + "moe")
+                                    name=prefix + "moe", **attrs)
     return out[0], out[1]
 
 
@@ -239,6 +274,35 @@ def _canon_block_types(block_type, num_layers):
                 "block_type entries must be 'attention', 'ssm' or "
                 "'mamba2', got %r" % (b,))
     return kinds
+
+
+_LAYER_KINDS = ("attention", "ssm", "mamba2", "experts", "mlp")
+
+
+def _canon_layer_kinds(layer_kinds, num_layers):
+    """layer_kinds as a per-layer tuple, or None where the stack is
+    spelled the old way (block_type: a mixer and an FFN in every
+    layer). Each entry is ONE sublayer: a mixer ("attention" | "ssm" |
+    "mamba2"), a routed expert layer ("experts") or a dense FFN
+    ("mlp")."""
+    if layer_kinds is None:
+        return None
+    kinds = tuple(layer_kinds)
+    if len(kinds) != num_layers:
+        raise ValueError(
+            "layer_kinds names each layer: got %d entries for "
+            "num_layers=%d" % (len(kinds), num_layers))
+    for k in kinds:
+        if k not in _LAYER_KINDS:
+            raise ValueError("layer_kinds entries must be one of %r, "
+                             "got %r" % (_LAYER_KINDS, k))
+    return kinds
+
+
+def _mixer_kinds(kinds):
+    """The layers of a layer_kinds stack that mix positions (and hold
+    decode state), in order."""
+    return tuple(k for k in kinds if k not in ("experts", "mlp"))
 
 
 def _check_pos_encoding(pos_encoding, dim, num_heads):
@@ -382,27 +446,32 @@ def _decode_ssm_block(x, num_heads, dim, prefix, max_len, pos,
     return _merge_heads_proj(out, dim, prefix, quantized)
 
 
-MAMBA2_SIZES = ("num_heads", "head_dim", "d_state", "d_conv", "chunk")
+MAMBA2_SIZES = ("num_heads", "head_dim", "d_state", "d_conv", "chunk",
+                "n_groups")
 
 
 def _canon_mamba2(mamba2, btypes):
     """The Mamba-2 layers' sizes as a plain dict with every key of
-    MAMBA2_SIZES, or None when no layer is of that kind. d_conv and
-    chunk have the family's usual values as defaults; the three widths
-    have none."""
+    MAMBA2_SIZES, or None when no layer is of that kind. d_conv,
+    chunk and n_groups (the groups B and C come in: heads must divide
+    over them) have the family's usual values as defaults; the three
+    widths have none."""
     if "mamba2" not in btypes:
         if mamba2:
             raise ValueError("mamba2 sizes given but no block_type "
                              "entry is 'mamba2'")
         return None
-    sizes = dict({"d_conv": 4, "chunk": 256}, **dict(mamba2 or {}))
+    sizes = dict({"d_conv": 4, "chunk": 256, "n_groups": 1},
+                 **dict(mamba2 or {}))
     if set(sizes) != set(MAMBA2_SIZES) or \
             any(int(v) < 1 for v in sizes.values()) or \
-            int(sizes["d_conv"]) < 2:
+            int(sizes["d_conv"]) < 2 or \
+            int(sizes["num_heads"]) % int(sizes["n_groups"]):
         raise ValueError(
-            "block_type 'mamba2' needs mamba2=dict(num_heads=, "
-            "head_dim=, d_state=[, d_conv=4, chunk=256]) with "
-            "positive sizes and d_conv >= 2, got %r" % (mamba2,))
+            "'mamba2' layers need mamba2=dict(num_heads=, head_dim=, "
+            "d_state=[, d_conv=4, chunk=256, n_groups=1]) with "
+            "positive sizes, d_conv >= 2 and num_heads a multiple of "
+            "n_groups, got %r" % (mamba2,))
     return {k: int(sizes[k]) for k in MAMBA2_SIZES}
 
 
@@ -414,12 +483,19 @@ def _decode_mamba2_block(x, dim, prefix, max_len, pos, sizes,
     two per-layer aux states ("<prefix>mamba_conv_state",
     (B, d_conv-1, conv_dim) in the served dtype, and
     "<prefix>mamba_scan_state", (B, heads, head_dim, d_state) f32 —
-    neither has a length axis), the gated RMS norm over all of d_inner
-    ("<prefix>mnorm_gamma", gate first), and "<prefix>out_proj". The op
-    ignores pos, so the per-row-position serving twin is this graph."""
+    neither has a length axis), the gated RMS norm over d_inner, group
+    by group ("<prefix>mnorm_gamma", gate first), and
+    "<prefix>out_proj"; conv_dim = d_inner + 2 * n_groups * d_state. The
+    op ignores pos, so the per-row-position serving twin is this
+    graph."""
     H, P, N = sizes["num_heads"], sizes["head_dim"], sizes["d_state"]
+    G = sizes["n_groups"]
+    # one group is the ops' default: said only where it is not, so a
+    # one-group symbol is the symbol it always was
+    by_group = {"n_groups": G} if G > 1 else {}
+    sizes = {k: v for k, v in sizes.items() if k != "n_groups"}
     d_inner = H * P
-    conv_dim = d_inner + 2 * N
+    conv_dim = d_inner + 2 * G * N
     zxd = _fc(x, d_inner + conv_dim + H, prefix + "in_proj", quantized,
               no_bias)
     z = sym.slice_axis(zxd, axis=2, begin=0, end=d_inner)
@@ -428,8 +504,10 @@ def _decode_mamba2_block(x, dim, prefix, max_len, pos, sizes,
     dt = sym.slice_axis(zxd, axis=2, begin=d_inner + conv_dim,
                         end=d_inner + conv_dim + H)
     y = sym.contrib.Mamba2Cached(xbc, dt, pos=pos, max_len=max_len,
-                                 name=prefix + "mamba", **sizes)
-    y = sym.contrib.GatedRMSNorm(y, z, eps=eps, name=prefix + "mnorm")
+                                 name=prefix + "mamba", **sizes,
+                                 **by_group)
+    y = sym.contrib.GatedRMSNorm(y, z, eps=eps, name=prefix + "mnorm",
+                                 **({"groups": G} if G > 1 else {}))
     return _fc(y, dim, prefix + "out_proj", quantized, no_bias)
 
 
@@ -447,7 +525,10 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
                       experts_per_token=1, expert_hidden=None,
                       norm_topk_prob=False, head_dim=None,
                       qk_norm=False, rope_base=None, attention_block=0,
-                      moe_stats=False, head_rows=0):
+                      moe_stats=False, head_rows=0, layer_kinds=None,
+                      expert_scoring="softmax",
+                      routed_scaling_factor=1.0, expert_latent=0,
+                      shared_expert_hidden=0, experts_held=None):
     """Autoregressive-decode twin of get_symbol.
 
     Inputs: data (B, Tnew) token ids for the tokens being appended
@@ -483,8 +564,18 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
     "mamba2" entries of block_type are Mamba-2 mixers sized by
     `mamba2` (a dict of MAMBA2_SIZES; see _decode_mamba2_block): two
     aux states a layer, a convolution window and a float32 scan state,
-    with the composition rules of "ssm" layers. They exist on this
-    decode path only (serving); get_symbol does not build them.
+    with the composition rules of "ssm" layers; n_groups > 1 gives B,
+    C and the gated norm by group. They exist on this decode path only
+    (serving); get_symbol does not build them.
+
+    layer_kinds: a per-layer sequence that spells the stack ONE
+    SUBLAYER A LAYER, h <- h + f(norm(h)) with the layer's one norm
+    "layerN_ln1": "attention" | "ssm" | "mamba2" (the mixers above),
+    "experts" (a routed expert layer sized by the expert arguments
+    below) or "mlp" (the dense FFN of kind `ffn`). A layer of the last
+    two kinds holds no decode state at all. block_type then stays at
+    its default: it is the other spelling, a mixer AND an FFN in every
+    layer, and builds the symbol it always built.
 
     The remaining arguments are what the hybrid families' published
     equations need beyond the block above, each with the default that
@@ -499,16 +590,29 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
     is added, logits_scaling DIVIDES the logits; attention_scale
     replaces head_dim ** -0.5.
 
-    num_experts > 0 makes every layer's FFN a routed expert layer
-    (_contrib_RoutedExperts, parallel/moe.py::routed_experts): the
-    experts_per_token largest float32 softmax scores (divided by
-    their sum under norm_topk_prob), every routed pair computed,
-    nothing dropped; experts of width expert_hidden (default
-    ffn_hidden) and of kind `ffn`. The defaults (top-1, "relu", the
-    score itself as the weight) serve a Switch checkpoint trained
-    through get_symbol. moe_stats=True adds a second output, (layers,
-    3) int32: each layer's pairs computed, distinct experts hit and
-    largest expert batch. head_dim: a head size other than dim /
+    num_experts > 0 makes every layer's FFN (under layer_kinds: every
+    "experts" layer) a routed expert layer (_contrib_RoutedExperts,
+    parallel/moe.py::routed_experts): the experts_per_token largest
+    float32 softmax scores (divided by their sum under
+    norm_topk_prob), every routed pair computed, nothing dropped;
+    experts of width expert_hidden (default ffn_hidden) and of kind
+    `ffn` ("relu" | "relu2" | "gated_silu"). The defaults (top-1,
+    "relu", the score itself as the weight) serve a Switch checkpoint
+    trained through get_symbol. expert_scoring="sigmoid": sigmoid
+    scores and a score-correction bias "layerN_gate_score_bias" that
+    chooses the experts and does not weigh them;
+    routed_scaling_factor multiplies the weights. expert_latent=Z: the
+    experts map Z -> expert_hidden -> Z between one down-projection a
+    token and one up-projection of the weighted sum.
+    shared_expert_hidden=Hs: a shared expert of kind `ffn` over the
+    full width, added whole. experts_held=(first, count): THE CHIP'S
+    SHARE: the layer routes over all num_experts, holds experts first
+    .. first + count - 1 and computes the pairs routed to those; what
+    the other experts would add is left to their chips. moe_stats=True
+    adds a second output, (expert layers, 3) int32: each layer's pairs
+    routed, distinct held experts hit and largest expert batch, and
+    with experts_held a fourth column, the pairs computed here.
+    head_dim: a head size other than dim /
     num_heads (the q block and the out-projection's input are then
     num_heads * head_dim wide; the cache rows Hkv * head_dim).
     qk_norm: each head of q and k RMS-normalised with a learned gain,
@@ -535,7 +639,25 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
         raise ValueError("head_rows needs per_row_pos (head_pos is "
                          "one offset a row)")
     _check_kv_heads(num_heads, num_kv_heads)
-    btypes = _canon_block_types(block_type, num_layers)
+    kinds = _canon_layer_kinds(layer_kinds, num_layers)
+    if kinds is None:
+        btypes = _canon_block_types(block_type, num_layers)
+    else:
+        if block_type != "attention":
+            raise ValueError("layer_kinds and block_type are two "
+                             "spellings of the stack: give one")
+        if bool(num_experts) != ("experts" in kinds):
+            raise ValueError("'experts' layers and num_experts > 0 go "
+                             "together: got num_experts=%d for %r"
+                             % (num_experts, kinds))
+        btypes = _mixer_kinds(kinds)
+    if experts_held is not None:
+        first, count = (int(v) for v in experts_held)
+        if first < 0 or count < 1 or first + count > num_experts:
+            raise ValueError("experts_held=(first, count) must lie "
+                             "inside num_experts=%d, got %r"
+                             % (num_experts, experts_held))
+        experts_held = (first, count)
     mamba2 = _canon_mamba2(mamba2, btypes)
     has_ssm = "ssm" in btypes or "mamba2" in btypes
     has_attn = "attention" in btypes
@@ -622,48 +744,58 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
         return sym.contrib.AddScaledF32(
             x, branch, scalar=float(residual_multiplier))
 
+    def mixer(kind, a, prefix):
+        if kind == "ssm":
+            return _decode_ssm_block(a, num_heads, dim, prefix,
+                                     max_len, cache_pos,
+                                     quantized=quantized)
+        if kind == "mamba2":
+            return _decode_mamba2_block(a, dim, prefix, max_len,
+                                        cache_pos, mamba2,
+                                        quantized=quantized,
+                                        no_bias=no_bias, eps=norm_eps)
+        return _decode_attention_block(
+            a, num_heads, dim, prefix, max_len, cache_pos,
+            num_kv_heads=num_kv_heads, quantized=quantized,
+            rope_positions=rope_positions,
+            window=attention_window, rolling=rolling_cache,
+            kv_quantize=kv_quantize, scale=attention_scale,
+            no_bias=no_bias, head_dim=head_dim,
+            qk_norm_eps=norm_eps if qk_norm else None,
+            rope_base=rope_base, block=attention_block)
+
+    def experts(f, prefix):
+        # inference never capacity-drops: every token is served, and
+        # only the routed pairs are computed. Training-time drops mean
+        # a dropping checkpoint's decode can differ exactly where
+        # training zeroed a token's FFN. (Expert weights stay float —
+        # quantized= covers the dense projections.)
+        ff, stats = _routed_block(
+            f, dim, expert_hidden or ffn_hidden, num_experts, prefix,
+            top_k=experts_per_token, kind=ffn,
+            renormalize=norm_topk_prob, scoring=expert_scoring,
+            scale=routed_scaling_factor, held=experts_held,
+            latent=expert_latent, shared_hidden=shared_expert_hidden)
+        layer_stats.append(stats)
+        return ff
+
+    def dense(f, prefix):
+        return _ffn_block(f, dim, ffn_hidden, prefix,
+                          quantized=quantized, kind=ffn, no_bias=no_bias)
+
     layer_stats = []
     for i in range(num_layers):
         prefix = "layer%d_" % i
         a = _norm(x, prefix + "ln1", norm, norm_eps)
-        if btypes[i] == "ssm":
-            mixed = _decode_ssm_block(a, num_heads, dim, prefix,
-                                      max_len, cache_pos,
-                                      quantized=quantized)
-        elif btypes[i] == "mamba2":
-            mixed = _decode_mamba2_block(a, dim, prefix, max_len,
-                                         cache_pos, mamba2,
-                                         quantized=quantized,
-                                         no_bias=no_bias, eps=norm_eps)
-        else:
-            mixed = _decode_attention_block(
-                a, num_heads, dim, prefix, max_len, cache_pos,
-                num_kv_heads=num_kv_heads, quantized=quantized,
-                rope_positions=rope_positions,
-                window=attention_window, rolling=rolling_cache,
-                kv_quantize=kv_quantize, scale=attention_scale,
-                no_bias=no_bias, head_dim=head_dim,
-                qk_norm_eps=norm_eps if qk_norm else None,
-                rope_base=rope_base, block=attention_block)
-        x = residual(x, mixed)
+        if kinds is not None:
+            # one sublayer a layer: a mixer, an expert layer or an FFN
+            f = {"experts": experts, "mlp": dense}.get(kinds[i])
+            x = residual(x, f(a, prefix) if f else
+                         mixer(kinds[i], a, prefix))
+            continue
+        x = residual(x, mixer(btypes[i], a, prefix))
         f = _norm(x, prefix + "ln2", norm, norm_eps)
-        if num_experts:
-            # inference never capacity-drops: every token is served,
-            # and only the routed pairs are computed. Training-time
-            # drops mean a dropping checkpoint's decode can differ
-            # exactly where training zeroed a token's FFN. (Expert
-            # weights stay float — quantized= covers the dense
-            # projections.)
-            ff, stats = _routed_block(
-                f, dim, expert_hidden or ffn_hidden, num_experts,
-                prefix, top_k=experts_per_token, kind=ffn,
-                renormalize=norm_topk_prob)
-            layer_stats.append(stats)
-        else:
-            ff = _ffn_block(f, dim, ffn_hidden, prefix,
-                            quantized=quantized, kind=ffn,
-                            no_bias=no_bias)
-        x = residual(x, ff)
+        x = residual(x, (experts if num_experts else dense)(f, prefix))
 
     if head_rows:
         x = sym.contrib.RowsAt(x, sym.Variable("head_pos"),

@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .registry import register
+from .registry import register, set_arg_select
 
 
 @register("_contrib_fft", arg_names=("data",),
@@ -228,26 +228,63 @@ def _moe_ffn_op(data, gate_weight, expert_w1, expert_w2,
     return out.astype(data.dtype).reshape(orig_shape)
 
 
-@register("_contrib_RoutedExperts",
-          arg_names=("data", "gate_weight", "expert_w1", "expert_w2"),
+_ROUTED_ARGS = ("data", "gate_weight", "expert_w1", "expert_w2",
+                "score_bias", "latent_down", "latent_up", "shared_w1",
+                "shared_w2")
+
+
+def _routed_experts_args(attrs):
+    """The inputs a routed expert layer takes under its attrs: the
+    score-correction bias with sigmoid scores, the latent pair and the
+    shared expert's pair where the layer has them."""
+    names = list(_ROUTED_ARGS[:4])
+    if attrs.get("scoring", "softmax") == "sigmoid":
+        names.append("score_bias")
+    if attrs.get("latent"):
+        names += ["latent_down", "latent_up"]
+    if attrs.get("shared"):
+        names += ["shared_w1", "shared_w2"]
+    return tuple(names)
+
+
+@register("_contrib_RoutedExperts", arg_names=_ROUTED_ARGS,
           differentiable=False, num_visible=2,
           defaults={"top_k": 1, "act": "relu", "renormalize": False})
-def _routed_experts_op(data, gate_weight, expert_w1, expert_w2, top_k=1,
-                       act="relu", renormalize=False, **_):
-    """Top-k mixture-of-experts FFN as it is served: float32 softmax
-    routing, every routed (token, expert) pair computed by a grouped
-    product over the ragged per-expert batches, nothing dropped under
-    any imbalance (parallel/moe.py::routed_experts; contrast
-    _contrib_MoEFFN, the capacity-buffer training form).
+def _routed_experts_op(data, gate_weight, expert_w1, expert_w2, *more,
+                       top_k=1, act="relu", renormalize=False,
+                       scoring="softmax", scale=1.0, first_expert=0,
+                       latent=False, shared=False, **_):
+    """Top-k mixture-of-experts FFN as it is served: float32 routing
+    over every expert, every routed (token, expert) pair whose expert
+    is held here computed by a grouped product over the ragged
+    per-expert batches, nothing dropped under any imbalance
+    (parallel/moe.py::routed_experts; contrast _contrib_MoEFFN, the
+    capacity-buffer training form).
 
-    data (B, T, D) or (N, D); gate_weight (D, E); expert_w1 (E, D, H)
-    for act "relu", (E, D, 2H) = [gate | up] for "gated_silu";
-    expert_w2 (E, H, D). Outputs: y, shaped like data, and stats (3,)
-    int32 = pairs computed, distinct experts hit, largest expert
-    batch. Inference-only."""
+    data (B, T, D) or (N, D); gate_weight (D, E); expert_w1 (Eh, Z, H)
+    for act "relu" | "relu2", (Eh, Z, 2H) = [gate | up] for
+    "gated_silu"; expert_w2 (Eh, H, Z): experts first_expert ..
+    first_expert + Eh - 1 of the E routed over. Then, as the attrs
+    say (_routed_experts_args): score_bias (E,) with scoring
+    "sigmoid"; latent_down (D, Z) and latent_up (Z, D) with latent
+    (else Z = D); shared_w1 (D, Hs) and shared_w2 (Hs, D) with shared.
+    Outputs: y, shaped like data, and stats int32 = pairs routed,
+    distinct held experts hit, largest expert batch and, where Eh < E,
+    the pairs computed here. Inference-only."""
     from ..parallel.moe import routed_experts
+    more = dict(zip(_routed_experts_args(
+        dict(scoring=scoring, latent=latent, shared=shared))[4:], more))
     y, stats = routed_experts(
         data.reshape(-1, data.shape[-1]), gate_weight, expert_w1,
         expert_w2, top_k=int(top_k), act=str(act),
-        renormalize=bool(renormalize))
+        renormalize=bool(renormalize), scoring=str(scoring),
+        score_bias=more.get("score_bias"), scale=float(scale),
+        first_expert=int(first_expert),
+        latent=(more["latent_down"], more["latent_up"])
+        if latent else None,
+        shared=(more["shared_w1"], more["shared_w2"])
+        if shared else None)
     return y.reshape(data.shape), stats
+
+
+set_arg_select("_contrib_RoutedExperts", _routed_experts_args)

@@ -6,14 +6,19 @@ What `ops/ssm.py` does for a bare gated linear-attention layer (one
 hybrid models (Dao & Gu 2024, "Transformers are SSMs"; the public
 `granitemoehybrid` / `mamba2` implementations name the pieces the same
 way). After the input projection a layer holds `xBC` (B, T, conv_dim)
-and per-head step logits `dt` (B, T, H), with conv_dim = H*P + 2*N
-(one group):
+and per-head step logits `dt` (B, T, H), with conv_dim = H*P + 2*G*N
+(G groups: head h reads B and C of group floor(h / (H/G))):
 
     xBC_t   = silu(b + sum_j w_j * xBC_{t-(K-1)+j})   depthwise, causal
-    x, B, C = split(xBC_t)                    (H, P), (N,), (N,)
+    x, B, C = split(xBC_t)                    (H, P), (G, N), (G, N)
     D_t     = softplus(dt_t + dt_bias)        per head
     S_t     = exp(D_t * A) * S_{t-1} + D_t * x_t (x) B_t
     y_t     = S_t . C_t + D * x_t             A = -exp(a_log) per head
+
+With G > 1 the scan and the step are the one-group forms mapped over
+the groups (`jax.vmap`: a group's H/G heads with its own B and C), and
+the gated norm takes its mean square over each group's channels; G = 1
+runs the one-group code as it always has, operation for operation.
 
 The convolution needs the last K-1 rows of `xBC` BEFORE the
 convolution (the "convolution window", in the served dtype: it holds
@@ -49,6 +54,8 @@ The device work carries `jax.named_scope("mamba2.conv" | "mamba2.scan"
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -58,20 +65,24 @@ _F32 = jnp.float32
 _HI = jax.lax.Precision.HIGHEST
 
 
-def _sizes(xbc, dt, num_heads, head_dim, d_state):
-    """(H, P, N) checked against the inputs' shapes."""
-    H, P, N = int(num_heads), int(head_dim), int(d_state)
+def _sizes(xbc, dt, num_heads, head_dim, d_state, n_groups=1):
+    """(H, P, N, G) checked against the inputs' shapes."""
+    H, P, N, G = (int(num_heads), int(head_dim), int(d_state),
+                  int(n_groups))
     if xbc.ndim != 3 or dt.ndim != 3 or dt.shape[:2] != xbc.shape[:2]:
         raise ValueError(
             "Mamba2 xbc must be (B, T, conv_dim) and dt (B, T, H): got "
             "%r and %r" % (xbc.shape, dt.shape))
-    if xbc.shape[2] != H * P + 2 * N or dt.shape[2] != H:
+    if G < 1 or H % G:
+        raise ValueError("Mamba2 num_heads (%d) must be a multiple of "
+                         "n_groups (%d)" % (H, G))
+    if xbc.shape[2] != H * P + 2 * G * N or dt.shape[2] != H:
         raise ValueError(
             "Mamba2 sizes disagree: conv_dim %d must be num_heads*"
-            "head_dim + 2*d_state = %d*%d + 2*%d, and dt's last axis "
-            "%d must be num_heads" % (xbc.shape[2], H, P, N,
-                                      dt.shape[2]))
-    return H, P, N
+            "head_dim + 2*n_groups*d_state = %d*%d + 2*%d*%d, and dt's "
+            "last axis %d must be num_heads"
+            % (xbc.shape[2], H, P, G, N, dt.shape[2]))
+    return H, P, N, G
 
 
 def mamba2_conv(xbc, conv_state, weight, bias):
@@ -96,12 +107,28 @@ def mamba2_conv(xbc, conv_state, weight, bias):
     return jax.nn.silu(acc), full[:, T:].astype(conv_state.dtype)
 
 
-def _split(act, H, P, N):
-    """activated xBC (B, T, C) -> x (B, T, H, P), B and C (B, T, N)."""
+def _split(act, H, P, N, G=1):
+    """activated xBC (B, T, C) -> x (B, T, H, P), B and C (B, T, N);
+    with G > 1 groups x (B, T, G, H/G, P), B and C (B, T, G, N)."""
     B_, T = act.shape[:2]
     d = H * P
-    return (act[..., :d].reshape(B_, T, H, P), act[..., d:d + N],
-            act[..., d + N:])
+    if G == 1:
+        return (act[..., :d].reshape(B_, T, H, P), act[..., d:d + N],
+                act[..., d + N:])
+    return (act[..., :d].reshape(B_, T, G, H // G, P),
+            act[..., d:d + G * N].reshape(B_, T, G, N),
+            act[..., d + G * N:].reshape(B_, T, G, N))
+
+
+def _by_group(fn, seq, **kw):
+    """`fn` (mamba2_chunk_scan or mamba2_step) over all groups at once:
+    x (B, [T,] G, Hg, P), dt (B, [T,] G, Hg), A and D (G, Hg), B and C
+    (B, [T,] G, N), state (B, G, Hg, P, N); `seq` says whether the
+    time axis is there. One group's heads meet that group's B and C,
+    as the one-group form has them meet the only one."""
+    g = 2 if seq else 1
+    return jax.vmap(lambda *a: fn(*a, **kw),
+                    in_axes=(g, g, 0, g, g, 0, 1), out_axes=(g, 1))
 
 
 def _chunk(S, x, dt, A, Bm, Cm):
@@ -170,41 +197,52 @@ def mamba2_step(x, dt, A, Bm, Cm, D, state):
 
 def mamba2_mix(xbc, dt, conv_weight, conv_bias, dt_bias, a_log, d_skip,
                conv_state, scan_state, num_heads, head_dim, d_state,
-               chunk=256):
+               chunk=256, n_groups=1):
     """Convolution then scan over both carried states; static dispatch
-    on T: one token runs the step form, more run the chunked scan.
+    on T: one token runs the step form, more run the chunked scan;
+    with n_groups > 1 either form is mapped over the groups, the heads
+    of A, D, the step sizes and the state cut into (G, H/G) for it.
     Returns (y (B, T, H*P) in xbc's dtype, conv_state, scan_state)."""
-    H, P, N = _sizes(xbc, dt, num_heads, head_dim, d_state)
+    H, P, N, G = _sizes(xbc, dt, num_heads, head_dim, d_state, n_groups)
     B_, T = xbc.shape[:2]
     if scan_state.shape != (B_, H, P, N):
         raise ValueError(
             "Mamba2 scan_state must be (B, H, head_dim, d_state) = %r:"
             " got %r" % ((B_, H, P, N), scan_state.shape))
-    A = -jnp.exp(a_log.astype(_F32))
-    D = d_skip.astype(_F32)
+    if G == 1:
+        by_group = lambda v: v
+        one_step = mamba2_step
+        scan = functools.partial(mamba2_chunk_scan, chunk=chunk)
+    else:
+        by_group = lambda v: v.reshape(v.shape[:-1] + (G, H // G))
+        one_step = _by_group(mamba2_step, seq=False)
+        scan = _by_group(mamba2_chunk_scan, seq=True, chunk=chunk)
+    A = by_group(-jnp.exp(a_log.astype(_F32)))
+    D = by_group(d_skip.astype(_F32))
     S = scan_state.astype(_F32)
+    if G > 1:
+        S = S.reshape(B_, G, H // G, P, N)
     if T == 1:
         with jax.named_scope("mamba2.step"):
             act, conv_state = mamba2_conv(xbc, conv_state, conv_weight,
                                           conv_bias)
-            x, Bm, Cm = _split(act, H, P, N)
-            step = jax.nn.softplus(dt.astype(_F32) +
-                                   dt_bias.astype(_F32))
-            y, S = mamba2_step(x[:, 0], step[:, 0], A, Bm[:, 0],
-                               Cm[:, 0], D, S)
+            x, Bm, Cm = _split(act, H, P, N, G)
+            step = by_group(jax.nn.softplus(dt.astype(_F32) +
+                                            dt_bias.astype(_F32)))
+            y, S = one_step(x[:, 0], step[:, 0], A, Bm[:, 0],
+                            Cm[:, 0], D, S)
             y = y[:, None]
     else:
         with jax.named_scope("mamba2.conv"):
             act, conv_state = mamba2_conv(xbc, conv_state, conv_weight,
                                           conv_bias)
         with jax.named_scope("mamba2.scan"):
-            x, Bm, Cm = _split(act, H, P, N)
-            step = jax.nn.softplus(dt.astype(_F32) +
-                                   dt_bias.astype(_F32))
-            y, S = mamba2_chunk_scan(x, step, A, Bm, Cm, D, S,
-                                     chunk=chunk)
+            x, Bm, Cm = _split(act, H, P, N, G)
+            step = by_group(jax.nn.softplus(dt.astype(_F32) +
+                                            dt_bias.astype(_F32)))
+            y, S = scan(x, step, A, Bm, Cm, D, S)
     return (y.reshape(B_, T, H * P).astype(xbc.dtype), conv_state,
-            S.astype(scan_state.dtype))
+            S.reshape(B_, H, P, N).astype(scan_state.dtype))
 
 
 _ATTRS = {"num_heads": 0, "head_dim": 0, "d_state": 0, "d_conv": 4,
@@ -220,11 +258,13 @@ _PARAMS = ("xbc", "dt", "conv_weight", "conv_bias", "dt_bias", "a_log",
           defaults=dict(_ATTRS, max_len=0))
 def _mamba2_cached_op(xbc, dt, conv_weight, conv_bias, dt_bias, a_log,
                       d_skip, conv_state, scan_state, pos, num_heads=0,
-                      head_dim=0, d_state=0, d_conv=4, chunk=256, **_):
+                      head_dim=0, d_state=0, d_conv=4, chunk=256,
+                      n_groups=1, **_):
     """Incremental Mamba-2 over two carried aux states, threaded in
     place by the executor like a KV cache: `conv_state`
     (B, d_conv-1, conv_dim), the convolution window in the served
-    dtype, and `scan_state` (B, H, head_dim, d_state) float32. T == 1
+    dtype (conv_dim = H*head_dim + 2*n_groups*d_state), and
+    `scan_state` (B, H, head_dim, d_state) float32. T == 1
     runs the one-token update, T > 1 the chunked scan continuing from
     the carried states, so prefill, chunked prefill and decode are one
     op. `pos` is accepted and ignored, as in `_contrib_SSMCached`: the
@@ -233,7 +273,8 @@ def _mamba2_cached_op(xbc, dt, conv_weight, conv_bias, dt_bias, a_log,
     del pos
     return mamba2_mix(xbc, dt, conv_weight, conv_bias, dt_bias, a_log,
                       d_skip, conv_state, scan_state, num_heads,
-                      head_dim, d_state, chunk=int(chunk))
+                      head_dim, d_state, chunk=int(chunk),
+                      n_groups=int(n_groups))
 
 
 def _rms(x, gamma, eps, dtype):
@@ -254,8 +295,14 @@ def _rms_norm(data, gamma, eps=1e-5, **_):
 
 @register("_contrib_GatedRMSNorm", arg_names=("data", "gate", "gamma"),
           defaults={"eps": 1e-5})
-def _gated_rms_norm(data, gate, gamma, eps=1e-5, **_):
-    """RMSNorm(data * silu(gate)) over the whole last axis — the gate
-    first, then the norm (Mamba-2's output norm, one group)."""
-    return _rms(data.astype(_F32) * jax.nn.silu(gate.astype(_F32)),
-                gamma, eps, data.dtype)
+def _gated_rms_norm(data, gate, gamma, eps=1e-5, groups=1, **_):
+    """RMSNorm(data * silu(gate)) — the gate first, then the norm
+    (Mamba-2's output norm): the mean square over the whole last axis,
+    or with groups > 1 over each of that many equal runs of it."""
+    x = data.astype(_F32) * jax.nn.silu(gate.astype(_F32))
+    G = int(groups)
+    if G == 1:
+        return _rms(x, gamma, eps, data.dtype)
+    by_group = x.shape[:-1] + (G, x.shape[-1] // G)
+    return _rms(x.reshape(by_group), gamma.reshape(by_group[-2:]), eps,
+                data.dtype).reshape(x.shape)

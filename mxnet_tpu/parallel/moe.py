@@ -15,13 +15,34 @@ per-expert capacity buffers, exchanges buffers with one all_to_all
 (ICI), runs its resident experts' FFN, and all_to_alls results back.
 
 **Top-k routing, nothing dropped** (:func:`routed_experts`): the
-``top_k`` largest float32 softmax scores of each token, renormalised
-to sum to one where the model says so; every (token, expert) pair is
-computed, under any imbalance, and nothing else: the pairs are sorted
-by expert and each expert's weights meet its own ragged batch in one
-grouped matrix product (no ``(E, N, D)`` buffer, no capacity). ReLU or
-gated-SiLU experts (``w1`` then holds ``[gate | up]``, ``(E, D, 2H)``).
-The serving form: the decode symbol's expert layers are this one.
+``top_k`` experts of each token by float32 scores, every (token,
+expert) pair computed, under any imbalance, and nothing else: the
+pairs are sorted by expert and each expert's weights meet its own
+ragged batch in one grouped matrix product (no ``(E, N, D)`` buffer, no
+capacity). The serving form: the decode symbol's expert layers are
+this one. The routings it holds (:func:`route_topk`):
+
+- ``"softmax"``: the k largest softmax scores, each the pair's weight,
+  renormalised to sum to one where the model says so;
+- ``"sigmoid"``: scores ``sigmoid(x W_r)``; the k largest of score +
+  bias are CHOSEN (the score-correction bias chooses and does not
+  weigh), the weights are the chosen scores themselves, renormalised
+  where the model says so (``s / (sum + 1e-20)``);
+- either way times a ``scale`` (the routed scaling factor).
+
+Experts are ReLU, squared-ReLU (``"relu2"``) or gated-SiLU (``w1`` then
+holds ``[gate | up]``, ``(E, D, 2H)``). Around them, where the model
+has them: a latent pair of projections (``D -> Z`` once a token before
+the experts, which then map ``Z -> H -> Z``, and ``Z -> D`` once on the
+weighted sum) and a shared expert over the full width, added whole.
+
+**The chip's share**: ``w1`` and ``w2`` may hold fewer experts than the
+router has outputs: ``first_expert`` says which run of them. Routing
+is over all E; the pairs whose expert is held are computed and the
+others contribute nothing (their chips would add their parts), so the
+shares of all chips, with the shared expert counted once, add up to
+the whole layer. Absent pairs sort behind every held expert's and the
+grouped product never visits them.
 """
 from __future__ import annotations
 
@@ -130,19 +151,39 @@ def moe_ffn(x, gate_w, w1, w2, mesh, axis_name="expert",
     return fn(x, gate_w, w1, w2)
 
 
-def route_topk(x, gate_w, top_k, renormalize):
+def route_topk(x, gate_w, top_k, renormalize, scoring="softmax",
+               score_bias=None, scale=1.0):
     """The ``top_k`` experts of each token and their weights, in
     float32 throughout (a bf16 product would move near-tied scores
-    past each other): softmax over all E scores, the k largest (a tie
-    goes to the lower expert index, as ``lax.top_k`` orders them),
-    divided by their sum under ``renormalize``. x (N, D); gate_w
-    (D, E) -> weights (N, k) f32, experts (N, k) int32."""
+    past each other). ``scoring="softmax"``: softmax over all E
+    scores, the k largest (a tie goes to the lower expert index, as
+    ``lax.top_k`` orders them), divided by their sum under
+    ``renormalize``. ``scoring="sigmoid"``: sigmoid scores; the k
+    largest of score + ``score_bias`` are chosen, the weights are the
+    chosen SCORES (the bias chooses and does not weigh), divided by
+    their sum + 1e-20 under ``renormalize``. Either way times
+    ``scale``. x (N, D); gate_w (D, E); score_bias (E,) -> weights
+    (N, k) f32, experts (N, k) int32."""
     scores = jnp.dot(x.astype(jnp.float32), gate_w.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
-    weights, experts = lax.top_k(jax.nn.softmax(scores, axis=-1),
-                                 int(top_k))
-    if renormalize:
-        weights = weights / weights.sum(axis=-1, keepdims=True)
+    if scoring == "softmax":
+        weights, experts = lax.top_k(jax.nn.softmax(scores, axis=-1),
+                                     int(top_k))
+        if renormalize:
+            weights = weights / weights.sum(axis=-1, keepdims=True)
+    elif scoring == "sigmoid":
+        scores = jax.nn.sigmoid(scores)
+        _, experts = lax.top_k(
+            scores + score_bias.astype(jnp.float32), int(top_k))
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
+        if renormalize:
+            weights = weights / (weights.sum(axis=-1, keepdims=True)
+                                 + 1e-20)
+    else:
+        raise ValueError("scoring must be 'softmax' or 'sigmoid', got "
+                         "%r" % (scoring,))
+    if scale != 1.0:
+        weights = weights * jnp.float32(scale)
     return weights, experts.astype(jnp.int32)
 
 
@@ -184,56 +225,107 @@ def _grouped_dot(rows, weights, sizes):
                    interpret=_pallas.interpret())
 
 
-def routed_experts(x, gate_w, w1, w2, top_k=1, act="relu",
-                   renormalize=False):
-    """Top-k mixture-of-experts FFN that drops nothing and computes
-    only the routed (token, expert) pairs.
+_ACTS = {"relu": jax.nn.relu,
+         "relu2": lambda h: jnp.square(jax.nn.relu(h))}
 
-    x (N, D); gate_w (D, E); w1 (E, D, H) for ``act="relu"`` or
-    (E, D, 2H) holding ``[gate | up]`` for ``act="gated_silu"``; w2
-    (E, H, D). Returns ``(y, stats)``: y (N, D) in x's dtype, ``sum_k
-    weight_k * expert_k(x)``; stats (3,) int32 = pairs computed,
-    distinct experts with at least one token, the largest expert
-    batch.
+
+def _activate(h, act):
+    if act == "gated_silu":
+        half = h.shape[1] // 2
+        return jax.nn.silu(h[:, :half]) * h[:, half:]
+    return _ACTS[act](h)
+
+
+def routed_experts(x, gate_w, w1, w2, top_k=1, act="relu",
+                   renormalize=False, scoring="softmax",
+                   score_bias=None, scale=1.0, first_expert=0,
+                   latent=None, shared=None):
+    """Top-k mixture-of-experts FFN that drops nothing and computes
+    only the routed (token, expert) pairs whose expert is held here.
+
+    x (N, D); gate_w (D, E); w1 (Eh, Z, H) for ``act`` "relu" or
+    "relu2", (Eh, Z, 2H) holding ``[gate | up]`` for "gated_silu"; w2
+    (Eh, H, Z): the Eh experts ``first_expert .. first_expert + Eh -
+    1`` of the router's E (all of them by default), over Z = D or,
+    with ``latent=(down (D, Z), up (Z, D))``, over the latent width:
+    ``down`` runs once a token before the experts, ``up`` once on the
+    weighted sum. ``shared=(p (D, Hs), q (Hs, D))`` adds
+    ``act(x p) q`` whole. ``scoring``, ``score_bias``, ``scale``: see
+    :func:`route_topk`. Returns ``(y, stats)``: y (N, D) in x's dtype;
+    stats int32 = pairs routed (N * k), distinct held experts with at
+    least one token, the largest expert batch and, where fewer
+    experts are held than routed over, the pairs computed here.
 
     The N * k pairs are sorted by expert (a stable sort: within an
-    expert, token order), the tokens' rows gathered in that order, and
-    each of the two projections is ONE grouped product over the ragged
-    per-expert batches (:func:`_grouped_dot`); the outputs return to
-    token order through the inverse permutation and are summed over k
-    in float32 — a gather, never a scatter. The three stages carry
-    ``jax.named_scope("moe.route" | "moe.experts" | "moe.combine")``
-    so a device trace can tell them apart."""
+    expert, token order; pairs of experts not held here behind all
+    others), the tokens' rows gathered in that order, and each of the
+    two projections is ONE grouped product over the ragged per-expert
+    batches (:func:`_grouped_dot`), which visits only the held
+    experts' rows; the outputs return to token order through the
+    inverse permutation and are summed over k in float32 — a gather,
+    never a scatter. The stages carry ``jax.named_scope("moe.route" |
+    "moe.latent" | "moe.experts" | "moe.combine" | "moe.shared")`` so
+    a device trace can tell them apart."""
     N, D = x.shape
     E = gate_w.shape[1]
+    held = w1.shape[0]
     k = int(top_k)
-    if act not in ("relu", "gated_silu"):
-        raise ValueError("act must be 'relu' or 'gated_silu', got %r"
-                         % (act,))
+    if act not in ("relu", "relu2", "gated_silu"):
+        raise ValueError("act must be 'relu', 'relu2' or 'gated_silu', "
+                         "got %r" % (act,))
+    first = int(first_expert)
+    if first < 0 or first + held > E:
+        raise ValueError(
+            "experts %d..%d are held but the router has %d outputs"
+            % (first, first + held - 1, E))
+    part = held != E
+    inner = x
+    if latent is not None:
+        with jax.named_scope("moe.latent"):
+            inner = jnp.dot(x, latent[0].astype(x.dtype),
+                            preferred_element_type=jnp.float32) \
+                .astype(x.dtype)
     with jax.named_scope("moe.route"):
-        weights, experts = route_topk(x, gate_w, k, renormalize)
+        weights, experts = route_topk(x, gate_w, k, renormalize,
+                                      scoring, score_bias, scale)
         flat = experts.reshape(-1)                      # (N*k,)
+        if part:
+            here = (flat >= first) & (flat < first + held)
+            flat = jnp.where(here, flat - first, held)
         order = jnp.argsort(flat, stable=True)
         # (a compare and a sum, not a scatter-add: the TPU runs a
         # scatter as a loop over its updates)
-        sizes = (flat[:, None] == jnp.arange(E)).sum(
+        sizes = (flat[:, None] == jnp.arange(held)).sum(
             axis=0, dtype=jnp.int32)
         M = -(-N * k // _PAIR_TILE) * _PAIR_TILE        # whole tiles
         token = jnp.pad(order // k, (0, M - N * k))
-        rows = jnp.take(x, token, axis=0)               # (M, D)
-        stats = jnp.stack([jnp.int32(N * k),
-                           (sizes > 0).sum().astype(jnp.int32),
-                           sizes.max()])
+        rows = jnp.take(inner, token, axis=0)           # (M, Z)
+        stats = [jnp.int32(N * k),
+                 (sizes > 0).sum().astype(jnp.int32), sizes.max()]
+        if part:
+            stats.append(sizes.sum())
+        stats = jnp.stack(stats)
     with jax.named_scope("moe.experts"):
-        h = _grouped_dot(rows, w1, sizes)
-        if act == "gated_silu":
-            half = h.shape[1] // 2
-            h = jax.nn.silu(h[:, :half]) * h[:, half:]
-        else:
-            h = jax.nn.relu(h)
-        out = _grouped_dot(h, w2, sizes)                # (M, D)
+        h = _activate(_grouped_dot(rows, w1, sizes), act)
+        out = _grouped_dot(h, w2, sizes)                # (M, Z)
     with jax.named_scope("moe.combine"):
         back = jnp.argsort(order)                       # pair -> row
-        y = jnp.take(out, back, axis=0).reshape(N, k, D)
+        y = jnp.take(out, back, axis=0).reshape(N, k, -1)
+        if part:
+            # rows no group covers come back undefined: a pair whose
+            # expert lives elsewhere adds nothing here
+            y = jnp.where(here.reshape(N, k, 1), y, 0)
         y = (y.astype(jnp.float32) * weights[:, :, None]).sum(axis=1)
+    if latent is not None:
+        with jax.named_scope("moe.latent"):
+            y = jnp.dot(y.astype(x.dtype), latent[1].astype(x.dtype),
+                        preferred_element_type=jnp.float32)
+    if shared is not None:
+        with jax.named_scope("moe.shared"):
+            hs = _activate(
+                jnp.dot(x, shared[0].astype(x.dtype),
+                        preferred_element_type=jnp.float32)
+                .astype(x.dtype), act)
+            y = y + jnp.dot(hs, shared[1].astype(x.dtype),
+                            preferred_element_type=jnp.float32)
     return y.astype(x.dtype), stats

@@ -461,10 +461,12 @@ class ContinuousDecoder:
                     "a diffusion pool's step runs two blocks a row: "
                     "max_len (%d) must hold 2 x block_length (%d)"
                     % (generator.max_len, self._diff["block_length"]))
-            # the block step reports what its expert layers did, and
-            # its head reads the open block's positions alone
-            opts["moe_stats"] = bool(opts["num_experts"])
+            # the block step's head reads the open block's positions
+            # alone
             opts["head_rows"] = self._diff["block_length"]
+        # a step reports what its expert layers did (a second output
+        # of counts, read back with the step's other results)
+        opts["moe_stats"] = bool(opts["num_experts"])
         sym_p = transformer.get_decode_symbol(**opts)
         if [a for a in sym_p.list_arguments() if a != "head_pos"] != \
                 generator._sym.list_arguments():
@@ -620,7 +622,8 @@ class ContinuousDecoder:
         self._forwards = 0
         self._fused_commits = 0
         self._tokens_unmasked = 0
-        self._moe_assignments = 0
+        self._moe_assignments = 0  # pairs routed, over all experts
+        self._moe_pairs_here = 0   # pairs whose expert this chip holds
         self._moe_experts_hit = 0
         self._moe_max_load = 0.0   # largest expert batch over the mean
         self._imported = 0
@@ -791,10 +794,14 @@ class ContinuousDecoder:
                              dims(gen._conv_shape),
                              jnp.dtype(gen._cache_dtype),
                              by_kind["conv_window"]))
+        stateful = len({n.split("_", 1)[0] for n in self._aux})
         lines = [
             "ContinuousDecoder pool: %d slot(s), max_len=%d, "
-            "%d layer(s)" % (self._B, gen.max_len,
-                             gen.num_layers),
+            "%d layer(s)%s" % (
+                self._B, gen.max_len, gen.num_layers,
+                "" if stateful == gen.num_layers else
+                " (%d hold no decode state)"
+                % (gen.num_layers - stateful)),
             "  per-slot state: %s" % "; ".join(kinds),
             "  kv_bytes_per_slot: %d (%.2f MiB)  pool total: %.2f MiB"
             % (bps, bps / 2 ** 20, bps * self._B / 2 ** 20),
@@ -1672,7 +1679,13 @@ class ContinuousDecoder:
                 outs, self._aux = self._step_fn(args, self._aux,
                                                 self._rng0)
             with _trace.phase("step.wait"):
-                last = np.asarray(outs[0][:, -1].astype(jnp.float32))
+                last = outs[0][:, -1].astype(jnp.float32)
+                if len(outs) > 1:
+                    # the expert layers' counts ride the same read
+                    last, stats = jax.device_get((last, outs[1]))
+                    self._count_experts(stats)
+                else:
+                    last = np.asarray(last)
             with _trace.phase("step.emit"):
                 self._steps += 1
                 self._c_steps.inc()
@@ -1684,6 +1697,21 @@ class ContinuousDecoder:
                     tok = req._pick(last[i])
                     self._emit(req, tok)
                     self._maybe_finish(i, tok)
+
+    def _count_experts(self, stats):
+        """One step's expert counts from the device, (expert layers,
+        3 or 4) int32 as models/transformer.py ``moe_stats`` lays them
+        out: pairs routed, held experts hit, largest expert batch and,
+        where the pool holds a share of the experts, pairs computed
+        here (else every routed pair is)."""
+        self._moe_assignments += int(stats[:, 0].sum())
+        self._moe_pairs_here += int(stats[:, -1 if stats.shape[1] > 3
+                                          else 0].sum())
+        self._moe_experts_hit += int(stats[:, 1].sum())
+        experts = self._gen._decode_opts["num_experts"]
+        self._moe_max_load = max(
+            self._moe_max_load,
+            float((stats[:, 2] * experts / stats[:, 0]).max()))
 
     def _dispatch_block(self, rows):
         """Build and dispatch, without waiting, one (B, 2L) forward
@@ -1755,12 +1783,7 @@ class ContinuousDecoder:
                 self._forwards += len(rows)
                 self._fused_commits += len(fused)
                 if len(stats):
-                    self._moe_assignments += int(stats[:, 0].sum())
-                    self._moe_experts_hit += int(stats[:, 1].sum())
-                    experts = self._gen._decode_opts["num_experts"]
-                    self._moe_max_load = max(
-                        self._moe_max_load, float(
-                            (stats[:, 2] * experts / stats[:, 0]).max()))
+                    self._count_experts(stats)
                 hook = self.on_block_logits
                 if hook is not None:
                     logits = np.asarray(logits.astype(jnp.float32))
@@ -2262,6 +2285,7 @@ class ContinuousDecoder:
                 "blocks_committed": self._fused_commits,
                 "tokens_unmasked": self._tokens_unmasked,
                 "moe_assignments": self._moe_assignments,
+                "moe_pairs_here": self._moe_pairs_here,
                 "moe_experts_hit": self._moe_experts_hit,
                 "moe_max_load": self._moe_max_load,
                 "merge_programs": sum(

@@ -165,3 +165,60 @@ def test_expert_layer_compiles_for_v5e_without_a_dense_buffer(
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 8 * tokens * K * (D + 2 * H) * 2
     assert temp < E * tokens * D * 2 // 4
+
+
+@pytest.mark.parametrize("tokens", [32, 32 * 32])
+def test_a_share_of_latent_experts_compiles_for_v5e(
+        one_chip, no_compile_cache, monkeypatch, tokens):
+    """One expert layer at the Nemotron cell's widths (4096 -> a router
+    of 512, top 22; 128 of the experts held, 1024 -> 2688 -> 1024 in
+    the latent width; a shared expert of 5376), a decode step's 32
+    rows and a prefill's 1 024: Mosaic takes the grouped products'
+    tiles, the ragged contraction over 2688 among them, and the
+    temporaries follow the pairs, not experts x tokens."""
+    from mxnet_tpu.ops import _pallas
+    from mxnet_tpu.parallel.moe import routed_experts
+    monkeypatch.setattr(_pallas, "interpret", lambda: False)
+    D, E, HELD, K, Z, H, HS = 4096, 512, 128, 22, 1024, 2688, 5376
+
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    layer = jax.jit(lambda x, g, w1, w2, b, dn, up, p, q: routed_experts(
+        x, g, w1, w2, top_k=K, act="relu2", renormalize=True,
+        scoring="sigmoid", score_bias=b, scale=5.0, first_expert=128,
+        latent=(dn, up), shared=(p, q)))
+    compiled = layer.lower(
+        spec(tokens, D), spec(D, E), spec(HELD, Z, H), spec(HELD, H, Z),
+        spec(E, dtype=jnp.float32), spec(D, Z), spec(Z, D), spec(D, HS),
+        spec(HS, D)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 8 * tokens * K * (Z + H) * 2 + 2 ** 20
+    assert temp < HELD * tokens * Z * 2
+
+
+@pytest.mark.parametrize("tokens", [1, 256])
+def test_mamba2_in_eight_groups_compiles_for_v5e(
+        one_chip, no_compile_cache, tokens):
+    """The Nemotron cell's mixer core (128 heads x 64, state 128, 8
+    groups, chunks of 128) over 32 rows: the one-token step and a
+    prefill's chunked scan; the step keeps no second copy of the 134
+    MB of scan state beside the one it updates."""
+    from mxnet_tpu.ops import mamba2
+    B, H, P, N, G, K = 32, 128, 64, 128, 8, 4
+    conv = H * P + 2 * G * N
+
+    def spec(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    mix = jax.jit(lambda *a: mamba2.mamba2_mix(
+        *a, num_heads=H, head_dim=P, d_state=N, chunk=128, n_groups=G),
+        donate_argnums=(7, 8))
+    compiled = mix.lower(
+        spec(B, tokens, conv), spec(B, tokens, H), spec(conv, K),
+        spec(conv), spec(H), spec(H), spec(H), spec(B, K - 1, conv),
+        spec(B, H, P, N, dtype=jnp.float32)).compile()
+    state = B * H * P * N * 4
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < (state // 2 if tokens == 1 else 16 * state)
