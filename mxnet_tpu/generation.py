@@ -78,31 +78,39 @@ def block_picks(logits):
     return jnp.argmax(lf, axis=-1).astype(jnp.int32), conf
 
 
-def unmask_choice(masked, conf, diffusion):
-    """The positions of ONE block to unmask after a denoising forward:
-    masked (L,) bool (at least one True), conf (L,) float -> (L,) bool,
-    a subset of ``masked``. "sequential": the leftmost L / T masked
-    positions. "low_confidence_static": the L / T of highest
-    confidence (a tie goes to the left). "low_confidence_dynamic":
-    every one whose confidence is over the threshold, and the most
-    confident one where none is. Fewer remain than L / T: all of
-    them. An unmasked position is never masked again."""
-    masked = np.asarray(masked, bool)
-    where = np.flatnonzero(masked)
+def unmask_choice(masked, conf, diffusion, xp=np):
+    """The positions of a block to unmask after a denoising forward:
+    masked (..., L) bool, conf (..., L) float -> (..., L) bool, a
+    subset of ``masked`` (none of a block with no mask left).
+    "sequential": the leftmost L / T masked positions.
+    "low_confidence_static": the L / T of highest confidence (a tie
+    goes to the left). "low_confidence_dynamic": every one whose
+    confidence is over the threshold, and the most confident one where
+    none is. Fewer remain than L / T: all of them. An unmasked
+    position is never masked again.
+
+    ONE statement of the rule for the host and the device: ``xp`` is
+    ``numpy`` (``generate()``'s loop) or ``jax.numpy`` (the serving
+    pool's ``block_step``, every row at once), so the shapes never
+    follow the data; on float32 confidences the two agree bit for
+    bit."""
+    masked = xp.asarray(masked, bool)
+    conf = xp.where(masked, xp.asarray(conf), -xp.inf)
     per_step = diffusion["block_length"] // diffusion["steps"]
     rule = diffusion["remasking"]
-    conf = np.asarray(conf, np.float64)[where]
+    at = xp.arange(masked.shape[-1])
     if rule == "sequential":
-        take = where[:per_step]
+        ahead = xp.cumsum(masked, axis=-1) - masked    # masked, to the left
     elif rule == "low_confidence_static":
-        take = where[np.argsort(-conf, kind="stable")[:per_step]]
+        # positions that go first: a higher confidence, or the same
+        # one further left
+        ci, cj = conf[..., :, None], conf[..., None, :]
+        ahead = ((cj > ci) | ((cj == ci) & (at < at[:, None]))).sum(-1)
     else:
-        take = where[conf > diffusion["threshold"]]
-        if not len(take):
-            take = where[[np.argmax(conf)]]
-    out = np.zeros_like(masked)
-    out[take] = True
-    return out
+        over = masked & (conf > diffusion["threshold"])
+        best = masked & (at == xp.argmax(conf, axis=-1)[..., None])
+        return xp.where(over.any(-1, keepdims=True), over, best)
+    return masked & (ahead < per_step)
 
 
 def kv_blob_nbytes(blob):
@@ -1475,11 +1483,9 @@ class Generator:
                 if on_block_logits is not None:
                     on_block_logits(pos, ids.copy(), masked.copy(),
                                     np.asarray(logits, np.float32))
-                for b in range(B):
-                    if masked[b].any():
-                        take = unmask_choice(masked[b], conf[b], d)
-                        ids[b, take] = best[b, take]
-                        masked[b, take] = False
+                take = unmask_choice(masked, conf, d)
+                ids[take] = best[take]
+                masked &= ~take
             if on_token is not None:
                 for p in range(max(pos, P), min(pos + L, P + n)):
                     on_token(out[:, p].copy())

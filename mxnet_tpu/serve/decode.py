@@ -81,38 +81,58 @@ path with identical output. Every emission funnels through
 streamed frames and mid-stream failover cursors work unchanged.
 
 Generation by diffusion over blocks (``Generator(diffusion=...)``):
-the pool's one compiled program is then ``block_step``, (B, 2L) ids
-in, and a step no longer yields one token a row. A row carries its
-OPEN block's ids and which of its L positions are still masked (by
-position: a prompt or an answer may hold the mask id), and the ids of
-the CLEAN block before it. Every forward of a row is a denoising
-forward of its open block — the step returns each of its positions'
-best id and its confidence, picked on the device, and the row unmasks
-some by the generator's rule — over 2L positions: the clean block at
-its own depth, then the open block at depth + L. Under the block mask
-the clean block is blind to the open one, so the rows it writes are
-its final key/value rows: a block's COMMIT rides the next block's
-first denoising forward (a FUSED forward, after which the row's cached
-depth advances by L), and a block costs T forwards, not T + 1. A later
-forward of the same open block carries the same clean block at the
-same depth again (the same program over the same inputs writes the
-same rows); the open block's own rows land past the cached depth,
-where the next forward overwrites them. So no forward writes past the
-end of a row's open block, and a row of exactly ``max_len`` positions
-is served. What a new row brings as its clean block is its prompt's
-last whole block (admission prefills the whole blocks before that one
-and picks nothing); only a prompt shorter than a block has none, and
-its first block rides in the first L positions with L ignored ones
-after it. The final norm and the head read the open block's L
-positions alone, wherever a row has them (``head_pos``), so the logits
-stay (B, L, V). A step emits 0 to L tokens a row, each as soon as it
-and all before it are unmasked, one at a time through :meth:`_emit`.
-The step after is dispatched BEFORE a step's tokens are emitted (its
-inputs need only the rows' new block states), so the device runs while
-the host emits, finishes and admits; a row admitted meanwhile joins
-the step after, a finished row rides no further forward, and its last
-block is never stored. Greedy only; drafts, chunked prefill, handoff,
-resume and session export refuse such a generator.
+the pool's one compiled program is then ``block_step``, and a step no
+longer yields one token a row. A row's state LIVES ON THE DEVICE,
+beside the pool and donated to the step with it (``_fresh_block_state``):
+its OPEN block's ids and which of its L positions are still masked (by
+position: a prompt or an answer may hold the mask id), the ids of the
+CLEAN block before it, where the open block starts, whether the clean
+block is stored yet, whether the slot is live, the tokens the row
+still owes and its eos id. ``block_step`` does a step's whole turn
+from that state: it forms its own (B, 2L) inputs, runs the forward,
+picks each position's best id and its confidence, unmasks some by the
+generator's rule (``generation.unmask_choice``, the one statement of
+it, over ``jax.numpy`` here), ends the rows whose budget or eos id
+came up among the tokens that stream in order, and opens the next
+block of a row that has no mask left. Every forward of a row is a
+denoising forward of its open block over 2L positions: the clean
+block at its own depth, then the open block at depth + L. Under the
+block mask the clean block is blind to the open one, so the rows it
+writes are its final key/value rows: a block's COMMIT rides the next
+block's first denoising forward (a FUSED forward, after which the
+row's cached depth advances by L), and a block costs T forwards, not
+T + 1. A later forward of the same open block carries the same clean
+block at the same depth again (the same program over the same inputs
+writes the same rows); the open block's own rows land past the cached
+depth, where the next forward overwrites them. So no forward writes
+past the end of a row's open block, and a row of exactly ``max_len``
+positions is served. What a new row brings as its clean block is its
+prompt's last whole block (admission prefills the whole blocks before
+that one and picks nothing, then writes the row's state on the device
+with one compiled ``block_admit``, queued behind the step in flight);
+only a prompt shorter than a block has none, and its first block rides
+in the first L positions with L ignored ones after it. The final norm
+and the head read the open block's L positions alone, wherever a row
+has them, so the logits stay (B, L, V), on the device
+(``on_block_logits`` reads them).
+
+Since a step needs nothing the host holds, the loop runs ONE STEP
+AHEAD of what it has read: a call dispatches step n + 1 (parameters,
+pool, state), then reads step n's small record (the ids and mask it
+ran on, the picks, what it unmasked, which slots were live, fused,
+done, the experts' counts) and emits its 0 to L tokens a row, each as
+soon as it and all before it are unmasked, one at a time through
+:meth:`_emit`. The device runs while the host reads, emits, finishes
+and admits; a row admitted meanwhile joins the step after. A row that
+ends in step n is an idle slot in step n + 1 already (the device ended
+it; the host learns it a step late and frees the slot then), so a
+finished row rides no further forward and its last block is never
+stored; the price of running ahead is one all-idle step after the
+pool's last row ends. ``stats()`` counts ``steps_ahead`` (steps
+dispatched with the step before unread) and ``idle_forwards``
+(row-forwards the device spent on a row the host had let go: 0).
+Greedy only; drafts, chunked prefill, handoff, resume and session
+export refuse such a generator.
 """
 from __future__ import annotations
 
@@ -234,8 +254,7 @@ class DecodeFuture:
     __slots__ = ("prompt", "max_new", "eos_id", "temperature", "top_k",
                  "top_p", "seed", "_key", "t_enq", "t_admit", "t_last",
                  "tc", "emitted", "pending", "n_cached", "handoff",
-                 "resume", "speculative", "blk_ids", "blk_masked",
-                 "blk_start", "blk_prev",
+                 "resume", "speculative",
                  "_ev", "_value", "_exc", "_slock", "_sinks")
 
     def __init__(self, prompt, max_new, eos_id, temperature, top_k,
@@ -268,13 +287,6 @@ class DecodeFuture:
         self.emitted = []
         self.pending = None                # sampled but not yet fed
         self.n_cached = 0
-        # diffusion rows: the open block's L ids (the mask id where
-        # still masked), which of its positions are masked, where it
-        # starts, and the L ids of the clean block before it (None
-        # where there is none); n_cached reaches blk_start once a
-        # forward has stored that block
-        self.blk_ids = self.blk_masked = self.blk_prev = None
-        self.blk_start = 0
         self._ev = threading.Event()
         self._value = None
         self._exc = None
@@ -401,6 +413,119 @@ def _step_program(step, generator):
                    out_shardings=(None, generator._aux_shardings()))
 
 
+def _fresh_block_state(B, L):
+    """A diffusion pool's block state, every slot idle: what
+    ``block_step`` reads its inputs from and advances, kept on the
+    device beside the pool and donated with it. For each slot the open
+    block's ``ids`` (the mask id where still masked) and which of its L
+    positions are ``masked``, where it starts (``start``), the ids of
+    the clean block before it (``prev``, if ``has_prev``) and whether
+    that block is yet to be stored (``fused``: the row's next forward
+    stores it); whether the slot is ``live``, and what ends a row: the
+    tokens it still owes (``owed``) and its ``eos`` id (-1: none).
+    Built from shapes alone, whatever the model."""
+    def zeros(dtype, *tail):
+        return jnp.zeros((B,) + tail, dtype)
+    return {"ids": zeros(jnp.int32, L), "masked": zeros(bool, L),
+            "prev": zeros(jnp.int32, L), "has_prev": zeros(bool),
+            "start": zeros(jnp.int32), "fused": zeros(bool),
+            "live": zeros(bool), "owed": zeros(jnp.int32),
+            "eos": zeros(jnp.int32)}
+
+
+def _block_programs(eval_fn, generator):
+    """The two compiled programs of a diffusion pool, over the block
+    state of :func:`_fresh_block_state`.
+
+    ``block_step(params, (pool, state), rng) -> ((best, conf, logits,
+    record), (pool, state))`` does a step's whole turn on the device,
+    with the pool and the state donated: it forms the (B, 2L) forward's
+    inputs from the state (a row's clean block at its own depth and its
+    open block after it, the head on the open block; a row with no
+    block before its open one feeds that one first; an idle slot feeds
+    zeros at position 0), runs the forward, picks
+    (:func:`block_picks`), unmasks by the generator's rule
+    (:func:`unmask_choice`, the host's own statement of it), ends the
+    rows whose budget or eos id came up among the tokens that stream in
+    order (such a row is an idle slot from the next step on), and opens
+    the next block of a row that has no mask left. So the step after
+    needs nothing from the host, which reads a step late: each
+    position's ``best`` id and its ``conf``, and the ``record``: the
+    ``ids`` and ``masked`` the forward ran on, what it unmasked
+    (``take``), ``start``, which slots were ``live``, which forwards
+    were ``fused`` (stored a clean block), which rows are ``done``, and
+    the expert layers' ``stats``. The float32 logits of 16 x 4
+    positions over a 152k vocabulary are 39 MB a step: they stay on the
+    device for whoever asks (``on_block_logits``).
+
+    ``block_admit(state, rows, sel) -> state`` writes the rows ``sel``
+    marks, one compiled shape whatever their number, the state donated
+    (queued behind the step in flight, like the cache merge)."""
+    d = generator._diffusion
+    L, mask_id = d["block_length"], d["mask_id"]
+
+    def first(flags):
+        # the leftmost True of each row, L where there is none
+        return jnp.where(flags.any(-1), jnp.argmax(flags, -1), L)
+
+    def block_step(params, held, rng):
+        # named, not a lambda: the device's module name says which
+        # program ran
+        aux, state = held
+        ids, masked, start = state["ids"], state["masked"], state["start"]
+        live, owed = state["live"], state["owed"]
+        two = live & state["has_prev"]
+        data = jnp.concatenate(
+            [jnp.where(two[:, None], state["prev"], ids),
+             jnp.where(two[:, None], ids, 0)], axis=1)
+        pos = jnp.where(live, start - jnp.where(two, L, 0), 0).astype(
+            jnp.float32)
+        args = dict(
+            params,
+            data=jnp.where(live[:, None], data, 0).astype(jnp.float32),
+            positions=pos[:, None] + jnp.arange(2 * L, dtype=jnp.float32),
+            cache_pos=pos, head_pos=jnp.where(two, float(L), 0.0))
+        outs, aux = eval_fn(args, aux, rng, False)
+        best, conf = block_picks(outs[0])
+        take = unmask_choice(masked, conf, d, xp=jnp) & live[:, None]
+        ids, left = jnp.where(take, best, ids), masked & ~take
+        # in order: a token streams once it and all before it are
+        # unmasked, and the row ends with the first that is its eos id
+        # or its last owed (the rule of _maybe_finish)
+        sent, upto = first(masked), first(left)
+        at = jnp.arange(L)
+        streams = (at >= sent[:, None]) & (at < upto[:, None])
+        stop = jnp.minimum(
+            first(streams & (ids == state["eos"][:, None])),
+            sent + owed - 1)
+        done = live & (stop < upto)
+        opens = live & ~done & ~left.any(-1)
+        record = {"ids": state["ids"], "masked": masked, "take": take,
+                  "start": start, "live": live,
+                  "fused": live & state["fused"], "done": done,
+                  "stats": outs[1] if len(outs) > 1 else
+                  jnp.zeros((0, 3), jnp.int32)}
+        state = {"ids": jnp.where(opens[:, None], mask_id, ids),
+                 "masked": left | opens[:, None],
+                 "prev": jnp.where(opens[:, None], ids, state["prev"]),
+                 "has_prev": state["has_prev"] | opens,
+                 "start": start + jnp.where(opens, L, 0),
+                 # this forward stored the clean block it carried; the
+                 # one a row leaves behind waits for its next forward
+                 "fused": opens, "live": live & ~done,
+                 "owed": owed - (upto - sent), "eos": state["eos"]}
+        return (best, conf, outs[0], record), (aux, state)
+
+    def block_admit(state, rows, sel):
+        return {k: jnp.where(sel.reshape((-1,) + (1,) * (v.ndim - 1)),
+                             rows[k], v) for k, v in state.items()}
+
+    return (jax.jit(block_step, donate_argnums=1,
+                    out_shardings=(None, (generator._aux_shardings(),
+                                          None))),
+            jax.jit(block_admit, donate_argnums=0))
+
+
 class ContinuousDecoder:
     """Fixed-slot continuous batching over a Generator's decode state
     (KV caches for attention blocks, O(1) recurrent blobs for ssm
@@ -483,29 +608,29 @@ class ContinuousDecoder:
             # device's module name say which program ran
             return eval_fn(args, aux, rng, False)
 
-        def block_step(args, aux, rng):
-            # the diffusion pool's one program: (B, 2L) ids in; each
-            # position of the open blocks' best id and its confidence
-            # out, picked on the device (the float32 logits of 16 x 4
-            # positions over a 152k vocabulary are 39 MB a step), the
-            # logits themselves left on the device for whoever asks
-            # (on_block_logits), and the expert layers' counts
-            outs, aux = eval_fn(args, aux, rng, False)
-            best, conf = block_picks(outs[0])
-            stats = outs[1] if len(outs) > 1 else \
-                jnp.zeros((0, 3), jnp.int32)
-            return (best, conf, outs[0], stats), aux
-
-        self._step_fn = _step_program(
-            block_step if self._diff else decode_step, generator)
         self._rng0 = jax.random.PRNGKey(0)
         # set by whoever wants the logits behind served tokens: called
         # on the decode thread for every denoising forward of every
         # active row as fn(request, block_start, ids (L,), masked (L,),
         # logits (L, V) float32); costs a device-to-host copy a step
         self.on_block_logits = None
-        # a diffusion pool's step dispatched ahead of the host's work on
-        # the step before: (rows, fused, outputs), see _block_step
+        if self._diff:
+            # a diffusion pool's rows live on the device: the step
+            # program advances their block state and admission writes
+            # a new row's (see _block_programs), so the host reads a
+            # step only after it has dispatched the next
+            self._step_fn, self._block_admit_fn = _block_programs(
+                eval_fn, generator)
+            self._bstate = _fresh_block_state(
+                self._B, self._diff["block_length"])
+        else:
+            self._step_fn = _step_program(decode_step, generator)
+        # a diffusion pool's step still unread: (rows, outputs), with
+        # rows the request each slot held when it was dispatched. ONE
+        # step rides ahead of what the host has read, and one is
+        # enough: the host's work on a step (a few ms) hides under the
+        # program of the next, and a second would only delay what a row
+        # admitted now joins
         self._inflight = None
 
         self._aux = generator._fresh_aux()     # the pool caches
@@ -622,6 +747,10 @@ class ContinuousDecoder:
         self._forwards = 0
         self._fused_commits = 0
         self._tokens_unmasked = 0
+        # steps dispatched while the step before was still unread, and
+        # row-forwards the device spent on a row the host had let go
+        self._steps_ahead = 0
+        self._idle_forwards = 0
         self._moe_assignments = 0  # pairs routed, over all experts
         self._moe_pairs_here = 0   # pairs whose expert this chip holds
         self._moe_experts_hit = 0
@@ -860,14 +989,21 @@ class ContinuousDecoder:
                 return jax.ShapeDtypeStruct(a.shape, a.dtype,
                                             sharding=a.sharding)
             aux = {n: spec(a) for n, a in self._aux.items()}
+            args = {n: spec(a) for n, a in self._gen._params.items()}
+            if self._diff:
+                # block_step forms its inputs from the rows' block
+                # state, which is donated beside the pool and counted
+                # with it
+                aux = (aux, {n: spec(a)
+                             for n, a in self._bstate.items()})
+            else:
+                row = jax.ShapeDtypeStruct((self._B, 1), jnp.float32)
+                args.update(data=row, positions=row,
+                            cache_pos=jax.ShapeDtypeStruct(
+                                (self._B,), jnp.float32))
             held = sum(
                 int(np.prod(a.sharding.shard_shape(a.shape)))
-                * a.dtype.itemsize for a in aux.values())
-            args = {n: spec(a) for n, a in self._gen._params.items()}
-            row = jax.ShapeDtypeStruct((self._B, 1), jnp.float32)
-            args.update(data=row, positions=row,
-                        cache_pos=jax.ShapeDtypeStruct((self._B,),
-                                                       jnp.float32))
+                * a.dtype.itemsize for a in jax.tree_util.tree_leaves(aux))
             analysis = self._step_fn.lower(
                 args, aux, self._rng0).compile().memory_analysis()
             aliased = getattr(analysis, "alias_size_in_bytes", None)
@@ -1550,9 +1686,10 @@ class ContinuousDecoder:
         prefill of those under the block mask, with no logits read
         (the first tokens come from the first block's denoising
         forward, which also stores the prompt's last whole block), the
-        merge, and each row's first block opened on the prompt's
-        remainder. A prompt shorter than two blocks prefills
-        nothing."""
+        merge, and each row's block state written on the device by
+        one compiled program (``block_admit``) queued behind the step
+        in flight: the row joins the step after. A prompt shorter than
+        two blocks prefills nothing."""
         if P0:
             rows = np.stack([r.prompt[:P0] for r in reqs] +
                             [reqs[0].prompt[:P0]] *
@@ -1567,31 +1704,35 @@ class ContinuousDecoder:
             with _trace.phase("admit.merge", rows=len(reqs)):
                 self._aux = self._merge_rows(self._aux, pref_aux,
                                              free[:len(reqs)])
-        with _trace.phase("admit.emit"):
-            for req in reqs:
-                self._slots[free.pop(0)] = req
-                req.t_admit = _telemetry.now_ms()
-                req.n_cached = P0
-                self._open_block(req)
-
-    def _open_block(self, req):
-        """The row's next block: the prompt's remainder (the first
-        block only) and the mask id elsewhere. The block before it is
-        the clean block its next forward stores: the one the row
-        leaves or, for a new row, its prompt's last whole block (none
-        where the prompt is shorter than a block)."""
         d = self._diff
         L = d["block_length"]
-        if req.blk_ids is None:
-            start = len(req.prompt) // L * L
-            req.blk_prev = req.prompt[start - L:start] if start else None
-        else:
-            start, req.blk_prev = req.blk_start + L, req.blk_ids
-        req.blk_start = start
-        known = req.prompt[start:start + L]
-        req.blk_ids = np.full((L,), d["mask_id"], np.int64)
-        req.blk_ids[:len(known)] = known
-        req.blk_masked = np.arange(L) >= len(known)
+        with _trace.phase("admit.emit"):
+            # each row's first block, opened on the prompt's remainder
+            # (the mask id elsewhere), and the clean block its first
+            # forward stores: its prompt's last whole block (none where
+            # the prompt is shorter than a block)
+            rows = {k: np.zeros(v.shape, v.dtype)
+                    for k, v in self._bstate.items()}
+            sel = np.zeros((self._B,), bool)
+            for req in reqs:
+                slot = free.pop(0)
+                self._slots[slot] = req
+                req.t_admit = _telemetry.now_ms()
+                req.n_cached = P0
+                start = len(req.prompt) // L * L
+                known = req.prompt[start:start + L]
+                rows["ids"][slot] = d["mask_id"]
+                rows["ids"][slot, :len(known)] = known
+                rows["masked"][slot] = np.arange(L) >= len(known)
+                rows["prev"][slot] = req.prompt[start - L:start] \
+                    if start else 0
+                rows["has_prev"][slot] = rows["fused"][slot] = start > 0
+                rows["start"][slot] = start
+                rows["owed"][slot] = req.max_new
+                rows["eos"][slot] = -1 if req.eos_id is None \
+                    else req.eos_id
+                rows["live"][slot] = sel[slot] = True
+            self._bstate = self._block_admit_fn(self._bstate, rows, sel)
 
     def _emit(self, req, tok):
         """One token emission: latency metrics (TTFT on the first
@@ -1713,126 +1854,105 @@ class ContinuousDecoder:
             self._moe_max_load,
             float((stats[:, 2] * experts / stats[:, 0]).max()))
 
-    def _dispatch_block(self, rows):
-        """Build and dispatch, without waiting, one (B, 2L) forward
-        for ``rows`` ((slot, request) pairs): each row's clean block at
-        its own depth and its open block after it, the head on the
-        open block; a row with no block before its open one feeds that
-        one first, and the other slots feed zeros at position 0. What
-        is in flight is kept for :meth:`_block_step` to read: the rows
-        and which of them the forward is FUSED for (it stores a clean
-        block no forward has stored yet)."""
-        L = self._diff["block_length"]
-        with _trace.phase("step.inputs"):
-            toks = np.zeros((self._B, 2 * L), np.float32)
-            pos = np.zeros((self._B,), np.float32)
-            head = np.zeros((self._B,), np.float32)
-            for i, req in rows:
-                if req.blk_prev is None:
-                    toks[i, :L] = req.blk_ids
-                    pos[i] = float(req.blk_start)
-                else:
-                    toks[i, :L], toks[i, L:] = req.blk_prev, req.blk_ids
-                    pos[i] = float(req.blk_start - L)
-                    head[i] = float(L)
-            fused = {i for i, req in rows if req.n_cached < req.blk_start}
-            args = dict(self._gen._params)
-            args["data"] = jnp.asarray(toks)
-            args["positions"] = jnp.asarray(
-                pos[:, None] + np.arange(2 * L, dtype=np.float32))
-            args["cache_pos"] = jnp.asarray(pos)
-            args["head_pos"] = jnp.asarray(head)
-        with _trace.phase("step.dispatch"):
-            outs, self._aux = self._step_fn(args, self._aux, self._rng0)
-        self._inflight = (rows, fused, outs)
-
     def _block_step(self):
-        """One (B, 2L) step of a diffusion pool (see the module
-        docstring): every active row runs one denoising forward of its
-        open block, which for a row whose clean block no forward has
-        stored yet is the fused forward that stores it. The phase
+        """One call of a diffusion pool's loop (see the module
+        docstring): dispatch the next (B, 2L) step, THEN read the one
+        the call before left in flight and emit its tokens. The device
+        forms a step's inputs from the block state it keeps, so the
+        dispatch needs nothing of the step before it and the device
+        runs while the host reads, emits, finishes and admits; a row
+        admitted meanwhile joins the step after. After an idle period
+        there is nothing to read yet; with every slot let go there is
+        nothing more to dispatch, and the last step is read alone.
+
+        Every active row of the step read ran one denoising forward of
+        its open block, which for a row whose clean block no forward
+        had stored yet is the fused forward that stores it. The phase
         carries ``forward`` ("denoise" | "fused" where every row ran
         the same kind, else "mixed"), ``unmasked`` and ``fused`` (the
-        blocks the step stored).
-
-        The NEXT step is dispatched before this one's tokens are
-        emitted: its inputs need only the rows' new block states, so
-        the device runs it while the host emits, finishes and admits.
-        A call therefore reads the step left in flight by the call
-        before it (or dispatches one, after an idle period); a row
-        admitted meanwhile joins the step after."""
-        if self._inflight is None:
-            rows = [(i, s) for i, s in enumerate(self._slots)
-                    if s is not None]
-            if not rows:
-                return
-        d = self._diff
-        L = d["block_length"]
+        blocks the step stored)."""
+        last = self._inflight
+        if last is None and all(s is None for s in self._slots):
+            return
         with _trace.phase("serve.decode.step") as ph:
-            if self._inflight is None:
-                self._dispatch_block(rows)
-            (rows, fused, (best, conf, logits, stats)), \
-                self._inflight = self._inflight, None
-            with _trace.phase("step.wait"):
-                best, conf, stats = jax.device_get((best, conf, stats))
-            with _trace.phase("step.emit"):
-                self._steps += 1
-                self._c_steps.inc()
-                self._h_slotfill.observe(len(rows))
-                self._g_active.set(len(rows))
-                self._forwards += len(rows)
-                self._fused_commits += len(fused)
-                if len(stats):
-                    self._count_experts(stats)
-                hook = self.on_block_logits
-                if hook is not None:
-                    logits = np.asarray(logits.astype(jnp.float32))
-                unmasked = 0
-                out, done = [], set()
-                for i, req in rows:
-                    if self._slots[i] is not req:
-                        continue          # failed or evacuated meanwhile
-                    if i in fused:
-                        req.n_cached = req.blk_start
-                    if hook is not None:
-                        hook(req, req.blk_start, req.blk_ids.copy(),
-                             req.blk_masked.copy(), logits[i])
-                    take = unmask_choice(req.blk_masked, conf[i], d)
-                    req.blk_ids[take] = best[i, take]
-                    req.blk_masked[take] = False
-                    unmasked += int(take.sum())
-                    # in order: a token streams once it and all before
-                    # it are unmasked; the row ends by the rule of
-                    # _maybe_finish
-                    toks = []
-                    sent = len(req.prompt) + len(req.emitted) - \
-                        req.blk_start
-                    while sent + len(toks) < L and \
-                            not req.blk_masked[sent + len(toks)]:
-                        toks.append(int(req.blk_ids[sent + len(toks)]))
-                        if toks[-1] == req.eos_id or len(req.emitted) + \
-                                len(toks) >= req.max_new:
-                            done.add(i)
-                            break
-                    out.append((i, req, toks))
-                    if i not in done and not req.blk_masked.any():
-                        self._open_block(req)
-                self._tokens_unmasked += unmasked
-                ph.note(active=len(rows), unmasked=unmasked,
-                        fused=len(fused),
-                        forward="fused" if len(fused) == len(rows)
-                        else "mixed" if fused else "denoise")
+            self._inflight = None
             try:
-                ahead = [(i, s) for i, s in enumerate(self._slots)
-                         if s is not None and i not in done]
-                if ahead:
-                    self._dispatch_block(ahead)
+                with _trace.phase("step.inputs"):
+                    # the device holds a step's inputs; the host keeps
+                    # who was in which slot, for the record's tokens
+                    rows = {i: s for i, s in enumerate(self._slots)
+                            if s is not None}
+                if rows:
+                    with _trace.phase("step.dispatch"):
+                        out, (self._aux, self._bstate) = self._step_fn(
+                            self._gen._params,
+                            (self._aux, self._bstate), self._rng0)
+                    self._inflight = (rows, out)
+                    self._steps_ahead += last is not None
             finally:
-                with _trace.phase("step.emit"):
-                    for i, req, toks in out:
-                        for tok in toks:
-                            self._emit(req, tok)
-                            self._maybe_finish(i, tok)
+                # a dispatch that raises loses no token of the step
+                # before it
+                if last is not None:
+                    self._read_block_step(ph, *last)
+
+    def _read_block_step(self, ph, rows, out):
+        """Read one step's results from the device and do the host's
+        part of it: the counters, ``on_block_logits``, and each row's
+        tokens through :meth:`_emit` / :meth:`_maybe_finish`, from the
+        ids and the mask the record says the forward ran on and what it
+        unmasked. ``rows`` maps a slot to the request it held when the
+        step was dispatched; which of them the step ran, the device
+        says (a row it had ended rode as an idle slot)."""
+        best, _, logits, rec = out
+        with _trace.phase("step.wait"):
+            best, rec = jax.device_get((best, rec))
+        with _trace.phase("step.emit"):
+            live, fused = rec["live"], int(rec["fused"].sum())
+            active, unmasked = int(live.sum()), int(rec["take"].sum())
+            self._steps += 1
+            self._c_steps.inc()
+            self._h_slotfill.observe(active)
+            self._g_active.set(active)
+            self._forwards += active
+            self._fused_commits += fused
+            self._tokens_unmasked += unmasked
+            if len(rec["stats"]):
+                self._count_experts(rec["stats"])
+            ph.note(active=active, unmasked=unmasked, fused=fused,
+                    forward="fused" if fused == active
+                    else "mixed" if fused else "denoise")
+            hook = self.on_block_logits
+            if hook is not None:
+                logits = np.asarray(logits.astype(jnp.float32))
+            L = rec["ids"].shape[1]
+            ids = np.where(rec["take"], best, rec["ids"]).tolist()
+            masked = (rec["masked"] & ~rec["take"]).tolist()
+            for i in np.flatnonzero(live).tolist():
+                req = rows.get(i)
+                if req is None or self._slots[i] is not req:
+                    # the device ran a row the host had let go
+                    self._idle_forwards += 1
+                    continue
+                start = int(rec["start"][i])
+                if rec["fused"][i]:
+                    req.n_cached = start
+                if hook is not None:
+                    hook(req, start, rec["ids"][i].astype(np.int64),
+                         rec["masked"][i].copy(), logits[i])
+                # in order: a token streams once it and all before it
+                # are unmasked; the row ends by the rule of
+                # _maybe_finish, which is the device's too
+                sent = len(req.prompt) + len(req.emitted) - start
+                while sent < L and not masked[i][sent] and \
+                        self._slots[i] is req:
+                    tok = ids[i][sent]
+                    sent += 1
+                    self._emit(req, tok)
+                    self._maybe_finish(i, tok)
+                if (self._slots[i] is not req) != bool(rec["done"][i]):
+                    raise RuntimeError(
+                        "slot %d: the host and the device disagree on "
+                        "whether the row has ended" % i)
 
     def _step_failed(self, exc):
         """A step (or speculative round) raised. The pool it was given
@@ -1858,6 +1978,9 @@ class ContinuousDecoder:
         self._aux = self._gen._fresh_aux()
         if self._draft is not None:
             self._daux = self._draft._fresh_aux()
+        if self._diff:
+            self._bstate = _fresh_block_state(
+                self._B, self._diff["block_length"])
         self._publish_pool_gauges()
 
     def _publish_pool_gauges(self):
@@ -2105,6 +2228,7 @@ class ContinuousDecoder:
             not self._evac_waiters and \
             not self._evac_flag and \
             self._chunking is None and \
+            self._inflight is None and \
             all(s is None for s in self._slots)
 
     def _loop(self):
@@ -2219,6 +2343,12 @@ class ContinuousDecoder:
             req._fail(EngineClosed(
                 "evacuated before admission — replay the request on "
                 "another replica"))
+        if self._diff:
+            # every row was let go: the device's rows go with them,
+            # and the step in flight is theirs alone
+            self._inflight = None
+            self._bstate = _fresh_block_state(
+                self._B, self._diff["block_length"])
         self._evacuated += n
         if n:
             self._c_evacuated.inc(n)
@@ -2284,6 +2414,8 @@ class ContinuousDecoder:
                 "fused_commits": self._fused_commits,
                 "blocks_committed": self._fused_commits,
                 "tokens_unmasked": self._tokens_unmasked,
+                "steps_ahead": self._steps_ahead,
+                "idle_forwards": self._idle_forwards,
                 "moe_assignments": self._moe_assignments,
                 "moe_pairs_here": self._moe_pairs_here,
                 "moe_experts_hit": self._moe_experts_hit,

@@ -190,6 +190,53 @@ def test_unmask_choice_by_rule():
         d("random", 2)
 
 
+def _unmask_by_loop(masked, conf, d):
+    """The rule as the host's loop stated it before it had to run on
+    the device too: one block, by indices."""
+    where = np.flatnonzero(masked)
+    per_step = d["block_length"] // d["steps"]
+    c = np.asarray(conf, np.float64)[where]
+    if d["remasking"] == "sequential":
+        take = where[:per_step]
+    elif d["remasking"] == "low_confidence_static":
+        take = where[np.argsort(-c, kind="stable")[:per_step]]
+    else:
+        take = where[c > d["threshold"]]
+        if not len(take) and len(where):
+            take = where[[np.argmax(c)]]
+    out = np.zeros_like(masked)
+    out[take] = True
+    return out
+
+
+@pytest.mark.parametrize("steps", [1, 2, 4])
+@pytest.mark.parametrize("rule", REMASKING)
+def test_the_rule_on_the_device_is_the_rule_on_the_host(rule, steps):
+    """`unmask_choice` over jax.numpy under jit, every row at once (what
+    `block_step` runs), against numpy (what `generate()` runs) and
+    against the loop: 400 seeded blocks of every mask, confidences from
+    a set of five so that ties are the rule, a threshold among them,
+    and blocks with no mask left (none is taken)."""
+    d = canon_diffusion(dict(block_length=L, mask_id=0, steps=steps,
+                             remasking=rule, threshold=0.5))
+    rng = np.random.default_rng(steps)
+    masked = rng.integers(0, 2, (400, L)).astype(bool)
+    masked[:16] = [[bool(m >> i & 1) for i in range(L)]
+                   for m in range(16)]
+    conf = rng.choice(np.array([0.1, 0.5, 0.50001, 0.9, 1.0], np.float32),
+                      (400, L))
+    host = unmask_choice(masked, conf, d)
+    device = jax.jit(lambda m, c: unmask_choice(m, c, d, xp=jnp))(
+        masked, conf)
+    assert device.dtype == bool and host.dtype == bool
+    np.testing.assert_array_equal(np.asarray(device), host)
+    np.testing.assert_array_equal(
+        host, np.stack([_unmask_by_loop(m, c, d)
+                        for m, c in zip(masked, conf)]))
+    assert not host[~masked].any() and not host[0].any()
+    assert host[masked.any(1)].any(1).all()      # one at least
+
+
 # -- Generator.generate against the reference --------------------------------
 
 def _state_logits(clean, start, noisy):
@@ -277,9 +324,10 @@ def served(params):
         for f, s in zip(futs, streams):
             f.subscribe(s.append)
         rows = [np.asarray(f.result(timeout=120)) for f in futs]
-        stats = dec.stats()
     finally:
         dec.close(30)
+    stats = dict(dec.stats(), programs=(dec._step_fn._cache_size(),
+                                        dec._block_admit_fn._cache_size()))
     want = [one.generate(p[None], n)[0]
             for p, (_, n) in zip(prompts, CASES)]
     return rows, want, streams, stats
@@ -316,16 +364,43 @@ def test_decoder_counts_forwards_blocks_and_expert_pairs(served):
     assert st["moe_assignments"] == st["steps"] * layers * 2 * 2 * L * k
     assert 0 < st["moe_experts_hit"] <= st["steps"] * layers * experts
     assert 1.0 <= st["moe_max_load"] <= experts
+    # the pool was never idle between the first admission and the last
+    # row's end: every step but the first was dispatched with the one
+    # before it unread, no forward was spent on a row that had ended,
+    # and neither the step nor the admission of a row's block state
+    # compiled a second time over six admission rounds
+    assert st["steps_ahead"] == st["steps"] - 1
+    assert st["idle_forwards"] == 0
+    assert st["programs"] == (1, 1)
+
+
+def _order_of(dec):
+    """Log, as `dec` runs, ("dispatch", n) when step n goes to the
+    device and ("read", n) when the host asks for its results."""
+    order, step, read = [], dec._step_fn, dec._read_block_step
+
+    def dispatching(*a):
+        order.append(("dispatch", sum(k == "dispatch" for k, _ in order)))
+        return step(*a)
+
+    def reading(*a):
+        order.append(("read", sum(k == "read" for k, _ in order)))
+        return read(*a)
+
+    dec._step_fn, dec._read_block_step = dispatching, reading
+    return order
 
 
 def test_next_step_is_in_flight_while_tokens_are_emitted(params):
-    """The step after is dispatched before a step's tokens go out: the
-    decode thread has a step in flight at every token but those of the row's
-    last step, and a finished row rides no further forward (two blocks
-    at two steps a block: 2 + 2, the first of each pair storing the
-    block before: the prompt's last, then the first answered)."""
+    """Step n + 1 is dispatched before step n is READ: the device forms
+    its inputs from the block state it keeps, so the decode thread has
+    a step in flight at every token it emits. Two blocks at two steps a
+    block: 2 + 2 forwards, the first of each pair storing the block
+    before (the prompt's last, then the first answered); the fifth step
+    went out before the host had read that the row ended in the fourth,
+    and the row rode it as an idle slot."""
     dec = _gen(params, 2, 2).serving_decoder()
-    seen, emit = [], dec._emit
+    order, seen, emit = _order_of(dec), [], dec._emit
 
     def spy(req, tok):                         # on the decode thread
         seen.append(dec._inflight is not None)
@@ -334,12 +409,123 @@ def test_next_step_is_in_flight_while_tokens_are_emitted(params):
     dec._emit = spy
     try:
         dec.submit(_prompts([8], seed=3)[0], 8).result(timeout=60)
-        st = dec.stats()
     finally:
         dec.close(30)
-    assert seen == [True] * 6 + [False] * 2
+    st = dec.stats()
+    assert order == [("dispatch", 0)] + [
+        (k, n + (k == "dispatch")) for n in range(4)
+        for k in ("dispatch", "read")] + [("read", 4)]
+    assert seen == [True] * 8
+    assert (st["steps"], st["steps_ahead"], st["idle_forwards"]) == (5, 4, 0)
     assert (st["forwards"], st["commit_forwards"], st["fused_commits"],
             st["blocks_committed"]) == (4, 0, 2, 2)
+
+
+def test_rows_that_end_ride_no_further_forward(params):
+    """One row ends by its eos id, one by its budget, both in their
+    second forward and with tokens of the block still masked, beside a
+    row that runs on for four more steps: the device makes each an idle
+    slot in the step it ends in (the host reads that a step late), so
+    no forward is spent on them after it."""
+    prompts = _prompts([8, 9, 10], seed=12)
+    one = _gen(params, 1, 2)
+    want = [one.generate(p[None], 12)[0] for p in prompts]
+    eos = int(want[0][8 + 2])
+    k = list(want[0][8:]).index(eos)           # where it comes up first
+    dec = _gen(params, 3, 2).serving_decoder()
+    try:
+        futs = [dec.submit(prompts[0], 12, eos_id=eos),
+                dec.submit(prompts[1], 3), dec.submit(prompts[2], 12)]
+        rows = [np.asarray(f.result(timeout=120)) for f in futs]
+    finally:
+        dec.close(30)
+    st = dec.stats()
+    np.testing.assert_array_equal(rows[0], want[0][:8 + k + 1])
+    np.testing.assert_array_equal(rows[1], want[1][:9 + 3])
+    np.testing.assert_array_equal(rows[2], want[2])
+    # two tokens a forward; prompt 10 opens on two known positions, so
+    # its first forward yields the block's other two
+    assert st["forwards"] == (k // 2 + 1) + 2 + 6
+    assert st["idle_forwards"] == 0 and st["step_failures"] == 0
+
+
+def test_a_slot_freed_and_taken_again_while_a_step_is_in_flight(params):
+    """Two slots, three requests: the short one ends, and the queued
+    one is admitted into its slot while the step dispatched before the
+    host read that end is still unread; its block state is written
+    behind that step, it joins the one after, and every row is the
+    one-shot row."""
+    prompts = _prompts([8, 9, 11], seed=13)
+    dec = _gen(params, 2, 2).serving_decoder()
+    admit, found = dec._admit_blocks, []
+
+    def admitting(P0, reqs, free):
+        found.append((free[0], dec._inflight is not None))
+        admit(P0, reqs, free)
+
+    dec._admit_blocks = admitting
+    try:
+        futs = [dec.submit(prompts[0], 2), dec.submit(prompts[1], 12),
+                dec.submit(prompts[2], 6)]
+        rows = [np.asarray(f.result(timeout=120)) for f in futs]
+    finally:
+        dec.close(30)
+    st = dec.stats()
+    assert found[-1] == (0, True) and found[0][0] == 0
+    one = _gen(params, 1, 2)
+    for p, n, row in zip(prompts, (2, 12, 6), rows):
+        np.testing.assert_array_equal(row, one.generate(p[None], n)[0])
+    assert st["idle_forwards"] == 0
+    assert st["steps_ahead"] == st["steps"] - 1
+
+
+def test_a_pool_evacuated_lets_its_rows_go_on_the_device_too(params):
+    """Session export refuses a diffusion generator, so an evacuation
+    fails the active rows: their block state goes with them (nothing
+    rides on for a request that has failed) and the next request is
+    served from an idle state, exactly."""
+    import threading
+    prompts = _prompts([8, 9], seed=14)
+    dec = _gen(params, 2, 2).serving_decoder()
+    gone = []
+
+    def sink(tok):                 # on the decode thread, mid-block
+        if not gone:
+            gone.append(threading.Thread(
+                target=lambda: gone.append(dec.evacuate(30))))
+            gone[0].start()
+
+    try:
+        first = dec.submit(prompts[0], 36)
+        first.subscribe(sink)
+        with pytest.raises(ValueError, match="export_session"):
+            first.result(timeout=60)
+        gone[0].join(30)
+        assert gone[1:] == [0] and len(first.emitted) < 36
+        assert dec._inflight is None
+        assert not np.asarray(dec._bstate["live"]).any()
+        row = dec.submit(prompts[1], 8).result(timeout=60)
+    finally:
+        dec.close(30)
+    st = dec.stats()
+    np.testing.assert_array_equal(
+        row, _gen(params, 1, 2).generate(prompts[1][None], 8)[0])
+    assert st["idle_forwards"] == 0 and st["step_failures"] == 0
+
+
+def test_describe_counts_the_block_state_with_the_pool(params):
+    """`describe()` lowers the step as the pool runs it: parameters,
+    the pool and the rows' block state, both donated."""
+    dec = _gen(params, 2, 2).serving_decoder()
+    try:
+        text = dec.describe()
+    finally:
+        dec.close(30)
+    pool = sum(a.nbytes for a in dec._aux.values())
+    state = sum(a.nbytes for a in dec._bstate.values())
+    assert 0 < state < 64 * 2
+    assert "writes %d of the pool's %d bytes" % (
+        pool + state, pool + state) in text
 
 
 # -- the fused step: a block's commit rides the next block's first forward ---
@@ -380,28 +566,28 @@ def test_fused_pool_rows_equal_the_one_shot_rows(fused, rem):
 def _watch_writes(dec):
     """Check every step of `dec` as it runs: slot b's cache rows change
     inside [cache_pos[b], cache_pos[b] + 2L) and nowhere else (a start
-    past capacity would clamp and land lower), and a row that rides a
-    step with its clean block already stored rewrites that block's
-    rows with the very values they hold. Returns the list the steps'
-    (cache_pos, rewrites checked) go to."""
+    past capacity would clamp and land lower), where cache_pos is what
+    the step forms from the block state it is given; and a row that
+    rides a step with its clean block already stored rewrites that
+    block's rows with the very values they hold. Returns the list the
+    steps' (cache_pos, rewrites checked) go to."""
     real, seen = dec._step_fn, []
 
-    def checked(args, aux, rng):
-        before = {k: np.asarray(v) for k, v in aux.items()}
-        pos = np.asarray(args["cache_pos"]).astype(int)
-        head = np.asarray(args["head_pos"]).astype(int)
-        stored = {i: r.n_cached for i, r in enumerate(dec._slots)
-                  if r is not None}
-        outs, new = real(args, aux, rng)
+    def checked(args, held, rng):
+        before = {k: np.asarray(v) for k, v in held[0].items()}
+        st = {k: np.asarray(v) for k, v in held[1].items()}
+        two = st["live"] & st["has_prev"]
+        pos = np.where(st["live"], st["start"] - L * two, 0)
+        outs, new = real(args, held, rng)
         again = 0
         for k, was in before.items():
-            now = np.asarray(new[k])
+            now = np.asarray(new[0][k])
             for b in range(len(pos)):
                 lo, hi = pos[b], pos[b] + 2 * L
                 assert hi <= now.shape[1]
                 np.testing.assert_array_equal(now[b, :lo], was[b, :lo])
                 np.testing.assert_array_equal(now[b, hi:], was[b, hi:])
-                if head[b] and stored.get(b, 0) > lo:
+                if two[b] and not st["fused"][b]:
                     np.testing.assert_array_equal(now[b, lo:lo + L],
                                                   was[b, lo:lo + L])
                     again += 1
@@ -459,8 +645,9 @@ def test_a_prompt_shorter_than_a_block(params, p, n):
 
 def test_a_row_admitted_while_another_is_mid_block(params):
     """The second request is submitted from inside the first one's
-    first emission, so it is admitted with that row half unmasked and
-    rides its first (fused) forward beside the other's second."""
+    first emission, with the first row's second step already on the
+    device: its block state is written behind that step, and it rides
+    its first (fused) forward beside the other's third."""
     prompts = _prompts([9, 10], seed=8)
     dec = _gen(params, 2, 2).serving_decoder()
     emit, admit = dec._emit, dec._admit_blocks
@@ -472,8 +659,10 @@ def test_a_row_admitted_while_another_is_mid_block(params):
         emit(req, tok)
 
     def admitting(P0, reqs, free):
-        found.extend((r.blk_masked.sum(), r.n_cached, r.blk_start)
-                     for r in dec._slots if r is not None)
+        st = {k: np.asarray(v) for k, v in dec._bstate.items()}
+        found.extend((int(st["masked"][i].sum()), int(st["start"][i]),
+                      bool(st["fused"][i]), r.n_cached)
+                     for i, r in enumerate(dec._slots) if r is not None)
         admit(P0, reqs, free)
 
     dec._emit, dec._admit_blocks = spy, admitting
@@ -482,9 +671,11 @@ def test_a_row_admitted_while_another_is_mid_block(params):
         rows = [first.result(timeout=60), futs[0].result(timeout=60)]
     finally:
         dec.close(30)
-    # the first row: two of its three masks gone, its prompt block
-    # stored
-    assert [tuple(int(x) for x in f) for f in found] == [(1, 8, 8)]
+    # the first row on the device: its first block's three masks gone
+    # in two steps, the next block open and all masked, the block it
+    # left yet to be stored; the host has read the first step only, in
+    # which the prompt's last block was stored
+    assert found == [(L, 12, True, 8)]
     one = _gen(params, 1, 2)
     for p, row in zip(prompts, rows):
         np.testing.assert_array_equal(row, one.generate(p[None], 6)[0])
@@ -492,33 +683,44 @@ def test_a_row_admitted_while_another_is_mid_block(params):
 
 def test_a_fused_step_in_flight_when_a_step_fails(params):
     """The step dispatched ahead raises: every active row fails with
-    the error, nothing stays in flight, the pool is built anew and the
-    next request is served from it, exactly."""
+    the error, nothing stays in flight, the pool and the rows' block
+    state are built anew and the next request is served from them,
+    exactly."""
     prompts = _prompts([8, 10, 9], seed=6)
     dec = _gen(params, 2, 2).serving_decoder()
     real, calls = dec._step_fn, []
 
-    def failing(args, aux, rng):
+    def failing(args, held, rng):
         calls.append(1)
         if len(calls) > 1 and None not in dec._slots and \
                 "raised" not in calls:
             # a step dispatched ahead, both rows in it
             calls.append("raised")
             raise RuntimeError("injected step fault")
-        return real(args, aux, rng)
+        return real(args, held, rng)
 
-    dec._step_fn = failing
+    def admitting(P0, reqs, free):
+        # on the decode thread: what a round finds before it writes
+        found.append((dec._inflight is None, any(
+            np.asarray(v).any() for v in list(dec._aux.values()) +
+            list(dec._bstate.values()))))
+        admit(P0, reqs, free)
+
+    admit, found = dec._admit_blocks, []
+    dec._step_fn, dec._admit_blocks = failing, admitting
     try:
         first = [dec.submit(p, 8) for p in prompts[:2]]
         for f in first:
             with pytest.raises(RuntimeError, match="injected"):
                 f.result(timeout=60)
-        assert dec._inflight is None
         row = dec.submit(prompts[2], 8).result(timeout=60)
         st = dec.stats()
     finally:
         dec.close(30)
     assert st["step_failures"] == 1
+    # the third request's round found nothing in flight and both the
+    # pool and the rows' block state zeroed
+    assert found[-1] == (True, False)
     np.testing.assert_array_equal(
         row, _gen(params, 1, 2).generate(prompts[2][None], 8)[0])
 

@@ -222,3 +222,57 @@ def test_mamba2_in_eight_groups_compiles_for_v5e(
     state = B * H * P * N * 4
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < (state // 2 if tokens == 1 else 16 * state)
+
+
+@pytest.mark.parametrize("rule", ["sequential", "low_confidence_static",
+                                  "low_confidence_dynamic"])
+def test_block_step_with_the_rows_state_compiles_for_v5e(
+        one_chip, no_compile_cache, monkeypatch, rule):
+    """The diffusion pool's step at the SDAR cell's pool (16 slots x
+    1 024 positions, blocks of 4, two steps a block; one layer, 16
+    experts and a short vocabulary to keep it seconds): the program
+    that forms its inputs from the rows' block state, unmasks by the
+    rule and advances the state compiles for a v5e, with the grouped
+    products the kernel; it updates every byte of the pool and of the
+    state it was given in place, and so does the program that writes an
+    admitted row's state."""
+    import json
+    import os
+    from cellbench.models import sdar as model
+    from cellbench.reference import sdar as ref
+    from mxnet_tpu.ops import _pallas
+    # a compile for a described chip still sees the CPU backend
+    monkeypatch.setattr(_pallas, "interpret", lambda: False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "cellbench", "configs",
+                           "sdar-30b-a3b-chat.json")) as f:
+        cfg = json.load(f)
+    cfg.update(num_hidden_layers=1, vocab_size=8192, num_experts=16)
+    cfg["assumed"] = dict(cfg["assumed"], mask_token_id=8191)
+    gen = Generator(ref.make_params(cfg, 1, "bfloat16"), 8192, 1024,
+                    batch_size=16, dtype="bfloat16",
+                    **model.generator_args(cfg, {"denoising_steps": 2,
+                                                 "remasking": rule}))
+
+    def spec(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    with gen.serving_decoder() as dec:
+        state = {n: spec(a) for n, a in dec._bstate.items()}
+        held = ({n: spec(a) for n, a in dec._aux.items()}, state)
+        step = dec._step_fn.lower(
+            {n: spec(a) for n, a in gen._params.items()}, held,
+            spec(dec._rng0)).compile()
+        admit = dec._block_admit_fn.lower(
+            state, state, jax.ShapeDtypeStruct(
+                (16,), bool, sharding=one_chip)).compile()
+    nbytes = lambda tree: sum(
+        int(np.prod(a.shape)) * a.dtype.itemsize
+        for a in jax.tree_util.tree_leaves(tree))
+    assert step.as_text().count("tpu_custom_call") >= 2
+    # the device pads the small arrays: at least their bytes
+    assert step.memory_analysis().alias_size_in_bytes >= nbytes(held)
+    assert admit.memory_analysis().alias_size_in_bytes >= nbytes(state)
+    # nothing pool-sized beside the pool
+    assert step.memory_analysis().temp_size_in_bytes < \
+        nbytes(held[0]) // 2
