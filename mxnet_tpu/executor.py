@@ -109,7 +109,11 @@ def _graph_eval_fn(symbol, mesh=None, group2spec=None, capture=None,
     dims at module boundaries (sharding.BOUNDARY_OPS) with LENIENT
     constraints — explicit __shard__/__shard_hint__ annotations win.
     capture: debugging hook called with (node_name, [outputs]) for
-    every op node — only useful un-jitted (Monitor path)."""
+    every op node — only useful un-jitted (Monitor path).
+
+    Every op node is lowered under two nested ``jax.named_scope``s, its
+    name and ``op.<operator>``, so a device trace says which node and
+    operator each operation came from (docs/observability.md)."""
     from .symbol.symbol import _topo_order
 
     boundary_ops = None
@@ -147,7 +151,15 @@ def _graph_eval_fn(symbol, mesh=None, group2spec=None, capture=None,
             kw = {}
             if node.op.needs_rng:
                 kw["rng"] = jax.random.fold_in(rng, node_uid[id(node)])
-            raw = node.op.fn(*xs, **kw, **attrs)
+            # the device operations this node lowers to carry its name
+            # and its operator's on the profiler's timeline (`tf_op`):
+            # `<node>/op.<Operator>/...`. The node comes first: under a
+            # transform JAX wraps the outermost scope (`jvp(<node>)`,
+            # `transpose(jvp(<node>))`) and leaves the kind a whole
+            # part. `/` separates the parts of a name stack
+            with jax.named_scope(node.name.replace("/", "_")), \
+                    jax.named_scope("op." + node.op.name):
+                raw = node.op.fn(*xs, **kw, **attrs)
             outs = list(raw) if isinstance(raw, (tuple, list)) else [raw]
             n_state = node.op.num_state
             if n_state:

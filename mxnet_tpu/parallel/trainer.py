@@ -333,18 +333,22 @@ class TrainStep:
                                  rng, mstats, inject):
                 (p, o, a), outs, ok = raw_step(
                     params, opt_state, aux, batch, lr, rng, inject)
-                stats = metric.device_update(
-                    [batch[n] for n in label_names], list(outs))
-                stats = _guardrail.mask_stats(stats, ok)
-                return (p, o, a), outs, accumulate(mstats, stats), ok
+                with jax.named_scope("train.metric"):
+                    stats = metric.device_update(
+                        [batch[n] for n in label_names], list(outs))
+                    stats = _guardrail.mask_stats(stats, ok)
+                    mstats = accumulate(mstats, stats)
+                return (p, o, a), outs, mstats, ok
         else:
             def step_with_metric(params, opt_state, aux, batch, lr,
                                  rng, mstats):
                 (p, o, a), outs = raw_step(params, opt_state, aux,
                                            batch, lr, rng)
-                stats = metric.device_update(
-                    [batch[n] for n in label_names], list(outs))
-                return (p, o, a), outs, accumulate(mstats, stats)
+                with jax.named_scope("train.metric"):
+                    stats = metric.device_update(
+                        [batch[n] for n in label_names], list(outs))
+                    mstats = accumulate(mstats, stats)
+                return (p, o, a), outs, mstats
 
         return raw_step, jax.jit(
             step_with_metric,
@@ -999,6 +1003,13 @@ class TrainStep:
                     v, layout.batch_nsharding(jnp.ndim(v)))
                     for k, v in batch.items()}
 
+            # device scopes (`docs/observability.md`): `train.fwd` is
+            # the outermost scope of the differentiated function, so it
+            # takes the transform's wrapper (`jvp(train.fwd)` forward,
+            # `transpose(jvp(train.fwd))` backward) and every part
+            # below it, `train.cast` and the graph's `<node>/op.<Kind>`,
+            # stays a whole part in both directions
+            @jax.named_scope("train.fwd")
             def fwd(p):
                 feed = dict(batch)
                 if cdt is not None:
@@ -1006,15 +1017,17 @@ class TrainStep:
                     # Labels and Embedding-fed inputs carry ids — bf16
                     # would alias ids >= 256 (8-bit significand). The
                     # cast is linear so vjp returns float32 grads.
-                    p = {k: v.astype(cdt) for k, v in p.items()}
-                    for k in data_names:
-                        if k not in id_inputs:
-                            feed[k] = feed[k].astype(cdt)
+                    with jax.named_scope("train.cast"):
+                        p = {k: v.astype(cdt) for k, v in p.items()}
+                        for k in data_names:
+                            if k not in id_inputs:
+                                feed[k] = feed[k].astype(cdt)
                 outs, new_aux = eval_fn({**feed, **p}, aux, rng, True)
                 if cdt is not None:
                     # BN moving stats stay float32 master copies
-                    new_aux = {k: v.astype(aux[k].dtype)
-                               for k, v in new_aux.items()}
+                    with jax.named_scope("train.cast"):
+                        new_aux = {k: v.astype(aux[k].dtype)
+                                   for k, v in new_aux.items()}
                 return outs, new_aux
 
             fwd_fn = jax.checkpoint(fwd) if remat else fwd
@@ -1040,90 +1053,93 @@ class TrainStep:
                 # signal dynamic scaling reacts to) plus the loss
                 # outputs; fused into the step, it piggybacks on work
                 # XLA already scheduled — no extra host sync ever
-                finite = _guardrail.all_finite(
-                    list(grads.values()) + list(outs))
-                if scale is not None:
-                    inv = 1.0 / scale
-                    grads = {n: (g_ * inv).astype(g_.dtype)
-                             for n, g_ in grads.items()}
+                with jax.named_scope("train.guard"):
+                    finite = _guardrail.all_finite(
+                        list(grads.values()) + list(outs))
+                    if scale is not None:
+                        inv = 1.0 / scale
+                        grads = {n: (g_ * inv).astype(g_.dtype)
+                                 for n, g_ in grads.items()}
 
             if clip_norm is not None:
                 # bound the EFFECTIVE gradient's global norm (after the
                 # optimizer's rescale_grad, i.e. the per-example mean) —
                 # "clip at 1.0" then means what LM recipes mean by it
                 rescale = float(attrs.get("rescale_grad", 1.0))
-                gnorm = rescale * jnp.sqrt(sum(
-                    jnp.sum(jnp.square(g.astype(jnp.float32)))
-                    for g in grads.values()))
-                gscale = jnp.minimum(1.0, clip_norm /
-                                     jnp.maximum(gnorm, 1e-12))
-                grads = {n: (g * gscale).astype(g.dtype)
-                         for n, g in grads.items()}
+                with jax.named_scope("train.clip"):
+                    gnorm = rescale * jnp.sqrt(sum(
+                        jnp.sum(jnp.square(g.astype(jnp.float32)))
+                        for g in grads.values()))
+                    gscale = jnp.minimum(1.0, clip_norm /
+                                         jnp.maximum(gnorm, 1e-12))
+                    grads = {n: (g * gscale).astype(g.dtype)
+                             for n, g in grads.items()}
 
-            new_params, new_opt = {}, {}
-            for n in param_names:
-                p, g = params[n], grads[n]
-                if zero1:
-                    # reduce-scatter the grad onto the owned 1/N slice,
-                    # run the fused update there, all-gather the result
-                    # back to the parameter's own layout. XLA turns the
-                    # psum+constraint pair into a reduce_scatter and the
-                    # final constraint into an all_gather over the
-                    # replica axes (data × fsdp under a SpecLayout).
-                    zs = layout.opt_nsharding(n, p.shape, zero=True)
-                    p = shd.constrain(p, zs)
-                    g = shd.constrain(g, zs)
-                res = opt_fn(p, g, *opt_state[n], lr=lr, **attrs)
-                new_params[n] = res[0] if n_state else res
-                new_opt[n] = tuple(res[1:]) if n_state else ()
-            if guard is not None:
-                # mask the whole update out on device: a non-finite
-                # step leaves params, optimizer state AND BN statistics
-                # exactly as they were — the weights never ingest a NaN
-                new_params = {n: jnp.where(finite, new_params[n],
-                                           params[n])
-                              for n in param_names}
-                new_opt = {n: tuple(
-                    jnp.where(finite, s_new, s_old)
-                    for s_new, s_old in zip(new_opt[n], opt_state[n]))
-                    for n in param_names}
-                new_aux = {k: jnp.where(finite, v, aux[k])
-                           for k, v in new_aux.items()}
-                if scaler is not None:
-                    new_scale, new_good = scaler.next_state(
-                        gr_state[_guardrail.SCALE_KEY],
-                        gr_state[_guardrail.GOOD_KEY], finite)
-                    gr_state = {_guardrail.SCALE_KEY: new_scale,
-                                _guardrail.GOOD_KEY: new_good}
-            if zero1 or pin_state:
-                # pin the OUTGOING layouts explicitly, and pin them
-                # LAST — after the guardrail masking, so the pinned
-                # value IS the jit output (a constraint upstream of the
-                # jnp.where mask pins only the where's operand; the
-                # partitioner then re-chooses the output layout and the
-                # donated buffers miss the jit cache on the next step —
-                # the step-2-recompile class tools/perf_gate.py gates
-                # via the trainstep.jit_cache_size gauge). Fresh params
-                # all-gather back to the parameter layout; persistent
-                # optimizer state STAYS in its 1/N zero1 slice (a
-                # propagated replicated choice would also break the
-                # sharded-optimizer memory claim).
-                new_params = {n: shd.constrain(
-                    v, layout.param_nsharding(n, v.shape))
-                    for n, v in new_params.items()}
-                new_opt = {n: tuple(
-                    shd.constrain(s_, layout.opt_nsharding(
-                        n, s_.shape, zero=zero1))
-                    for s_ in ss) for n, ss in new_opt.items()}
-            if pin_state:
-                # aux (BN moving stats) must come back REPLICATED like
-                # init_state placed it — left to propagation, the
-                # boundary constraints shard it over fsdp and the
-                # drifted layout misses the jit cache (a full step-2
-                # recompile, measured ~2 s on the CPU mesh)
-                new_aux = {k: shd.constrain(
-                    v, layout.replicated_nsharding())
-                    for k, v in new_aux.items()}
+            with jax.named_scope("train.update"):
+                new_params, new_opt = {}, {}
+                for n in param_names:
+                    p, g = params[n], grads[n]
+                    if zero1:
+                        # reduce-scatter the grad onto the owned 1/N slice,
+                        # run the fused update there, all-gather the result
+                        # back to the parameter's own layout. XLA turns the
+                        # psum+constraint pair into a reduce_scatter and the
+                        # final constraint into an all_gather over the
+                        # replica axes (data × fsdp under a SpecLayout).
+                        zs = layout.opt_nsharding(n, p.shape, zero=True)
+                        p = shd.constrain(p, zs)
+                        g = shd.constrain(g, zs)
+                    res = opt_fn(p, g, *opt_state[n], lr=lr, **attrs)
+                    new_params[n] = res[0] if n_state else res
+                    new_opt[n] = tuple(res[1:]) if n_state else ()
+                if guard is not None:
+                    # mask the whole update out on device: a non-finite
+                    # step leaves params, optimizer state AND BN statistics
+                    # exactly as they were — the weights never ingest a NaN
+                    new_params = {n: jnp.where(finite, new_params[n],
+                                               params[n])
+                                  for n in param_names}
+                    new_opt = {n: tuple(
+                        jnp.where(finite, s_new, s_old)
+                        for s_new, s_old in zip(new_opt[n], opt_state[n]))
+                        for n in param_names}
+                    new_aux = {k: jnp.where(finite, v, aux[k])
+                               for k, v in new_aux.items()}
+                    if scaler is not None:
+                        new_scale, new_good = scaler.next_state(
+                            gr_state[_guardrail.SCALE_KEY],
+                            gr_state[_guardrail.GOOD_KEY], finite)
+                        gr_state = {_guardrail.SCALE_KEY: new_scale,
+                                    _guardrail.GOOD_KEY: new_good}
+                if zero1 or pin_state:
+                    # pin the OUTGOING layouts explicitly, and pin them
+                    # LAST — after the guardrail masking, so the pinned
+                    # value IS the jit output (a constraint upstream of the
+                    # jnp.where mask pins only the where's operand; the
+                    # partitioner then re-chooses the output layout and the
+                    # donated buffers miss the jit cache on the next step —
+                    # the step-2-recompile class tools/perf_gate.py gates
+                    # via the trainstep.jit_cache_size gauge). Fresh params
+                    # all-gather back to the parameter layout; persistent
+                    # optimizer state STAYS in its 1/N zero1 slice (a
+                    # propagated replicated choice would also break the
+                    # sharded-optimizer memory claim).
+                    new_params = {n: shd.constrain(
+                        v, layout.param_nsharding(n, v.shape))
+                        for n, v in new_params.items()}
+                    new_opt = {n: tuple(
+                        shd.constrain(s_, layout.opt_nsharding(
+                            n, s_.shape, zero=zero1))
+                        for s_ in ss) for n, ss in new_opt.items()}
+                if pin_state:
+                    # aux (BN moving stats) must come back REPLICATED like
+                    # init_state placed it — left to propagation, the
+                    # boundary constraints shard it over fsdp and the
+                    # drifted layout misses the jit cache (a full step-2
+                    # recompile, measured ~2 s on the CPU mesh)
+                    new_aux = {k: shd.constrain(
+                        v, layout.replicated_nsharding())
+                        for k, v in new_aux.items()}
             new_aux = {**new_aux, **gr_state}
             if guard is not None:
                 return (new_params, new_opt, new_aux), outs, finite

@@ -14,7 +14,12 @@ TPU-native mapping, two layers:
 - **Device traces**: when a trace dir is configured (``xplane_dir`` or
   MXNET_PROFILER_XPLANE), start/stop also drive ``jax.profiler`` which
   records XLA/TPU activity as TensorBoard xplane + trace.json.gz — the
-  ground-truth per-kernel timeline.
+  ground-truth per-kernel timeline. Every device operation on it names
+  the graph node and operator it was lowered from
+  (``<node>/op.<Operator>``; ``train.*`` in a train step), the
+  reference profiler's per-operator record
+  (docs/observability.md, "Reading the device's seconds by node and
+  operator").
 
 Env parity (docs/how_to/env_var.md:97-108): MXNET_PROFILER_AUTOSTART,
 MXNET_PROFILER_MODE (0 => symbolic-only, 1 => all ops).

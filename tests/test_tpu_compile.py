@@ -6,6 +6,8 @@ All such tests live in THIS file and describe the topology inside a
 fixture: the TPU's library can be held by one process at a time, so
 only the worker that is given this file may load it, and only once a
 test of it has started."""
+import contextlib
+
 import numpy as np
 import pytest
 
@@ -30,17 +32,25 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture
-def no_compile_cache():
+@contextlib.contextmanager
+def _compile_cache_off():
     """A compile for a described chip is written to the persistent
     cache but cannot be read back without the chip; keep it out."""
     from jax.experimental.compilation_cache import compilation_cache
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def no_compile_cache():
+    with _compile_cache_off():
+        yield
 
 
 def _entry_results(compiled):
@@ -276,3 +286,69 @@ def test_block_step_with_the_rows_state_compiles_for_v5e(
     # nothing pool-sized beside the pool
     assert step.memory_analysis().temp_size_in_bytes < \
         nbytes(held[0]) // 2
+
+
+@pytest.fixture(scope="module")
+def named_train_step(one_chip):
+    """[(name stack, whether it holds a convolution)] for every fusion
+    of a toy ResNet's `step_with_metric` compiled for a v5e."""
+    import re
+    import mxnet_tpu as mx
+    from mxnet_tpu import models
+    from mxnet_tpu.initializer import Uniform
+    from mxnet_tpu.parallel import make_train_step
+    shapes = {"data": (8, 3, 32, 32), "softmax_label": (8,)}
+    step = make_train_step(
+        models.get_symbol(network="resnet", num_layers=18,
+                          image_shape=(3, 32, 32), num_classes=10),
+        optimizer="sgd", optimizer_params={"momentum": 0.9},
+        compute_dtype="bfloat16")
+    state = step.init_state(Uniform(0.01), shapes)
+    metric = mx.metric.CrossEntropy()
+    raw, fused = step._metric_fused_step(metric, None)
+    placed = {k: jnp.zeros(s, jnp.float32) for k, s in shapes.items()}
+    rng = jax.random.PRNGKey(0)
+    mstats = step._zero_metric_stats(raw, metric, state, placed, 0.1, rng)
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=one_chip),
+        (*state, placed, jnp.float32(0.1), rng, mstats))
+    with _compile_cache_off():
+        text = fused.lower(*args).compile().as_text()
+    bodies = {m.group(1): m.group(2) for m in re.finditer(
+        r"\n%?([\w.\-]+) \([^\n]*\{\n(.*?)\n\}", text, re.S)}
+    out = []
+    for line in text[text.index("\nENTRY"):].splitlines():
+        if " fusion(" not in line:
+            continue
+        name = re.search(r'op_name="([^"]*)"', line)
+        body = bodies.get(re.search(r"calls=%?([\w.\-]+)",
+                                    line).group(1), "")
+        out.append((name.group(1) if name else "",
+                    " convolution(" in body))
+    return out
+
+
+@pytest.mark.parametrize("wrapper", ["jvp(train.fwd)",
+                                     "transpose(jvp(train.fwd))"])
+def test_the_tpu_compiler_keeps_the_names_on_its_fusions(
+        named_train_step, wrapper):
+    """What the device trace will show (`tf_op` is this metadata): each
+    fusion of the compiled step carries one name stack, every one of
+    them with an `op.` or `train.` part, and a fusion that holds a
+    convolution is named after a Convolution or FullyConnected node,
+    forward and backward, not after the batch norm, ReLU or sum fused
+    into it."""
+    fusions = named_train_step
+    assert len(fusions) > 100
+    kinds = lambda s: [p for p in s.split("/")
+                       if p.startswith(("op.", "train."))]
+    assert all(kinds(name) for name, _conv in fusions)
+    convs = [name for name, conv in fusions
+             if conv and ("/%s/" % wrapper) in name]
+    assert len(convs) >= 20                   # 21 convolutions a pass
+    for name in convs:
+        assert kinds(name)[0] in ("op.Convolution",
+                                  "op.FullyConnected"), name
+    assert any("train.update" in name.split("/")
+               for name, _conv in fusions)
