@@ -654,21 +654,29 @@ class Generator:
         return {name: place(name)
                 for name in self._sym.list_auxiliary_states()}
 
-    def _fresh_aux(self):
+    def _fresh_aux(self, rows=None):
         """A zeroed decode-state pytree: ONE compiled program for the
         whole pytree (a dispatch per call, not an eager zeros +
-        device_put per cache array — 2 x num_layers of them)."""
-        fn = self._loop_cache.get("fresh_aux")
+        device_put per cache array — 2 x num_layers of them).
+        ``rows`` batch rows in place of ``batch_size``: the state of a
+        prefill that runs fewer rows than the pool is wide (a program
+        a row count; under a mesh the ``data`` axis must divide it)."""
+        rows = self.batch_size if rows is None else int(rows)
+        key = "fresh_aux" if rows == self.batch_size else \
+            ("fresh_aux", rows)
+        fn = self._loop_cache.get(key)
         if fn is None:
-            specs = {name: self._aux_spec(name)
-                     for name in self._sym.list_auxiliary_states()}
+            specs = {}
+            for name in self._sym.list_auxiliary_states():
+                shape, dtype = self._aux_spec(name)
+                specs[name] = ((rows,) + shape[1:], dtype)
 
             def fresh_aux():
                 return {name: jnp.zeros(shape, dtype)
                         for name, (shape, dtype) in specs.items()}
 
             fn = jax.jit(fresh_aux, out_shardings=self._aux_shardings())
-            self._loop_cache["fresh_aux"] = fn
+            self._loop_cache[key] = fn
         return fn()
 
     def _forward(self, aux, tokens, pos):
@@ -1447,8 +1455,12 @@ class Generator:
         return ContinuousDecoder(self, **kwargs)
 
     def _prefill(self, aux, tokens):
-        """The caches after ``tokens`` (B, P0) from position 0, and
-        nothing else: a diffusion prefill reads no logits."""
+        """The caches after ``tokens`` (R, P0) from position 0, and
+        nothing else: a diffusion prefill reads no logits. ``aux`` is
+        an R-row state (``_fresh_aux(R)``): ``batch_size`` rows for
+        generate(), fewer where a pool admits fewer
+        (serve/decode.py's ``_admit_blocks``), each R a shape of the
+        one program."""
         args = dict(self._params)
         args["data"] = jnp.asarray(tokens, jnp.float32)
         args["positions"] = jnp.arange(tokens.shape[1],

@@ -108,8 +108,10 @@ depth, where the next forward overwrites them. So no forward writes
 past the end of a row's open block, and a row of exactly ``max_len``
 positions is served. What a new row brings as its clean block is its
 prompt's last whole block (admission prefills the whole blocks before
-that one and picks nothing, then writes the row's state on the device
-with one compiled ``block_admit``, queued behind the step in flight);
+that one, at the rows the group needs and not the pool's width
+(``_row_rungs``), and picks nothing, then writes the row's state on
+the device with one compiled ``block_admit``, queued behind the step
+in flight);
 only a prompt shorter than a block has none, and its first block rides
 in the first L positions with L ignored ones after it. The final norm
 and the head read the open block's L positions alone, wherever a row
@@ -140,6 +142,7 @@ import logging
 import signal
 import threading
 from collections import OrderedDict, deque
+from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
@@ -401,6 +404,36 @@ def _merge_program(generator):
                    out_shardings=generator._aux_shardings())
 
 
+# What the rungs below were chosen from (my chip runs, PR 37; TPU v5
+# lite; the SDAR cell: 16 slots, six layers of 128 experts of 768, top
+# 8, bf16). One `block_prefill` on the device, ms, at 56 / 120 / 248 /
+# 504 positions a row: 16 rows 9.98 / 18.70 / 46.18 / 87.92, 2 rows
+# 4.24 / 5.79 / 7.99 / 12.19. Of a window's 350 groups 97% are one
+# row, 3% two, one in 300 three. Tokens/s, one seed, by ladder: (2,
+# 16) 2 683.7; (1, 16) 2 692.2; (1, 2, 16) 2 692.3; (1, 4, 16)
+# 2 685.1, where 16 alone reads 2 148-2 184 over three seeds: beside
+# an eighth of the pool a rung of one row or of a quarter buys nothing
+# the runs' spread (0.6%) would show, and each costs a compile of 4-6 s
+# a prompt length on an empty cache.
+def _row_rungs(generator):
+    """The row counts a pool may prefill a length group at, ascending:
+    a group runs at the smallest that holds it
+    (:meth:`ContinuousDecoder._group_rows`), and the pool's width ``B``
+    is the top rung, so a group of any size is ONE prefill. A rule from
+    ``B``, not a knob: an eighth of the pool and the pool (a pool under
+    eight rows has the one rung), and where the caches are split over a
+    mesh's ``data`` axis only the rungs that axis divides. A rung below
+    ``B`` is one more compiled shape of ``block_prefill`` a prompt
+    length (and one of ``fresh_aux`` and of ``cache_merge``), so it
+    stays only where the chip showed that it pays."""
+    B = int(generator.batch_size)
+    split = 1
+    shard = generator._cache_sharding
+    if shard is not None and shard.spec[0] is not None:
+        split = generator.mesh.shape[shard.spec[0]]
+    return sorted({r for r in (B // 8, B) if r and r % split == 0})
+
+
 def _step_program(step, generator):
     """The compiled per-row step of ``generator``'s pool: ``step(args,
     aux, rng) -> (outs, aux)`` with the pool DONATED, so each row's new
@@ -638,6 +671,8 @@ class ContinuousDecoder:
         self._import_jit = {}                  # pos -> fused scatter
         self._merge_fn = _merge_program(generator)
         self._dmerge_fn = None                 # the draft pool's twin
+        self._rungs = _row_rungs(generator)    # rows a prefill may run
+        self._built_lengths = set()            # P0 whose rungs are built
 
         # -- speculative decoding (docs/serving.md §speculative) --
         # draft=None consults MXNET_SPEC_DRAFT so subprocess replicas
@@ -735,7 +770,7 @@ class ContinuousDecoder:
         self._steps = 0
         self._prefills = 0
         self._admit_rounds = 0     # _admit calls that admitted
-        self._prefill_rows = 0     # rows of every prefill forward
+        self._prefill_rows = 0     # rows every prefill forward RAN
         self._merges = 0           # compiled cache-merge dispatches
         self._step_failures = 0    # steps that raised (_step_failed)
         # diffusion pools, in rows x forwards (a step runs one forward
@@ -1474,6 +1509,15 @@ class ContinuousDecoder:
         fn = self._dmerge_fn if draft else self._merge_fn
         return fn(pool, src, padded, np.int32(len(slots)))
 
+    def _group_rows(self, prompts):
+        """A length group as its prefill runs it: the equal-length
+        ``prompts``, then copies of the first up to the smallest rung
+        that holds them (:func:`_row_rungs`). The copies' rows are
+        never merged. ``len()`` of the result is the rows run."""
+        run = next(r for r in self._rungs if r >= len(prompts))
+        return np.stack(list(prompts) +
+                        [prompts[0]] * (run - len(prompts)))
+
     def _draft_prefill_rows(self, slot, tokens):
         """Prefill the DRAFT cache for one admitted row from raw token
         ids — the local draft leg of handoff/resume admission (the
@@ -1683,24 +1727,27 @@ class ContinuousDecoder:
     def _admit_blocks(self, P0, reqs, free):
         """A diffusion round's group: prompts whose whole blocks but
         the last are the same ``P0`` positions. One shared-position
-        prefill of those under the block mask, with no logits read
-        (the first tokens come from the first block's denoising
+        prefill of those under the block mask, at the rows of the
+        smallest rung that holds the group (:meth:`_group_rows`: the
+        pool's width only where the group needs it), with no logits
+        read (the first tokens come from the first block's denoising
         forward, which also stores the prompt's last whole block), the
         merge, and each row's block state written on the device by
         one compiled program (``block_admit``) queued behind the step
         in flight: the row joins the step after. A prompt shorter than
         two blocks prefills nothing."""
         if P0:
-            rows = np.stack([r.prompt[:P0] for r in reqs] +
-                            [reqs[0].prompt[:P0]] *
-                            (self._B - len(reqs)))
+            if P0 not in self._built_lengths:
+                self._build_rungs(P0)
+            rows = self._group_rows([r.prompt[:P0] for r in reqs])
             with _trace.phase("admit.fresh_aux"):
-                fresh = self._gen._fresh_aux()
-            with _trace.phase("admit.prefill", P=P0, rows=len(reqs)):
+                fresh = self._gen._fresh_aux(len(rows))
+            with _trace.phase("admit.prefill", P=P0, rows=len(reqs),
+                              run=len(rows)):
                 pref_aux = self._gen._prefill(fresh, rows)
             del fresh
             self._prefills += 1
-            self._prefill_rows += self._B
+            self._prefill_rows += len(rows)
             with _trace.phase("admit.merge", rows=len(reqs)):
                 self._aux = self._merge_rows(self._aux, pref_aux,
                                              free[:len(reqs)])
@@ -1733,6 +1780,30 @@ class ContinuousDecoder:
                     else req.eos_id
                 rows["live"][slot] = sel[slot] = True
             self._bstate = self._block_admit_fn(self._bstate, rows, sel)
+
+    def _build_rungs(self, P0):
+        """The first sight of a prefill length: every rung's programs
+        for it are built here and now (the state, the prefill and the
+        merge, each run once on a state that is thrown away; the merge
+        installs no row), so a group size first met later at this
+        length compiles nothing in the serving path. The rungs'
+        prefills compile side by side, a thread each (the compiler
+        holds no interpreter lock). On an empty compile cache a
+        length's first sight took 12.6 / 10.4 / 13.7 / 17.9 s at 56 /
+        120 / 248 / 504 positions in the SDAR cell, where the pool's
+        width alone took 8.6 / 7.0 / 10.6 / 17.0 and the 2-row program
+        alone compiles for 4.9-6.2 (my chip runs, PR 37)."""
+        def prefilled(run):
+            return self._gen._prefill(self._gen._fresh_aux(run),
+                                      np.zeros((run, P0), np.float32))
+
+        nothing = np.zeros((self._B,), np.int32)
+        with _trace.phase("admit.build", P=P0, rungs=self._rungs), \
+                ThreadPoolExecutor(len(self._rungs)) as pool:
+            for pref_aux in pool.map(prefilled, self._rungs):
+                self._aux = self._merge_fn(self._aux, pref_aux, nothing,
+                                           np.int32(0))
+        self._built_lengths.add(P0)
 
     def _emit(self, req, tok):
         """One token emission: latency metrics (TTFT on the first

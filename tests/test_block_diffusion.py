@@ -6,6 +6,7 @@ the benchmark's plain float32 reference at toy widths with seeded
 weights."""
 import json
 import os
+import threading
 
 import numpy as np
 import pytest
@@ -309,25 +310,51 @@ def test_sequential_rows_replay_from_their_tokens_alone(params):
 CASES = [(r, n) for r in range(L) for n in (2, 5)]
 
 
-@pytest.fixture(scope="module")
-def served(params):
+@pytest.fixture(scope="module", params=[2, 8],
+                ids=lambda slots: "pool%d" % slots)
+def served(request, params):
     """Eight requests, every remainder of the prompt against a block
-    with max_new 2 and 5, through a pool of two slots: six of them are
-    admitted while others are mid-block. Beside each, the one-shot
-    row of a batch-1 generator."""
+    with max_new 2 and 5, all of one prefill length. Through a pool of
+    two slots: six of them are admitted while others are mid-block.
+    Through a pool of eight (rungs of 1 and 8 rows): one, then two
+    from inside the first one's first emission, then five from inside
+    the first emission of those, so a group runs on either rung, the
+    later ones beside rows that are mid-block. Beside each, the one-shot row of a
+    batch-1 generator."""
+    slots = request.param
     one = _gen(params, 1, 2)
-    dec = _gen(params, 2, 2).serving_decoder()
+    dec = _gen(params, slots, 2).serving_decoder()
     prompts = _prompts([8 + r for r, _ in CASES], seed=5)
     streams = [[] for _ in CASES]
+    futs, emit, first_in = [], dec._emit, threading.Event()
+
+    def submit(*cases):
+        for i in cases:
+            futs.append(dec.submit(prompts[i], CASES[i][1]))
+            futs[-1].subscribe(streams[i].append)
+
+    def spy(req, tok):                 # on the decode thread: what it
+        first_in.wait(30)              # submits lands in ONE round
+        if len(futs) == 1:
+            submit(1, 2)
+        elif len(futs) == 3 and req is futs[1]:
+            submit(3, 4, 5, 6, 7)
+        emit(req, tok)
+
     try:
-        futs = [dec.submit(p, n) for p, (_, n) in zip(prompts, CASES)]
-        for f, s in zip(futs, streams):
-            f.subscribe(s.append)
+        if slots == 2:
+            submit(*range(len(CASES)))
+        else:
+            dec._emit = spy
+            submit(0)
+            first_in.set()
+            futs[0].result(timeout=120)
         rows = [np.asarray(f.result(timeout=120)) for f in futs]
     finally:
         dec.close(30)
-    stats = dict(dec.stats(), programs=(dec._step_fn._cache_size(),
-                                        dec._block_admit_fn._cache_size()))
+    stats = dict(dec.stats(), slots=slots,
+                 programs=(dec._step_fn._cache_size(),
+                           dec._block_admit_fn._cache_size()))
     want = [one.generate(p[None], n)[0]
             for p, (_, n) in zip(prompts, CASES)]
     return rows, want, streams, stats
@@ -361,7 +388,8 @@ def test_decoder_counts_forwards_blocks_and_expert_pairs(served):
     assert st["tokens_unmasked"] >= sum(n for _, n in CASES)
     # a step runs 2L positions a row: the clean block and the open one
     layers, experts, k = 2, 8, 2
-    assert st["moe_assignments"] == st["steps"] * layers * 2 * 2 * L * k
+    assert st["moe_assignments"] == \
+        st["steps"] * layers * st["slots"] * 2 * L * k
     assert 0 < st["moe_experts_hit"] <= st["steps"] * layers * experts
     assert 1.0 <= st["moe_max_load"] <= experts
     # the pool was never idle between the first admission and the last
@@ -372,6 +400,12 @@ def test_decoder_counts_forwards_blocks_and_expert_pairs(served):
     assert st["steps_ahead"] == st["steps"] - 1
     assert st["idle_forwards"] == 0
     assert st["programs"] == (1, 1)
+    # every group is one prefill at the rows of its rung: two a group
+    # in the pool of two, 1 + 8 + 8 in the pool of eight
+    if st["slots"] == 8:
+        assert (st["prefills"], st["prefill_rows"]) == (3, 17)
+    else:
+        assert st["prefill_rows"] == 2 * st["prefills"]
 
 
 def _order_of(dec):
@@ -643,19 +677,23 @@ def test_a_prompt_shorter_than_a_block(params, p, n):
     assert st["blocks_committed"] == -(-(p + n) // L) - 1
 
 
-def test_a_row_admitted_while_another_is_mid_block(params):
-    """The second request is submitted from inside the first one's
+@pytest.mark.parametrize("slots,late,rows_run", [(2, 1, 2 + 2),
+                                                 (16, 2, 2 + 2)])
+def test_a_row_admitted_while_another_is_mid_block(params, slots, late,
+                                                   rows_run):
+    """The second request (in a pool of 16: a group of two, one
+    prefill of two rows) is submitted from inside the first one's
     first emission, with the first row's second step already on the
     device: its block state is written behind that step, and it rides
     its first (fused) forward beside the other's third."""
-    prompts = _prompts([9, 10], seed=8)
-    dec = _gen(params, 2, 2).serving_decoder()
+    prompts = _prompts([9, 10, 11], seed=8)[:1 + late]
+    dec = _gen(params, slots, 2).serving_decoder()
     emit, admit = dec._emit, dec._admit_blocks
     futs, found = [], []
 
     def spy(req, tok):
         if not futs:
-            futs.append(dec.submit(prompts[1], 6))
+            futs.extend(dec.submit(p, 6) for p in prompts[1:])
         emit(req, tok)
 
     def admitting(P0, reqs, free):
@@ -668,7 +706,8 @@ def test_a_row_admitted_while_another_is_mid_block(params):
     dec._emit, dec._admit_blocks = spy, admitting
     try:
         first = dec.submit(prompts[0], 6)
-        rows = [first.result(timeout=60), futs[0].result(timeout=60)]
+        rows = [first.result(timeout=60)] + \
+            [f.result(timeout=60) for f in futs]
     finally:
         dec.close(30)
     # the first row on the device: its first block's three masks gone
@@ -676,6 +715,8 @@ def test_a_row_admitted_while_another_is_mid_block(params):
     # left yet to be stored; the host has read the first step only, in
     # which the prompt's last block was stored
     assert found == [(L, 12, True, 8)]
+    st = dec.stats()
+    assert (st["prefills"], st["prefill_rows"]) == (2, rows_run)
     one = _gen(params, 1, 2)
     for p, row in zip(prompts, rows):
         np.testing.assert_array_equal(row, one.generate(p[None], 6)[0])
@@ -781,6 +822,204 @@ def test_logits_hook_reads_what_tokens_were_picked_from(params):
     for got, exp, p, row in zip(logits, want, prompts, rows):
         np.testing.assert_allclose(got, exp, rtol=2e-4, atol=2e-4)
         np.testing.assert_array_equal(got.argmax(-1), row[len(p):])
+
+
+# -- the rows a prefill runs: a short ladder of row counts -------------------
+
+SLOTS = 16                                     # the cell's pool: 2, 16
+
+
+def _in_one_round(dec):
+    """Hold `dec`'s admission while the returned event is clear, so
+    that what is submitted meanwhile is admitted in ONE round."""
+    admit, gate = dec._admit, threading.Event()
+
+    def gated():
+        gate.wait(30)
+        admit()
+
+    dec._admit = gated
+    gate.set()
+    return gate
+
+
+@pytest.fixture(scope="module")
+def groups(params):
+    """k = 1 ... 16 prompts of one length, each k submitted in one
+    breath to an idle pool of 16 slots (the harness's `_warm_groups`),
+    then a second length in groups of 1, 3 and 5. For each group what
+    the pool's counters rose by and how many prefill and merge
+    programs existed after it; for some, the rows served."""
+    gen = _gen(params, SLOTS, 2)
+    dec = gen.serving_decoder()
+    gate = _in_one_round(dec)
+    seen = {}
+    try:
+        for length, k in [(9, k) for k in range(1, SLOTS + 1)] + \
+                [(14, 1), (14, 3), (14, 5)]:
+            prompts = _prompts([length] * k, seed=length + k)
+            before = dec.stats()
+            gate.clear()
+            futs = [dec.submit(p, 2) for p in prompts]
+            gate.set()
+            rows = [np.asarray(f.result(timeout=120)) for f in futs]
+            after = dec.stats()
+            seen[length, k] = dict(
+                {key: after[key] - before[key] for key in
+                 ("prefills", "prefill_rows", "admit_rounds", "merges")},
+                prefill_programs=gen._prefill_fn._cache_size(),
+                merge_programs=after["merge_programs"],
+                prompts=prompts, rows=rows)
+    finally:
+        dec.close(30)
+    return seen
+
+
+@pytest.mark.parametrize("k", range(1, SLOTS + 1))
+def test_a_group_is_one_prefill_at_the_rung_that_holds_it(groups, k):
+    """What the harness's warm-up counts on: k prompts of one length
+    make ONE prefill whatever k is; it runs 2 or 16 rows."""
+    got = groups[9, k]
+    assert (got["admit_rounds"], got["prefills"], got["merges"]) == \
+        (1, 1, 1)
+    assert got["prefill_rows"] == (2 if k <= 2 else 16)
+
+
+@pytest.mark.parametrize("length,k", [(9, 1), (9, 2), (9, 3), (9, 16),
+                                      (14, 1), (14, 3)])
+def test_rows_through_every_rung_equal_the_one_shot_rows(
+        params, groups, length, k):
+    one = _gen(params, 1, 2)
+    got = groups[length, k]
+    for p, row in zip(got["prompts"], got["rows"]):
+        np.testing.assert_array_equal(row, one.generate(p[None], 2)[0])
+
+
+def test_a_lengths_first_admission_builds_every_rung(groups):
+    """Two prefill programs and two merge programs after the very
+    first admission (one row of one length), and no more however the
+    groups of that length grow; two more prefill programs at the
+    second length's first sight, and the merge programs as they were:
+    they do not follow the length."""
+    assert [groups[9, k]["prefill_programs"]
+            for k in range(1, SLOTS + 1)] == [2] * SLOTS
+    assert [groups[14, k]["prefill_programs"] for k in (1, 3, 5)] == \
+        [4, 4, 4]
+    assert {g["merge_programs"] for g in groups.values()} == {2}
+
+
+def test_a_group_size_first_met_later_compiles_nothing(params):
+    """After a length's first admission (one row), groups of two, of
+    three and of five at that length are served without one backend
+    compile: nothing of the serving path is built inside it."""
+    import jax.monitoring
+    dec = _gen(params, SLOTS, 2).serving_decoder()
+    gate = _in_one_round(dec)
+    compiles = []
+
+    def on_event(name, _secs, **_kw):
+        if name == "/jax/core/compile/backend_compile_duration":
+            compiles.append(name)
+
+    try:
+        dec.submit(_prompts([9])[0], 6).result(timeout=120)
+        jax.monitoring.register_event_duration_secs_listener(on_event)
+        for k in (2, 3, 5):
+            gate.clear()
+            futs = [dec.submit(p, 6)
+                    for p in _prompts([8 + k % 4] * k, seed=k)]
+            gate.set()
+            for f in futs:
+                f.result(timeout=120)
+        st = dec.stats()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+        dec.close(30)
+    assert compiles == []
+    assert (st["prefills"], st["prefill_rows"]) == (4, 2 + 2 + 16 + 16)
+
+
+@pytest.mark.parametrize("slots,data,rungs", [
+    (16, 1, [2, 16]), (8, 1, [1, 8]), (4, 1, [4]), (2, 1, [2]),
+    (1, 1, [1]), (32, 1, [4, 32]), (16, 2, [2, 16]), (8, 2, [8]),
+    (32, 4, [4, 32]), (16, 4, [16])])
+def test_the_ladder_is_a_rule_from_the_pools_width(slots, data, rungs):
+    """An eighth of the pool and the pool, one rung under eight rows;
+    where the caches are split over a mesh's `data` axis, only the
+    rungs that axis divides."""
+    from types import SimpleNamespace
+    from jax.sharding import PartitionSpec
+    from mxnet_tpu.serve.decode import _row_rungs
+    gen = SimpleNamespace(batch_size=slots, _cache_sharding=None)
+    if data > 1:
+        gen._cache_sharding = SimpleNamespace(
+            spec=PartitionSpec("data", None, None))
+        gen.mesh = SimpleNamespace(shape={"data": data})
+    assert _row_rungs(gen) == rungs
+
+
+def test_a_pool_over_a_data_mesh_runs_the_rungs_it_can_split(params):
+    """Sixteen slots over a `data` axis of two: a group of one and a
+    group of two each run two rows, one on each device, and the rows
+    are the one-shot rows."""
+    from jax.sharding import Mesh
+    mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
+    prompts = _prompts([9, 10, 11], seed=21)
+    dec = _gen(params, SLOTS, 2, mesh=mesh).serving_decoder()
+    gate = _in_one_round(dec)
+    try:
+        assert dec._rungs == [2, SLOTS]
+        rows = [dec.submit(prompts[0], 6).result(timeout=120)]
+        gate.clear()
+        futs = [dec.submit(p, 6) for p in prompts[1:]]
+        gate.set()
+        rows += [f.result(timeout=120) for f in futs]
+        st = dec.stats()
+    finally:
+        dec.close(30)
+    assert (st["prefills"], st["prefill_rows"]) == (2, 2 + 2)
+    one = _gen(params, 1, 2)
+    for p, row in zip(prompts, rows):
+        np.testing.assert_array_equal(row, one.generate(p[None], 6)[0])
+
+
+def test_the_prefill_span_says_the_rows_it_ran(params, tmp_path):
+    """`admit.prefill` carries `P`, `rows` (the real ones) and `run`
+    (the rung); `admit.build` is there once a length, with the rungs
+    it built."""
+    import sys
+    from mxnet_tpu import config, trace
+    sys.path.insert(0, ROOT)
+    from tools import trace_report
+    trace.stop_tracing()
+    config.set_override("MXNET_TRACE", str(tmp_path / "spill"))
+    try:
+        dec = _gen(params, SLOTS, 2).serving_decoder()
+        gate = _in_one_round(dec)
+        try:
+            dec.submit(_prompts([9])[0], 2).result(timeout=120)
+            gate.clear()
+            futs = [dec.submit(p, 2) for p in _prompts([10] * 3, seed=1)]
+            gate.set()
+            for f in futs:
+                f.result(timeout=120)
+        finally:
+            dec.close(30)
+    finally:
+        path = trace.stop_tracing()
+        config.clear_override("MXNET_TRACE")
+    spans = [r for r in trace_report.load(path)
+             if r.get("kind") == "span"]
+    named = lambda name: [r["attrs"] for r in spans
+                          if r["name"] == name]
+    assert named("admit.prefill") == [
+        {"P": 4, "rows": 1, "run": 2}, {"P": 4, "rows": 3, "run": 16}]
+    assert named("admit.build") == [{"P": 4, "rungs": [2, 16]}]
+    assert [a["rows"] for a in named("admit.merge")] == [1, 3]
+    ids = {r["span"]: r["name"] for r in spans}
+    assert {ids[r["parent"]] for r in spans if r["name"] in (
+        "admit.prefill", "admit.build", "admit.merge")} == \
+        {"serve.decode.admit"}
 
 
 # -- what refuses a diffusion generator --------------------------------------
