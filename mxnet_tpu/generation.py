@@ -165,14 +165,18 @@ class Generator:
         expert layers (top-k, nothing dropped), a head size apart from
         dim / num_heads, per-head RMS norm of q and k, the rotary base.
     layer_kinds : optional per-layer sequence spelling the stack one
-        sublayer a layer ("attention" | "ssm" | "mamba2" | "experts" |
-        "mlp"; get_decode_symbol); ``num_layers`` is then its length.
-        A layer of the last two kinds owns no decode state: a slot's
-        bytes count the layers that hold some.
-    expert_scoring, routed_scaling_factor, expert_latent,
-    shared_expert_hidden, experts_held :
+        sublayer a layer ("attention" | "ssm" | "mamba2" | "shortconv"
+        | "experts" | "mlp"; get_decode_symbol); ``num_layers`` is then
+        its length. A layer of the last two kinds owns no decode state:
+        a slot's bytes count the layers that hold some. A "shortconv"
+        layer (ops/shortconv.py, ``shortconv_kernel`` taps) holds ONE
+        blob per slot, a (shortconv_kernel - 1, dim) window of gated
+        rows in the cache dtype, and nothing with a length axis.
+    expert_scoring, norm_topk_eps, routed_scaling_factor,
+    expert_latent, shared_expert_hidden, experts_held :
         The expert layers' routing ("softmax" | "sigmoid" with a
-        choosing bias, kept in float32), the weights' scale, latent
+        choosing bias, kept in float32, and what its renormalisation
+        adds to the sum it divides by), the weights' scale, latent
         experts between one down- and one up-projection, a shared
         expert, and the chip's share ``(first, count)`` of the
         ``num_experts`` routed over — as get_decode_symbol documents
@@ -211,7 +215,8 @@ class Generator:
                  rope_base=None, diffusion=None, layer_kinds=None,
                  expert_scoring="softmax", routed_scaling_factor=1.0,
                  expert_latent=0, shared_expert_hidden=0,
-                 experts_held=None):
+                 experts_held=None, shortconv_kernel=3,
+                 norm_topk_eps=None):
         from .parallel import sharding as shd
 
         if quantize not in (None, "int8"):
@@ -256,10 +261,12 @@ class Generator:
                                                           num_layers)
         mamba2 = transformer._canon_mamba2(mamba2, self._btypes)
         # "ssm" here means RECURRENT: any layer whose state has no
-        # per-position entries (gated linear attention or Mamba-2) —
-        # what speculation cannot roll back and a padded prefill would
-        # absorb, so every refusal and split keyed on it covers both
-        self._has_ssm = bool({"ssm", "mamba2"} & set(self._btypes))
+        # per-position entries (gated linear attention, Mamba-2 or a
+        # gated short convolution's window) — what speculation cannot
+        # roll back and a padded prefill would absorb, so every
+        # refusal and split keyed on it covers them all
+        self._has_ssm = bool(set(transformer._RECURRENT) &
+                             set(self._btypes))
         # kept for twin-symbol builders (serve/decode.py rebuilds this
         # graph with per_row_pos=True against the SAME parameters)
         self._decode_opts = dict(
@@ -287,7 +294,9 @@ class Generator:
             routed_scaling_factor=routed_scaling_factor,
             expert_latent=expert_latent,
             shared_expert_hidden=shared_expert_hidden,
-            experts_held=experts_held)
+            experts_held=experts_held,
+            shortconv_kernel=shortconv_kernel,
+            norm_topk_eps=norm_topk_eps)
         sym = transformer.get_decode_symbol(**self._decode_opts)
         if quantize:
             arg_params = _quantize_weights(
@@ -391,6 +400,11 @@ class Generator:
             self._conv_shape = (self.batch_size, mamba2["d_conv"] - 1,
                                 H * P + 2 * mamba2["n_groups"] * N)
             self._scan_shape = (self.batch_size, H, P, N)
+        # gated short convolutions: a window of the last gated rows in
+        # the cache dtype, and nothing else (ops/shortconv.py)
+        self._short_shape = (self.batch_size, int(shortconv_kernel) - 1,
+                             int(dim)) \
+            if "shortconv" in self._btypes else None
         # quantize_kv: k/v live int8 with per-token f32 scale caches —
         # halves decode's dominant HBM stream (the cache is re-read
         # every step; each weight only once)
@@ -411,8 +425,11 @@ class Generator:
         gauge/slot math can never drift from what is actually
         allocated."""
         if name.endswith("_conv_state"):
-            # Mamba-2 convolution window: fixed size, served dtype
-            return self._conv_shape, jnp.dtype(self._cache_dtype)
+            # a convolution window (Mamba-2's over x|B|C, or a gated
+            # short convolution's over dim): fixed size, served dtype
+            return (self._short_shape
+                    if name.endswith("_shortconv_conv_state")
+                    else self._conv_shape), jnp.dtype(self._cache_dtype)
         if name.endswith("_scan_state"):
             # Mamba-2 scan state: fixed size, always f32
             return self._scan_shape, jnp.dtype(jnp.float32)
@@ -471,9 +488,10 @@ class Generator:
     @staticmethod
     def _aux_kind(name):
         """Which kind of decode state an aux name is, for the sizing
-        reports: "scan_state" / "conv_window" (Mamba-2), "ssm_state"
-        (gated linear attention) or "kv_rows" (k/v rows and their int8
-        scales: everything with a length axis)."""
+        reports: "scan_state" (Mamba-2), "conv_window" (Mamba-2's or
+        a gated short convolution's), "ssm_state" (gated linear
+        attention) or "kv_rows" (k/v rows and their int8 scales:
+        everything with a length axis)."""
         if name.endswith("_scan_state"):
             return "scan_state"
         if name.endswith("_conv_state"):
@@ -643,9 +661,10 @@ class Generator:
         def place(name):
             shape = self._aux_spec(name)[0]
             if name.endswith(("_conv_state", "_scan_state")):
-                # Mamba-2 states: batch over 'data' only (the window
-                # axis is d_conv-1 long, x|B|C share the last one, and
-                # the mixer's heads need not divide the 'model' axis)
+                # Mamba-2 states and a short convolution's window:
+                # batch over 'data' only (the window axis is d_conv-1
+                # long, x|B|C share the last one, and the mixer's
+                # heads need not divide the 'model' axis)
                 return NamedSharding(self.mesh, PartitionSpec(
                     self._cache_sharding.spec[0],
                     *([None] * (len(shape) - 1))))

@@ -190,7 +190,8 @@ def _moe_block(x, dim, hidden, num_experts, prefix, expert_axis=None,
 
 def _routed_block(x, dim, hidden, num_experts, prefix, top_k=1,
                   kind="relu", renormalize=False, scoring="softmax",
-                  scale=1.0, held=None, latent=0, shared_hidden=0):
+                  scale=1.0, held=None, latent=0, shared_hidden=0,
+                  renorm_eps=None):
     """The expert layer as it is served (_contrib_RoutedExperts): the
     top_k experts by float32 score, every routed (token, expert) pair
     computed and nothing dropped. Binds the parameter names of
@@ -198,7 +199,10 @@ def _routed_block(x, dim, hidden, num_experts, prefix, top_k=1,
     through it; kind "gated_silu" makes experts_w1 twice as wide,
     [gate | up], by the rule of _ffn_block's fc1. scoring "sigmoid"
     adds "<prefix>gate_score_bias" (E,), which chooses and does not
-    weigh; scale multiplies the weights. held=(first, count): the
+    weigh, and renorm_eps is what its renormalisation adds to the
+    sum it divides by, where the model states one (route_topk's
+    default otherwise); scale multiplies the weights. held=(first,
+    count): the
     experts this chip holds of the num_experts routed over (the
     expert arrays have `count` rows). latent=Z: the experts live in Z
     channels between "<prefix>latent_down_weight" (dim, Z) and
@@ -224,6 +228,8 @@ def _routed_block(x, dim, hidden, num_experts, prefix, top_k=1,
                                  shape=(num_experts,)))
     if scale != 1.0:
         attrs["scale"] = float(scale)
+    if renorm_eps is not None:
+        attrs["renorm_eps"] = float(renorm_eps)
     if first:
         attrs["first_expert"] = int(first)
     if latent:
@@ -276,15 +282,18 @@ def _canon_block_types(block_type, num_layers):
     return kinds
 
 
-_LAYER_KINDS = ("attention", "ssm", "mamba2", "experts", "mlp")
+_LAYER_KINDS = ("attention", "ssm", "mamba2", "shortconv", "experts",
+                "mlp")
+# the mixers whose decode state has no per-position entries
+_RECURRENT = ("ssm", "mamba2", "shortconv")
 
 
 def _canon_layer_kinds(layer_kinds, num_layers):
     """layer_kinds as a per-layer tuple, or None where the stack is
     spelled the old way (block_type: a mixer and an FFN in every
     layer). Each entry is ONE sublayer: a mixer ("attention" | "ssm" |
-    "mamba2"), a routed expert layer ("experts") or a dense FFN
-    ("mlp")."""
+    "mamba2" | "shortconv"), a routed expert layer ("experts") or a
+    dense FFN ("mlp")."""
     if layer_kinds is None:
         return None
     kinds = tuple(layer_kinds)
@@ -511,6 +520,24 @@ def _decode_mamba2_block(x, dim, prefix, max_len, pos, sizes,
     return _fc(y, dim, prefix + "out_proj", quantized, no_bias)
 
 
+def _decode_shortconv_block(x, prefix, max_len, pos, d_conv):
+    """A gated short convolution (ops/shortconv.py) on the decode
+    path, the whole operator in one node: "<prefix>in_proj_weight"
+    (3*dim, dim) to [b | c | u], the d_conv causal depthwise taps
+    "<prefix>shortconv_conv_weight" (dim, d_conv) over b * u, the gate
+    c, and "<prefix>out_proj_weight" (dim, dim); no bias, no
+    activation. One per-layer aux state, "<prefix>shortconv_conv_state"
+    (B, d_conv-1, dim) in the served dtype: the last gated rows, with
+    no length axis. The op ignores pos, so the per-row-position
+    serving twin is this graph. The projections are the operator's own
+    inputs: quantized= passes them by."""
+    return sym.contrib.ShortConvCached(
+        x, sym.Variable(prefix + "in_proj_weight"),
+        out_proj_weight=sym.Variable(prefix + "out_proj_weight"),
+        pos=pos, max_len=max_len, d_conv=int(d_conv),
+        name=prefix + "shortconv")
+
+
 def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
                       dim=128, ffn_hidden=None, num_experts=0,
                       quantized=False, compute_dtype=None,
@@ -528,7 +555,8 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
                       moe_stats=False, head_rows=0, layer_kinds=None,
                       expert_scoring="softmax",
                       routed_scaling_factor=1.0, expert_latent=0,
-                      shared_expert_hidden=0, experts_held=None):
+                      shared_expert_hidden=0, experts_held=None,
+                      shortconv_kernel=3, norm_topk_eps=None):
     """Autoregressive-decode twin of get_symbol.
 
     Inputs: data (B, Tnew) token ids for the tokens being appended
@@ -571,10 +599,18 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
     layer_kinds: a per-layer sequence that spells the stack ONE
     SUBLAYER A LAYER, h <- h + f(norm(h)) with the layer's one norm
     "layerN_ln1": "attention" | "ssm" | "mamba2" (the mixers above),
-    "experts" (a routed expert layer sized by the expert arguments
-    below) or "mlp" (the dense FFN of kind `ffn`). A layer of the last
-    two kinds holds no decode state at all. block_type then stays at
-    its default: it is the other spelling, a mixer AND an FFN in every
+    "shortconv" (a gated short convolution of shortconv_kernel taps,
+    ops/shortconv.py: one aux state a layer, a window of
+    shortconv_kernel - 1 gated rows in the served dtype, no length
+    axis; it exists under this spelling only), "experts" (a routed
+    expert layer sized by the expert arguments below) or "mlp" (the
+    dense FFN of kind `ffn` and width ffn_hidden). A layer of the last
+    two kinds holds no decode state at all. A pre-norm block of two
+    sublayers is two entries, so a stack whose FFN differs by layer
+    (dense of ffn_hidden in the leading layers, experts of
+    expert_hidden after) is spelled as it is: ("shortconv", "mlp",
+    "attention", "experts", ...). block_type then stays at its
+    default: it is the other spelling, a mixer AND an FFN in every
     layer, and builds the symbol it always built.
 
     The remaining arguments are what the hybrid families' published
@@ -600,8 +636,10 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
     "relu", the score itself as the weight) serve a Switch checkpoint
     trained through get_symbol. expert_scoring="sigmoid": sigmoid
     scores and a score-correction bias "layerN_gate_score_bias" that
-    chooses the experts and does not weigh them;
-    routed_scaling_factor multiplies the weights. expert_latent=Z: the
+    chooses the experts and does not weigh them (norm_topk_eps: what
+    norm_topk_prob adds to the sum it divides by, where the model
+    states one: 1e-6 in the lfm2_moe block); routed_scaling_factor
+    multiplies the weights. expert_latent=Z: the
     experts map Z -> expert_hidden -> Z between one down-projection a
     token and one up-projection of the weighted sum.
     shared_expert_hidden=Hs: a shared expert of kind `ffn` over the
@@ -659,12 +697,13 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
                              % (num_experts, experts_held))
         experts_held = (first, count)
     mamba2 = _canon_mamba2(mamba2, btypes)
-    has_ssm = "ssm" in btypes or "mamba2" in btypes
+    has_ssm = bool(set(_RECURRENT) & set(btypes))
     has_attn = "attention" in btypes
     no_bias = not use_bias
-    if tie_embeddings and num_experts:
-        raise ValueError("tie_embeddings is not supported with "
-                         "num_experts (no model here needs both)")
+    if int(shortconv_kernel) < 2:
+        raise ValueError("shortconv_kernel must be at least 2 (the "
+                         "window holds shortconv_kernel - 1 rows), "
+                         "got %r" % (shortconv_kernel,))
     if rolling_cache and not attention_window:
         raise ValueError("rolling_cache needs attention_window > 0 "
                          "(the circular capacity covers one window)")
@@ -754,6 +793,9 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
                                         cache_pos, mamba2,
                                         quantized=quantized,
                                         no_bias=no_bias, eps=norm_eps)
+        if kind == "shortconv":
+            return _decode_shortconv_block(a, prefix, max_len,
+                                           cache_pos, shortconv_kernel)
         return _decode_attention_block(
             a, num_heads, dim, prefix, max_len, cache_pos,
             num_kv_heads=num_kv_heads, quantized=quantized,
@@ -775,7 +817,8 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
             top_k=experts_per_token, kind=ffn,
             renormalize=norm_topk_prob, scoring=expert_scoring,
             scale=routed_scaling_factor, held=experts_held,
-            latent=expert_latent, shared_hidden=shared_expert_hidden)
+            latent=expert_latent, shared_hidden=shared_expert_hidden,
+            renorm_eps=norm_topk_eps)
         layer_stats.append(stats)
         return ff
 

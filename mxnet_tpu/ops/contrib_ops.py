@@ -253,7 +253,8 @@ def _routed_experts_args(attrs):
 def _routed_experts_op(data, gate_weight, expert_w1, expert_w2, *more,
                        top_k=1, act="relu", renormalize=False,
                        scoring="softmax", scale=1.0, first_expert=0,
-                       latent=False, shared=False, **_):
+                       latent=False, shared=False, renorm_eps=1e-20,
+                       **_):
     """Top-k mixture-of-experts FFN as it is served: float32 routing
     over every expert, every routed (token, expert) pair whose expert
     is held here computed by a grouped product over the ragged
@@ -266,7 +267,8 @@ def _routed_experts_op(data, gate_weight, expert_w1, expert_w2, *more,
     "gated_silu"; expert_w2 (Eh, H, Z): experts first_expert ..
     first_expert + Eh - 1 of the E routed over. Then, as the attrs
     say (_routed_experts_args): score_bias (E,) with scoring
-    "sigmoid"; latent_down (D, Z) and latent_up (Z, D) with latent
+    "sigmoid" (renorm_eps: what its renormalisation adds to the sum
+    it divides by); latent_down (D, Z) and latent_up (Z, D) with latent
     (else Z = D); shared_w1 (D, Hs) and shared_w2 (Hs, D) with shared.
     Outputs: y, shaped like data, and stats int32 = pairs routed,
     distinct held experts hit, largest expert batch and, where Eh < E,
@@ -279,7 +281,7 @@ def _routed_experts_op(data, gate_weight, expert_w1, expert_w2, *more,
         expert_w2, top_k=int(top_k), act=str(act),
         renormalize=bool(renormalize), scoring=str(scoring),
         score_bias=more.get("score_bias"), scale=float(scale),
-        first_expert=int(first_expert),
+        first_expert=int(first_expert), renorm_eps=float(renorm_eps),
         latent=(more["latent_down"], more["latent_up"])
         if latent else None,
         shared=(more["shared_w1"], more["shared_w2"])

@@ -85,26 +85,40 @@ def _sizes(xbc, dt, num_heads, head_dim, d_state, n_groups=1):
     return H, P, N, G
 
 
-def mamba2_conv(xbc, conv_state, weight, bias):
-    """Causal depthwise convolution + SiLU over [window | xbc].
+def causal_conv(x, conv_state, weight, bias=None, act=None):
+    """Causal depthwise convolution over [window | x], in float32.
 
-    xbc (B, T, C); conv_state (B, K-1, C) — the K-1 rows before
-    position 0; weight (C, K) with weight[:, K-1] on the current row;
-    bias (C,). Returns (activated (B, T, C) float32, new window
-    (B, K-1, C) in conv_state's dtype: the last K-1 rows seen)."""
+    x (B, T, C); conv_state (B, K-1, C), the K-1 rows before position
+    0; weight (C, K) with weight[:, K-1] on the current row; bias (C,)
+    and act (a function of the float32 sum), where the layer has them.
+    Returns (act(bias + sum_j weight[:, j] * row_{t-(K-1)+j}) (B, T, C)
+    float32, the new window (B, K-1, C) in conv_state's dtype: the
+    last K-1 rows seen). The window's layout and its update are every
+    short convolution's here: Mamba-2's over x|B|C (`mamba2_conv`) and
+    the gated one's over its gated rows (ops/shortconv.py)."""
     K = weight.shape[1]
-    T = xbc.shape[1]
-    if conv_state.shape != (xbc.shape[0], K - 1, xbc.shape[2]):
+    T = x.shape[1]
+    if conv_state.shape != (x.shape[0], K - 1, x.shape[2]):
         raise ValueError(
-            "Mamba2 conv_state must be (B, d_conv-1, conv_dim) = %r: "
-            "got %r" % ((xbc.shape[0], K - 1, xbc.shape[2]),
-                        conv_state.shape))
-    full = jnp.concatenate([conv_state.astype(xbc.dtype), xbc], axis=1)
+            "conv_state must be (B, d_conv-1, channels) = %r: got %r"
+            % ((x.shape[0], K - 1, x.shape[2]), conv_state.shape))
+    full = jnp.concatenate([conv_state.astype(x.dtype), x], axis=1)
     w = weight.astype(_F32)
-    acc = jnp.broadcast_to(bias.astype(_F32), xbc.shape)
+    acc = None if bias is None else \
+        jnp.broadcast_to(bias.astype(_F32), x.shape)
     for j in range(K):
-        acc = acc + full[:, j:j + T].astype(_F32) * w[:, j]
-    return jax.nn.silu(acc), full[:, T:].astype(conv_state.dtype)
+        tap = full[:, j:j + T].astype(_F32) * w[:, j]
+        acc = tap if acc is None else acc + tap
+    if act is not None:
+        acc = act(acc)
+    return acc, full[:, T:].astype(conv_state.dtype)
+
+
+def mamba2_conv(xbc, conv_state, weight, bias):
+    """Mamba-2's convolution: `causal_conv` with a bias and SiLU over
+    xbc (B, T, conv_dim). Returns (activated (B, T, C) float32, new
+    window (B, K-1, C) in conv_state's dtype)."""
+    return causal_conv(xbc, conv_state, weight, bias, jax.nn.silu)
 
 
 def _split(act, H, P, N, G=1):

@@ -372,7 +372,7 @@ set_param_shapes("_contrib_SSMCached", _ssm_cached_shapes)
 
 
 # -- Mamba2Cached (two carried states, neither with a length
-# axis) and the RMS norms ---------------------------------------------------
+# axis), ShortConvCached (one such state) and the RMS norms ------------------
 
 def _mamba2_shapes(shapes, attrs):
     """Everything is sized from xbc (B, T, conv_dim) and the attrs:
@@ -390,6 +390,21 @@ def _mamba2_shapes(shapes, attrs):
 
 
 set_param_shapes("_contrib_Mamba2Cached", _mamba2_shapes)
+
+
+def _short_conv_shapes(shapes, attrs):
+    """Everything is sized from data (B, T, D) and d_conv: the two
+    projections as FullyConnected holds them, the taps, the window of
+    d_conv-1 gated rows, the ignored pos."""
+    data = shapes[0]
+    if data is None:
+        return shapes
+    D, K = data[2], int(attrs.get("d_conv", 3))
+    want = [data, (3 * D, D), (D, K), (D, D), (data[0], K - 1, D), (1,)]
+    return [w if s is None else s for s, w in zip(shapes, want)]
+
+
+set_param_shapes("_contrib_ShortConvCached", _short_conv_shapes)
 
 
 def _rms_shapes(shapes, attrs):

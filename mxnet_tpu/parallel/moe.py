@@ -152,7 +152,7 @@ def moe_ffn(x, gate_w, w1, w2, mesh, axis_name="expert",
 
 
 def route_topk(x, gate_w, top_k, renormalize, scoring="softmax",
-               score_bias=None, scale=1.0):
+               score_bias=None, scale=1.0, renorm_eps=1e-20):
     """The ``top_k`` experts of each token and their weights, in
     float32 throughout (a bf16 product would move near-tied scores
     past each other). ``scoring="softmax"``: softmax over all E
@@ -161,9 +161,11 @@ def route_topk(x, gate_w, top_k, renormalize, scoring="softmax",
     ``renormalize``. ``scoring="sigmoid"``: sigmoid scores; the k
     largest of score + ``score_bias`` are chosen, the weights are the
     chosen SCORES (the bias chooses and does not weigh), divided by
-    their sum + 1e-20 under ``renormalize``. Either way times
-    ``scale``. x (N, D); gate_w (D, E); score_bias (E,) -> weights
-    (N, k) f32, experts (N, k) int32."""
+    their sum + ``renorm_eps`` under ``renormalize`` (the published
+    equations differ in that term: 1e-20 here by default, 1e-6 in the
+    ``lfm2_moe`` block, which is 4 float32 ulps of a sum near 2).
+    Either way times ``scale``. x (N, D); gate_w (D, E); score_bias
+    (E,) -> weights (N, k) f32, experts (N, k) int32."""
     scores = jnp.dot(x.astype(jnp.float32), gate_w.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
     if scoring == "softmax":
@@ -178,7 +180,7 @@ def route_topk(x, gate_w, top_k, renormalize, scoring="softmax",
         weights = jnp.take_along_axis(scores, experts, axis=-1)
         if renormalize:
             weights = weights / (weights.sum(axis=-1, keepdims=True)
-                                 + 1e-20)
+                                 + renorm_eps)
     else:
         raise ValueError("scoring must be 'softmax' or 'sigmoid', got "
                          "%r" % (scoring,))
@@ -239,7 +241,7 @@ def _activate(h, act):
 def routed_experts(x, gate_w, w1, w2, top_k=1, act="relu",
                    renormalize=False, scoring="softmax",
                    score_bias=None, scale=1.0, first_expert=0,
-                   latent=None, shared=None):
+                   latent=None, shared=None, renorm_eps=1e-20):
     """Top-k mixture-of-experts FFN that drops nothing and computes
     only the routed (token, expert) pairs whose expert is held here.
 
@@ -250,11 +252,12 @@ def routed_experts(x, gate_w, w1, w2, top_k=1, act="relu",
     with ``latent=(down (D, Z), up (Z, D))``, over the latent width:
     ``down`` runs once a token before the experts, ``up`` once on the
     weighted sum. ``shared=(p (D, Hs), q (Hs, D))`` adds
-    ``act(x p) q`` whole. ``scoring``, ``score_bias``, ``scale``: see
-    :func:`route_topk`. Returns ``(y, stats)``: y (N, D) in x's dtype;
-    stats int32 = pairs routed (N * k), distinct held experts with at
-    least one token, the largest expert batch and, where fewer
-    experts are held than routed over, the pairs computed here.
+    ``act(x p) q`` whole. ``scoring``, ``score_bias``, ``scale``,
+    ``renorm_eps``: see :func:`route_topk`. Returns ``(y, stats)``: y
+    (N, D) in x's dtype; stats int32 = pairs routed (N * k), distinct
+    held experts with at least one token, the largest expert batch
+    and, where fewer experts are held than routed over, the pairs
+    computed here.
 
     The N * k pairs are sorted by expert (a stable sort: within an
     expert, token order; pairs of experts not held here behind all
@@ -287,7 +290,8 @@ def routed_experts(x, gate_w, w1, w2, top_k=1, act="relu",
                 .astype(x.dtype)
     with jax.named_scope("moe.route"):
         weights, experts = route_topk(x, gate_w, k, renormalize,
-                                      scoring, score_bias, scale)
+                                      scoring, score_bias, scale,
+                                      renorm_eps)
         flat = experts.reshape(-1)                      # (N*k,)
         if part:
             here = (flat >= first) & (flat < first + held)

@@ -808,7 +808,7 @@ class ContinuousDecoder:
         self._g_kv = _telemetry.gauge("serve.decode.kv_bytes_per_slot")
         self._g_kv.set(self._kv_bytes_per_slot)
         # the same bytes by kind of state (k/v rows, SSM blob, Mamba-2
-        # scan state and convolution window), from the shapes the pool
+        # scan state, convolution window), from the shapes the pool
         # was allocated with. The kinds without a length axis get a
         # gauge of their own beside kv_bytes_per_slot, set here once
         # (a constant of the pool) and only where the pool has them, so
@@ -953,9 +953,16 @@ class ContinuousDecoder:
                          "max_len), %d bytes" % (
                              dims(gen._scan_shape),
                              by_kind["scan_state"]))
-            kinds.append("mamba2 convolution window %s (%s, O(1) in "
+        if "conv_window" in by_kind:
+            # Mamba-2's window beside its scan state, a gated short
+            # convolution's alone: a pool may hold either or both
+            windows = {n: dims(s) for n, s in (
+                ("mamba2", gen._conv_shape),
+                ("shortconv", gen._short_shape)) if s}
+            kinds.append("%s convolution window %s (%s, O(1) in "
                          "max_len), %d bytes" % (
-                             dims(gen._conv_shape),
+                             " and ".join(windows),
+                             ", ".join(windows.values()),
                              jnp.dtype(gen._cache_dtype),
                              by_kind["conv_window"]))
         stateful = len({n.split("_", 1)[0] for n in self._aux})

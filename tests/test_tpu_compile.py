@@ -234,6 +234,28 @@ def test_mamba2_in_eight_groups_compiles_for_v5e(
     assert temp < (state // 2 if tokens == 1 else 16 * state)
 
 
+@pytest.mark.parametrize("tokens", [1, 256])
+def test_the_gated_short_convolution_compiles_for_v5e(
+        one_chip, no_compile_cache, tokens):
+    """The LFM2 cell's operator (2 048 channels, 3 taps) over 16 rows:
+    the one-token step and the longest prefill; the window is updated
+    in place, and a prefill keeps a few (rows, positions, 3 x 2 048)
+    activations beside it and nothing larger."""
+    from mxnet_tpu.ops import shortconv
+    B, D, K = 16, 2048, 3
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                    sharding=one_chip)
+
+    op = jax.jit(shortconv.short_conv, donate_argnums=4)
+    compiled = op.lower(spec(B, tokens, D), spec(3 * D, D), spec(D, K),
+                        spec(D, D), spec(B, K - 1, D)).compile()
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes >= B * (K - 1) * D * 2
+    assert stats.temp_size_in_bytes < 8 * B * tokens * 3 * D * 2 + 2 ** 20
+
+
 @pytest.mark.parametrize("rule", ["sequential", "low_confidence_static",
                                   "low_confidence_dynamic"])
 def test_block_step_with_the_rows_state_compiles_for_v5e(
