@@ -128,10 +128,25 @@ def _stats_snapshot(decoder, telemetry, profiler, compiles):
     return {"t": _NOW(), "steps": st["steps"], "prefills": st["prefills"],
             "admitted": st["admitted"], "finished": st["finished"],
             "shed": st["shed"], "queued": st["queued"],
+            "prefill_rows": st["prefill_rows"],
+            "admit_rounds": st["admit_rounds"], "merges": st["merges"],
             "slot_fill_sum": fill.sum,
             "slot_fill_count": fill.count,
             "host_syncs": profiler.host_sync_count(),
             "compiles": len(compiles)}
+
+
+def _stats_readings(before, after, slots):
+    """The program's counters over the window (two `_stats_snapshot`s
+    apart), under the names the `counter` reader's metric files use;
+    the same in every serve kind."""
+    out = {"stats." + k: after[k] - before[k]
+           for k in ("steps", "prefills", "admitted", "shed",
+                     "prefill_rows", "admit_rounds", "merges",
+                     "slot_fill_sum", "host_syncs")}
+    out["stats.slot_rows"] = int(slots) * (
+        after["slot_fill_count"] - before["slot_fill_count"])
+    return out
 
 
 def run(ctx):
@@ -231,7 +246,8 @@ def run(ctx):
                           (d.memory_stats() or {}).get("bytes_in_use")
                           for d in jax.local_devices()]})
 
-    # -- reduce the client's log
+    # -- reduce the client's log, once
+    t_reduce = _NOW()
     done_idx = [i for i in range(issued) if log.done[i] is not None]
     token_times = [log.tokens[i] for i in range(issued)
                    if log.tokens[i] is not None]
@@ -244,14 +260,13 @@ def run(ctx):
     t_open, t_close = edges
     length = t_close - t_open
     n_tokens = window.count_in(arrivals, t_open, t_close)
-    gaps_ms = [1e3 * g for g in
-               window.gaps_in(token_times, t_open, t_close)]
+    gaps_ms, p50, p99, longest, long_by_100 = window.reduce_gaps(
+        token_times, t_open, t_close)
     in_win = [i for i in range(issued) if t_open < log.due[i] <= t_close]
     failed = [i for i in in_win if not isinstance(log.rows[i], np.ndarray)]
 
     e2e = {"serve_tokens_per_s": n_tokens / length,
-           "serve_itl_p99_ms": window.percentile(gaps_ms, 99)[0],
-           "serve_itl_p50_ms": window.median(gaps_ms)}
+           "serve_itl_p99_ms": p99, "serve_itl_p50_ms": p50}
     warm_gaps = sorted(((b - a, b) for ts in token_times
                         for a, b in zip(ts, ts[1:]) if b <= t_open),
                        reverse=True)[:3]
@@ -266,21 +281,19 @@ def run(ctx):
                        "requests_due": len(in_win),
                        "tokens_per_whole_second":
                            window.per_second(arrivals, t_open, t_close),
-                       "gaps_over_3x_median_ms":
-                           window.outliers(gaps_ms),
-                       "long_gaps_by_100_ms": window.histogram(
-                           [g for g in gaps_ms if g > 3 *
-                            (window.median(gaps_ms) or 0)], 100)})
+                       "gaps_over_3x_median_ms": longest,
+                       "long_gaps_by_100_ms": long_by_100})
     for secs in ctx.prefixes:
         cut = window.aligned_edges(marks, t_warm, secs)
         if cut and secs < seconds:
-            g = [1e3 * x for x in window.gaps_in(token_times, *cut)]
+            _g, cut50, cut99, _l, _h = window.reduce_gaps(
+                token_times, *cut)
             ctx.log("prefix", {
                 "seconds": secs, "length_s": cut[1] - cut[0],
                 "serve_tokens_per_s":
                     window.count_in(arrivals, *cut) / (cut[1] - cut[0]),
-                "serve_itl_p99_ms": window.percentile(g, 99)[0],
-                "serve_itl_p50_ms": window.median(g)})
+                "serve_itl_p99_ms": cut99, "serve_itl_p50_ms": cut50})
+    ctx.log("phase", {"reduced_s": _NOW() - t_reduce})
 
     # -- correct: every finished row is well-formed; a seeded sample
     # of the rows finished in the window, the longest among them,
@@ -340,15 +353,10 @@ def run(ctx):
         checks.append({"name": "requests_finished", "value": 0,
                        "limit": None, "ok": False})
 
-    d = lambda k: after[k] - before[k]
     nominal_tokens = window.count_in(arrivals, before["t"], after["t"])
     readings = {
         "series": {"gap_ms": gaps_ms},
-        "stats.steps": d("steps"), "stats.prefills": d("prefills"),
-        "stats.admitted": d("admitted"), "stats.shed": d("shed"),
-        "stats.slot_fill_sum": d("slot_fill_sum"),
-        "stats.slot_rows": d("slot_fill_count") * int(traffic["slots"]),
-        "stats.host_syncs": d("host_syncs"),
+        **_stats_readings(before, after, traffic["slots"]),
         "client.tokens": nominal_tokens,
         "compiles.window": sum(1 for t in ctx.compiles
                                if t_warm < t <= t_stop),

@@ -11,9 +11,6 @@ What differs from kind `serve`, and why it is a kind of its own:
   `serve_itl_p50_ms`. The pace of a stream is reported per block
   instead (`series.block_ms`: between the first tokens of consecutive
   blocks of a request), as a per-layer metric.
-- The log is reduced once: every percentile here sorts its series one
-  time (kind `serve` sorts all gaps once for each gap, which at this
-  cell's tokens/s would take minutes).
 - The logits behind served tokens are read through
   `decoder.on_block_logits` (`cellbench/models/<family>.py::
   served_logits`), since the step picks on the device.
@@ -31,8 +28,9 @@ import time
 import numpy as np
 
 from cellbench import deck, window
-from cellbench.drive.serve import (_Callers, _Log, _stats_snapshot,
-                                   _wait_until, _warm_groups)
+from cellbench.drive.serve import (_Callers, _Log, _stats_readings,
+                                   _stats_snapshot, _wait_until,
+                                   _warm_groups)
 
 _NOW = time.perf_counter
 _BLOCK_STATS = ("forwards", "commit_forwards", "blocks_committed",
@@ -150,6 +148,7 @@ def run(ctx):
                           for d in jax.local_devices()]})
 
     # -- reduce the client's log, once
+    t_reduce = _NOW()
     done_idx = [i for i in range(issued) if log.done[i] is not None]
     sent = [i for i in range(issued) if log.tokens[i] is not None]
     token_times = [log.tokens[i] for i in sent]
@@ -162,8 +161,8 @@ def run(ctx):
     t_open, t_close = edges
     length = t_close - t_open
     n_tokens = window.count_in(arrivals, t_open, t_close)
-    gaps_ms = sorted(1e3 * g for g in
-                     window.gaps_in(token_times, t_open, t_close))
+    gaps_ms, p50, p99, _longest, _by_100 = window.reduce_gaps(
+        token_times, t_open, t_close)
     block_ms = sorted(1e3 * g for g in block_times(
         token_times, [len(reqs[i]["prompt"]) for i in sent], block,
         t_open, t_close))
@@ -178,8 +177,7 @@ def run(ctx):
                            1 for t in ctx.compiles if t_open < t <= t_close),
                        "tokens": n_tokens, "gaps": len(gaps_ms),
                        "requests_due": len(in_win),
-                       "gap_ms_p50_p99": [window.median(gaps_ms),
-                                          window.percentile(gaps_ms, 99)[0]],
+                       "gap_ms_p50_p99": [p50, p99],
                        "block_ms_p50_p99": [
                            window.median(block_ms),
                            window.percentile(block_ms, 99)[0]],
@@ -194,6 +192,7 @@ def run(ctx):
                 "seconds": secs, "length_s": cut[1] - cut[0],
                 "serve_tokens_per_s":
                     window.count_in(arrivals, *cut) / (cut[1] - cut[0])})
+    ctx.log("phase", {"reduced_s": _NOW() - t_reduce})
 
     # -- correct: every finished row is well-formed; a seeded sample
     # of the rows finished in the window, the longest among them,
@@ -270,11 +269,7 @@ def run(ctx):
             if layer_forwards else None}
     readings = {
         "series": {"gap_ms": gaps_ms, "block_ms": block_ms},
-        "stats.steps": d("steps"), "stats.prefills": d("prefills"),
-        "stats.admitted": d("admitted"), "stats.shed": d("shed"),
-        "stats.slot_fill_sum": d("slot_fill_sum"),
-        "stats.slot_rows": d("slot_fill_count") * int(traffic["slots"]),
-        "stats.host_syncs": d("host_syncs"),
+        **_stats_readings(before, after, traffic["slots"]),
         "client.tokens": nominal_tokens,
         "compiles.window": sum(1 for t in ctx.compiles
                                if t_warm < t <= t_stop),

@@ -10,10 +10,6 @@ What differs from kind `serve`, and why it is a kind of its own:
   (`warm_lengths`): a prefill of this size compiles for half a minute
   a length on a cold machine, as long as a stream may stay silent, and
   the first admission round would compile three of them back to back.
-- The log is reduced once: every series is sorted one time and every
-  percentile reads the sorted list (kind `serve` sorts all gaps once
-  for each gap, which at this cell's 60 000-100 000 gaps a window
-  would take many minutes).
 - The program has no lower-precision path for expert weights, so the
   control (`--control 1`) is the reference's own int8 twin read in the
   program's place, with the program's numbers printed beside it (as
@@ -31,8 +27,9 @@ import time
 import numpy as np
 
 from cellbench import deck, window
-from cellbench.drive.serve import (_Callers, _Log, _stats_snapshot,
-                                   _wait_until, _warm_groups)
+from cellbench.drive.serve import (_Callers, _Log, _stats_readings,
+                                   _stats_snapshot, _wait_until,
+                                   _warm_groups)
 
 _NOW = time.perf_counter
 _EXPERT_STATS = ("moe_assignments", "moe_pairs_here", "moe_experts_hit")
@@ -47,22 +44,6 @@ def warm_lengths(decoder, traffic, vocab, seed):
     for plen in traffic["prompt_lengths"]:
         decoder.submit(rng.integers(0, vocab, plen), 2).result(
             timeout=600)
-
-
-def reduce_gaps(token_times, t_open, t_close):
-    """The window's gaps in milliseconds, sorted once, with what kind
-    `serve` logs of them: (sorted gaps, median, 99th percentile, the
-    ten largest over three medians, those over three medians counted
-    by 100 ms). One sort, and a scan of the sorted tail."""
-    gaps = sorted(1e3 * g for g in
-                  window.gaps_in(token_times, t_open, t_close))
-    p50 = window.median(gaps)             # sorting a sorted list: linear
-    p99 = window.percentile(gaps, 99)[0]
-    lo = len(gaps)
-    while lo and p50 and gaps[lo - 1] > 3 * p50:
-        lo -= 1
-    tail = gaps[lo:] if p50 else []
-    return gaps, p50, p99, tail[::-1][:10], window.histogram(tail, 100)
 
 
 def run(ctx):
@@ -165,6 +146,7 @@ def run(ctx):
                           for d in jax.local_devices()]})
 
     # -- reduce the client's log, once
+    t_reduce = _NOW()
     done_idx = [i for i in range(issued) if log.done[i] is not None]
     token_times = [log.tokens[i] for i in range(issued)
                    if log.tokens[i] is not None]
@@ -177,7 +159,7 @@ def run(ctx):
     t_open, t_close = edges
     length = t_close - t_open
     n_tokens = window.count_in(arrivals, t_open, t_close)
-    gaps_ms, p50, p99, longest, long_by_100 = reduce_gaps(
+    gaps_ms, p50, p99, longest, long_by_100 = window.reduce_gaps(
         token_times, t_open, t_close)
     in_win = [i for i in range(issued) if t_open < log.due[i] <= t_close]
     failed = [i for i in in_win if not isinstance(log.rows[i], np.ndarray)]
@@ -204,12 +186,14 @@ def run(ctx):
     for secs in ctx.prefixes:
         cut = window.aligned_edges(marks, t_warm, secs)
         if cut and secs < seconds:
-            _g, cut50, cut99, _l, _h = reduce_gaps(token_times, *cut)
+            _g, cut50, cut99, _l, _h = window.reduce_gaps(
+                token_times, *cut)
             ctx.log("prefix", {
                 "seconds": secs, "length_s": cut[1] - cut[0],
                 "serve_tokens_per_s":
                     window.count_in(arrivals, *cut) / (cut[1] - cut[0]),
                 "serve_itl_p99_ms": cut99, "serve_itl_p50_ms": cut50})
+    ctx.log("phase", {"reduced_s": _NOW() - t_reduce})
 
     # -- correct: every finished row is well-formed; a seeded sample
     # of the rows finished in the window, the longest among them,
@@ -288,11 +272,7 @@ def run(ctx):
         k: d(k) for k in _EXPERT_STATS}))
     readings = {
         "series": {"gap_ms": gaps_ms},
-        "stats.steps": d("steps"), "stats.prefills": d("prefills"),
-        "stats.admitted": d("admitted"), "stats.shed": d("shed"),
-        "stats.slot_fill_sum": d("slot_fill_sum"),
-        "stats.slot_rows": d("slot_fill_count") * int(traffic["slots"]),
-        "stats.host_syncs": d("host_syncs"),
+        **_stats_readings(before, after, traffic["slots"]),
         "client.tokens": nominal_tokens,
         "compiles.window": sum(1 for t in ctx.compiles
                                if t_warm < t <= t_stop),

@@ -57,9 +57,10 @@ def mamba2_step_need(cfg, traffic):
     return layers * slots * flops, layers * slots * nbytes
 
 
-def mamba2_scan_need(cfg, traffic, prompt):
+def mamba2_scan_need(cfg, traffic, prompt, rows=None):
     """(operations, bytes) of the chunked scans of ONE prefill of
-    `slots` rows of `prompt` tokens, all Mamba-2 layers. Per chunk of W
+    `rows` rows, `slots` where the program does not say, of `prompt`
+    tokens, all Mamba-2 layers. Per chunk of W
     tokens: the causal half of C.B^T and of the (W, W) scores times x
     (W (W + 1) / 2 pairs), the read of the entry state by every
     position and the chunk's own state (each W * heads * head_dim *
@@ -67,7 +68,8 @@ def mamba2_scan_need(cfg, traffic, prompt):
     the state read and written once."""
     s = sizes(cfg)
     layers, d_inner, conv = _mamba(s)
-    slots, t = int(traffic["slots"]), int(prompt)
+    rows = int(traffic["slots"] if rows is None else rows)
+    t = int(prompt)
     w = min(int(cfg["mamba_chunk_size"]), t)
     chunks = -(-t // w)
     pairs = w * (w + 1) // 2
@@ -75,7 +77,7 @@ def mamba2_scan_need(cfg, traffic, prompt):
         2 * 2 * w * d_inner * s["m_state"]
     nbytes = t * (conv + s["m_heads"] + d_inner) * _BF16 + \
         2 * d_inner * s["m_state"] * _F32
-    return layers * slots * chunks * per_chunk, layers * slots * nbytes
+    return layers * rows * chunks * per_chunk, layers * rows * nbytes
 
 
 def mean_depth(traffic):

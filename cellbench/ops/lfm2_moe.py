@@ -106,24 +106,25 @@ def shortconv_step_need(cfg, traffic):
     return layers * flops, layers * (weights + slots * per_slot)
 
 
-def shortconv_conv_need(cfg, traffic, prompt):
+def shortconv_conv_need(cfg, traffic, prompt, rows=None):
     """(operations, bytes) of the gated short convolutions of ONE
-    prefill of `slots` rows of `prompt` tokens (admission runs the
-    pool's full width whatever the number of real rows), all `conv`
-    layers, the whole operator: the weights once a layer, each
+    prefill of `rows` rows, `slots` where the program does not say
+    (`_admit_batch` runs the pool's full width whatever the number of
+    real rows), of `prompt` tokens, all `conv` layers, the whole
+    operator: the weights once a layer, each
     position's normed row in and output row out, each row's window
     read and written. With 512 positions and more the two projections'
     operations are the bound (2 x 4 x dim^2 a position against 33.5 MB
     of weights a layer), and those run inside the scope's own
     operations whatever the compiler prefetches."""
     s = sizes(cfg)
-    layers, slots, d = _layers(s, "conv"), int(traffic["slots"]), \
-        s["dim"]
-    tokens = slots * int(prompt)
+    layers, d = _layers(s, "conv"), s["dim"]
+    rows = int(traffic["slots"] if rows is None else rows)
+    tokens = rows * int(prompt)
     weights = sum(_bytes(n, s) for n in _KINDS["conv"]
                   if n != "ln1_gamma")
     nbytes = weights + tokens * 2 * d * _BF16 + \
-        slots * 2 * (s["taps"] - 1) * d * _BF16
+        rows * 2 * (s["taps"] - 1) * d * _BF16
     flops = tokens * (2 * 4 * d * d + (2 * s["taps"] + 2) * d)
     return layers * flops, layers * nbytes
 

@@ -23,8 +23,14 @@ Readings (`what`):
   bytes) asks of each execution, over the device seconds of the
   operations under `scope` inside it (of the whole execution where no
   scope is given), in per cent. A prefill's shapes follow its prompt
-  length: each execution takes `P` from the program's own
-  `mxnet.admit.prefill` span that dispatched it.
+  length and the rows it runs: each execution takes `P` from the
+  program's own `mxnet.admit.prefill` span that dispatched it, and the
+  rows from that span's `run` where the program writes one
+  (`serve/decode.py`: the rows the forward ran; a diffusion pool's
+  `_admit_blocks` does, `_admit_batch` runs the pool's width and says
+  nothing). A span's `rows` is the real prompts of the group and is
+  never read as the rows run: where there is no `run`, `need` is asked
+  with no row count and falls back to the pool's width.
 
 With a program that has no such scope or program (the parent of the PR
 that added them) there is nothing to read: `None`, and the metric is
@@ -75,8 +81,9 @@ def load(path):
     [(scope, start_ns, dur_ns)] of its `XLA Ops` line (scope: the
     operation's JAX name stack, "" where it has none) and `modules`
     [(name, start_ns, dur_ns)] of its `XLA Modules` line; and
-    `prefills` [(start_ns, P)], the program's `mxnet.admit.prefill`
-    spans on the host."""
+    `prefills` [(start_ns, P, run)], the program's
+    `mxnet.admit.prefill` spans on the host (`run`: the rows the
+    forward ran, None where the span does not say)."""
     space = _xplane_pb2().XSpace()
     with open(path, "rb") as f:
         space.ParseFromString(f.read())
@@ -113,11 +120,14 @@ def load(path):
                     stats = {names.get(s.metadata_id):
                              _stat_value(s, names) for s in e.stats}
                     if "P" in stats:
+                        run = stats.get("run")
                         prefills.append(
                             (line.timestamp_ns + e.offset_ps * 1e-3,
-                             int(stats["P"])))
+                             int(stats["P"]),
+                             None if run is None else int(run)))
     _busy, ops, modules = best or (0.0, [], [])
-    return {"ops": ops, "modules": modules, "prefills": sorted(prefills)}
+    return {"ops": ops, "modules": modules,
+            "prefills": sorted(prefills, key=lambda span: span[0])}
 
 
 # -- arithmetic on plain lists ----------------------------------------------
@@ -166,14 +176,14 @@ def executions(modules, module, ops, scope=None):
     return out
 
 
-def prompt_length_at(prefills, start_ns):
-    """`P` of the last `mxnet.admit.prefill` span that began before
-    `start_ns` (the program dispatches a prefill inside that span), or
-    None."""
+def prefill_at(prefills, start_ns):
+    """(`P`, `run`) of the last `mxnet.admit.prefill` span that began
+    before `start_ns` (the program dispatches a prefill inside that
+    span), or None."""
     best = None
-    for s, p in prefills:
+    for s, p, run in prefills:
         if s <= start_ns:
-            best = p
+            best = (p, run)
     return best
 
 
@@ -187,16 +197,18 @@ def least_seconds(flops, nbytes, device_kind):
 def roofline(view, need, device_kind, module, scope=None,
              by_prompt=False):
     """Per cent: least seconds over device seconds, summed over the
-    executions of `module` (those a prompt length is known for, where
-    `need` takes one)."""
+    executions of `module`. With `by_prompt`, those a prefill span is
+    known for: `need(P, run)` where the span says how many rows ran,
+    `need(P)` where it does not."""
     least = took = 0.0
     for start, secs in executions(view["modules"], module, view["ops"],
                                   scope):
         if by_prompt:
-            p = prompt_length_at(view["prefills"], start)
-            if p is None:
+            span = prefill_at(view["prefills"], start)
+            if span is None:
                 continue
-            flops, nbytes = need(p)
+            p, run = span
+            flops, nbytes = need(p) if run is None else need(p, run)
         else:
             flops, nbytes = need()
         if secs <= 0.0:

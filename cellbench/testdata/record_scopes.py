@@ -128,8 +128,8 @@ def expected(path):
         "scopes": scopes, "ops": len(v["ops"]),
         "modules": sorted({m[0].split("(", 1)[0] for m in v["modules"]}),
         "decode_steps": len(steps), "prefills": len(prefills),
-        "prefill_lengths": [p for _s, p in v["prefills"]],
-        "lengths_by_execution": [ds.prompt_length_at(v["prefills"], s)
+        "prefill_lengths": [p for _s, p, _run in v["prefills"]],
+        "lengths_by_execution": [ds.prefill_at(v["prefills"], s)[0]
                                  for s, _d in prefills],
         "mamba2_seconds": ds.scope_seconds(v["ops"], "mamba2."),
         "step_seconds": ds.scope_seconds(v["ops"], "mamba2.step"),

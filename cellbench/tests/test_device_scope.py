@@ -28,7 +28,7 @@ MODULES = [("jit_decode_step(17)", 100.0, 100.0),
            ("jit_generator_step(5)", 300.0, 200.0),
            ("jit_decode_step(17)", 600.0, 50.0),
            ("jit_cache_merge(3)", 700.0, 10.0)]
-VIEW = {"ops": OPS, "modules": MODULES, "prefills": [(250.0, 64)]}
+VIEW = {"ops": OPS, "modules": MODULES, "prefills": [(250.0, 64, None)]}
 
 
 def test_scopes_select_by_part_and_by_prefix():
@@ -48,8 +48,10 @@ def test_executions_of_a_program():
     whole = ds.executions(MODULES, "decode_step", OPS)
     assert [d for _, d in whole] == pytest.approx([100e-9, 50e-9])
     assert ds.executions(MODULES, "decode", OPS) == []
-    assert ds.prompt_length_at([(250.0, 64), (900.0, 32)], 300.0) == 64
-    assert ds.prompt_length_at([(250.0, 64)], 200.0) is None
+    spans = [(250.0, 64, None), (900.0, 32, 2)]
+    assert ds.prefill_at(spans, 300.0) == (64, None)
+    assert ds.prefill_at(spans, 950.0) == (32, 2)
+    assert ds.prefill_at(spans[:1], 200.0) is None
 
 
 def test_roofline_is_least_over_taken():
@@ -89,6 +91,67 @@ def test_read_returns_none_where_there_is_nothing():
         ds.read(readings, "nonsense")
 
 
+def test_a_prefill_s_need_takes_the_rows_that_ran():
+    """Two prefills of 100 ns of scan each: the first under a span
+    that says `run=2`, the second under one that says nothing. `need`
+    is asked for 2 rows and for no row count (the pool's width, here
+    16), never for the span's `rows` (the real prompts)."""
+    ops = OPS + [("jit(generator_step)/mamba2.scan/while:", 820.0, 100.0)]
+    modules = MODULES + [("jit_generator_step(5)", 800.0, 200.0)]
+    view = {"ops": ops, "modules": modules,
+            "prefills": [(250.0, 64, 2), (750.0, 32, None)]}
+    asked = []
+
+    def need(prompt, rows=None):
+        asked.append((prompt, rows))
+        # 197 operations a row and token: 1e-12 s of the chip's peak
+        return 197.0 * (16 if rows is None else rows) * prompt, 1
+
+    got = ds.roofline(view, need, KIND, "generator_step", "mamba2.scan",
+                      by_prompt=True)
+    assert asked == [(64, 2), (32, None)]
+    least = (2 * 64 + 16 * 32) * 1e-12
+    assert got == pytest.approx(100.0 * least / 200e-9)
+
+
+def _spans_file(path, spans):
+    """A trace file with nothing but `mxnet.admit.prefill` spans on a
+    host thread, written as `mxnet_tpu.trace.phase` leaves them: the
+    attributes as the event's own stats."""
+    space = ds._xplane_pb2().XSpace()
+    plane = space.planes.add()
+    plane.name = ds.HOST_PLANE
+    plane.event_metadata[1].id = 1
+    plane.event_metadata[1].name = ds.PREFILL_SPAN
+    ids = {}
+    line = plane.lines.add()
+    line.timestamp_ns = 1000
+    for offset_ns, attrs in spans:
+        ev = line.events.add()
+        ev.metadata_id = 1
+        ev.offset_ps = offset_ns * 1000
+        ev.duration_ps = 5000
+        for key, value in attrs.items():
+            if key not in ids:
+                ids[key] = len(ids) + 1
+                plane.stat_metadata[ids[key]].id = ids[key]
+                plane.stat_metadata[ids[key]].name = key
+            stat = ev.stats.add()
+            stat.metadata_id = ids[key]
+            stat.int64_value = value
+    with open(path, "wb") as f:
+        f.write(space.SerializeToString())
+
+
+def test_load_keeps_a_span_s_run_and_never_its_rows(tmp_path):
+    path = str(tmp_path / "spans.xplane.pb")
+    _spans_file(path, [(30, {"P": 128, "rows": 3, "run": 4}),
+                       (10, {"P": 64, "rows": 1}),
+                       (20, {"rows": 2})])          # no P: not a prefill
+    assert ds.load(path)["prefills"] == [(1010.0, 64, None),
+                                         (1030.0, 128, 4)]
+
+
 @pytest.fixture(scope="module")
 def recorded():
     path = os.path.join(HERE, "scopes.xplane.pb")
@@ -113,6 +176,9 @@ def test_the_recorded_trace_by_other_routes(recorded):
     every share between 0 and 100."""
     path, want = recorded
     v = ds.load(path)
+    # recorded before any span carried `run`: the lengths, and no rows
+    assert [(p, run) for _s, p, run in v["prefills"]] == \
+        [(p, None) for p in want["prefill_lengths"]]
     assert want["scopes"] == ["mamba2.conv", "mamba2.scan", "mamba2.step"]
     assert want["prefill_lengths"] == [p for p, _n in
                                        record_scopes.REQUESTS]

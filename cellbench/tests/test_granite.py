@@ -59,7 +59,15 @@ def test_the_cell_resolves_to_its_files(published):
     assert mine == {"mamba2_device_share.serve",
                     "mamba2_step_roofline.serve",
                     "mamba2_scan_roofline.serve",
-                    "decode_program_roofline.serve"}
+                    "decode_program_roofline.serve",
+                    # what every cell of kind `serve` reports (PR 40)
+                    "decode_step_host_ms.serve",
+                    "device_idle_share.serve", "peak_hbm_gb.serve",
+                    "admit_wall_share.serve",
+                    "idle_under_admit_share.serve",
+                    "decode_steps_per_token",
+                    "compiles_in_window.serve", "decode_slot_fill",
+                    "prefills_per_request", "prefill_rows_real_share"}
     assert {m["name"] for m in
             run.metrics_for(manifest, "end_to_end", CELL)} == {
         "serve_tokens_per_s", "serve_itl_p50_ms", "setup_s"}

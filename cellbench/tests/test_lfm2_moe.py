@@ -90,8 +90,12 @@ def test_the_cell_resolves_to_its_files(published):
            run.metrics_for(manifest, "end_to_end", CELL)}
     assert e2e == {"serve_tokens_per_s", "serve_itl_p50_ms", "setup_s"}
     layer = run.metrics_for(manifest, "per_layer", CELL)
-    assert {m["name"] for m in layer} == METRICS
-    assert all(m["workloads"] == [CELL] for m in layer)
+    # its own ten, and the three every serve cell shares (PR 40)
+    assert {m["name"] for m in layer} == METRICS | {
+        "admit_wall_share.serve", "idle_under_admit_share.serve",
+        "prefill_rows_real_share"}
+    assert all(m["workloads"] == [CELL] for m in layer
+               if m["name"] in METRICS)
     # the hit share's scale is 100 over the cell's expert layers x
     # experts: the counter reader has no other way to know them
     spec = run.load_json(run.HERE, "metrics",

@@ -1,5 +1,10 @@
 """Operations from shapes, against counts worked by hand at one small
 shape each."""
+import importlib
+
+import pytest
+
+from cellbench import run
 from cellbench.ops import opt, resnet
 from cellbench.reference import resnet as resnet_ref
 
@@ -47,3 +52,34 @@ def test_resnet50_macs_by_hand():
     n = sum(int(__import__("numpy").prod(s))
             for s in resnet_ref.param_shapes(cfg).values())
     assert 25.4e6 < n < 25.7e6             # 25.6 M parameters
+
+
+@pytest.mark.parametrize("family, need, config, traffic, today", [
+    # (operations, bytes) of a prefill of the pool's 16 rows of 32 and
+    # of 300 tokens, as the cells' rooflines have counted them so far
+    ("granite", "mamba2_scan_need", "granite-4.0-h-micro",
+     "chat_deck_long_answers",
+     {32: (41223979008, 2729705472), 300: (938622320640, 5357666304)}),
+    ("lfm2_moe", "shortconv_conv_need", "lfm2-24b-a2b",
+     "chat_deck_long_answers_16x1280",
+     {32: (120317804544, 266162176), 300: (1127979417600, 512053248)}),
+])
+def test_a_prefill_s_need_follows_the_rows_it_ran(family, need, config,
+                                                  traffic, today):
+    """`need(P, rows=r)` grows by the same operations and bytes with
+    every row, and with no row count it is the pool's width, which is
+    what `_admit_batch` runs and what the metric has read so far."""
+    fn = getattr(importlib.import_module("cellbench.ops." + family), need)
+    cfg = run.load_json(run.HERE, "configs", config + ".json")
+    mix = run.load_json(run.HERE, "traffic", traffic + ".json")
+    slots = mix["slots"]
+    for prompt, want in today.items():
+        assert fn(cfg, mix, prompt) == want
+        assert fn(cfg, mix, prompt, rows=slots) == want
+        f0, b0 = fn(cfg, mix, prompt, rows=0)   # weights read once
+        assert f0 == 0 and 0 <= b0 < want[1]
+        f1, b1 = fn(cfg, mix, prompt, rows=1)
+        for r in (2, 5, slots, 2 * slots):
+            f, b = fn(cfg, mix, prompt, rows=r)
+            assert f == r * f1
+            assert b - b0 == r * (b1 - b0)

@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from cellbench import run
+from cellbench.readers import counter
 from cellbench.tests import toy
 
 LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
@@ -94,6 +95,18 @@ def test_a_token_altered_where_it_is_produced_is_not_correct():
 
     good = run.run_cell(toy.OPT, toy.DECK, 5, 1.5)
     assert good["correct"] is True
+    # what the window hands to the `counter` reader of admission: a
+    # prefill runs the pool's 2 rows, whatever the group it holds
+    r = good["readings"]
+    assert r["stats.prefill_rows"] == 2 * r["stats.prefills"] > 0
+    assert 0 < r["stats.admit_rounds"] <= r["stats.prefills"]
+    spec = run.load_json(run.HERE, "metrics",
+                         "prefill_rows_real_share.json")
+    share = counter.read(r, **spec["args"])
+    assert share == 100.0 * r["stats.admitted"] / r["stats.prefill_rows"]
+    # one or two real rows of 2 (a round in flight at a snapshot may
+    # have counted its rows and not yet its requests)
+    assert 45.0 < share <= 100.0
     bad = run.run_cell(toy.OPT, toy.DECK, 5, 1.5, program_hook=break_step)
     assert bad["correct"] is False
     failed = [c["name"] for c in bad["checks"] if not c["ok"]]

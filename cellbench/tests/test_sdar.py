@@ -84,7 +84,8 @@ def test_the_cell_resolves_to_its_files(published):
         "block_step_roofline.serve_blocks",
         "block_forwards_per_token.serve_blocks",
         "serve_block_time_p50_ms", "block_step_host_ms.serve_blocks",
-        "device_idle_share.serve_blocks", "peak_hbm_gb.serve_blocks"}
+        "device_idle_share.serve_blocks", "peak_hbm_gb.serve_blocks",
+        "prefill_rows_real_share"}
 
 
 def test_the_configuration_keeps_every_number_but_the_depth(published):

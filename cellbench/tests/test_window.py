@@ -55,3 +55,74 @@ def test_spread_is_the_contract_s():
     import statistics
     q = statistics.quantiles(values, n=4)
     assert window.spread(values) == pytest.approx((q[2] - q[0]) / 100.0)
+
+
+def _log_of(requests, tokens, seed):
+    """A synthetic client log: `requests` streams of `tokens` tokens,
+    exponential gaps about a 12 ms step, a few long stops among them."""
+    import numpy as np
+    rng = np.random.default_rng(seed)
+    gaps = rng.exponential(0.012, (requests, tokens))
+    gaps[rng.random((requests, tokens)) < 0.002] += 0.25
+    return np.cumsum(gaps, axis=1).tolist()
+
+
+def _window_fields_as_first_written(token_times, t_open, t_close):
+    """What kind `serve` logged and reported of a window's gaps until
+    PR 40, kept as the plain statement of what is meant: the median is
+    taken again for every gap, so it costs the square of their number."""
+    gaps_ms = [1e3 * g for g in
+               window.gaps_in(token_times, t_open, t_close)]
+    return {"gaps": len(gaps_ms),
+            "serve_itl_p50_ms": window.median(gaps_ms),
+            "serve_itl_p99_ms": window.percentile(gaps_ms, 99)[0],
+            "gaps_over_3x_median_ms": window.outliers(gaps_ms),
+            "long_gaps_by_100_ms": window.histogram(
+                [g for g in gaps_ms if g > 3 *
+                 (window.median(gaps_ms) or 0)], 100)}
+
+
+def _window_fields(token_times, t_open, t_close):
+    gaps_ms, p50, p99, longest, by_100 = window.reduce_gaps(
+        token_times, t_open, t_close)
+    return {"gaps": len(gaps_ms), "serve_itl_p50_ms": p50,
+            "serve_itl_p99_ms": p99, "gaps_over_3x_median_ms": longest,
+            "long_gaps_by_100_ms": by_100}, gaps_ms
+
+
+def test_a_window_s_gaps_are_reduced_in_one_pass():
+    """The drives' one reduction: on 2 000 gaps the fields of the
+    `window` line are those of the first expression, key for key; on
+    100 000 it takes under two seconds (the first expression: hours)."""
+    import time
+    small = _log_of(20, 101, seed=1)
+    edges = (0.05, 1.1)
+    got, gaps = _window_fields(small, *edges)
+    assert got == _window_fields_as_first_written(small, *edges)
+    assert 1500 < got["gaps"] < 2000 and got["gaps_over_3x_median_ms"]
+    assert len(got["long_gaps_by_100_ms"]) >= 2
+    assert gaps == sorted(1e3 * g for g in window.gaps_in(small, *edges))
+    large = _log_of(200, 501, seed=2)
+    t0 = time.perf_counter()
+    got, gaps = _window_fields(large, 0.0, 1e9)
+    assert time.perf_counter() - t0 < 2.0
+    assert got["gaps"] == len(gaps) == 100000
+    assert got["serve_itl_p50_ms"] == window.median(gaps)
+    assert got["gaps_over_3x_median_ms"] == window.outliers(gaps)
+    assert sum(got["long_gaps_by_100_ms"].values()) == sum(
+        g > 3 * got["serve_itl_p50_ms"] for g in gaps)
+    assert window.reduce_gaps([], 0.0, 1.0) == ([], None, None, [], {})
+
+
+def test_a_window_whose_median_gap_is_nought_has_no_tail():
+    """A degenerate log (more than half of the gaps are 0): the
+    reduction reports the median and the 99th percentile and no tail,
+    in either of the two fields; the first expression agreed on the
+    largest and counted every gap above 0 in the histogram."""
+    log = [[0.0, 0.0, 0.0, 0.0, 0.5], [0.1, 0.1, 0.1]]
+    gaps, p50, p99, longest, by_100 = window.reduce_gaps(log, -1.0, 1.0)
+    assert gaps == [0.0] * 5 + [500.0] and p50 == 0.0 and p99 == 500.0
+    assert longest == [] and by_100 == {}
+    first = _window_fields_as_first_written(log, -1.0, 1.0)
+    assert first["gaps_over_3x_median_ms"] == []
+    assert first["long_gaps_by_100_ms"] == {500: 1}
