@@ -80,6 +80,42 @@ path with identical output. Every emission funnels through
 :meth:`_emit` one token at a time, so TTFT/inter-token metrics,
 streamed frames and mid-stream failover cursors work unchanged.
 
+The loop's order (docs/serving.md §the loop's order): a call of
+:meth:`_step` DISPATCHES STEP t + 1, THEN READS STEP t, for both kinds
+of pool, so the device runs the next program while the host reads,
+picks, emits, finishes and admits. An autoregressive row's state stays
+on the host (its position at t + 1 is its position at t plus one, and
+whether its budget ends it is known from ``len(emitted)``); only the
+token it feeds is not known before step t is read, so only the token
+moves: ``next_tokens`` (:func:`_next_program`), one small compiled
+program beside ``decode_step``, takes step t's logits as the step
+returned them and forms step t + 1's (B, 1) input on the device: the
+first index of the largest of a row's float32 last-position logits, or
+the host's ``pending`` token for a row that was not in step t
+(admitted, handed off, resumed or done with its chunks since). Its
+second result is those float32 rows, whose copy to the host starts at
+dispatch; the host reads them a step late and runs what it always ran
+(``_pick``, :meth:`_emit`, :meth:`_maybe_finish`), over the very
+values the device picked from, first index among equals on both
+sides. A row whose budget ends with the emission in flight is not
+dispatched again (``steps`` is what a loop that reads first would
+count). A row whose eos id comes up in step t was dispatched in t + 1
+already: that result is skipped and counted (``idle_forwards``), and
+the slot's cache rows are garbage until the next admission's merge,
+queued behind the step in flight by the donation chain, overwrites
+them wholesale, recurrent state included. WHAT MAY NOT RIDE AHEAD
+READS FIRST, decided by the requests in the pool and nothing else:
+while a held row samples (its key is split on the host and
+``_pick_token``'s arguments are static) each call reads the step in
+flight before it forms the next from the host's tokens; a speculative
+round, ``evacuate`` / ``export_session`` read it first too
+(:meth:`_read_inflight`), so ``pending`` / ``n_cached`` are exact for
+whoever looks. A dispatch that raises loses no token of the step
+before it. ``stats()`` counts ``steps_ahead`` (steps dispatched with
+the step before unread: ``steps - 1`` in a kept-full greedy pool) and
+``idle_forwards``; ``on_logits`` hands each step's rows to whoever
+asks.
+
 Generation by diffusion over blocks (``Generator(diffusion=...)``):
 the pool's one compiled program is then ``block_step``, and a step no
 longer yields one token a row. A row's state LIVES ON THE DEVICE,
@@ -446,6 +482,40 @@ def _step_program(step, generator):
                    out_shardings=(None, generator._aux_shardings()))
 
 
+def _next_program(whole):
+    """The small compiled program beside an autoregressive pool's
+    step: ``next_tokens(logits, host_tok, use_host) -> (data, last)``
+    over a step's logits AS THE STEP RETURNED THEM. ``last`` is what
+    the host reads of the step, each row's last position in float32;
+    ``data`` is the (B, 1) float32 input of the step after: a row's
+    ``host_tok`` where ``use_host`` (a row that was not in the step: the
+    host picked its pending token), else the first index of the largest
+    of ``last``, which is what ``DecodeFuture._pick`` makes of the very
+    same float32 values when it reads them (ties included: both take
+    the first). So the step after can be dispatched before the host has
+    read this one. Both results lie at ``whole``
+    (:func:`_row_placement`), so the step sees one placement of its
+    ``data`` whoever formed it."""
+    def next_tokens(logits, host_tok, use_host):
+        # named, not a lambda: the device's module name says which
+        # program ran
+        last = logits[:, -1].astype(jnp.float32)
+        picked = jnp.argmax(last, -1).astype(jnp.float32)
+        return jnp.where(use_host, host_tok[:, 0], picked)[:, None], last
+
+    return jax.jit(next_tokens, out_shardings=(whole, whole))
+
+
+def _row_placement(generator):
+    """Where a step's ``data`` lies under a mesh, from the host or
+    from ``next_tokens``: whole on every device (None without a mesh:
+    the default device)."""
+    if generator.mesh is None:
+        return None
+    from ..parallel import sharding as shd
+    return shd.replicated(generator.mesh)
+
+
 def _fresh_block_state(B, L):
     """A diffusion pool's block state, every slot idle: what
     ``block_step`` reads its inputs from and advances, kept on the
@@ -647,6 +717,12 @@ class ContinuousDecoder:
         # active row as fn(request, block_start, ids (L,), masked (L,),
         # logits (L, V) float32); costs a device-to-host copy a step
         self.on_block_logits = None
+        # its twin for an autoregressive pool: called on the decode
+        # thread as fn(request, logits (V,) float32) with what the
+        # request's ``_pick`` is handed next, for every row of every
+        # (B, 1) step (admission's first token is picked from the
+        # prefill); the read-back it looks at is made anyway
+        self.on_logits = None
         if self._diff:
             # a diffusion pool's rows live on the device: the step
             # program advances their block state and admission writes
@@ -658,7 +734,12 @@ class ContinuousDecoder:
                 self._B, self._diff["block_length"])
         else:
             self._step_fn = _step_program(decode_step, generator)
-        # a diffusion pool's step still unread: (rows, outputs), with
+            self._data_at = _row_placement(generator)
+            self._next_fn = _next_program(self._data_at)
+        # the step still unread: (rows, outputs) of a diffusion pool;
+        # of an autoregressive one its rows, its logits, its expert
+        # counts (or None) and ``last``, each row's last position in
+        # float32, once ``next_tokens`` has run over the logits; with
         # rows the request each slot held when it was dispatched. ONE
         # step rides ahead of what the host has read, and one is
         # enough: the host's work on a step (a few ms) hides under the
@@ -1166,6 +1247,11 @@ class ContinuousDecoder:
         if not 0 <= slot < self._B:
             raise ValueError("slot %d out of range for %d-slot pool"
                              % (slot, self._B))
+        if not self._diff:
+            # with a step in flight a row's depth and pending token are
+            # a step behind its cache rows: read it first (it may end
+            # the row)
+            self._read_inflight()
         req = self._slots[slot]
         if req is None:
             raise ValueError("slot %d holds no active sequence" % slot)
@@ -1873,49 +1959,129 @@ class ContinuousDecoder:
             self._slots[slot] = None
 
     def _step(self):
-        """One (B, 1) per-row-position decode step: every active slot
-        ingests its pending token at its own depth and samples the
-        next; inactive slots feed a dummy token at position 0 (their
+        """One call of an autoregressive pool's loop (see the module
+        docstring): dispatch the next (B, 1) per-row-position step,
+        THEN read the one the call before left in flight and emit its
+        tokens. Every slot held rides the step at its own depth, but a
+        row whose budget ends with the emission still in flight; a row
+        of the step in flight feeds what ``next_tokens`` picks from
+        that step's logits on the device, at its depth plus one, and a
+        row that was not in it (admitted, handed off, resumed or done
+        with its chunks since) its ``pending`` token, picked by the
+        host. Inactive slots feed a dummy token at position 0 (their
         cache rows are garbage until the next admission overwrites
-        them wholesale)."""
+        them wholesale).
+
+        What may not ride ahead reads first: while any held row samples
+        (its key is split on the host and ``_pick_token``'s arguments
+        are static), the step in flight is read and emitted before the
+        next is formed, from the host's tokens alone. The phase notes
+        ``active`` (rows dispatched) and ``ahead`` (dispatched with the
+        step before unread)."""
         if self._diff:
             return self._block_step()
-        active = [i for i, s in enumerate(self._slots) if s is not None]
-        if not active:
+        if self._inflight is None and \
+                all(s is None for s in self._slots):
             return
-        with _trace.phase("serve.decode.step", active=len(active)):
-            with _trace.phase("step.inputs"):
-                toks = np.zeros((self._B, 1), np.float32)
-                pos = np.zeros((self._B,), np.float32)
-                for i in active:
-                    toks[i, 0] = float(self._slots[i].pending)
-                    pos[i] = float(self._slots[i].n_cached)
-                args = dict(self._gen._params)
-                args["data"] = jnp.asarray(toks)
-                args["positions"] = jnp.asarray(pos[:, None])
-                args["cache_pos"] = jnp.asarray(pos)
-            with _trace.phase("step.dispatch"):
-                outs, self._aux = self._step_fn(args, self._aux,
-                                                self._rng0)
-            with _trace.phase("step.wait"):
-                last = outs[0][:, -1].astype(jnp.float32)
-                if len(outs) > 1:
-                    # the expert layers' counts ride the same read
-                    last, stats = jax.device_get((last, outs[1]))
-                    self._count_experts(stats)
-                else:
-                    last = np.asarray(last)
-            with _trace.phase("step.emit"):
-                self._steps += 1
-                self._c_steps.inc()
-                self._h_slotfill.observe(len(active))
-                self._g_active.set(len(active))
-                for i in active:
-                    req = self._slots[i]
-                    req.n_cached += 1
-                    tok = req._pick(last[i])
-                    self._emit(req, tok)
-                    self._maybe_finish(i, tok)
+        with _trace.phase("serve.decode.step") as ph:
+            last, self._inflight = self._inflight, None
+            if last is not None and any(
+                    s is not None and s.temperature > 0
+                    for s in self._slots):
+                self._read_step(**last)
+                last = None
+            try:
+                with _trace.phase("step.inputs"):
+                    rows = {}
+                    ahead = last["rows"] if last is not None else {}
+                    toks = np.zeros((self._B, 1), np.float32)
+                    pos = np.zeros((self._B,), np.float32)
+                    use_host = np.ones((self._B,), bool)
+                    for i, req in enumerate(self._slots):
+                        if req is None:
+                            continue
+                        if ahead.get(i) is not req:
+                            toks[i, 0] = float(req.pending)
+                            pos[i] = float(req.n_cached)
+                        elif len(req.emitted) + 1 < req.max_new:
+                            use_host[i] = False
+                            pos[i] = float(req.n_cached + 1)
+                        else:
+                            # the emission in flight is its last
+                            continue
+                        rows[i] = req
+                    args = dict(self._gen._params)
+                    args["positions"] = jnp.asarray(pos[:, None])
+                    args["cache_pos"] = jnp.asarray(pos)
+                ph.note(active=len(rows),
+                        ahead=bool(rows) and last is not None)
+                if rows:
+                    with _trace.phase("step.dispatch"):
+                        if last is None:
+                            args["data"] = jax.device_put(
+                                toks, self._data_at)
+                        else:
+                            args["data"], last["last"] = self._next_fn(
+                                last["logits"], toks, use_host)
+                            last["last"].copy_to_host_async()
+                        outs, self._aux = self._step_fn(
+                            args, self._aux, self._rng0)
+                    # the expert layers' counts ride the step's read
+                    self._inflight = {
+                        "rows": rows, "logits": outs[0], "last": None,
+                        "stats": outs[1] if len(outs) > 1 else None}
+                    self._steps_ahead += last is not None
+            finally:
+                # a dispatch that raises loses no token of the step
+                # before it
+                if last is not None:
+                    self._read_step(**last)
+
+    def _read_inflight(self):
+        """Read and emit the step in flight, if there is one: what
+        anything but :meth:`_step` does first (a speculative round, a
+        session's export), so that every row's ``pending`` and
+        ``n_cached`` are exact when it looks at them."""
+        last, self._inflight = self._inflight, None
+        if last is not None:
+            # a step's phase of its own: the read half alone
+            with _trace.phase("serve.decode.step", active=0,
+                              ahead=False):
+                self._read_step(**last)
+
+    def _read_step(self, rows, logits, stats, last):
+        """Read one step's results from the device and do the host's
+        part of it: the counters, ``on_logits``, and each row's token
+        through ``_pick`` / :meth:`_emit` / :meth:`_maybe_finish`.
+        ``rows`` maps a slot to the request it held when the step was
+        dispatched; a row the host has let go since (its eos id came up
+        in the step before) is skipped and counted. ``last`` is None
+        where no step was dispatched after this one: ``next_tokens``
+        then runs for the read alone."""
+        with _trace.phase("step.wait"):
+            if last is None:
+                _, last = self._next_fn(
+                    logits, np.zeros((self._B, 1), np.float32),
+                    np.ones((self._B,), bool))
+            last, stats = jax.device_get((last, stats))
+            if stats is not None:
+                self._count_experts(stats)
+        with _trace.phase("step.emit"):
+            mine = [(i, req) for i, req in rows.items()
+                    if self._slots[i] is req]
+            self._idle_forwards += len(rows) - len(mine)
+            self._steps += 1
+            self._c_steps.inc()
+            self._h_slotfill.observe(len(mine))
+            self._g_active.set(len(mine))
+            if self.on_logits is not None:
+                for i, req in mine:
+                    self.on_logits(req, last[i])
+            for i, req in mine:
+                req.n_cached += 1
+                tok = req._pick(last[i])
+                self._emit(req, tok)
+                self._maybe_finish(i, tok)
 
     def _count_experts(self, stats):
         """One step's expert counts from the device, (expert layers,
@@ -2301,6 +2467,10 @@ class ContinuousDecoder:
         self._emit(req, tok)
         self._maybe_finish(slot, tok)
 
+    def _speculating(self):
+        return self._draft is not None and any(
+            s is not None and s.speculative for s in self._slots)
+
     def _nothing_to_do(self):
         return not self._queue and \
             not self._evac_waiters and \
@@ -2332,9 +2502,12 @@ class ContinuousDecoder:
             self._admit()
             self._chunk_step()
             try:
-                if self._draft is not None and any(
-                        s is not None and s.speculative
-                        for s in self._slots):
+                if self._speculating():
+                    # a round's inputs are the host's: the step in
+                    # flight is read first (and may end the row that
+                    # asked for the round)
+                    self._read_inflight()
+                if self._speculating():
                     with _trace.phase("serve.spec.round") as ph:
                         ph.note(**self._spec_round())
                 else:
@@ -2394,6 +2567,12 @@ class ContinuousDecoder:
                 self._draining = True
             queued = list(self._queue)
             self._queue.clear()
+        if not self._diff:
+            try:
+                # what is exported is what the host has read
+                self._read_inflight()
+            except Exception as exc:      # noqa: BLE001 — as in _loop
+                self._step_failed(exc)
         n = 0
         for slot in range(self._B):
             req = self._slots[slot]

@@ -957,8 +957,9 @@ def _step_pool(kind, params, batch):
         return _gen(_params(seed=5, num_kv_heads=1), batch,
                     num_kv_heads=1), V
     over = {"q8": dict(quantize_kv=True),
-            "windowed": dict(attention_window=4),
-            "sharded": dict(mesh=_mesh_2x2())}.get(kind, {})
+            "windowed": dict(attention_window=4)}.get(kind, {})
+    if kind == "sharded":      # built only where asked: it needs 4 devices
+        over = dict(mesh=_mesh_2x2())
     return _gen(params, batch, **over), V
 
 
@@ -979,17 +980,19 @@ def _without_donation(dec):
 
 def _watch_pools(dec):
     """Wrap the loop's step and speculative round: for each one that
-    ran, record whether every leaf of the pool(s) held BEFORE it is
-    deleted afterwards and every leaf of the pool(s) bound after it is
-    live."""
+    dispatched (the pool was rebound: a call may only read the step in
+    flight), record whether every leaf of the pool(s) held BEFORE it
+    is deleted afterwards and every leaf of the pool(s) bound after it
+    is live."""
     seen = []
 
     def watched(run):
         def inner():
-            before = dict(dec._aux), dict(dec._daux or {})
-            n, d = dec._steps, dec._draft_steps
+            held = dec._aux
+            before = dict(held), dict(dec._daux or {})
+            d = dec._draft_steps
             out = run()
-            if dec._steps > n:
+            if dec._aux is not held:
                 gone = all(a.is_deleted() for a in before[0].values())
                 if dec._draft_steps > d:
                     gone = gone and all(a.is_deleted()
@@ -1197,3 +1200,289 @@ class TestDonatedStep:
         assert st["step_failures"] == 1
         assert (st["finished"], st["active"]) == (1, 0)
         assert "decode step failed" in caplog.text
+
+
+# -- one step rides ahead of what the host has read (PR 41) ----------------
+AHEAD_KINDS = ["kv", "mamba2", "lfm2"]
+
+
+def _ahead_pool(kind, params, batch):
+    """A Generator whose pool holds the decode state of a serve cell:
+    k/v rows (OPT); Mamba-2 window + scan state beside k/v rows
+    (Granite, Nemotron); a short convolution's window and routed
+    experts beside rotary QK-normed k/v rows (LFM2). Returns
+    (generator, vocabulary)."""
+    if kind == "lfm2":
+        from cellbench.models import lfm2_moe as model
+        from cellbench.reference import lfm2_moe as ref
+        import test_lfm2_moe as toy
+        return Generator(ref.make_params(toy.TOY, toy.SEED, "float32"),
+                         toy.V, toy.T, batch_size=batch,
+                         **model.generator_args(toy.TOY)), toy.V
+    return _step_pool(kind, params, batch)
+
+
+def _count_dispatches(dec):
+    """Count the step program's dispatches (a call of the loop may
+    only read)."""
+    real, calls = dec._step_fn, []
+
+    def counted(args, aux, rng):
+        calls.append(1)
+        return real(args, aux, rng)
+
+    dec._step_fn = counted
+    return calls
+
+
+def _wait_emitted(fut, n):
+    deadline = time.time() + 120.0
+    while len(fut.emitted) < n:
+        assert time.time() < deadline
+        time.sleep(0.002)
+
+
+class TestStepAhead:
+    @pytest.mark.parametrize("kind", AHEAD_KINDS)
+    @pytest.mark.parametrize("budgets", [(9, 9), (9, 4)],
+                             ids=["kept-full", "ragged"])
+    def test_greedy_rows_ahead_are_generate_s_rows(self, params, kind,
+                                                   budgets):
+        """ACCEPTANCE: greedy rows served with every step dispatched
+        before the one before it is read are `generate`'s rows, token
+        for token; over a kept-full pool every step but the first
+        rides ahead; a row that ends by its budget is not dispatched
+        again (as many dispatches and steps as the longest row needs:
+        the count of a loop that reads before it dispatches), and no
+        forward is spent on a row let go."""
+        single, vocab = _ahead_pool(kind, params, 1)
+        pool, _ = _ahead_pool(kind, params, 2)
+        rng = np.random.RandomState(41)
+        prompts = [rng.randint(1, vocab, (n,)) for n in (5, 7)]
+        with pool.serving_decoder() as dec:
+            calls = _count_dispatches(dec)
+            futs = _submit_together(dec, _hold_admission(dec),
+                                    list(zip(prompts, budgets)))
+            got = [f.result(120.0) for f in futs]
+            st = dec.stats()
+            assert dec._inflight is None
+        for p, n, g in zip(prompts, budgets, got):
+            np.testing.assert_array_equal(
+                g, single.generate(p[None], n)[0])
+        assert st["steps"] == len(calls) == max(budgets) - 1
+        assert st["steps_ahead"] == st["steps"] - 1
+        assert st["idle_forwards"] == 0
+        assert dec._next_fn._cache_size() == 1
+
+    @pytest.mark.parametrize("kind", AHEAD_KINDS)
+    def test_eos_with_a_step_ahead_costs_one_forward_and_frees_a_clean_slot(
+            self, params, kind):
+        """A row whose eos id comes up in step t was dispatched in
+        t + 1 already: that result is skipped and counted, the row
+        beside it goes on, and the slot's next tenant (its prefill
+        merged behind the step in flight, recurrent state and all) is
+        served as a solo `generate` would serve it."""
+        single, vocab = _ahead_pool(kind, params, 1)
+        pool, _ = _ahead_pool(kind, params, 2)
+        rng = np.random.RandomState(3)
+        first, beside, tenant = (rng.randint(1, vocab, (n,))
+                                 for n in (5, 6, 4))
+        with pool.serving_decoder() as dec:
+            def ending(req, row):
+                # on the decode thread: the token the row's fourth
+                # step is about to pick becomes its eos id (the toy
+                # models repeat themselves, so no id given at submit
+                # comes up mid-stream in all of them)
+                if req is a and len(req.emitted) == 4:
+                    req.eos_id = int(np.argmax(row))
+
+            a = None
+            dec.on_logits = ending
+            a, = _submit_together(dec, _hold_admission(dec),
+                                  [(first, 12)])
+            b = dec.submit(beside, 18)
+            c = dec.submit(tenant, 8)      # waits for the freed slot
+            got = [f.result(120.0) for f in (a, b, c)]
+            st = dec.stats()
+        np.testing.assert_array_equal(
+            got[0], single.generate(first[None], 12)[0][:5 + 5])
+        np.testing.assert_array_equal(
+            got[1], single.generate(beside[None], 18)[0])
+        np.testing.assert_array_equal(
+            got[2], single.generate(tenant[None], 8)[0])
+        assert st["idle_forwards"] == 1
+        assert st["step_failures"] == 0
+
+    def test_nothing_rides_ahead_of_a_sampled_row(self, params):
+        """A sampled request beside a greedy one is `generate`'s stream
+        for its seed, and while it lives every step is read before the
+        next is formed (its pick is the host's): `steps_ahead` counts
+        only the steps after it has gone."""
+        single, pool = _gen(params, 1), _gen(params, 2)
+        rng = np.random.RandomState(9)
+        p, q = rng.randint(1, V, (5,)), rng.randint(1, V, (4,))
+        opts = dict(temperature=0.8, top_k=5, seed=42)
+        with pool.serving_decoder() as dec:
+            hold = _hold_admission(dec)
+            hold[0] = 2
+            s = dec.submit(p, 6, **opts)
+            g = dec.submit(q, 15)
+            got = s.result(120.0), g.result(120.0)
+            st = dec.stats()
+        np.testing.assert_array_equal(
+            got[0], single.generate(p[None], 6, **opts)[0])
+        np.testing.assert_array_equal(
+            got[1], single.generate(q[None], 15)[0])
+        assert st["steps"] == 14
+        # steps 1-5 carried the sampled row; step 6 was formed in the
+        # call that read its last token: nothing was in flight
+        assert st["steps_ahead"] == 14 - 6
+        assert st["idle_forwards"] == 0
+
+    @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+    def test_ties_pick_the_same_id_on_the_device_and_the_host(self,
+                                                              dtype):
+        """`next_tokens` and `DecodeFuture._pick` read the same
+        float32 values and both take the first index among equals: a
+        constant row, a largest value that repeats (in `dtype`'s
+        rounding: neighbours that bfloat16 makes equal), the largest
+        last, and a row whose token is the host's."""
+        import jax.numpy as jnp
+        from mxnet_tpu.serve.decode import DecodeFuture, _next_program
+        rng = np.random.RandomState(0)
+        logits = rng.randn(6, 1, 300).astype(np.float32)
+        logits[0] = 0.25                          # a constant row
+        logits[1, 0, [7, 100, 299]] = 9.0         # the largest, thrice
+        logits[2, 0, 200:] = 5.0 + 1e-4 * np.arange(100)  # equal in bf16
+        logits[3, 0, -1] = 11.0                   # the last id
+        logits[4] = -np.inf                       # nothing is largest
+        host_tok = np.full((6, 1), 123.0, np.float32)
+        use_host = np.arange(6) == 5
+        data, last = _next_program(None)(
+            jnp.asarray(logits, dtype), host_tok, use_host)
+        data, last = np.asarray(data), np.asarray(last)
+        assert data.shape == (6, 1) and data.dtype == np.float32
+        assert last.shape == (6, 300) and last.dtype == np.float32
+        np.testing.assert_array_equal(
+            last, np.asarray(jnp.asarray(logits, dtype)[:, -1],
+                             np.float32))
+        req = DecodeFuture(np.zeros(1, np.int64), 4, None, 0.0, None,
+                           None, 0)
+        picks = [req._pick(last[i]) for i in range(5)]
+        assert data[:5, 0].tolist() == picks
+        assert picks[:2] == [0, 7] and picks[3:] == [299, 0]
+        assert picks[2] == (200 if dtype == "bfloat16" else 299)
+        assert data[5, 0] == 123.0
+
+    def test_a_dispatch_that_raises_loses_no_token_of_the_step_before(
+            self, params, caplog):
+        """The step in flight when a dispatch raises is still read and
+        emitted (its logits were picked from before the dispatch); then
+        the rows fail, the pool is built anew and serves on."""
+        single, pool = _gen(params, 1), _gen(params, 2)
+        rng = np.random.RandomState(5)
+        prompts = [rng.randint(1, V, (n,)) for n in (4, 6)]
+        with pool.serving_decoder() as dec:
+            real, calls = dec._step_fn, []
+
+            def failing(args, aux, rng_):
+                calls.append(1)
+                if len(calls) == 4:
+                    raise RuntimeError("injected dispatch fault")
+                return real(args, aux, rng_)
+
+            dec._step_fn = failing
+            futs = _submit_together(dec, _hold_admission(dec),
+                                    [(p, 12) for p in prompts])
+            for f in futs:
+                with pytest.raises(RuntimeError,
+                                   match="injected dispatch fault"):
+                    f.result(120.0)
+            later = rng.randint(1, V, (5,))
+            got = dec.submit(later, 6).result(120.0)
+            st = dec.stats()
+        for p, f in zip(prompts, futs):
+            # the first token came with admission, three steps were
+            # dispatched and all three were read
+            np.testing.assert_array_equal(
+                f.emitted, single.generate(p[None], 12)[0][len(p):][:4])
+        np.testing.assert_array_equal(
+            got, single.generate(later[None], 6)[0])
+        assert st["step_failures"] == 1
+        assert (st["finished"], st["active"]) == (1, 0)
+        assert "decode step failed" in caplog.text
+
+    @pytest.mark.parametrize("kind", AHEAD_KINDS)
+    def test_evacuate_with_a_step_in_flight_resumes_token_for_token(
+            self, params, kind):
+        """`evacuate` finds a greedy row's next step on its way: it is
+        read first, so the exported depth, pending token and cache rows
+        agree and the heir continues `generate`'s row."""
+        single, vocab = _ahead_pool(kind, params, 1)
+        donor, _ = _ahead_pool(kind, params, 2)
+        heir, _ = _ahead_pool(kind, params, 2)
+        p = np.random.RandomState(7).randint(1, vocab, (5,))
+        with donor.serving_decoder() as d1, \
+                heir.serving_decoder() as d2:
+            step, evacuate = d1._step, d1._do_evacuate
+            futs, found = [], []
+
+            def stepping():
+                # the loop stands still once three tokens are out
+                if not futs or len(futs[0].emitted) < 3:
+                    step()
+
+            def seen():
+                found.append(d1._inflight is not None)
+                evacuate()
+
+            d1._step, d1._do_evacuate = stepping, seen
+            futs.append(d1.submit(p, 16))
+            _wait_emitted(futs[0], 3)
+            assert d1.evacuate() == 1
+            with pytest.raises(SessionEvacuated) as ei:
+                futs[0].result(10.0)
+            state = ei.value.state
+            got = d2.submit(p, 16, resume=state).result(120.0)
+            assert d1._inflight is None
+        assert found == [True]
+        # the step in flight was the fourth token's
+        assert len(state["emitted"]) == 4
+        assert state["kv_blob"]["pos"] == 5 + 4 - 1
+        assert state["pending"] == state["emitted"][-1]
+        np.testing.assert_array_equal(
+            got, single.generate(p[None], 16)[0])
+
+    def test_on_logits_sees_what_pick_is_handed(self, params):
+        """`decoder.on_logits(req, row)`: None by default; set, it is
+        called before each step's picks with the float32 rows that
+        `_pick` is handed next (admission's first token is picked from
+        the prefill and is not a step's)."""
+        from mxnet_tpu.serve.decode import DecodeFuture
+        pool = _gen(params, 2)
+        rng = np.random.RandomState(2)
+        prompts = [rng.randint(1, V, (n,)) for n in (4, 6, 5)]
+        hooked, picked, pick = {}, {}, DecodeFuture._pick
+
+        def keeping(self, row):
+            picked.setdefault(id(self), []).append(np.array(row))
+            return pick(self, row)
+
+        with pool.serving_decoder() as dec:
+            assert dec.on_logits is None
+            dec.on_logits = lambda req, row: hooked.setdefault(
+                id(req), []).append(np.array(row))
+            DecodeFuture._pick = keeping
+            try:
+                futs = [dec.submit(p, n)
+                        for p, n in zip(prompts, (6, 3, 5))]
+                for f in futs:
+                    f.result(120.0)
+            finally:
+                DecodeFuture._pick = pick
+        for f, n in zip(futs, (6, 3, 5)):
+            assert len(picked[id(f)]) == n
+            assert len(hooked[id(f)]) == n - 1
+            assert hooked[id(f)][0].dtype == np.float32
+            np.testing.assert_array_equal(
+                np.stack(hooked[id(f)]), np.stack(picked[id(f)][1:]))

@@ -70,6 +70,29 @@ def _entry_results(compiled):
     return out
 
 
+@pytest.mark.parametrize("slots,vocab", [(8, 50272), (32, 32768)],
+                         ids=["opt", "nemotron"])
+def test_next_tokens_is_one_small_program_on_v5e(one_chip,
+                                                 no_compile_cache, slots,
+                                                 vocab):
+    """The program beside an autoregressive pool's step, at a cell's
+    rows and vocabulary in bfloat16: the TPU compiler takes it, and it
+    holds the step's logits, their float32 rows and little else."""
+    from mxnet_tpu.serve.decode import _next_program
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = _next_program(None).lower(
+        spec((slots, 1, vocab), jnp.bfloat16),
+        spec((slots, 1), jnp.float32), spec((slots,), bool)).compile()
+    data, last = compiled.output_shardings   # two results, one chip
+    assert data == last == one_chip
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes >= 4 * slots * vocab
+    assert mem.temp_size_in_bytes <= 8 * slots * vocab
+
+
 # the two serve cells' pools: slots, positions, query heads, kv heads
 # (x 64), and the longest prompt a prefill takes
 POOLS = {"opt-1.3b.serve_saturated": (8, 1536, 32, 32, 1024),
