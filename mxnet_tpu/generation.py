@@ -216,7 +216,8 @@ class Generator:
                  expert_scoring="softmax", routed_scaling_factor=1.0,
                  expert_latent=0, shared_expert_hidden=0,
                  experts_held=None, shortconv_kernel=3,
-                 norm_topk_eps=None):
+                 norm_topk_eps=None, attention_layers=None,
+                 parallel_block=False):
         from .parallel import sharding as shd
 
         if quantize not in (None, "int8"):
@@ -260,6 +261,16 @@ class Generator:
             self._btypes = transformer._canon_block_types(block_type,
                                                           num_layers)
         mamba2 = transformer._canon_mamba2(mamba2, self._btypes)
+        # attention that differs by layer: a rolling layer's circular
+        # buffer is sized here where the caller left it to us, and
+        # _rings keeps each one's (rows, window) by its aux prefix
+        self._rings = {}
+        if attention_layers is not None:
+            attention_layers, self._rings = self._size_rings(
+                attention_layers, layer_kinds or self._btypes)
+        # any circular cache, however spelled: what speculation and a
+        # padded remote prefill refuse
+        self._wraps = self._rolling or bool(self._rings)
         # "ssm" here means RECURRENT: any layer whose state has no
         # per-position entries (gated linear attention, Mamba-2 or a
         # gated short convolution's window) — what speculation cannot
@@ -296,7 +307,9 @@ class Generator:
             shared_expert_hidden=shared_expert_hidden,
             experts_held=experts_held,
             shortconv_kernel=shortconv_kernel,
-            norm_topk_eps=norm_topk_eps)
+            norm_topk_eps=norm_topk_eps,
+            attention_layers=attention_layers,
+            parallel_block=parallel_block)
         sym = transformer.get_decode_symbol(**self._decode_opts)
         if quantize:
             arg_params = _quantize_weights(
@@ -442,7 +455,63 @@ class Generator:
                     jnp.dtype(jnp.float32))
         if self._quantize_kv:
             return self._cache_shape, jnp.dtype(jnp.int8)
+        ring = self._ring_of(name)
+        if ring:
+            # a rolling layer's circular buffer: its own row count
+            B, _, width = self._cache_shape
+            return (B, ring[0], width), jnp.dtype(self._cache_dtype)
         return self._cache_shape, jnp.dtype(self._cache_dtype)
+
+    def _size_rings(self, attention_layers, kinds):
+        """(attention_layers with every rolling entry's ``rows``
+        filled in, {aux prefix: (rows, window)}). A buffer the caller
+        did not size holds one window and the widest forward a prompt
+        is fed by: ``window + MXNET_PREFILL_CHUNK - 1`` rows rounded up
+        to 8, at most ``max_len``; with chunked prefill off a prompt
+        arrives whole, and only ``max_len`` rows hold that."""
+        from . import config as _config
+        chunk = int(_config.get("MXNET_PREFILL_CHUNK") or 0)
+        layers = [i for i, k in enumerate(kinds) if k == "attention"]
+        out, rings = [], {}
+        for i, entry in zip(layers, attention_layers):
+            entry = dict(entry)
+            if entry.get("cache") == "rolling":
+                w = int(entry.get("window") or 0)
+                if not entry.get("rows"):
+                    entry["rows"] = self.max_len if chunk < 1 else min(
+                        self.max_len, -(-(w + chunk - 1) // 8) * 8)
+                rings["layer%d_" % i] = (int(entry["rows"]), w)
+            out.append(entry)
+        return tuple(out), rings
+
+    def _ring_of(self, name):
+        """(rows, window) of the circular buffer the aux ``name``
+        belongs to, or None: a full layer's rows, or no cache rows."""
+        return self._rings.get(name.split("_", 1)[0] + "_") \
+            if name.endswith(("_k_cache", "_v_cache")) else None
+
+    @property
+    def ring_feed(self):
+        """The widest forward (new rows at one call) that is safe at
+        any depth: the smallest circular buffer less its window plus
+        one. None without a rolling layer."""
+        return min((rows - w + 1 for rows, w in self._rings.values()),
+                   default=None)
+
+    def check_feed(self, P, feed, what="prompt"):
+        """Raise unless a sequence of ``P`` positions fed ``feed`` new
+        rows at a call keeps every window whole in every circular
+        buffer: either nothing wraps (P fits the buffer) or a call's
+        new rows never overwrite a slot one of them still attends."""
+        for prefix, (rows, w) in self._rings.items():
+            if feed > rows - w + 1 and P > rows:
+                raise ValueError(
+                    "%s of %d positions fed %d rows at a time would "
+                    "overwrite live slots of %sattn's circular cache "
+                    "(%d rows, window %d): feed at most %d rows a "
+                    "call (MXNET_PREFILL_CHUNK) or size the buffer "
+                    "for it" % (what, P, feed, prefix, rows, w,
+                                rows - w + 1))
 
     def _aux_row_shape(self, name, pos):
         """Shape of ONE batch row's exported state for aux ``name`` at
@@ -459,7 +528,12 @@ class Generator:
             return shape[1:]
         if name.endswith(("_k_scale", "_v_scale")):
             return (self._kv_heads, pos)
-        return (self._kv_heads, pos, shape[2] // self._kv_heads)
+        # a circular buffer ships its slots as they lie: the first
+        # ``pos`` while nothing has wrapped, all of them after (slot s
+        # holds the newest position congruent to s, which the importer
+        # reads back from ``pos`` alone)
+        return (self._kv_heads, min(pos, shape[1]),
+                shape[2] // self._kv_heads)
 
     def _wire_rows(self, name, rows, to_wire):
         """One sequence's state between the device's layout and the
@@ -500,15 +574,17 @@ class Generator:
 
     def state_bytes_by_kind(self):
         """Bytes of decode state one slot owns, by kind of state (see
-        _aux_kind); only the kinds this model has. Sums to
-        state_bytes_per_slot()."""
+        _aux_kind, and "kv_window" for a rolling layer's circular
+        rows, which do not grow with max_len); only the kinds this
+        model has. Sums to state_bytes_per_slot()."""
         out = {}
         for name in self._sym.list_auxiliary_states():
             shape, dtype = self._aux_spec(name)
             n = dtype.itemsize
             for d in shape[1:]:
                 n *= int(d)
-            kind = self._aux_kind(name)
+            kind = "kv_window" if self._ring_of(name) \
+                else self._aux_kind(name)
             out[kind] = out.get(kind, 0) + n
         return out
 
@@ -575,7 +651,8 @@ class Generator:
                 full = jax.lax.dynamic_index_in_dim(
                     a[n], r, axis=0, keepdims=False)
                 return full if n.endswith("_state") else \
-                    self._wire_rows(n, full[:pos], True)
+                    self._wire_rows(n, full[:pos], True)   # a ring
+                #                     shorter than pos ships whole
             fn = jax.jit(lambda a, r: {n: _one(a, r, n) for n in a})
             self._loop_cache[("export", pos)] = fn
         host = jax.device_get(fn(aux, jnp.int32(row)))
@@ -630,6 +707,8 @@ class Generator:
                 "prompt (%d) + max_new_tokens (%d) exceeds the cache "
                 "capacity max_len=%d" % (P, max_new_tokens,
                                          self.max_len))
+        # the Generator's own loops prefill a prompt in one forward
+        self.check_feed(P, P)
         return prompt, P
 
     def block_span(self, P, max_new_tokens):
@@ -1006,7 +1085,7 @@ class Generator:
                 draft.batch_size != self.batch_size:
             raise ValueError("draft must share vocab_size/batch_size "
                              "with the target")
-        if self._rolling or getattr(draft, "_rolling", False):
+        if self._wraps or getattr(draft, "_wraps", False):
             # rejected speculative slots could alias older positions in
             # a circular buffer (p_s mis-attribution) — not supported
             raise ValueError("speculative decoding is not supported "
@@ -1123,7 +1202,7 @@ class Generator:
                 "truncated_draft is not supported on a quantize='int8' "
                 "Generator (its stored weights are already int8; build "
                 "the draft from the float checkpoint instead)")
-        if self._rolling:
+        if self._wraps:
             raise ValueError("truncated_draft is not supported with "
                              "rolling caches (speculative decoding "
                              "rejects rolling models outright)")
@@ -1177,7 +1256,7 @@ class Generator:
                 draft.batch_size != self.batch_size:
             raise ValueError("draft must share vocab_size/batch_size "
                              "with the target")
-        if self._rolling or getattr(draft, "_rolling", False):
+        if self._wraps or getattr(draft, "_wraps", False):
             raise ValueError("speculative decoding is not supported "
                              "with rolling caches")
         if self._has_ssm or getattr(draft, "_has_ssm", False):

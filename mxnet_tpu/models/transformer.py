@@ -154,12 +154,15 @@ def _ffn_block(x, dim, hidden, prefix, quantized=False, kind="relu",
 
 def _norm(x, name, kind="layer", eps=1e-5):
     """The block's norm by kind: "layer" (LayerNorm, "<name>_gamma" and
-    "<name>_beta") or "rms" (RMSNorm, "<name>_gamma" alone)."""
+    "<name>_beta"), "layer_gain" (LayerNorm with a gain and no bias:
+    "<name>_gamma" alone) or "rms" (RMSNorm, "<name>_gamma" alone)."""
     if kind == "rms":
         return sym.RMSNorm(x, eps=eps, name=name)
+    if kind == "layer_gain":
+        return sym.LayerNorm(x, eps=eps, name=name, no_bias=True)
     if kind != "layer":
-        raise ValueError("norm must be 'layer' or 'rms', got %r"
-                         % (kind,))
+        raise ValueError("norm must be 'layer', 'layer_gain' or 'rms', "
+                         "got %r" % (kind,))
     return sym.LayerNorm(x, eps=eps, name=name)
 
 
@@ -207,9 +210,12 @@ def _routed_block(x, dim, hidden, num_experts, prefix, top_k=1,
     expert arrays have `count` rows). latent=Z: the experts live in Z
     channels between "<prefix>latent_down_weight" (dim, Z) and
     "<prefix>latent_up_weight" (Z, dim). shared_hidden=Hs: a shared
-    expert "<prefix>shared_w1_weight" (dim, Hs), "<prefix>
-    shared_w2_weight" (Hs, dim), added whole. Returns (y, stats): the
-    layer's output and its int32 counts."""
+    expert "<prefix>shared_w1_weight" (dim, Hs; (dim, 2 Hs) = [gate |
+    up] for "gated_silu"), "<prefix>shared_w2_weight" (Hs, dim), added
+    whole (m experts of width h whose outputs are averaged are ONE of
+    width m h, their gates, ups and downs side by side, the downs
+    times 1 / m: the loader's). Returns (y, stats): the layer's output
+    and its int32 counts."""
     if kind not in ("relu", "relu2", "gated_silu"):
         raise ValueError("ffn must be 'relu', 'relu2' or 'gated_silu', "
                          "got %r" % (kind,))
@@ -241,7 +247,8 @@ def _routed_block(x, dim, hidden, num_experts, prefix, top_k=1,
     if shared_hidden:
         attrs["shared"] = True
         more += [sym.Variable(prefix + "shared_w1_weight",
-                              shape=(dim, int(shared_hidden))),
+                              shape=(dim, int(shared_hidden) *
+                                     (wide // hidden))),
                  sym.Variable(prefix + "shared_w2_weight",
                               shape=(int(shared_hidden), dim))]
     out = sym.contrib.RoutedExperts(x, gate, w1, w2, *more,
@@ -312,6 +319,52 @@ def _mixer_kinds(kinds):
     """The layers of a layer_kinds stack that mix positions (and hold
     decode state), in order."""
     return tuple(k for k in kinds if k not in ("experts", "mlp"))
+
+
+def _canon_attention_layers(spec, n_attention, pos_encoding, window):
+    """attention_layers (get_decode_symbol) as a tuple with one dict
+    for each attention layer: ``window`` (0: none), ``rows`` (a
+    circular buffer's capacity; 0: a full cache of max_len rows) and
+    ``rope`` (whether the layer rotates q and k). None where the stack
+    says these once for all layers."""
+    if spec is None:
+        return None
+    spec = tuple(spec)
+    if len(spec) != n_attention:
+        raise ValueError("attention_layers names each attention layer: "
+                         "got %d entries for %d attention layer(s)"
+                         % (len(spec), n_attention))
+    out = []
+    for entry in spec:
+        entry = dict(entry)
+        w = int(entry.pop("window", window) or 0)
+        cache = entry.pop("cache", "full")
+        rows = int(entry.pop("rows", 0) or 0)
+        pos = entry.pop("pos", "rope" if pos_encoding == "rope"
+                        else "none")
+        if entry or cache not in ("full", "rolling") or \
+                pos not in ("rope", "none") or w < 0:
+            raise ValueError(
+                "an attention_layers entry is dict(window=, cache='full'"
+                " | 'rolling', rows=, pos='rope' | 'none'), got %r"
+                % (dict(entry, window=w, cache=cache, rows=rows,
+                        pos=pos),))
+        if pos == "rope" and pos_encoding != "rope":
+            raise ValueError("a layer with pos='rope' needs "
+                             "pos_encoding='rope' (the positions are "
+                             "an input of the symbol)")
+        if cache == "rolling":
+            if not w or rows < w:
+                raise ValueError(
+                    "a rolling layer needs window > 0 and rows >= "
+                    "window (the circular capacity covers one window),"
+                    " got window=%d rows=%d" % (w, rows))
+        elif rows:
+            raise ValueError("rows is a rolling layer's capacity; a "
+                             "full layer holds max_len rows")
+        out.append({"window": w, "rope": pos == "rope",
+                    "rows": rows if cache == "rolling" else 0})
+    return tuple(out)
 
 
 def _check_pos_encoding(pos_encoding, dim, num_heads):
@@ -396,7 +449,8 @@ def _decode_attention_block(x, num_heads, dim, prefix, max_len, pos,
                             window=0, rolling=False,
                             num_kv_heads=None, kv_quantize=False,
                             scale=None, no_bias=False, head_dim=None,
-                            qk_norm_eps=None, rope_base=None, block=0):
+                            qk_norm_eps=None, rope_base=None, block=0,
+                            scope=None):
     """Incremental variant of _attention_block: identical qkv/proj
     helpers (a training checkpoint binds unchanged), attention routed
     through _contrib_CachedAttention with per-layer k/v cache aux
@@ -407,7 +461,10 @@ def _decode_attention_block(x, num_heads, dim, prefix, max_len, pos,
     where the model states one (default head_dim ** -0.5). head_dim,
     qk_norm_eps: see _qkv_heads. rope_base: the rotation's base where
     it is not 10000. block: the block mask of _contrib_CachedAttention
-    (position i sees position j iff j's block is not after i's)."""
+    (position i sees position j iff j's block is not after i's).
+    rolling: the circular op, whose cache holds max_len rows HERE (the
+    layer's own capacity, not the sequence bound). scope: the plain
+    op's device scope, where the stack names its attention kinds."""
     q, k, v = _qkv_heads(x, num_heads, dim, prefix, quantized,
                          num_kv_heads=num_kv_heads, no_bias=no_bias,
                          head_dim=head_dim, qk_norm_eps=qk_norm_eps)
@@ -417,6 +474,8 @@ def _decode_attention_block(x, num_heads, dim, prefix, max_len, pos,
             raise ValueError("attention_block is built for the plain "
                              "cache only (no rolling, no int8 cache)")
         kw["block"] = int(block)
+    if scope and not (rolling or kv_quantize):
+        kw["scope"] = scope
     if rope_positions is not None:
         # rotate BEFORE caching: cached keys carry their absolute
         # rotation, so each step only rotates the new tokens
@@ -556,7 +615,8 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
                       expert_scoring="softmax",
                       routed_scaling_factor=1.0, expert_latent=0,
                       shared_expert_hidden=0, experts_held=None,
-                      shortconv_kernel=3, norm_topk_eps=None):
+                      shortconv_kernel=3, norm_topk_eps=None,
+                      attention_layers=None, parallel_block=False):
     """Autoregressive-decode twin of get_symbol.
 
     Inputs: data (B, Tnew) token ids for the tokens being appended
@@ -615,8 +675,8 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
 
     The remaining arguments are what the hybrid families' published
     equations need beyond the block above, each with the default that
-    leaves the symbol as it was: norm "layer" | "rms" (RMSNorm has a
-    gamma and no beta) with norm_eps; ffn "relu" | "gated_silu" (fc1
+    leaves the symbol as it was: norm "layer" | "layer_gain" | "rms"
+    (the last two have a gamma and no beta) with norm_eps; ffn "relu" | "gated_silu" (fc1
     twice as wide: [gate | up]); pos_encoding "none" (no position
     enters anywhere, and `positions` is no input of the symbol);
     use_bias=False drops every projection's bias; tie_embeddings
@@ -711,10 +771,6 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
         raise ValueError("kv_quantize is not supported with "
                          "rolling_cache (no int8 variant of the "
                          "circular-buffer op)")
-    if per_row_pos and rolling_cache:
-        raise ValueError("per_row_pos is not supported with "
-                         "rolling_cache (the circular-buffer op has "
-                         "no per-row-position variant)")
     if rolling_cache and has_ssm:
         raise ValueError(
             "rolling_cache is not supported with ssm blocks: the SSM "
@@ -733,6 +789,22 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
             "SSM layers have no attention window (their state decays "
             "continuously; mixed stacks compose — the window applies "
             "to the attention layers)")
+    by_layer = _canon_attention_layers(
+        attention_layers, btypes.count("attention"), pos_encoding,
+        attention_window)
+    if by_layer is not None:
+        if rolling_cache or attention_block:
+            raise ValueError("attention_layers says each layer's cache "
+                             "and mask: no rolling_cache or "
+                             "attention_block beside it")
+        if kv_quantize and any(a["rows"] for a in by_layer):
+            raise ValueError("kv_quantize is not supported with a "
+                             "rolling layer (no int8 variant of the "
+                             "circular-buffer op)")
+    if parallel_block and kinds is not None:
+        raise ValueError("parallel_block is a mixer and an FFN on one "
+                         "norm: spell the stack by block_type, not "
+                         "layer_kinds")
     data = sym.Variable("data")
     positions = sym.Variable("positions")
     cache_pos = sym.Variable("cache_pos") if per_row_pos \
@@ -783,6 +855,27 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
         return sym.contrib.AddScaledF32(
             x, branch, scalar=float(residual_multiplier))
 
+    layer_specs = iter(by_layer or ())
+
+    def attention(a, prefix):
+        # the stack's one window, cache and rotation, or this layer's
+        how = dict(window=attention_window, rolling=rolling_cache,
+                   rope_positions=rope_positions, block=attention_block)
+        rows = max_len
+        if by_layer is not None:
+            spec = next(layer_specs)
+            rows = spec["rows"] or max_len
+            how = dict(window=spec["window"], rolling=bool(spec["rows"]),
+                       rope_positions=rope_positions if spec["rope"]
+                       else None, scope="attn.full")
+        return _decode_attention_block(
+            a, num_heads, dim, prefix, rows, cache_pos,
+            num_kv_heads=num_kv_heads, quantized=quantized,
+            kv_quantize=kv_quantize, scale=attention_scale,
+            no_bias=no_bias, head_dim=head_dim,
+            qk_norm_eps=norm_eps if qk_norm else None,
+            rope_base=rope_base, **how)
+
     def mixer(kind, a, prefix):
         if kind == "ssm":
             return _decode_ssm_block(a, num_heads, dim, prefix,
@@ -796,15 +889,7 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
         if kind == "shortconv":
             return _decode_shortconv_block(a, prefix, max_len,
                                            cache_pos, shortconv_kernel)
-        return _decode_attention_block(
-            a, num_heads, dim, prefix, max_len, cache_pos,
-            num_kv_heads=num_kv_heads, quantized=quantized,
-            rope_positions=rope_positions,
-            window=attention_window, rolling=rolling_cache,
-            kv_quantize=kv_quantize, scale=attention_scale,
-            no_bias=no_bias, head_dim=head_dim,
-            qk_norm_eps=norm_eps if qk_norm else None,
-            rope_base=rope_base, block=attention_block)
+        return attention(a, prefix)
 
     def experts(f, prefix):
         # inference never capacity-drops: every token is served, and
@@ -837,7 +922,9 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
                          mixer(kinds[i], a, prefix))
             continue
         x = residual(x, mixer(btypes[i], a, prefix))
-        f = _norm(x, prefix + "ln2", norm, norm_eps)
+        # the parallel block's FFN reads the layer's one norm too
+        f = a if parallel_block else \
+            _norm(x, prefix + "ln2", norm, norm_eps)
         x = residual(x, (experts if num_experts else dense)(f, prefix))
 
     if head_rows:

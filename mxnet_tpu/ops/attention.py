@@ -23,6 +23,7 @@ numerics are identical everywhere; ops/_pallas.py makes that choice.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 
@@ -910,41 +911,91 @@ def _rope_op(data, positions, base=10000.0, **_):
     return rope(data, positions, base=float(base))
 
 
+def _write_ring(cache, new, pos):
+    """Rows ``new[b]`` into the circular ``cache[b]`` at slots
+    ``(pos[b] + r) % C``: a (B, C, ...) cache, (B, Tn, ...) rows, pos
+    () or (B,). One token never wraps, so a decode step is
+    :func:`_write_rows` at ``pos % C``. A chunk of Tn > 1 rows may
+    wrap once (C >= Tn), and is written as two windows of Tn slots
+    each, read, blended and put back where they lie: the one that ends
+    at the buffer's end (or starts at the first slot written, where
+    nothing wraps) and the buffer's first Tn slots for what wrapped.
+    A rotation of the new rows and two ``dynamic_update_slice`` a row:
+    no scatter (the TPU runs one as a loop over its updates), and only
+    2 Tn rows of the buffer move."""
+    C, Tn = cache.shape[1], new.shape[1]
+    if Tn == 1:
+        return _write_rows(cache, new, pos % C)
+    if Tn > C:
+        raise ValueError("a circular cache of %d rows cannot take %d "
+                         "new rows at once" % (C, Tn))
+    at = jnp.arange(Tn).reshape((1, Tn) + (1,) * (cache.ndim - 2))
+    tail = (0,) * (cache.ndim - 2)
+
+    def blend(buf, rows, row, start, shift, keep):
+        # slot start + i takes rows[(i - shift) % Tn] where keep[i]
+        old = jax.lax.dynamic_slice(
+            buf, (row, start) + tail, (rows.shape[0], Tn) + buf.shape[2:])
+        mix = jnp.where(keep, jnp.roll(rows, shift, axis=1), old)
+        return jax.lax.dynamic_update_slice(buf, mix, (row, start) + tail)
+
+    def one(buf, rows, row, p):
+        s = p % C
+        a = jnp.minimum(s, C - Tn)
+        buf = blend(buf, rows, row, a, s - a, at >= s - a)
+        return blend(buf, rows, row, 0, s - C, at < Tn - (C - s))
+
+    if pos.ndim == 0:
+        return one(cache, new, 0, pos)
+    for b in range(cache.shape[0]):
+        cache = one(cache, new[b:b + 1], b, pos[b])
+    return cache
+
+
 def rolling_cached_attention(query, key, value, k_cache, v_cache, pos,
                              window, scale=None):
     """Sliding-window decode attention over a CIRCULAR cache.
 
     Caches are (B, C, Hkv*hd), token-contiguous like
-    cached_attention's, with fixed capacity C; position p lives
-    in slot p % C, so memory stays O(C) however long generation runs
-    (pair with RoPE — a learned position table would still bound
-    absolute positions). Correctness needs C >= window + Tnew - 1:
-    appending Tnew tokens may overwrite up to Tnew-1 older slots, and
-    every new row must still find its full window (the Generator
-    checks this against the prefill length).
+    cached_attention's, with fixed capacity C; position p of row b
+    lives in slot p % C OF ITS ROW, so memory stays O(C) however long
+    generation runs (pair with RoPE or with no position at all — a
+    learned position table would still bound absolute positions).
+    pos: (1,) int, one depth for every row (a prefill, or a chunk of
+    one at a shared offset), or (B,), one depth a row (the serving slot
+    pool's step: a row prefilled by chunks at a shared offset merges
+    into the pool and steps on from its own depth, in the same
+    buffer). Correctness needs C >= window + Tnew - 1 from the first
+    forward that wraps: appending Tnew tokens may overwrite up to
+    Tnew-1 older slots, and every new row must still find its full
+    window (the Generator sizes the buffer for the chunk it feeds and
+    checks a prompt against it).
 
-    Masking derives each slot's ABSOLUTE position in closed form:
-    after appending through pos_end, slot s holds
-    p_s = pos_end - ((pos_end - s) mod C) — the newest position
-    congruent to s. Valid for query row r iff 0 <= p_s <= p0+r and
-    p0+r - p_s < window."""
+    Masking derives each slot's ABSOLUTE position in closed form from
+    its row's own depth: after appending through pos_end = pos[b] +
+    Tnew - 1, slot s holds p_s = pos_end - ((pos_end - s) mod C) — the
+    newest position congruent to s. Valid for query row r iff
+    0 <= p_s <= pos[b]+r and pos[b]+r - p_s < window. A slot never
+    written reads p_s < 0 (pos_end < C), so a fresh or merged row
+    needs no clearing."""
     B, H, Tn, D = query.shape
     _check_heads(query, k_cache)
     C = k_cache.shape[1]
     if scale is None:
         scale = D ** -0.5
-    p0 = jnp.reshape(pos, ()).astype(jnp.int32)
-    slots = (p0 + jnp.arange(Tn)) % C
-    k_cache = k_cache.at[:, slots].set(
-        _token_rows(key).astype(k_cache.dtype))
-    v_cache = v_cache.at[:, slots].set(
-        _token_rows(value).astype(v_cache.dtype))
-    pos_end = p0 + Tn - 1
-    slot_ids = jnp.arange(C)[None, :]
-    p_s = pos_end - ((pos_end - slot_ids) % C)      # (1, C)
-    rows = p0 + jnp.arange(Tn)[:, None]             # (Tn, 1)
-    valid = (p_s >= 0) & (p_s <= rows) & (rows - p_s < window)
-    out = _attend(query, k_cache, v_cache, valid[None], float(scale))
+    pos = _row_pos(pos, B)
+    with jax.named_scope("attn.window"):
+        k_cache = _write_ring(
+            k_cache, _token_rows(key).astype(k_cache.dtype), pos)
+        v_cache = _write_ring(
+            v_cache, _token_rows(value).astype(v_cache.dtype), pos)
+        p0 = jnp.reshape(pos, (-1, 1, 1))                   # (1|B, 1, 1)
+        pos_end = p0 + Tn - 1
+        slot_ids = jnp.arange(C)[None, None, :]
+        p_s = pos_end - ((pos_end - slot_ids) % C)          # (1|B, 1, C)
+        rows = p0 + jnp.arange(Tn)[None, :, None]           # (1|B, Tn, 1)
+        valid = (p_s >= 0) & (p_s <= rows) & (rows - p_s < window)
+        out = _attend(query, k_cache, v_cache, valid, float(scale))
     return out.astype(query.dtype), k_cache, v_cache
 
 
@@ -957,8 +1008,8 @@ def rolling_cached_attention(query, key, value, k_cache, v_cache, pos,
 def _rolling_cached_attention_op(query, key, value, k_cache, v_cache,
                                  pos, scale=None, window=0, **_):
     """Circular-buffer twin of _contrib_CachedAttention for sliding-
-    window models; max_len is the cache CAPACITY here, not a sequence
-    bound."""
+    window layers; max_len is the cache CAPACITY here, not a sequence
+    bound, and pos is (1,) or one depth a row, (B,)."""
     if not window:
         raise ValueError("_contrib_RollingCachedAttention needs "
                          "window > 0")
@@ -975,14 +1026,20 @@ def _rolling_cached_attention_op(query, key, value, k_cache, v_cache,
           defaults={"scale": None, "max_len": 0, "window": 0,
                     "block": 0})
 def _cached_attention_op(query, key, value, k_cache, v_cache, pos,
-                         scale=None, window=0, block=0, **_):
+                         scale=None, window=0, block=0, scope=None,
+                         **_):
     """(B, H, Tnew, hd) decode attention; k_cache/v_cache
     ((B, max_len, Hkv*hd)) are aux states updated in place (the executor threads them like BN moving
     stats — but unconditionally, since appending to the cache is the
-    op's purpose at inference)."""
-    return cached_attention(query, key, value, k_cache, v_cache, pos,
-                            scale=scale, window=int(window or 0),
-                            block=int(block or 0))
+    op's purpose at inference). scope: a ``jax.named_scope`` around
+    the write and the read, where the graph names one (a stack whose
+    attention differs by layer tells its kinds apart in a device
+    trace: "attn.full" beside the circular op's "attn.window")."""
+    with jax.named_scope(scope) if scope else contextlib.nullcontext():
+        return cached_attention(query, key, value, k_cache, v_cache,
+                                pos, scale=scale,
+                                window=int(window or 0),
+                                block=int(block or 0))
 
 
 def _q8_quantize(x):

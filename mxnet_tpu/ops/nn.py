@@ -264,14 +264,18 @@ def _instance_norm(data, gamma, beta, eps=1e-3, **_):
 
 @register("LayerNorm", arg_names=("data", "gamma", "beta"),
           defaults={"axis": -1, "eps": 1e-5, "output_mean_var": False})
-def _layer_norm(data, gamma, beta, axis=-1, eps=1e-5,
+def _layer_norm(data, gamma, beta=None, axis=-1, eps=1e-5,
                 output_mean_var=False, **_):
+    """no_bias (an attr; ops/shape_hooks.py drops the argument): a
+    gain and no "<name>_beta"."""
     mean = jnp.mean(data, axis=axis, keepdims=True)
     var = jnp.var(data, axis=axis, keepdims=True)
     out = (data - mean) * lax.rsqrt(var + eps)
     bshape = [1] * data.ndim
     bshape[axis] = data.shape[axis]
-    out = out * gamma.reshape(bshape) + beta.reshape(bshape)
+    out = out * gamma.reshape(bshape)
+    if beta is not None:
+        out = out + beta.reshape(bshape)
     if output_mean_var:
         return out, jnp.squeeze(mean, axis), jnp.squeeze(var, axis)
     return out

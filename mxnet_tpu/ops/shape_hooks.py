@@ -118,6 +118,9 @@ def _ln_shapes(shapes, attrs):
 
 
 set_param_shapes("LayerNorm", _ln_shapes)
+set_arg_select("LayerNorm", lambda a: (
+    ("data", "gamma") if str(a.get("no_bias", False)) in
+    ("True", "true", "1") else ("data", "gamma", "beta")))
 
 
 # -- Embedding --------------------------------------------------------------

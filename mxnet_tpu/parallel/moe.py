@@ -252,7 +252,8 @@ def routed_experts(x, gate_w, w1, w2, top_k=1, act="relu",
     with ``latent=(down (D, Z), up (Z, D))``, over the latent width:
     ``down`` runs once a token before the experts, ``up`` once on the
     weighted sum. ``shared=(p (D, Hs), q (Hs, D))`` adds
-    ``act(x p) q`` whole. ``scoring``, ``score_bias``, ``scale``,
+    ``act(x p) q`` whole (p is (D, 2 Hs) = [gate | up] for
+    "gated_silu"). ``scoring``, ``score_bias``, ``scale``,
     ``renorm_eps``: see :func:`route_topk`. Returns ``(y, stats)``: y
     (N, D) in x's dtype; stats int32 = pairs routed (N * k), distinct
     held experts with at least one token, the largest expert batch

@@ -645,10 +645,18 @@ class ContinuousDecoder:
     scale rows at each slot's own depth, halving cache bytes per slot.
     SSM blocks (``block_type="ssm"``) are supported: each slot's state
     is a constant-size blob, so a slot costs the same HBM at any
-    depth. Not supported: rolling caches (the circular-buffer op has
-    no per-row-position variant) and speculative drafts with ssm
-    blocks (no per-position state to roll back) — both raised at
-    construction here, not mid-request.
+    depth. Attention that differs by layer
+    (``Generator(attention_layers=...)``) is supported: a rolling
+    layer's circular rows (``window + MXNET_PREFILL_CHUNK - 1`` of
+    them, whatever ``max_len``) live beside a full layer's ``max_len``
+    rows in the one pool, each row at its own depth; a prompt longer
+    than the chunk prefills by chunks between steps and merges, and a
+    row steps past the buffer and past the window. Not supported: the
+    whole-stack ``rolling_cache`` spelling (its ``max_len`` is the
+    buffer, not the bound on a request), speculative drafts beside a
+    circular cache (a rejected proposal would overwrite a live slot)
+    and with ssm blocks (no per-position state to roll back) — all
+    raised at construction here, not mid-request.
 
     Disaggregated serving (docs/serving.md §disaggregated prefill):
     ``submit(handoff=...)`` admits a sequence whose prefill ran on a
@@ -663,11 +671,11 @@ class ContinuousDecoder:
                  install_sigterm=False, draft=None, lookahead=None):
         if getattr(generator, "_rolling", False):
             raise ValueError(
-                "continuous batching does not support rolling caches "
-                "(the circular-buffer op has no per-row-position "
-                "variant; quantize_kv int8 caches ARE supported — "
-                "drop rolling_cache and size max_len to prompt + "
-                "max_new_tokens instead)")
+                "continuous batching does not support rolling_cache= "
+                "(its max_len is the circular capacity, not the bound "
+                "on prompt + max_new_tokens): give the window layers "
+                "by attention_layers=[dict(window=, cache='rolling'), "
+                "...] and size max_len to prompt + max_new_tokens")
         self._gen = generator
         self._B = int(generator.batch_size)
         self._log = logger or logging.getLogger(__name__)
@@ -790,10 +798,13 @@ class ContinuousDecoder:
                     "pool" % (draft.vocab_size, draft.batch_size,
                               generator.vocab_size,
                               generator.batch_size))
-            if getattr(draft, "_rolling", False):
+            if getattr(draft, "_wraps", False) or \
+                    getattr(generator, "_wraps", False):
                 raise ValueError(
-                    "speculative draft must not use a rolling cache "
-                    "(rejected entries could alias older positions)")
+                    "speculative decoding is not supported beside a "
+                    "rolling cache, the target's or the draft's "
+                    "(rejected entries would overwrite live slots of "
+                    "the circular buffer)")
             # the draft's own per-row-position twin: γ (B, 1) propose
             # steps per round, ONE compiled program across slot
             # turnover — same discipline as the target step
@@ -853,6 +864,8 @@ class ContinuousDecoder:
         self._admit_rounds = 0     # _admit calls that admitted
         self._prefill_rows = 0     # rows every prefill forward RAN
         self._merges = 0           # compiled cache-merge dispatches
+        self._chunks = 0           # chunk forwards of chunked prefills
+        self._chunk_rows = 0       # rows those forwards RAN (1 is real)
         self._step_failures = 0    # steps that raised (_step_failed)
         # diffusion pools, in rows x forwards (a step runs one forward
         # for every active row): all forwards, those that also stored
@@ -1025,6 +1038,15 @@ class ContinuousDecoder:
                 else str(jnp.dtype(gen._cache_dtype))
             kinds.append("KV rows %s (%s), %d bytes" % (
                 dims(gen._cache_shape), kind, by_kind["kv_rows"]))
+        if "kv_window" in by_kind:
+            rings = sorted(set(gen._rings.values()))
+            kinds.append(
+                "rolling KV rows %s (%s, circular: O(1) in max_len) in "
+                "%d layer(s), %d bytes" % (
+                    ", ".join("%dx%d of window %d" % (
+                        r, gen._cache_shape[2], w) for r, w in rings),
+                    jnp.dtype(gen._cache_dtype), len(gen._rings),
+                    by_kind["kv_window"]))
         if "ssm_state" in by_kind:
             kinds.append("ssm state %s (float32, O(1) in max_len), "
                          "%d bytes" % (dims(gen._state_shape),
@@ -1408,6 +1430,12 @@ class ContinuousDecoder:
             raise ValueError(
                 "prompt (%d) + max_new_tokens (%d) exceeds the cache "
                 "capacity max_len=%d" % (P, n, self._gen.max_len))
+        if handoff is None and resume is None:
+            # a prompt prefilled here is fed whole or by chunks: every
+            # circular buffer must keep its window whole under that
+            chunk = prefill_chunk()
+            self._gen.check_feed(
+                P, chunk if chunk and P > chunk else P)
         if self._draft is not None and P + n > self._spec_cap:
             # pool-wide, not per-request: verify rounds write up to
             # lookahead speculative entries past EVERY live row's
@@ -2427,7 +2455,7 @@ class ContinuousDecoder:
         hi = min(lo + prefill_chunk(), P)
         rows = np.stack([req.prompt[lo:hi]] * self._B)
         with _trace.phase("serve.decode.prefill_chunk", parent=req.tc,
-                          slot=slot, lo=lo, hi=hi):
+                          slot=slot, lo=lo, hi=hi, run=self._B):
             try:
                 logits, ch["aux"] = self._gen._forward(
                     ch["aux"], rows.astype(np.float32), lo)
@@ -2442,6 +2470,8 @@ class ContinuousDecoder:
                 req._fail(exc)
                 return
         self._prefill_rows += self._B
+        self._chunks += 1
+        self._chunk_rows += self._B
         ch["pos"] = hi
         self._c_chunks.inc()
         if hi < P:
@@ -2661,6 +2691,8 @@ class ContinuousDecoder:
                 "admit_rounds": self._admit_rounds,
                 "prefill_rows": self._prefill_rows,
                 "merges": self._merges,
+                "chunks": self._chunks,
+                "chunk_rows": self._chunk_rows,
                 "step_failures": self._step_failures,
                 "forwards": self._forwards,
                 # blocks stored, each by the first denoising forward

@@ -95,7 +95,7 @@ class PrefillEngine:
     role = "prefill"                      # the hello frame's identity
 
     def __init__(self, generator, warm_lengths=(), logger=None):
-        if getattr(generator, "_rolling", False):
+        if getattr(generator, "_wraps", False):
             raise ValueError(
                 "prefill disaggregation does not support rolling "
                 "caches (export_kv_rows needs position-aligned rows)")

@@ -238,15 +238,16 @@ class TestContract:
             dec.submit(np.arange(4), 2)
 
     def test_rolling_cache_still_refused(self, params):
-        """Rolling caches remain shared-position only — and the
-        refusal must now name quantize_kv as supported (the contract
-        text changed when the int8 per-row op landed)."""
+        """The whole-stack spelling stays refused in a slot pool (its
+        max_len is the circular capacity, not the bound on a request),
+        and the refusal names the spelling that is served since the
+        circular op reads one depth a row: attention_layers (PR 42)."""
         rolling = Generator(params, V, T, num_layers=L, num_heads=H,
                             dim=DIM, batch_size=B, rolling_cache=True,
                             attention_window=8)
         with pytest.raises(ValueError, match="rolling") as e:
             rolling.serving_decoder()
-        assert "quantize_kv" in str(e.value)
+        assert "attention_layers" in str(e.value)
 
     def test_sampling_contract_checked_at_submit(self, params):
         pool = _gen(params, B)

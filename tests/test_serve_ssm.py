@@ -192,6 +192,7 @@ class TestHandoff:
         """Evacuate a seeded session mid-decode, resume it on a
         second pool: remaining tokens bit-identical — the state blob
         round-trips exactly and the PRNG re-derives its splits."""
+        import threading
         import time
         single = _gen(params, 1)
         p = np.arange(1, 6)
@@ -203,11 +204,23 @@ class TestHandoff:
         d1 = _gen(params, 2).serving_decoder()
         d2 = _gen(params, 2).serving_decoder()
         try:
+            # no race with the clock: the decode thread stops where
+            # the row has emitted three tokens (`on_logits` runs on it,
+            # before the next is picked) until the evacuation is
+            # queued, so the row cannot run out first however late
+            # this thread is scheduled
+            three = threading.Event()
+
+            def hold(req, _row):
+                if len(req.emitted) >= 3 and not three.is_set():
+                    three.set()
+                    end = time.time() + 60.0
+                    while not d1._evac_waiters and time.time() < end:
+                        time.sleep(0.0005)
+
+            d1.on_logits = hold
             fut = d1.submit(p, 16, temperature=0.8, top_k=8, seed=7)
-            deadline = time.time() + 60.0
-            while len(fut.emitted) < 3:
-                assert time.time() < deadline, "3 emitted tokens"
-                time.sleep(0.001)
+            assert three.wait(60.0), "3 emitted tokens"
             assert d1.evacuate() == 1
             with pytest.raises(SessionEvacuated) as ei:
                 fut.result(10.0)

@@ -387,9 +387,10 @@ class TestBatchedPrefill:
 # -- (e) idle timeout ----------------------------------------------------
 class _StallingEngine:
     """Wire-level stall double: streams the real decoder's frames on
-    every call EXCEPT the stalled one, where it emits one frame and
-    then goes silent (socket open, no frames — the failure mode only
-    a per-frame idle timeout can see)."""
+    every call EXCEPT the stalled one (every call, with
+    ``stall_on=None``), where it emits one frame and then goes silent
+    (socket open, no frames — the failure mode only a per-frame idle
+    timeout can see)."""
 
     def __init__(self, dec, stall_on=2):
         self._dec = dec
@@ -402,7 +403,7 @@ class _StallingEngine:
 
     def handle_generate_stream(self, payload, emit):
         self._calls += 1
-        if self._calls != self._stall_on:
+        if self._stall_on not in (None, self._calls):
             return self._dec.handle_generate_stream(payload, emit)
         row = self._dec.handle_generate(payload)
         tail = [int(t) for t in
@@ -465,7 +466,15 @@ class TestIdleTimeout:
         blanket generate deadline."""
         monkeypatch.setenv("MXNET_STREAM_IDLE_TIMEOUT", "0.2")
         dec = ContinuousDecoder(_gen(params, 2))
-        stall = _StallingEngine(dec, stall_on=1)
+        # the clock below is the idle timeouts' alone: the decoder's
+        # programs are compiled before it starts (on a machine shared
+        # by six test workers the prefill and the step compile for
+        # longer than the 10 s this test allows two missed gaps), and
+        # the replica is silent on EVERY call (with only the first one
+        # stalled the replay met a warm decoder and succeeded: what
+        # made the test raise was the compile, not the stall)
+        dec.submit(np.arange(1, 5), 8, eos_id=0).result(120.0)
+        stall = _StallingEngine(dec, stall_on=None)
         srv = ServeServer(stall)
         try:
             cli = ServeClient(srv.host, srv.port,
