@@ -1557,7 +1557,7 @@ class Generator:
         nothing else: a diffusion prefill reads no logits. ``aux`` is
         an R-row state (``_fresh_aux(R)``): ``batch_size`` rows for
         generate(), fewer where a pool admits fewer
-        (serve/decode.py's ``_admit_blocks``), each R a shape of the
+        (serve/decode.py's ``_prefill_group``), each R a shape of the
         one program."""
         args = dict(self._params)
         args["data"] = jnp.asarray(tokens, jnp.float32)

@@ -26,6 +26,20 @@ admission reuses the Generator's ordinary shared-position prefill
 state into the pool with a batch-axis scatter — under ``quantize_kv``
 that merge carries the per-token f32 scale caches alongside the int8
 rows, and SSM state blobs ride the same scatter with no length axis.
+A PREFILL RUNS THE ROWS IT ADMITS, not the pool's width: every
+prefill forward of either kind of pool — a round's group of
+equal-length prompts, one chunk of a chunked prompt, the draft's
+twins of both — runs at the smallest rung of a short ladder of row
+counts that holds its real rows (:func:`_row_rungs`: an eighth of the
+pool, at least one row, and the pool), on a fresh state of that many
+rows (``Generator._fresh_aux(rows)``), and the merge installs the
+real rows from that narrower source. One admission for both pools
+(:meth:`ContinuousDecoder._prefill_group`); a prompt length's first
+sight builds every rung's programs side by side
+(:meth:`ContinuousDecoder._build_rungs`), so a group size first met
+later compiles nothing; ``stats()`` counts the rows run
+(``prefill_rows``, ``chunk_rows``) and the ``admit.prefill`` and
+``serve.decode.prefill_chunk`` spans carry them as ``run``.
 
 Decode is bandwidth-bound and the per-slot state is its dominant HBM
 stream (re-read every step; each weight read once), so shrinking that
@@ -451,23 +465,35 @@ def _merge_program(generator):
 # an eighth of the pool a rung of one row or of a quarter buys nothing
 # the runs' spread (0.6%) would show, and each costs a compile of 4-6 s
 # a prompt length on an empty cache.
+# What the same ladder gave the autoregressive pool (my chip runs,
+# PR 43; TPU v5 lite). The OPT cell (8 slots, 24 layers of 2 048, bf16):
+# one `generator_step` on the device, ms, at 128 / 256 / 512 / 1 024
+# positions: 1 row 5.43 / 9.81 / 12.47 / 22.88, 8 rows - / - / 96.3 /
+# 191.1; 47 of a traced window's 49 groups are one row; tokens/s
+# 394.0 -> 763.0. The command-a cell (4 slots, chunks of 256): a chunk
+# forward 110.0 ms at 4 rows, 22.7 at 1; tokens/s 25.0 -> 99.9. On an
+# empty compile cache the OPT cell's window opened 17 s later (301.3 ->
+# 318.3 s) for the one-row programs of its four prompt lengths.
 def _row_rungs(generator):
-    """The row counts a pool may prefill a length group at, ascending:
-    a group runs at the smallest that holds it
-    (:meth:`ContinuousDecoder._group_rows`), and the pool's width ``B``
-    is the top rung, so a group of any size is ONE prefill. A rule from
-    ``B``, not a knob: an eighth of the pool and the pool (a pool under
-    eight rows has the one rung), and where the caches are split over a
-    mesh's ``data`` axis only the rungs that axis divides. A rung below
-    ``B`` is one more compiled shape of ``block_prefill`` a prompt
-    length (and one of ``fresh_aux`` and of ``cache_merge``), so it
-    stays only where the chip showed that it pays."""
+    """The row counts a pool may prefill at, ascending, for both kinds
+    of pool and every prefill forward they make: a whole-prompt length
+    group runs at the smallest that holds it
+    (:meth:`ContinuousDecoder._group_rows`), a chunk of a chunked
+    prompt, which is ONE row, at the bottom rung, and the pool's width
+    ``B`` is the top rung, so a group of any size is ONE prefill. A
+    rule from ``B``, not a knob: an eighth of the pool, one row under
+    eight slots, and the pool ({1, 4} at 4 slots, {1, 8}, {2, 16},
+    {4, 32}); where the caches are split over a mesh's ``data`` axis,
+    only the rungs that axis divides. A rung below ``B`` is one more
+    compiled shape of the prefill a prompt length (and one of
+    ``fresh_aux`` and of ``cache_merge``), so it stays only where the
+    chip showed that it pays."""
     B = int(generator.batch_size)
     split = 1
     shard = generator._cache_sharding
     if shard is not None and shard.spec[0] is not None:
         split = generator.mesh.shape[shard.spec[0]]
-    return sorted({r for r in (B // 8, B) if r and r % split == 0})
+    return sorted({r for r in (max(1, B // 8), B) if r % split == 0})
 
 
 def _step_program(step, generator):
@@ -504,6 +530,14 @@ def _next_program(whole):
         return jnp.where(use_host, host_tok[:, 0], picked)[:, None], last
 
     return jax.jit(next_tokens, out_shardings=(whole, whole))
+
+
+def _last_rows(logits):
+    """Each row's float32 logits at the last position of a prefill
+    forward, read on the host (the blocking read of an admission): two
+    small eager programs a (rows, positions) shape, which a length's
+    first sight builds with the prefill (:meth:`_build_rungs`)."""
+    return np.asarray(logits[:, -1].astype(jnp.float32))
 
 
 def _row_placement(generator):
@@ -761,7 +795,7 @@ class ContinuousDecoder:
         self._merge_fn = _merge_program(generator)
         self._dmerge_fn = None                 # the draft pool's twin
         self._rungs = _row_rungs(generator)    # rows a prefill may run
-        self._built_lengths = set()            # P0 whose rungs are built
+        self._built_lengths = set()    # (P, draft) whose rungs are built
 
         # -- speculative decoding (docs/serving.md §speculative) --
         # draft=None consults MXNET_SPEC_DRAFT so subprocess replicas
@@ -1621,9 +1655,10 @@ class ContinuousDecoder:
         (:meth:`import_kv_rows`): the caller rebinds ``self._aux`` /
         ``self._daux`` to the result at once, and a reference kept to
         the old pytree raises "Array has been deleted" when read. The
-        loop thread is the one aux mutator. The prefill's own pool
-        (``src``, a fresh pool run through ``generator_step``) is not
-        donated."""
+        loop thread is the one aux mutator. The prefill's own state
+        (``src``, a fresh state of its rung's rows run through
+        ``generator_step``: as wide as the pool or narrower, a shape
+        of the one program each) is not donated."""
         padded = np.zeros((self._B,), np.int32)
         padded[:len(slots)] = slots
         self._merges += 1
@@ -1631,32 +1666,35 @@ class ContinuousDecoder:
         return fn(pool, src, padded, np.int32(len(slots)))
 
     def _group_rows(self, prompts):
-        """A length group as its prefill runs it: the equal-length
-        ``prompts``, then copies of the first up to the smallest rung
-        that holds them (:func:`_row_rungs`). The copies' rows are
-        never merged. ``len()`` of the result is the rows run."""
+        """A prefill forward's tokens as it runs them: the
+        equal-length ``prompts`` (a length group's, or the one chunk
+        of a chunked prompt), then copies of the first up to the
+        smallest rung that holds them (:func:`_row_rungs`), as the
+        float32 the graph's ``data`` takes (converted here, on the
+        host: no program of its own on the device). The copies' rows
+        are never merged. ``len()`` of the result is the rows run."""
         run = next(r for r in self._rungs if r >= len(prompts))
-        return np.stack(list(prompts) +
-                        [prompts[0]] * (run - len(prompts)))
+        return np.stack(list(prompts) + [prompts[0]] *
+                        (run - len(prompts))).astype(np.float32)
 
     def _draft_prefill_rows(self, slot, tokens):
         """Prefill the DRAFT cache for one admitted row from raw token
         ids — the local draft leg of handoff/resume admission (the
         wire blobs carry TARGET rows only; prefill replicas stay
         draft-agnostic). Rides the draft Generator's shared-position
-        prefill graph, chunked by ``MXNET_PREFILL_CHUNK`` when set so
+        prefill graph at the pool's bottom rung of rows (one row is
+        real), chunked by ``MXNET_PREFILL_CHUNK`` when set so
         arbitrary handoff lengths reuse the chunk-width programs
         instead of compiling one prefill shape per length."""
         toks = np.asarray(tokens, np.int64).reshape(-1)
         n = len(toks)
-        aux = self._draft._fresh_aux()
+        aux = self._draft._fresh_aux(self._rungs[0])
         width = prefill_chunk() or n
         lo = 0
         while lo < n:
             hi = min(lo + width, n)
-            rows = np.stack([toks[lo:hi]] * self._B)
             _, aux = self._draft._forward(
-                aux, rows.astype(np.float32), lo)
+                aux, self._group_rows([toks[lo:hi]]), lo)
             lo = hi
         self._daux = self._merge_rows(self._daux, aux, [slot],
                                       draft=True)
@@ -1730,12 +1768,13 @@ class ContinuousDecoder:
         shipped rows directly — no prefill graph call. Fresh prompts:
         one shared-position prefill per distinct prompt length per
         round (all admitted rows start at position 0, so the
-        Generator's ordinary prefill graph serves); cache rows merge
-        into the pool by ONE compiled, donated program over the WHOLE
-        aux pytree (:meth:`_merge_rows`) — under quantize_kv that
-        carries the per-token f32 scale caches alongside the int8 k/v
-        rows (a merged row without its scales would dequant to
-        garbage)."""
+        Generator's ordinary prefill graph serves), at the smallest
+        rung of rows that holds the group (:meth:`_prefill_group`);
+        cache rows merge into the pool by ONE compiled, donated
+        program over the WHOLE aux pytree (:meth:`_merge_rows`) —
+        under quantize_kv that carries the per-token f32 scale caches
+        alongside the int8 k/v rows (a merged row without its scales
+        would dequant to garbage)."""
         with self._lock:
             free = self._free_slots()
             if not free or not self._queue:
@@ -1749,10 +1788,11 @@ class ContinuousDecoder:
         self._publish_pool_gauges()
 
     def _admit_batch(self, batch, free):
-        """The round's work under its ``serve.decode.admit`` phase;
-        each child phase is the boundary of one thing a later change
-        would replace (fresh pool, full-``B`` prefill, the blocking
-        logits read, the cache merge, first-token emission)."""
+        """The round's work under its ``serve.decode.admit`` phase:
+        resumed and handed-off rows straight into their slots, a
+        prompt longer than the chunk into the one chunked prefill (or
+        back to the queue's front behind it), the rest by length,
+        each length group through :meth:`_admit_group`."""
         chunk = prefill_chunk()
         by_len = {}
         waiting = []       # long prompts parked behind an active chunk
@@ -1773,14 +1813,16 @@ class ContinuousDecoder:
                 if self._chunking is None:
                     slot = free.pop(0)
                     self._reserved.add(slot)
-                    self._chunking = {"req": req, "slot": slot,
-                                      "aux": self._gen._fresh_aux(),
-                                      "pos": 0}
+                    # its state is the bottom rung's rows, the fewest
+                    # that hold the one row a chunk is
+                    self._chunking = {
+                        "req": req, "slot": slot, "pos": 0,
+                        "aux": self._gen._fresh_aux(self._rungs[0])}
                     if self._draft is not None and req.speculative:
                         # the draft cache prefills alongside, chunk
-                        # by chunk on the same widths
+                        # by chunk on the same widths and rows
                         self._chunking["daux"] = \
-                            self._draft._fresh_aux()
+                            self._draft._fresh_aux(self._rungs[0])
                 else:
                     waiting.append(req)
                 continue
@@ -1796,135 +1838,166 @@ class ContinuousDecoder:
             with self._lock:
                 self._queue.extendleft(reversed(waiting))
         for P, reqs in sorted(by_len.items()):
-            if self._diff:
-                self._admit_blocks(P, reqs, free)
-                continue
-            rows = np.stack([r.prompt for r in reqs] +
-                            [reqs[0].prompt] * (self._B - len(reqs)))
-            with _trace.phase("admit.fresh_aux"):
-                fresh = self._gen._fresh_aux()
-            with _trace.phase("admit.prefill", P=P, rows=len(reqs)):
-                logits, pref_aux = self._gen._forward(
-                    fresh, rows.astype(np.float32), 0)
-            del fresh
-            self._prefills += 1
-            self._prefill_rows += self._B
-            with _trace.phase("admit.wait"):
-                last = np.asarray(logits[:, -1].astype(jnp.float32))
-            slots = free[:len(reqs)]
-            with _trace.phase("admit.merge", rows=len(reqs)):
-                self._aux = self._merge_rows(self._aux, pref_aux,
-                                             slots)
+            self._admit_group(P, reqs, free)
+
+    def _admit_group(self, P, reqs, free):
+        """One length group of a round, in either kind of pool: its
+        prefill (:meth:`_prefill_group`; the draft's after it where a
+        row speculates), then each row into its slot: an
+        autoregressive row with its first token, picked from the
+        prefill's last logits; a diffusion row with its block state
+        (:meth:`_open_blocks`)."""
+        slots = free[:len(reqs)]
+        last = None
+        if P:
+            last = self._prefill_group(self._gen, P, reqs, slots)
             if self._draft is not None and \
                     any(r.speculative for r in reqs):
-                # the draft's cache rows for this group, one shared-
-                # position prefill on the draft's OWN graph (its
+                # the draft's cache rows for this group, the same rows
+                # through the draft's OWN shared-position graph (its
                 # per-row propose program never sees prefill shapes) —
                 # scattered for the whole group: non-speculative rows'
                 # draft rows are unread garbage either way
-                with _trace.phase("admit.fresh_aux", draft=1):
-                    fresh = self._draft._fresh_aux()
-                with _trace.phase("admit.prefill", P=P,
-                                  rows=len(reqs), draft=1):
-                    _, d_pref = self._draft._forward(
-                        fresh, rows.astype(np.float32), 0)
-                del fresh
-                with _trace.phase("admit.merge", rows=len(reqs),
-                                  draft=1):
-                    self._daux = self._merge_rows(
-                        self._daux, d_pref, slots, draft=True)
-                self._draft_prefills += 1
-                self._c_dprefills.inc()
-            with _trace.phase("admit.emit"):
-                for i, req in enumerate(reqs):
-                    slot = free.pop(0)
-                    self._slots[slot] = req
-                    req.t_admit = _telemetry.now_ms()
-                    req.n_cached = P
-                    tok = req._pick(last[i])
-                    self._emit(req, tok)
-                    self._maybe_finish(slot, tok)
-
-    def _admit_blocks(self, P0, reqs, free):
-        """A diffusion round's group: prompts whose whole blocks but
-        the last are the same ``P0`` positions. One shared-position
-        prefill of those under the block mask, at the rows of the
-        smallest rung that holds the group (:meth:`_group_rows`: the
-        pool's width only where the group needs it), with no logits
-        read (the first tokens come from the first block's denoising
-        forward, which also stores the prompt's last whole block), the
-        merge, and each row's block state written on the device by
-        one compiled program (``block_admit``) queued behind the step
-        in flight: the row joins the step after. A prompt shorter than
-        two blocks prefills nothing."""
-        if P0:
-            if P0 not in self._built_lengths:
-                self._build_rungs(P0)
-            rows = self._group_rows([r.prompt[:P0] for r in reqs])
-            with _trace.phase("admit.fresh_aux"):
-                fresh = self._gen._fresh_aux(len(rows))
-            with _trace.phase("admit.prefill", P=P0, rows=len(reqs),
-                              run=len(rows)):
-                pref_aux = self._gen._prefill(fresh, rows)
-            del fresh
-            self._prefills += 1
-            self._prefill_rows += len(rows)
-            with _trace.phase("admit.merge", rows=len(reqs)):
-                self._aux = self._merge_rows(self._aux, pref_aux,
-                                             free[:len(reqs)])
-        d = self._diff
-        L = d["block_length"]
+                self._prefill_group(self._draft, P, reqs, slots)
         with _trace.phase("admit.emit"):
-            # each row's first block, opened on the prompt's remainder
-            # (the mask id elsewhere), and the clean block its first
-            # forward stores: its prompt's last whole block (none where
-            # the prompt is shorter than a block)
-            rows = {k: np.zeros(v.shape, v.dtype)
-                    for k, v in self._bstate.items()}
-            sel = np.zeros((self._B,), bool)
-            for req in reqs:
+            if self._diff:
+                self._open_blocks(P, reqs, free)
+                return
+            for i, req in enumerate(reqs):
                 slot = free.pop(0)
                 self._slots[slot] = req
                 req.t_admit = _telemetry.now_ms()
-                req.n_cached = P0
-                start = len(req.prompt) // L * L
-                known = req.prompt[start:start + L]
-                rows["ids"][slot] = d["mask_id"]
-                rows["ids"][slot, :len(known)] = known
-                rows["masked"][slot] = np.arange(L) >= len(known)
-                rows["prev"][slot] = req.prompt[start - L:start] \
-                    if start else 0
-                rows["has_prev"][slot] = rows["fused"][slot] = start > 0
-                rows["start"][slot] = start
-                rows["owed"][slot] = req.max_new
-                rows["eos"][slot] = -1 if req.eos_id is None \
-                    else req.eos_id
-                rows["live"][slot] = sel[slot] = True
-            self._bstate = self._block_admit_fn(self._bstate, rows, sel)
+                req.n_cached = P
+                tok = req._pick(last[i])
+                self._emit(req, tok)
+                self._maybe_finish(slot, tok)
 
-    def _build_rungs(self, P0):
-        """The first sight of a prefill length: every rung's programs
-        for it are built here and now (the state, the prefill and the
-        merge, each run once on a state that is thrown away; the merge
-        installs no row), so a group size first met later at this
-        length compiles nothing in the serving path. The rungs'
-        prefills compile side by side, a thread each (the compiler
-        holds no interpreter lock). On an empty compile cache a
-        length's first sight took 12.6 / 10.4 / 13.7 / 17.9 s at 56 /
-        120 / 248 / 504 positions in the SDAR cell, where the pool's
-        width alone took 8.6 / 7.0 / 10.6 / 17.0 and the 2-row program
-        alone compiles for 4.9-6.2 (my chip runs, PR 37)."""
+    def _prefill_forward(self, gen, aux, rows):
+        """One prefill forward of ``rows`` (R, P) token ids from
+        position 0 on the R-row state ``aux``: (logits, caches). A
+        diffusion prefill reads no logits (the first tokens come from
+        the first block's denoising forward), so there the first is
+        None and the head is never computed."""
+        if self._diff:
+            return None, gen._prefill(aux, rows)
+        return gen._forward(aux, rows, 0)
+
+    def _prefill_group(self, gen, P, reqs, slots):
+        """THE prefill of a length group, for both kinds of pool and
+        for the draft (``gen`` is the pool's generator or its draft):
+        the first ``P`` positions of ``reqs``' prompts in one
+        shared-position forward from position 0, at the rows of the
+        smallest rung that holds the group (:meth:`_group_rows`: the
+        pool's width only where the group needs it) on a fresh state
+        of that many rows, merged from it into ``slots`` of ``gen``'s
+        pool. Returns the real rows' float32 logits at the last
+        position, read on the host (None for the draft, whose picks
+        nobody reads, and for a diffusion pool). Each child phase is
+        the boundary of one thing a later change would replace (fresh
+        state, the prefill, the blocking logits read, the merge)."""
+        draft = gen is not self._gen
+        tag = {"draft": 1} if draft else {}
+        if (P, draft) not in self._built_lengths:
+            self._build_rungs(gen, P)
+        rows = self._group_rows([r.prompt[:P] for r in reqs])
+        with _trace.phase("admit.fresh_aux", **tag):
+            fresh = gen._fresh_aux(len(rows))
+        with _trace.phase("admit.prefill", P=P, rows=len(reqs),
+                          run=len(rows), **tag):
+            logits, pref_aux = self._prefill_forward(gen, fresh, rows)
+        del fresh
+        last = None
+        if draft:
+            self._draft_prefills += 1
+            self._c_dprefills.inc()
+        else:
+            self._prefills += 1
+            self._prefill_rows += len(rows)
+            if logits is not None:
+                with _trace.phase("admit.wait"):
+                    last = _last_rows(logits)[:len(reqs)]
+        with _trace.phase("admit.merge", rows=len(reqs), **tag):
+            if draft:
+                self._daux = self._merge_rows(self._daux, pref_aux,
+                                              slots, draft=True)
+            else:
+                self._aux = self._merge_rows(self._aux, pref_aux,
+                                             slots)
+        return last
+
+    def _open_blocks(self, P0, reqs, free):
+        """A diffusion group after its prefill (prompts whose whole
+        blocks but the last are the same ``P0`` positions; a prompt
+        shorter than two blocks prefills nothing): each row's block
+        state written on the device by one compiled program
+        (``block_admit``) queued behind the step in flight, so the row
+        joins the step after. No first token is picked here: it comes
+        from the first block's denoising forward, which also stores
+        the prompt's last whole block."""
+        d = self._diff
+        L = d["block_length"]
+        # each row's first block, opened on the prompt's remainder
+        # (the mask id elsewhere), and the clean block its first
+        # forward stores: its prompt's last whole block (none where
+        # the prompt is shorter than a block)
+        rows = {k: np.zeros(v.shape, v.dtype)
+                for k, v in self._bstate.items()}
+        sel = np.zeros((self._B,), bool)
+        for req in reqs:
+            slot = free.pop(0)
+            self._slots[slot] = req
+            req.t_admit = _telemetry.now_ms()
+            req.n_cached = P0
+            start = len(req.prompt) // L * L
+            known = req.prompt[start:start + L]
+            rows["ids"][slot] = d["mask_id"]
+            rows["ids"][slot, :len(known)] = known
+            rows["masked"][slot] = np.arange(L) >= len(known)
+            rows["prev"][slot] = req.prompt[start - L:start] \
+                if start else 0
+            rows["has_prev"][slot] = rows["fused"][slot] = start > 0
+            rows["start"][slot] = start
+            rows["owed"][slot] = req.max_new
+            rows["eos"][slot] = -1 if req.eos_id is None \
+                else req.eos_id
+            rows["live"][slot] = sel[slot] = True
+        self._bstate = self._block_admit_fn(self._bstate, rows, sel)
+
+    def _build_rungs(self, gen, P):
+        """The first sight of a prefill length (by the pool's
+        generator or by its draft, ``gen``): every rung's programs for
+        it are built here and now (the state, the prefill, the read of
+        its last logits and the merge, each run once on a state that
+        is thrown away; the merge installs no row), so a group size
+        first met later at this length compiles nothing in the serving
+        path. The rungs' prefills compile side by side, a thread each
+        (the compiler holds no interpreter lock). On an empty compile
+        cache a length's first sight took 12.6 / 10.4 / 13.7 / 17.9 s
+        at 56 / 120 / 248 / 504 positions in the SDAR cell, where the
+        pool's width alone took 8.6 / 7.0 / 10.6 / 17.0 and the 2-row
+        program alone compiles for 4.9-6.2 (my chip runs, PR 37)."""
+        draft = gen is not self._gen
+
         def prefilled(run):
-            return self._gen._prefill(self._gen._fresh_aux(run),
-                                      np.zeros((run, P0), np.float32))
+            logits, aux = self._prefill_forward(
+                gen, gen._fresh_aux(run), np.zeros((run, P), np.float32))
+            if logits is not None and not draft:
+                _last_rows(logits)
+            return aux
 
         nothing = np.zeros((self._B,), np.int32)
-        with _trace.phase("admit.build", P=P0, rungs=self._rungs), \
+        merge = self._dmerge_fn if draft else self._merge_fn
+        with _trace.phase("admit.build", P=P, rungs=self._rungs,
+                          **({"draft": 1} if draft else {})), \
                 ThreadPoolExecutor(len(self._rungs)) as pool:
             for pref_aux in pool.map(prefilled, self._rungs):
-                self._aux = self._merge_fn(self._aux, pref_aux, nothing,
-                                           np.int32(0))
-        self._built_lengths.add(P0)
+                if draft:
+                    self._daux = merge(self._daux, pref_aux, nothing,
+                                       np.int32(0))
+                else:
+                    self._aux = merge(self._aux, pref_aux, nothing,
+                                      np.int32(0))
+        self._built_lengths.add((P, draft))
 
     def _emit(self, req, tok):
         """One token emission: latency metrics (TTFT on the first
@@ -2440,12 +2513,17 @@ class ContinuousDecoder:
         once per loop iteration between admission and the (B, 1) step,
         so active sessions pay one chunk-width forward per token
         instead of the whole prompt at once. Chunk forwards ride the
-        Generator's ordinary shared-position graph (one XLA program
-        per chunk width — the ragged final chunk adds at most one
-        more); the per-row (B, 1) step's jit cache never moves. The
-        math is bit-identical to the monolithic prefill: every forward
-        attends the full masked cache buffer, so splitting the query
-        axis changes no reduction a kept position sees."""
+        Generator's ordinary shared-position graph at the pool's
+        BOTTOM RUNG of rows (:func:`_row_rungs`: a chunk is one
+        prompt's, so one row wherever no mesh splits the batch), on
+        the prompt's own state of that many rows, which the final
+        merge installs from: one XLA program per chunk width at that
+        rung (the ragged final chunk adds at most one more) and none
+        at the pool's width; the per-row (B, 1) step's jit cache never
+        moves. The math is bit-identical to the monolithic prefill:
+        every forward attends the full masked cache buffer, so
+        splitting the query axis changes no reduction a kept position
+        sees, and rows are independent."""
         ch = self._chunking
         if ch is None:
             return
@@ -2453,15 +2531,15 @@ class ContinuousDecoder:
         P = len(req.prompt)
         lo = ch["pos"]
         hi = min(lo + prefill_chunk(), P)
-        rows = np.stack([req.prompt[lo:hi]] * self._B)
+        rows = self._group_rows([req.prompt[lo:hi]])
         with _trace.phase("serve.decode.prefill_chunk", parent=req.tc,
-                          slot=slot, lo=lo, hi=hi, run=self._B):
+                          slot=slot, lo=lo, hi=hi, run=len(rows)):
             try:
                 logits, ch["aux"] = self._gen._forward(
-                    ch["aux"], rows.astype(np.float32), lo)
+                    ch["aux"], rows, lo)
                 if "daux" in ch:
                     _, ch["daux"] = self._draft._forward(
-                        ch["daux"], rows.astype(np.float32), lo)
+                        ch["daux"], rows, lo)
             except Exception as exc:      # noqa: BLE001 — the future
                 # is this sequence's one response; a failed chunk must
                 # not kill the decode loop for every other slot
@@ -2469,9 +2547,9 @@ class ContinuousDecoder:
                 self._reserved.discard(slot)
                 req._fail(exc)
                 return
-        self._prefill_rows += self._B
+        self._prefill_rows += len(rows)
         self._chunks += 1
-        self._chunk_rows += self._B
+        self._chunk_rows += len(rows)
         ch["pos"] = hi
         self._c_chunks.inc()
         if hi < P:
@@ -2486,7 +2564,7 @@ class ContinuousDecoder:
             self._draft_prefills += 1
             self._c_dprefills.inc()
         self._prefills += 1
-        last = np.asarray(logits[:1, -1].astype(jnp.float32))
+        last = _last_rows(logits)
         self._chunking = None
         self._reserved.discard(slot)
         self._slots[slot] = req

@@ -400,12 +400,13 @@ def test_decoder_counts_forwards_blocks_and_expert_pairs(served):
     assert st["steps_ahead"] == st["steps"] - 1
     assert st["idle_forwards"] == 0
     assert st["programs"] == (1, 1)
-    # every group is one prefill at the rows of its rung: two a group
-    # in the pool of two, 1 + 8 + 8 in the pool of eight
+    # every group is one prefill at the rows of its rung: 1 + 8 + 8 in
+    # the pool of eight; the pool of two has the rungs 1 and 2, so a
+    # group of either size runs no row but its own
     if st["slots"] == 8:
         assert (st["prefills"], st["prefill_rows"]) == (3, 17)
     else:
-        assert st["prefill_rows"] == 2 * st["prefills"]
+        assert st["prefill_rows"] == st["admitted"] == len(CASES)
 
 
 def _order_of(dec):
@@ -491,13 +492,13 @@ def test_a_slot_freed_and_taken_again_while_a_step_is_in_flight(params):
     one-shot row."""
     prompts = _prompts([8, 9, 11], seed=13)
     dec = _gen(params, 2, 2).serving_decoder()
-    admit, found = dec._admit_blocks, []
+    admit, found = dec._admit_group, []
 
     def admitting(P0, reqs, free):
         found.append((free[0], dec._inflight is not None))
         admit(P0, reqs, free)
 
-    dec._admit_blocks = admitting
+    dec._admit_group = admitting
     try:
         futs = [dec.submit(prompts[0], 2), dec.submit(prompts[1], 12),
                 dec.submit(prompts[2], 6)]
@@ -677,7 +678,7 @@ def test_a_prompt_shorter_than_a_block(params, p, n):
     assert st["blocks_committed"] == -(-(p + n) // L) - 1
 
 
-@pytest.mark.parametrize("slots,late,rows_run", [(2, 1, 2 + 2),
+@pytest.mark.parametrize("slots,late,rows_run", [(2, 1, 1 + 1),
                                                  (16, 2, 2 + 2)])
 def test_a_row_admitted_while_another_is_mid_block(params, slots, late,
                                                    rows_run):
@@ -688,7 +689,7 @@ def test_a_row_admitted_while_another_is_mid_block(params, slots, late,
     its first (fused) forward beside the other's third."""
     prompts = _prompts([9, 10, 11], seed=8)[:1 + late]
     dec = _gen(params, slots, 2).serving_decoder()
-    emit, admit = dec._emit, dec._admit_blocks
+    emit, admit = dec._emit, dec._admit_group
     futs, found = [], []
 
     def spy(req, tok):
@@ -703,7 +704,7 @@ def test_a_row_admitted_while_another_is_mid_block(params, slots, late,
                      for i, r in enumerate(dec._slots) if r is not None)
         admit(P0, reqs, free)
 
-    dec._emit, dec._admit_blocks = spy, admitting
+    dec._emit, dec._admit_group = spy, admitting
     try:
         first = dec.submit(prompts[0], 6)
         rows = [first.result(timeout=60)] + \
@@ -747,8 +748,8 @@ def test_a_fused_step_in_flight_when_a_step_fails(params):
             list(dec._bstate.values()))))
         admit(P0, reqs, free)
 
-    admit, found = dec._admit_blocks, []
-    dec._step_fn, dec._admit_blocks = failing, admitting
+    admit, found = dec._admit_group, []
+    dec._step_fn, dec._admit_group = failing, admitting
     try:
         first = [dec.submit(p, 8) for p in prompts[:2]]
         for f in first:
@@ -940,11 +941,11 @@ def test_a_group_size_first_met_later_compiles_nothing(params):
 
 
 @pytest.mark.parametrize("slots,data,rungs", [
-    (16, 1, [2, 16]), (8, 1, [1, 8]), (4, 1, [4]), (2, 1, [2]),
+    (16, 1, [2, 16]), (8, 1, [1, 8]), (4, 1, [1, 4]), (2, 1, [1, 2]),
     (1, 1, [1]), (32, 1, [4, 32]), (16, 2, [2, 16]), (8, 2, [8]),
     (32, 4, [4, 32]), (16, 4, [16])])
 def test_the_ladder_is_a_rule_from_the_pools_width(slots, data, rungs):
-    """An eighth of the pool and the pool, one rung under eight rows;
+    """An eighth of the pool, one row under eight slots, and the pool;
     where the caches are split over a mesh's `data` axis, only the
     rungs that axis divides."""
     from types import SimpleNamespace
@@ -956,6 +957,30 @@ def test_the_ladder_is_a_rule_from_the_pools_width(slots, data, rungs):
             spec=PartitionSpec("data", None, None))
         gen.mesh = SimpleNamespace(shape={"data": data})
     assert _row_rungs(gen) == rungs
+
+
+@pytest.mark.parametrize("slots", [2, 4])
+def test_a_pool_under_eight_slots_has_a_rung_of_one_row(params, slots):
+    """The bottom rung is one row where an eighth of the pool is less:
+    a lone prompt prefills one row, a group of the pool's width that
+    many, each in ONE prefill, and the rows are the one-shot rows."""
+    prompts = _prompts([9] * (1 + slots), seed=slots)
+    dec = _gen(params, slots, 2).serving_decoder()
+    gate = _in_one_round(dec)
+    try:
+        assert dec._rungs == [1, slots]
+        rows = [dec.submit(prompts[0], 6).result(timeout=120)]
+        gate.clear()
+        futs = [dec.submit(p, 6) for p in prompts[1:]]
+        gate.set()
+        rows += [f.result(timeout=120) for f in futs]
+        st = dec.stats()
+    finally:
+        dec.close(30)
+    assert (st["prefills"], st["prefill_rows"]) == (2, 1 + slots)
+    one = _gen(params, 1, 2)
+    for p, row in zip(prompts, rows):
+        np.testing.assert_array_equal(row, one.generate(p[None], 6)[0])
 
 
 def test_a_pool_over_a_data_mesh_runs_the_rungs_it_can_split(params):

@@ -336,7 +336,10 @@ def test_chunks_then_steps_through_the_pool_match_one_forward(pool):
     # five prompts went by chunks, one whole
     assert stats["chunks"] == sum(-(-len(p) // CHUNK) for p in prompts
                                   if len(p) > CHUNK)
-    assert stats["chunk_rows"] == 2 * stats["chunks"]
+    # each at the bottom rung of the pool of two, which is the one row
+    # a chunk is; the short prompt whole, alone in its group, likewise
+    assert stats["chunk_rows"] == stats["chunks"]
+    assert stats["prefill_rows"] == stats["chunks"] + 1
     assert stats["prefills"] == 6
 
 
@@ -593,7 +596,12 @@ def test_the_lowered_programs_carry_the_two_kinds_names():
         assert "moe.shared" in text and "moe.experts" in text
 
 
-def test_the_chunk_span_says_how_many_rows_ran(monkeypatch):
+@pytest.mark.parametrize("slots", [3, 4, 8])
+def test_the_chunk_span_says_how_many_rows_ran(monkeypatch, slots):
+    """A chunked prompt's forwards run the pool's bottom rung (one row
+    under a mesh-less pool of any width), on a state of that many rows,
+    and the span and the counters say so; the row served is the row the
+    prompt gives alone, prefilled whole."""
     from mxnet_tpu import trace
     seen = []
     real = trace.phase
@@ -604,12 +612,19 @@ def test_the_chunk_span_says_how_many_rows_ran(monkeypatch):
         return real(name, **kw)
 
     monkeypatch.setattr(trace, "phase", phase)
-    with _gen(TOY, 3).serving_decoder() as dec:
-        dec.submit(_prompts([10])[0], 2).result(60.0)
-        assert dec.stats()["chunks"] == 3
-        assert dec.stats()["chunk_rows"] == 9
+    prompt = _prompts([10])[0]
+    with _gen(TOY, slots).serving_decoder() as dec:
+        assert dec._rungs == [1, slots]
+        row = dec.submit(prompt, 2).result(60.0)
+        st = dec.stats()
+        assert dec._gen._step_fn._cache_size() == 2   # (1, 4), (1, 2)
+    assert (st["chunks"], st["chunk_rows"], st["prefill_rows"]) == \
+        (3, 3, 3)
     assert [(k["lo"], k["hi"], k["run"]) for k in seen] == \
-        [(0, 4, 3), (4, 8, 3), (8, 10, 3)]
+        [(0, 4, 1), (4, 8, 1), (8, 10, 1)]
+    config.set_override("MXNET_PREFILL_CHUNK", 0)
+    np.testing.assert_array_equal(
+        row, np.asarray(_gen(TOY, 1).generate(prompt[None], 2))[0])
 
 
 # -- (g) the configuration's count, and the other families' programs ----------------

@@ -36,6 +36,7 @@ from mxnet_tpu.serve import (ContinuousDecoder, PrefillEngine,
                              ServeRouter, ServeServer)
 from mxnet_tpu.serve.decode import prefill_chunk
 from mxnet_tpu.serve.net import ServeClient, stream_idle_timeout
+from test_block_diffusion import _in_one_round
 
 pytestmark = pytest.mark.serve
 
@@ -382,6 +383,182 @@ class TestBatchedPrefill:
         eng.close()
         with pytest.raises(EngineClosed):
             eng.prefill(np.arange(1, 5))
+
+
+# -- (d2) the rows a prefill runs ----------------------------------------
+SLOTS = 8                                      # the rungs 1 and 8
+
+def _spans(monkeypatch, *names):
+    """The attributes of every span of `names` opened from here on."""
+    from mxnet_tpu import trace
+    seen, real = [], trace.phase
+
+    def phase(name, **kw):
+        if name in names:
+            seen.append((name, kw))
+        return real(name, **kw)
+
+    monkeypatch.setattr(trace, "phase", phase)
+    return seen
+
+
+class TestPrefillRungs:
+    """An autoregressive pool's prefill forwards — a whole-prompt
+    group, a chunk, the draft's twins of both — run the smallest rung
+    of `_row_rungs` that holds the rows that are real, on a state of
+    that many rows, as a diffusion pool's do
+    (tests/test_block_diffusion.py): one mechanism for both."""
+
+    @pytest.fixture(scope="class")
+    def groups(self, params):
+        """Groups of 1, 2 and all 8 prompts of one length, each
+        submitted in one breath to an idle pool of 8 slots (the
+        harness's `_warm_groups`), then a second length alone: what
+        the counters rose by, the programs that existed after each,
+        the `admit.*` spans and the rows served."""
+        mp = pytest.MonkeyPatch()
+        seen = _spans(mp, "admit.prefill", "admit.build", "admit.merge")
+        gen = _gen(params, SLOTS)
+        out = {}
+        try:
+            with gen.serving_decoder() as dec:
+                gate = _in_one_round(dec)
+                rng = np.random.RandomState(17)
+                for length, k in [(5, 1), (5, 2), (5, SLOTS), (7, 1)]:
+                    prompts = [rng.randint(1, V, (length,))
+                               for _ in range(k)]
+                    before, n_seen = dec.stats(), len(seen)
+                    gate.clear()
+                    futs = [dec.submit(p, 3) for p in prompts]
+                    gate.set()
+                    rows = [f.result(120.0) for f in futs]
+                    after = dec.stats()
+                    out[length, k] = dict(
+                        {key: after[key] - before[key] for key in (
+                            "prefills", "prefill_rows", "admit_rounds",
+                            "merges")},
+                        prefill_programs=gen._step_fn._cache_size(),
+                        merge_programs=after["merge_programs"],
+                        spans=seen[n_seen:], prompts=prompts, rows=rows)
+        finally:
+            mp.undo()
+        return out
+
+    @pytest.mark.parametrize("k", [1, 2, SLOTS])
+    def test_a_group_is_one_prefill_at_the_rung_that_holds_it(
+            self, params, groups, k):
+        """k prompts of one length make ONE prefill whatever k is; it
+        runs 1 or 8 rows, its span says which, and every row served is
+        the one-shot row."""
+        got = groups[5, k]
+        run = 1 if k == 1 else SLOTS
+        assert (got["admit_rounds"], got["prefills"], got["merges"]) == \
+            (1, 1, 1)
+        assert got["prefill_rows"] == run
+        assert [kw for name, kw in got["spans"]
+                if name == "admit.prefill"] == \
+            [{"P": 5, "rows": k, "run": run}]
+        assert [kw for name, kw in got["spans"]
+                if name == "admit.merge"] == [{"rows": k}]
+        one = _gen(params, 1)
+        for p, row in zip(got["prompts"], got["rows"]):
+            np.testing.assert_array_equal(row, one.generate(p[None], 3)[0])
+
+    def test_a_lengths_first_admission_builds_every_rung(self, groups):
+        """Two prefill programs and two merge programs after the very
+        first admission (one row of one length), `admit.build` there
+        once a length with the rungs it built, and no more however
+        the groups of that length grow; two more prefill programs at
+        the second length's first sight, the merge programs as they
+        were."""
+        assert [groups[5, k]["prefill_programs"]
+                for k in (1, 2, SLOTS)] == [2, 2, 2]
+        assert groups[7, 1]["prefill_programs"] == 4
+        assert {g["merge_programs"] for g in groups.values()} == {2}
+        assert [[kw for name, kw in groups[key]["spans"]
+                 if name == "admit.build"] for key in groups] == \
+            [[{"P": 5, "rungs": [1, SLOTS]}], [], [],
+             [{"P": 7, "rungs": [1, SLOTS]}]]
+
+    @pytest.mark.parametrize("spec", [False, True],
+                             ids=["target", "with-draft"])
+    def test_a_group_size_first_met_later_compiles_nothing(
+            self, params, spec, monkeypatch):
+        """After a length's first admission (one row), groups of two,
+        of three and of the pool's width at that length are served
+        without one backend compile, the draft's prefills with them;
+        the draft's prefill runs the rows the pool's ran."""
+        import jax.monitoring
+        seen = _spans(monkeypatch, "admit.prefill")
+        pool = _gen(params, SLOTS)
+        kw = dict(draft=pool.truncated_draft(num_layers=1),
+                  lookahead=3) if spec else {}
+        compiles = []
+
+        def on_event(name, _secs, **_kw):
+            if name == "/jax/core/compile/backend_compile_duration":
+                compiles.append(name)
+
+        rng = np.random.RandomState(5)
+        with pool.serving_decoder(**kw) as dec:
+            gate = _in_one_round(dec)
+            dec.submit(rng.randint(1, V, (6,)), 6,
+                       speculative=spec).result(120.0)
+            jax.monitoring.register_event_duration_secs_listener(
+                on_event)
+            try:
+                for k in (2, 3, SLOTS):
+                    gate.clear()
+                    futs = [dec.submit(rng.randint(1, V, (6,)), 6,
+                                       speculative=spec)
+                            for _ in range(k)]
+                    gate.set()
+                    for f in futs:
+                        f.result(120.0)
+            finally:
+                jax.monitoring.unregister_event_duration_listener(
+                    on_event)
+            st = dec.stats()
+        assert compiles == []
+        assert (st["prefills"], st["prefill_rows"]) == \
+            (4, 1 + 3 * SLOTS)
+        assert st["draft_prefills"] == (4 if spec else 0)
+        mine = [(a["rows"], a["run"]) for _n, a in seen
+                if "draft" not in a]
+        assert mine == [(1, 1), (2, SLOTS), (3, SLOTS), (SLOTS, SLOTS)]
+        assert [(a["rows"], a["run"]) for _n, a in seen
+                if "draft" in a] == (mine if spec else [])
+
+    @pytest.mark.parametrize("slots,spec", [(3, False), (4, False),
+                                            (8, False), (4, True)])
+    def test_a_chunk_runs_the_bottom_rung(self, params, monkeypatch,
+                                          slots, spec):
+        """A chunked prompt's forwards (and the draft's beside them)
+        run the pool's bottom rung on a state of that many rows: the
+        (slots, chunk) program is never built, and the row served is
+        the monolithic one."""
+        seen = _spans(monkeypatch, "serve.decode.prefill_chunk")
+        p = np.arange(1, 11)                       # 10 > chunk 3
+        want = _gen(params, 1).generate(p[None], 6, eos_id=0)[0]
+        monkeypatch.setenv("MXNET_PREFILL_CHUNK", "3")
+        pool = _gen(params, slots)
+        kw = dict(draft=pool.truncated_draft(num_layers=1),
+                  lookahead=3) if spec else {}
+        with pool.serving_decoder(**kw) as dec:
+            assert dec._rungs == [1, slots]
+            out = dec.submit(p, 6, eos_id=0,
+                             speculative=spec).result(120.0)
+            st = dec.stats()
+            chunk_programs = [g._step_fn._cache_size() for g in
+                              (dec._gen, dec._draft) if g is not None]
+        np.testing.assert_array_equal(out, want)
+        assert (st["chunks"], st["chunk_rows"], st["prefill_rows"]) == \
+            (4, 4, 4)
+        assert (st["prefills"], st["merges"]) == (1, 2 if spec else 1)
+        assert st["draft_prefills"] == int(spec)
+        assert [a["run"] for _n, a in seen] == [1] * 4
+        # widths 3 and 1 at one row each, in the draft as in the pool
+        assert chunk_programs == [2] * (1 + spec)
 
 
 # -- (e) idle timeout ----------------------------------------------------
