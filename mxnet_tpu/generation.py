@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from . import telemetry as _telemetry
 from .executor import _graph_eval_fn
 from .models import transformer
+from .ops import attention as _attention
 
 __all__ = ["Generator", "kv_blob_nbytes", "replay_key",
            "canon_diffusion", "unmask_choice", "block_picks"]
@@ -315,7 +316,19 @@ class Generator:
             arg_params = _quantize_weights(
                 arg_params, sym.list_arguments())
         self._sym = sym
-        eval_fn = _graph_eval_fn(sym, mesh=mesh)
+        graph_fn = _graph_eval_fn(sym, mesh=mesh)
+        # forwards (one a compiled prefill program) in whose trace
+        # _attend took blocks of rows inside a kv head
+        self.attend_split_programs = 0
+
+        def eval_fn(args, aux, rng, train):
+            # runs where a program is traced, never where one is called
+            before = _attention.split_traces()
+            out = graph_fn(args, aux, rng, train)
+            self.attend_split_programs += \
+                _attention.split_traces() > before
+            return out
+
         self._eval_fn = eval_fn
 
         def generator_step(args, aux, rng):

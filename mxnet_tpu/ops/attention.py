@@ -26,6 +26,7 @@ from __future__ import annotations
 import contextlib
 import functools
 import math
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -632,6 +633,50 @@ def _check_heads(query, k_cache):
 # the MXU's pass is 128 rows deep
 _MXU_ROWS = 128
 
+# float32 scores, bytes, that one step of _attend's map may hold. A
+# v5e core has 128 MiB of fast memory, and beside the scores the step
+# keeps their bfloat16 cast (half as many bytes) and the mask broadcast
+# over the group (a quarter) there: 1.75 x 72 MiB = 126 MiB. The
+# command-a cell's sliding layers (68.0 MiB a kv head) are within it
+# and stay whole; its full layer (132.0 MiB) is cut in two. Settled on
+# the chip (my chip runs, PR 44; one chunk forward of that cell, ms on
+# the device): no budget 22.04, 72 MiB 18.64, 36 MiB 18.62 (the
+# sliding layers cut in two as well: 2.91 against 2.97 ms for three,
+# within what two runs differ by), 18 MiB 18.95
+_SCORE_BYTES = 72 << 20
+
+_split = threading.local()
+
+
+def split_traces():
+    """How many times, on this thread, a trace of :func:`_attend` took
+    blocks of rows inside a kv head. A caller that traces a program
+    reads it before and after to learn whether the program splits
+    (``Generator.attend_split_programs``)."""
+    return getattr(_split, "traces", 0)
+
+
+def _score_blocks(B, G, Tn, C):
+    """(Bb, Gb, Tb): the batch rows, the query heads of a group and
+    the rows of a head whose float32 scores, ``Bb * Gb * Tb * C * 4``
+    bytes, one step of :func:`_attend`'s map may hold. (B, G, Tn), a
+    whole kv head, where that is within ``_SCORE_BYTES``. Else batch
+    rows go first (the largest divisor of B that fits), then, at one
+    batch row, the ``G * Tn`` query rows: whole query heads, then rows
+    inside one, the largest block that fits and divides, never under
+    ``_MXU_ROWS`` rows (the smallest such block where none fits)."""
+    def fits(rows):
+        return rows * C * 4 <= _SCORE_BYTES
+
+    for Bb in range(B, 0, -1):
+        if B % Bb == 0 and fits(Bb * G * Tn):
+            return Bb, G, Tn
+    blocks = sorted({h * Tn for h in range(1, G + 1) if G % h == 0}
+                    | {t for t in range(1, Tn + 1) if Tn % t == 0})
+    blocks = [r for r in blocks if r >= _MXU_ROWS] or [G * Tn]
+    rows = max([r for r in blocks if fits(r)] or blocks[:1])
+    return 1, max(1, rows // Tn), min(rows, Tn)
+
 
 def _attend(query, k_cache, v_cache, valid, scale, k_scale=None,
             v_scale=None):
@@ -660,18 +705,45 @@ def _attend(query, k_cache, v_cache, valid, scale, k_scale=None,
     where it lies, and the compiled step holds no loop. A prefill
     chunk (more rows) takes one kv head at a time, in a ``lax.map``
     over the heads that slices that head's lanes out of the cache
-    where it lies: the zeros would double its arithmetic, a head's
-    (B, G*Tn, C) scores are small enough to stay in fast memory
-    between the two products, and the body compiles once (unrolled
-    over 32 heads x 24 layers the prefill program compiled for 58 s a
-    prompt length instead of 11). Measured on a v5e (my chip runs, PR
-    30; us a layer, write + attend, (8, 1536, 32 x 64) bf16): one
-    token 158 batched against 401 a head at a time (228 before,
-    head-major); 1 024 tokens 2 877 mapped and 3 706 unrolled a head
-    at a time against 14 420 batched (6 116 before). An einsum over a
-    (B, C, Hkv, D) view instead makes the TPU compiler copy each whole
-    cache into a head-major layout and back, every call (662 and
-    6 602)."""
+    where it lies: the zeros would double its arithmetic, and the body
+    compiles once (unrolled over 32 heads x 24 layers the prefill
+    program compiled for 58 s a prompt length instead of 11). Measured
+    on a v5e (my chip runs, PR 30; us a layer, write + attend, (8,
+    1536, 32 x 64) bf16): one token 158 batched against 401 a head at
+    a time (228 before, head-major); 1 024 tokens 2 877 mapped and
+    3 706 unrolled a head at a time against 14 420 batched (6 116
+    before). An einsum over a (B, C, Hkv, D) view instead makes the
+    TPU compiler copy each whole cache into a head-major layout and
+    back, every call (662 and 6 602).
+
+    A step of that map must keep its float32 scores in the chip's fast
+    memory between the two products, so it holds no more of them than
+    ``_SCORE_BYTES``: where a head's (B, G*Tn, C) scores are over it
+    the map runs over (kv head, block of rows) instead
+    (:func:`_score_blocks`: batch rows first, then query heads of a
+    group, then rows of a head), every block against all C columns,
+    so a row's softmax is the one it had and blocking changes no
+    reduction. A shape within the budget lowers as it always did.
+    Compiled for a v5e (PR 44; a chunk of 256 tokens, 16 query heads a
+    kv head of 128, bf16): over 4 352 columns a head's scores are 71.3
+    MB and the program keeps them, their bfloat16 cast (half) and the
+    mask broadcast over the group (a quarter) in memory space S(1),
+    with 0.1 MB of temporaries in HBM; over 8 448 columns they are
+    138.4 MB, the product's fusion writes them to HBM and the sum's
+    and the cast's fusions read them back (415 MB a head, 138.5 MB of
+    temporaries), and only the cast stays in S(1); as two blocks of
+    2 048 rows, 69.2 MB, they are in S(1) again. On the chip (my chip
+    runs, PR 44; ms a layer inside one chunk forward): the sliding
+    layer 0.99; the full layer whole 5.19, of which the product that
+    writes the scores 1.56 and the two fusions that read them back
+    1.47 each (8 x 138.4 MB at 751 GB/s: HBM's pace); in two blocks
+    1.77 (0.44 + 0.24 + 0.36, the values' product 0.45, the mask's
+    broadcast 0.22), in four 1.86, in eight 1.80 with the sliding
+    layers, then cut too, at 1.08. The kernel alone, the full layer's
+    shape (wall ms): whole 5.98, two blocks 2.42, four 2.56, eight
+    2.80, sixteen 3.24, blocks of 128 rows 6.38; the sliding layer's
+    whole 1.61, in two 1.64, in four 1.71: nothing gains from blocks
+    smaller than fast memory asks for."""
     B, H, Tn, D = query.shape
     C, F = k_cache.shape[1:]
     Hkv, G = _check_heads(query, k_cache)
@@ -685,11 +757,14 @@ def _attend(query, k_cache, v_cache, valid, scale, k_scale=None,
     # own[m, j]: query head m of a group reads the group's kv head j
     own = jnp.repeat(jnp.eye(g, dtype=bool), G, axis=0)[:, None, :, None]
 
-    def products(q, k, v, ks, vs):
+    def products(q, k, v, ks, vs, valid=valid, own=own):
         """q (B, j, g*G, Tn, D) over k, v (B, C, j, W) [and their
         scales (B, C, j, g)]: (B, j, g*G, Tn, D) for the j groups
-        given."""
-        j = q.shape[1]
+        given. Or a block of it: fewer batch rows, query heads of a
+        group or rows of a head, with ``valid`` and ``own`` cut to
+        match."""
+        B, j, _, Tn = q.shape[:4]
+        G = q.shape[2] // g
         if ks is not None:
             # a row of the block-diagonal query meets one kv head only,
             # so that head's scales multiply the small scores and
@@ -726,7 +801,9 @@ def _attend(query, k_cache, v_cache, valid, scale, k_scale=None,
 
     if batched:
         o = products(q, *lanes(lambda c, w: c.reshape(B, C, J, w)))
-    else:
+        return o.reshape(B, H, Tn, D)
+    Bb, Gb, Tb = _score_blocks(B, G, Tn, C)
+    if (Bb, Gb, Tb) == (B, G, Tn):
         def group(x):
             j, qj = x
             return products(qj[:, None], *lanes(
@@ -735,7 +812,30 @@ def _attend(query, k_cache, v_cache, valid, scale, k_scale=None,
 
         o = jnp.moveaxis(jax.lax.map(
             group, (jnp.arange(J), jnp.moveaxis(q, 1, 0))), 0, 1)
-    return o.reshape(B, H, Tn, D)
+        return o.reshape(B, H, Tn, D)
+
+    # a kv head's scores are over the budget: the map takes (kv head,
+    # block of rows), the kv head outermost; every row still meets all
+    # C columns, so each row's softmax is the one it had
+    _split.traces = split_traces() + 1
+    steps = (J, B // Bb, G // Gb, Tn // Tb)
+
+    def block(x):
+        j, b, t, qb = x                               # qb (Bb, Gb, Tb, D)
+        ok = jax.lax.dynamic_slice(
+            valid, (b * Bb if valid.shape[0] == B else 0, t * Tb, 0),
+            (min(Bb, valid.shape[0]), Tb, C))
+        return products(qb[:, None], *lanes(
+            lambda c, w: jax.lax.dynamic_slice(
+                c, (b * Bb, 0, j * w), (Bb, C, w))[:, :, None]),
+            valid=ok, own=own[:Gb])[:, 0]
+
+    j, b, _, t = (i.reshape(-1) for i in jnp.indices(steps))
+    qb = q.reshape(steps[1], Bb, J, steps[2], Gb, steps[3], Tb, D)
+    o = jax.lax.map(block, (j, b, t, qb.transpose(
+        2, 0, 3, 5, 1, 4, 6, 7).reshape(-1, Bb, Gb, Tb, D)))
+    return o.reshape(steps + (Bb, Gb, Tb, D)).transpose(
+        1, 4, 0, 2, 5, 3, 6, 7).reshape(B, H, Tn, D)
 
 
 def _causal(pos, Tn, C, window, block=0):

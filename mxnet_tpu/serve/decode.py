@@ -2782,6 +2782,11 @@ class ContinuousDecoder:
                 "blocks_committed": self._fused_commits,
                 "tokens_unmasked": self._tokens_unmasked,
                 "steps_ahead": self._steps_ahead,
+                # prefill programs of the pool's generator (and its
+                # draft's) in which _attend split a kv head's rows
+                "attend_split_programs": sum(
+                    g.attend_split_programs
+                    for g in (self._gen, self._draft) if g is not None),
                 "idle_forwards": self._idle_forwards,
                 "moe_assignments": self._moe_assignments,
                 "moe_pairs_here": self._moe_pairs_here,

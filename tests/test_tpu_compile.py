@@ -170,6 +170,46 @@ def test_serve_programs_keep_a_token_contiguous_on_v5e(
     assert prefill.memory_analysis().temp_size_in_bytes < scores // 2
 
 
+# the command-a cell's chunk forward: 256 new rows of 128 query heads
+# over 8 kv heads of 128, against a sliding layer's circular buffer and
+# the full layer's rows (columns)
+CHUNK_ATTENTION = {"sliding": 4352, "full": 8448, "full_unsplit": 8448}
+
+
+@pytest.mark.parametrize("layer", sorted(CHUNK_ATTENTION))
+def test_a_map_step_s_scores_stay_out_of_hbm_on_v5e(
+        one_chip, no_compile_cache, monkeypatch, layer):
+    """`_attend`'s prefill path at the command-a cell's two shapes,
+    compiled for a v5e: a step of the map keeps its float32 scores (a
+    sliding layer's 71.3 MB whole, the full layer's 138.4 MB as two
+    blocks of 2 048 rows) in fast memory, so the program has no
+    temporary in HBM to speak of; with the budget lifted the full
+    layer's scores are one 138.4 MB temporary, which is what the
+    budget is for."""
+    from mxnet_tpu.ops import attention
+    C = CHUNK_ATTENTION[layer]
+    if layer == "full_unsplit":
+        monkeypatch.setattr(attention, "_SCORE_BYTES", 1 << 40)
+
+    def attend(q, k, v, pos):
+        return attention._attend(
+            q, k, v, attention._causal(pos, 256, C, 0), 128 ** -0.5)
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    before = attention.split_traces()
+    compiled = jax.jit(attend).lower(
+        spec((1, 128, 256, 128)), spec((1, C, 1024)), spec((1, C, 1024)),
+        spec((), jnp.int32)).compile()
+    assert attention.split_traces() - before == (layer == "full")
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if layer == "full_unsplit":
+        assert temp >= 4096 * C * 4
+    else:
+        assert temp < 4 << 20
+
+
 @pytest.mark.parametrize("tokens", [16 * 4, 16 * 8, 16 * 60])
 def test_expert_layer_compiles_for_v5e_without_a_dense_buffer(
         one_chip, no_compile_cache, monkeypatch, tokens):
