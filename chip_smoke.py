@@ -67,7 +67,7 @@ class Widths:
     anchors: int
 
 
-# bench.py's _TLM at full width; only the depth is a cut
+# the transformer_lm at full width; only the depth is a cut
 FULL = Widths(vocab=32768, seq=2048, dim=2048, heads=16, ffn=8192,
               layers=4, batch=8, steps_per_epoch=4, epochs=3, slots=4,
               prompts=(16, 48, 128, 16, 48, 128), new_tokens=32,

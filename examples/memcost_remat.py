@@ -11,7 +11,7 @@ forward (TrainStep(remat=True), honoring the reference's
 MXNET_BACKWARD_DO_MIRROR env var), and the ledger comes from the
 compiler itself:
 
-* `TrainStep.cost_analysis` (lowered-HLO flops) shows the PRICE:
+* the compiled step's `cost_analysis()` (flops) shows the PRICE:
   rematerialization re-runs the forward inside the backward, so step
   flops rise by roughly the forward's share;
 * `compiled.memory_analysis()` (XLA's buffer assignment) shows the
@@ -154,8 +154,8 @@ def main():
     # 3. the payoff, where the backend keeps the ledger. Strict shrink
     #    is asserted on TPU only: the CPU backend either reports 0 or
     #    schedules this toy net into the same slab either way — at
-    #    real scale the drop is the whole point (bench.py --remat
-    #    trains 32k-token context that OOMs without it)
+    #    real scale the drop is the whole point (a long context
+    #    that does not fit without it)
     if t_plain > 0:
         assert t_mirror <= t_plain, \
             "remat INCREASED temp memory (%d -> %d)" \

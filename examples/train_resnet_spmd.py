@@ -1,6 +1,7 @@
 """Workload config #2, TPU-native: ResNet over a device mesh with the
 compiled SPMD TrainStep (dp x tp mesh, bf16 compute, f32 master
-weights) — the path bench.py measures. Runs on any device count:
+weights) — the path the `resnet-50.train` cell of BENCHMARK.json
+measures. Runs on any device count:
 `JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
    python examples/train_resnet_spmd.py --num-devices 8`
 """

@@ -96,12 +96,6 @@ define("MXNET_NATIVE_RECORDIO", bool, True,
 define("MXNET_NATIVE_IMAGE", bool, True,
        "use the native C++ batched image decode+crop+resize pipeline "
        "when the augment list allows it")
-define("MXNET_EMBED_GRAD", str, "",
-       "Embedding backward: empty = the default (scatter-add; won the "
-       "staged A/B at the flagship LM shape on the host CPU, not "
-       "measured on the chip) | scatter | segsum = sort + "
-       "segment-sum (kept until the chip decides the traced "
-       "embedding-update headroom)")
 define("MXNET_PROFILER_AUTOSTART", bool, False,
        "start profiler collection at import")
 define("MXNET_PROFILER_MODE", bool, False,
@@ -167,8 +161,7 @@ define("MXNET_TRACE", str, "",
 define("MXNET_PEAK_FLOPS", float, 0.0,
        "peak accelerator FLOP/s hint for MFU reporting: with it set, "
        "tools/telemetry_report.py prints achieved FLOP/s and MFU from "
-       "the step.model_flops gauge (docs/mfu_analysis.md methodology; "
-       "0 = unset)")
+       "the step.model_flops gauge (0 = unset)")
 define("MXNET_SERVE_BUCKETS", str, "1,2,4,8",
        "serving batch buckets (comma-separated, ascending): the "
        "ServeEngine batcher pads each coalesced request group to the "

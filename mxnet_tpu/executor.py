@@ -483,8 +483,9 @@ class Executor:
         return self.outputs
 
     def _variant_flops(self, variant, arg_vals, aux_vals, rng):
-        """XLA ``cost_analysis()`` FLOPs of one jit variant (trace +
-        lower only; see TrainStep.cost_analysis for the same trick).
+        """XLA ``cost_analysis()`` FLOPs of one jit variant, read off the
+        lowered module (trace cost only; ``.compile()`` here would redo
+        the whole XLA compilation).
         None when the backend reports nothing."""
         try:
             if variant == "fwd_bwd":

@@ -406,8 +406,8 @@ def _layer_block(x, num_heads, dim, ffn_hidden, prefix, seq_axis=None,
     if seq_axis:
         # keep the (B, T, C) residual stream T-sharded between layers —
         # without the hint GSPMD re-replicates it around the ring
-        # shard_map boundary (an all-gather per layer, visible in
-        # bench_scaling --seq-parallel). Lenient: inert off-mesh.
+        # shard_map boundary (an all-gather per layer in the compiled
+        # step's text). Lenient: inert off-mesh.
         out._set_attr(__shard_hint__="None,%s,None" % seq_axis)
     return out
 

@@ -6,74 +6,18 @@ pair onto the TPU natively; no custom kernel needed.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 from jax import lax
 
 from .registry import register
 
 
-# Embedding backward default. The staged A/B
-# (benchmark/bench_embgrad.py at the flagship LM shape) has only been
-# run on the host CPU, where scatter-add beat sort+segment-sum; on the
-# chip it is not measured. The segsum formulation stays one env var
-# away until the chip decides, where the traced ~8x-off-roofline
-# scatter+Adam update (bench_out/trace_tlm_summary.txt) is still the
-# open question (ROADMAP Speed 5).
-_EMBED_GRAD_DEFAULT = "scatter"
-
-
 @register("Embedding", arg_names=("data", "weight"), nondiff_inputs=(0,),
           defaults={"input_dim": 0, "output_dim": 0, "dtype": "float32"})
 def _embedding(data, weight, **_):
-    from .. import config as _config
-    choice = _config.get("MXNET_EMBED_GRAD") or _EMBED_GRAD_DEFAULT
-    if choice == "segsum":
-        # backward as sort + segment-sum instead of autodiff's
-        # scatter-add. Same values (duplicate ids accumulate in id
-        # order after a stable sort).
-        return _embedding_segsum(data, weight)
-    if choice != "scatter":
-        raise ValueError(
-            "MXNET_EMBED_GRAD must be 'scatter', 'segsum' or unset "
-            "(measured default: %r), got %r"
-            % (_EMBED_GRAD_DEFAULT, choice))
+    # backward is autodiff's scatter-add; sort + segment-sum read a
+    # third slower on the chip at OPT-1.3B's table (PERF.md §6, PR 45)
     return jnp.take(weight, data.astype(jnp.int32), axis=0)
-
-
-@jax.custom_vjp
-def _embedding_segsum(data, weight):
-    return jnp.take(weight, data.astype(jnp.int32), axis=0)
-
-
-def _embedding_segsum_fwd(data, weight):
-    return _embedding_segsum(data, weight), (data, weight.shape[0])
-
-
-def _embedding_segsum_bwd(res, dy):
-    data, V = res
-    ids = data.astype(jnp.int32).reshape(-1)
-    D = dy.shape[-1]
-    if ids.shape[0] == 0:        # empty batch: reshape(-1) can't infer
-        dw = jnp.zeros((V, D), dy.dtype)
-        return jnp.zeros(data.shape, data.dtype), dw
-    dy2 = dy.reshape(ids.shape[0], D)
-    # stable sort by id, then tell the segment reduce the ids ARE
-    # sorted — otherwise it lowers to the very scatter-add this
-    # experiment exists to beat. Duplicate-id partials accumulate in
-    # f32 here where scatter-add rounds to the weight dtype per step:
-    # bit-equal in f32, equal up to (strictly less) rounding in bf16.
-    order = jnp.argsort(ids, stable=True)
-    dw = jax.ops.segment_sum(
-        jnp.take(dy2, order, axis=0).astype(jnp.float32),
-        jnp.take(ids, order), num_segments=V,
-        indices_are_sorted=True)
-    # ids are not differentiable; they ride the float32-input
-    # convention, so their cotangent is explicit zeros
-    return jnp.zeros(data.shape, data.dtype), dw.astype(dy.dtype)
-
-
-_embedding_segsum.defvjp(_embedding_segsum_fwd, _embedding_segsum_bwd)
 
 
 @register("take", arg_names=("a", "indices"), nondiff_inputs=(1,),

@@ -220,7 +220,7 @@ def _batch_norm(data, gamma, beta, moving_mean, moving_var, eps=1e-3,
         # so XLA fuses the statistics into the neighbouring convolutions.
         # The rewrites tried against it (one-pass closed-form vjp, einsum
         # statistics always and shape-gated, Pallas; and a dense max-pool
-        # backward) all lost on the chip: bench_out/ab_regression.jsonl.
+        # backward) all lost on the chip and were deleted in PR 29.
         # fix_gamma: g is ones_like(gamma), a constant, so gamma's
         # gradient is zero as in the reference.
         xf = data.astype(jnp.float32)

@@ -1158,15 +1158,6 @@ class TrainStep:
         return self._jit_step.lower(params, opt_state, aux, batch,
                                     jnp.asarray(lr, jnp.float32), rng)
 
-    def cost_analysis(self, state, batch, lr, rng):
-        """XLA cost analysis (flops, bytes) of the step — used by bench.py
-        for the MFU estimate. Reads it off the lowered module (trace cost
-        only); .compile() here would redo the whole XLA compilation."""
-        ca = self.lower(state, batch, lr, rng).cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
-        return dict(ca or {})
-
     # -- AOT training export -------------------------------------------------
     def export(self, prefix, state, batch):
         """Serialize the WHOLE training step (forward + backward +

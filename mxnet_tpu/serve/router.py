@@ -753,12 +753,6 @@ class ServeRouter:
         return _DoneFuture(self._dispatch(
             [np.asarray(a) for a in inputs], deadline_ms, session, tc))
 
-    def request(self, inputs, deadline_ms=None, session=None):
-        """Blocking convenience twin of ServeClient.request for
-        in-process callers (the fleet bench drives this)."""
-        return self._dispatch([np.asarray(a) for a in inputs],
-                              deadline_ms, session, None)
-
     def infer(self, *inputs, deadline_ms=None, session=None,
               timeout=None):
         """submit + result in one call (engine-surface parity;

@@ -296,8 +296,8 @@ def test_gspmd_aux_stays_replicated_no_step2_recompile():
     """BN moving stats were placed replicated by init_state but came
     back sharded over fsdp via GSPMD propagation — the drifted layout
     missed the jit cache and every SpecLayout run paid a full step-2
-    recompile (caught by review on the bench_scaling GSPMD row: 1590 ms
-    headline vs 100 ms telemetry p50). The step must pin aux back to
+    recompile (caught by review: a GSPMD run's wall time a step stood
+    far above its telemetry p50). The step must pin aux back to
     the replicated layout, and the executable must be compiled ONCE."""
     net = mx.sym.Variable("data")
     net = mx.sym.FullyConnected(net, name="fc1", num_hidden=32)

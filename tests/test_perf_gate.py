@@ -221,7 +221,7 @@ def test_committed_baselines_parse_and_cover_scenarios(pg):
 def test_gate_reports_dead_scenario_cleanly(pg, tmp_path):
     """A scenario child that dies before producing any journal is a
     gate FAILURE with the child's stderr attached — never a traceback
-    (the bench_common error-stub contract, applied to the gate). The
+    (every exit of the gate is one parseable verdict). The
     child resolves the scenario name itself, so a name only the parent
     knows makes it die deterministically before opening the journal."""
     fp, err = pg.run_scenario("no_such_scenario_xyz",

@@ -427,23 +427,3 @@ class TestTelemetryReport:
         assert summary["serving"]["mean_fill"] >= 1.0
         text = telemetry_report.format_report(summary)
         assert "serving:" in text and "mean batch fill" in text
-
-
-class TestBenchServe:
-    def test_bench_serve_emits_sweep_json(self, capsys):
-        import bench_serve
-        assert bench_serve.main(["--concurrency", "1,2",
-                                 "--requests", "5",
-                                 "--features", str(FEAT),
-                                 "--hidden", "16",
-                                 "--classes", str(CLASSES)]) == 0
-        rec = json.loads(
-            capsys.readouterr().out.strip().splitlines()[-1])
-        assert rec["metric"] == "serve_throughput"
-        assert rec["unit"] == "req/s"
-        assert rec["value"] > 0
-        assert len(rec["sweep"]) == 2
-        row = rec["sweep"][0]
-        assert {"concurrency", "throughput_rps", "latency_ms",
-                "mean_batch_fill"} <= set(row)
-        assert {"p50", "p95", "p99"} <= set(row["latency_ms"])

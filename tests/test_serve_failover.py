@@ -292,9 +292,9 @@ class TestMigration:
         sessions (the caller gets SessionEvacuated, resumable
         elsewhere) instead of killing them, and drains the pool."""
         # pin a benign base handler first: GracefulShutdown CHAINS
-        # whatever is installed, and earlier tests in a full-suite run
-        # leave process-exiting handlers behind (bench_serve's death
-        # stub) that a real SIGTERM would otherwise reach
+        # whatever is installed, and an earlier test in a full-suite
+        # run may leave a process-exiting handler behind that a real
+        # SIGTERM would otherwise reach
         prev = signal.signal(signal.SIGTERM, lambda *_a: None)
         d1 = ContinuousDecoder(_gen(params, 2), install_sigterm=True)
         d2 = _gen(params, 2).serving_decoder()

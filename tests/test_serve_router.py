@@ -562,34 +562,6 @@ class TestRollingRestart:
             assert f.router.infer(x)[0].shape == (1, CLASSES)
 
 
-class TestBenchFleet:
-    @pytest.mark.slow
-    def test_bench_serve_fleet_emits_json(self, capsys):
-        """--replicas N: router + subprocess replicas emit the
-        serve_fleet_throughput line with per-replica fill."""
-        import json
-
-        import bench_serve
-        assert bench_serve.main(["--replicas", "2",
-                                 "--concurrency", "2,4",
-                                 "--requests", "5",
-                                 "--work-ms", "1",
-                                 "--features", str(FEAT),
-                                 "--hidden", "16",
-                                 "--classes", str(CLASSES)]) == 0
-        rec = json.loads(
-            capsys.readouterr().out.strip().splitlines()[-1])
-        assert rec["metric"] == "serve_fleet_throughput"
-        assert rec["replicas"] == 2
-        assert rec["value"] > 0
-        assert len(rec["per_replica_fill"]) == 2
-        assert sum(rec["per_replica_fill"].values()) > 0
-        assert len(rec["sweep"]) == 2
-        assert {"p50", "p95", "p99"} <= \
-            set(rec["sweep"][0]["latency_ms"])
-        assert sum(r["errors"] for r in rec["sweep"]) == 0
-
-
 class TestRouterTelemetry:
     def test_gauges_and_fleet_report(self, pred):
         """The serve.router.* gauges track the fleet, and the

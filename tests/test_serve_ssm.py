@@ -134,7 +134,7 @@ class TestSlotAccounting:
     def test_ssm_slot_beats_attention_slot(self, params):
         """The capacity prize in miniature: even at this toy max_len
         the SSM slot is smaller; the ratio grows linearly with
-        max_len (benchmark/bench_decode.py measures the flagship)."""
+        max_len."""
         attn = Generator(_params(block_type="attention", seed=2),
                          V, T, num_layers=L, num_heads=H, dim=DIM,
                          batch_size=2)

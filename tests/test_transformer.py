@@ -269,40 +269,6 @@ def test_chunked_loss_head_on_mesh():
                                rtol=1e-5, atol=1e-6)
 
 
-def test_segsum_embedding_grad_matches_scatter(monkeypatch):
-    """MXNET_EMBED_GRAD=segsum (sort + segment-sum embedding backward,
-    the staged experiment for the traced scatter-update headroom):
-    bit-equal gradients to autodiff's scatter-add in f32 (duplicate
-    ids included), allclose in bf16 (segsum accumulates duplicates in
-    f32 where scatter rounds per step — strictly less rounding), and
-    alive on an EMPTY batch (reshape(-1) cannot infer there)."""
-    from mxnet_tpu.ops.indexing import _embedding
-    rng = np.random.RandomState(0)
-    ids = jnp.asarray(rng.randint(0, 7, (3, 5)), jnp.float32)
-    w = jnp.asarray(rng.randn(7, 4), jnp.float32)
-    dy = jnp.asarray(rng.randn(3, 5, 4), jnp.float32)
-
-    def grad(w_, dy_):
-        return np.asarray(jax.grad(
-            lambda p: jnp.sum((_embedding(ids, p) *
-                               dy_).astype(jnp.float32)))(w_))
-
-    monkeypatch.delenv("MXNET_EMBED_GRAD", raising=False)
-    g_scatter = grad(w, dy)
-    g_scatter_bf = grad(w.astype(jnp.bfloat16), dy.astype(jnp.bfloat16))
-    monkeypatch.setenv("MXNET_EMBED_GRAD", "segsum")
-    g_segsum = grad(w, dy)
-    g_segsum_bf = grad(w.astype(jnp.bfloat16), dy.astype(jnp.bfloat16))
-    np.testing.assert_array_equal(g_scatter, g_segsum)
-    np.testing.assert_allclose(g_scatter_bf, g_segsum_bf,
-                               rtol=2e-2, atol=2e-2)
-
-    empty = jnp.zeros((2, 0), jnp.float32)
-    g_empty = np.asarray(jax.grad(lambda p: jnp.sum(
-        _embedding(empty, p).astype(jnp.float32)))(w))
-    assert g_empty.shape == w.shape and (g_empty == 0).all()
-
-
 @pytest.mark.slow
 def test_chunked_loss_head_bf16_remat():
     """The production long-context configuration: chunked-CE head

@@ -441,6 +441,22 @@ def _run(args):
     return 0 if ok else 1
 
 
+def require_cpu_fleet():
+    """Call before spawning replica processes. Each child builds a
+    model on its default backend, and a chip belongs to one process:
+    on a chip machine the second child fails or hangs at start-up. So
+    a subprocess fleet runs only where the environment pins the
+    platform to the CPU, where it checks the protocol and counts, not
+    speed. Reads the environment only: the parent must not initialise
+    a backend to find out."""
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() != "cpu":
+        raise SystemExit(
+            "chaos_fleet.py: starts several replica processes, which "
+            "cannot share a chip; set JAX_PLATFORMS=cpu for the "
+            "subprocess fleet (JAX_PLATFORMS is %r)"
+            % os.environ.get("JAX_PLATFORMS"))
+
+
 def main(argv=None):
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--replicas", type=int, default=3)
@@ -479,8 +495,7 @@ def main(argv=None):
         args.default_spec = "kill1@40"
     if args.replica:
         return _replica_child(args)
-    from bench_common import require_cpu_fleet
-    require_cpu_fleet("chaos_fleet.py")
+    require_cpu_fleet()
     return _run(args)
 
 

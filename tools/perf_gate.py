@@ -1125,7 +1125,7 @@ def run_scenario(name, out_dir, timeout=600):
     """Run one scenario subprocess; returns (fingerprint, None) or
     (None, failure_text). A scenario that dies before producing any
     journal is a GATE FAILURE with the child's stderr attached, never
-    an unhandled traceback (the bench_common error-stub contract)."""
+    an unhandled traceback."""
     os.makedirs(out_dir, exist_ok=True)
     env = scenario_env(out_dir)
     # journal + spill open in APPEND mode; a reused --keep dir must

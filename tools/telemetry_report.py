@@ -32,8 +32,7 @@ within 5% in tests/test_telemetry.py).
 
 With ``MXNET_PEAK_FLOPS`` set (peak accelerator FLOP/s), the
 steady-state section also prints achieved FLOP/s and MFU from the
-``step.model_flops`` gauge the Executor records at each compile event
-(docs/mfu_analysis.md methodology).
+``step.model_flops`` gauge the Executor records at each compile event.
 
 ``--stats host:port`` instead queries a live ``ServeServer``'s
 introspection frame (telemetry registry snapshot + engine queue/bucket
@@ -91,9 +90,8 @@ def load(path):
 def _quantile(sorted_vals, q):
     """Exact quantile of an already-sorted list (nearest-rank with the
     numpy 'linear' convention's index rounding). Mirrors
-    mxnet_tpu.telemetry.quantile — kept standalone so this tool (and
-    xplane_summary, which imports it) never drags the framework/jax
-    import."""
+    mxnet_tpu.telemetry.quantile — kept standalone so this tool never
+    drags the framework/jax import."""
     if not sorted_vals:
         return None
     idx = int(round(q * (len(sorted_vals) - 1)))
@@ -165,7 +163,7 @@ def summarize(records):
                 if total_s else None
         out["throughput_curve"] = _curve(steady)
 
-        # MFU (docs/mfu_analysis.md): achieved FLOP/s = the compiled
+        # MFU: achieved FLOP/s = the compiled
         # step's cost-analysis FLOPs (step.model_flops gauge) times
         # steady-state steps/sec; MFU against the MXNET_PEAK_FLOPS
         # hint (read here, at report time — the journal predates it)
