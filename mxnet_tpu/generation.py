@@ -172,7 +172,16 @@ class Generator:
         a slot's bytes count the layers that hold some. A "shortconv"
         layer (ops/shortconv.py, ``shortconv_kernel`` taps) holds ONE
         blob per slot, a (shortconv_kernel - 1, dim) window of gated
-        rows in the cache dtype, and nothing with a length axis.
+        rows in the cache dtype, and nothing with a length axis. An
+        "mla" layer (ops/mla.py: latent attention over a learned
+        selection of keys), sized by ``mla=dict(q_lora_rank=,
+        kv_lora_rank=, qk_nope_head_dim=, qk_rope_head_dim=,
+        v_head_dim=, index_heads=, index_head_dim=, index_topk=)``,
+        holds TWO arrays of rows per slot, each with a width of its
+        own: latent rows (max_len, kv_lora_rank + qk_rope_head_dim)
+        and index-key rows (max_len, index_head_dim), in the cache
+        dtype: a third kind of rows beside k/v rows and their rolling
+        twins, exported, imported and merged as those are.
     expert_scoring, norm_topk_eps, routed_scaling_factor,
     expert_latent, shared_expert_hidden, experts_held :
         The expert layers' routing ("softmax" | "sigmoid" with a
@@ -218,7 +227,7 @@ class Generator:
                  expert_latent=0, shared_expert_hidden=0,
                  experts_held=None, shortconv_kernel=3,
                  norm_topk_eps=None, attention_layers=None,
-                 parallel_block=False):
+                 parallel_block=False, mla=None):
         from .parallel import sharding as shd
 
         if quantize not in (None, "int8"):
@@ -262,6 +271,13 @@ class Generator:
             self._btypes = transformer._canon_block_types(block_type,
                                                           num_layers)
         mamba2 = transformer._canon_mamba2(mamba2, self._btypes)
+        mla = transformer._canon_mla(mla, self._btypes)
+        if mla and self._diffusion:
+            raise ValueError("diffusion is built for the plain cache "
+                             "(an 'mla' layer takes no block mask)")
+        # latent attention over selected keys: what speculation is not
+        # yet held to (_refuse_latent)
+        self._latent = bool(mla)
         # attention that differs by layer: a rolling layer's circular
         # buffer is sized here where the caller left it to us, and
         # _rings keeps each one's (rows, window) by its aux prefix
@@ -310,7 +326,7 @@ class Generator:
             shortconv_kernel=shortconv_kernel,
             norm_topk_eps=norm_topk_eps,
             attention_layers=attention_layers,
-            parallel_block=parallel_block)
+            parallel_block=parallel_block, mla=mla)
         sym = transformer.get_decode_symbol(**self._decode_opts)
         if quantize:
             arg_params = _quantize_weights(
@@ -431,6 +447,16 @@ class Generator:
         self._short_shape = (self.batch_size, int(shortconv_kernel) - 1,
                              int(dim)) \
             if "shortconv" in self._btypes else None
+        # latent attention over selected keys: a position's latent and
+        # its shared rotary key in one row, and the indexer's key in
+        # another (ops/mla.py): rows like k/v rows, each array with a
+        # width of its own
+        self._row_widths = {}
+        if mla:
+            self._row_widths = {
+                "_latent_cache": mla["kv_lora_rank"] +
+                mla["qk_rope_head_dim"],
+                "_index_cache": mla["index_head_dim"]}
         # quantize_kv: k/v live int8 with per-token f32 scale caches —
         # halves decode's dominant HBM stream (the cache is re-read
         # every step; each weight only once)
@@ -450,6 +476,11 @@ class Generator:
         (sizing) and _aux_row_shape (export/import) read, so the
         gauge/slot math can never drift from what is actually
         allocated."""
+        width = self._own_width(name)
+        if width:
+            # latent or index-key rows: max_len of them, their own width
+            return (self._cache_shape[:2] + (width,),
+                    jnp.dtype(self._cache_dtype))
         if name.endswith("_conv_state"):
             # a convolution window (Mamba-2's over x|B|C, or a gated
             # short convolution's over dim): fixed size, served dtype
@@ -474,6 +505,18 @@ class Generator:
             B, _, width = self._cache_shape
             return (B, ring[0], width), jnp.dtype(self._cache_dtype)
         return self._cache_shape, jnp.dtype(self._cache_dtype)
+
+    def _own_width(self, name):
+        """The row width of an aux that keeps rows of its own width
+        (an "mla" layer's latent and index-key rows), else None."""
+        return next((w for suffix, w in self._row_widths.items()
+                     if name.endswith(suffix)), None)
+
+    def _wire_heads(self, name):
+        """How many heads a row of aux ``name`` holds side by side, for
+        the head-major wire format: the kv heads, or ONE for rows that
+        every head shares (latent and index-key rows)."""
+        return 1 if self._own_width(name) else self._kv_heads
 
     def _size_rings(self, attention_layers, kinds):
         """(attention_layers with every rolling entry's ``rows``
@@ -545,8 +588,8 @@ class Generator:
         # ``pos`` while nothing has wrapped, all of them after (slot s
         # holds the newest position congruent to s, which the importer
         # reads back from ``pos`` alone)
-        return (self._kv_heads, min(pos, shape[1]),
-                shape[2] // self._kv_heads)
+        heads = self._wire_heads(name)
+        return (heads, min(pos, shape[1]), shape[2] // heads)
 
     def _wire_rows(self, name, rows, to_wire):
         """One sequence's state between the device's layout and the
@@ -557,12 +600,13 @@ class Generator:
         sits in the export program and in the import scatter."""
         if name.endswith("_state"):
             return rows
+        heads = self._wire_heads(name)
         if to_wire:
             return jnp.moveaxis(
-                rows.reshape(rows.shape[0], self._kv_heads, -1), 0, 1
+                rows.reshape(rows.shape[0], heads, -1), 0, 1
             ).reshape(self._aux_row_shape(name, rows.shape[0]))
         pos = rows.shape[1]
-        return jnp.moveaxis(rows.reshape(self._kv_heads, pos, -1),
+        return jnp.moveaxis(rows.reshape(heads, pos, -1),
                             0, 1).reshape(pos, -1)
 
     def kv_cache_bytes(self):
@@ -577,8 +621,13 @@ class Generator:
         """Which kind of decode state an aux name is, for the sizing
         reports: "scan_state" (Mamba-2), "conv_window" (Mamba-2's or
         a gated short convolution's), "ssm_state" (gated linear
-        attention) or "kv_rows" (k/v rows and their int8 scales:
-        everything with a length axis)."""
+        attention), "latent_rows" and "index_rows" (latent attention's
+        two arrays of rows) or "kv_rows" (k/v rows and their int8
+        scales: everything else with a length axis)."""
+        if name.endswith("_latent_cache"):
+            return "latent_rows"
+        if name.endswith("_index_cache"):
+            return "index_rows"
         if name.endswith("_scan_state"):
             return "scan_state"
         if name.endswith("_conv_state"):
@@ -734,6 +783,15 @@ class Generator:
             total = -(-total // L) * L
         return total
 
+    def _refuse_latent(self, draft):
+        """Speculation over latent and index-key rows is not held to
+        anything yet, the target's or the draft's: refused."""
+        if self._latent or getattr(draft, "_latent", False):
+            raise ValueError(
+                "speculative decoding is not supported with 'mla' "
+                "layers (latent rows whose keys an indexer selects: no "
+                "test holds a verify forward over them yet)")
+
     def _refuse_diffusion(self, what):
         if self._diffusion:
             raise ValueError(
@@ -752,11 +810,13 @@ class Generator:
 
         def place(name):
             shape = self._aux_spec(name)[0]
-            if name.endswith(("_conv_state", "_scan_state")):
+            if name.endswith(("_conv_state", "_scan_state")) or \
+                    self._own_width(name):
                 # Mamba-2 states and a short convolution's window:
                 # batch over 'data' only (the window axis is d_conv-1
                 # long, x|B|C share the last one, and the mixer's
-                # heads need not divide the 'model' axis)
+                # heads need not divide the 'model' axis); latent and
+                # index-key rows, which every head shares, likewise
                 return NamedSharding(self.mesh, PartitionSpec(
                     self._cache_sharding.spec[0],
                     *([None] * (len(shape) - 1))))
@@ -1103,6 +1163,7 @@ class Generator:
             # a circular buffer (p_s mis-attribution) — not supported
             raise ValueError("speculative decoding is not supported "
                              "with rolling caches")
+        self._refuse_latent(draft)
         if self._has_ssm or getattr(draft, "_has_ssm", False):
             # the recurrent state is mutated by EVERY fed token and
             # has no per-position rows — rejected speculative tokens
@@ -1272,6 +1333,7 @@ class Generator:
         if self._wraps or getattr(draft, "_wraps", False):
             raise ValueError("speculative decoding is not supported "
                              "with rolling caches")
+        self._refuse_latent(draft)
         if self._has_ssm or getattr(draft, "_has_ssm", False):
             raise ValueError(
                 "speculative decoding is not supported with ssm "

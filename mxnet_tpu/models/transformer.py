@@ -289,8 +289,8 @@ def _canon_block_types(block_type, num_layers):
     return kinds
 
 
-_LAYER_KINDS = ("attention", "ssm", "mamba2", "shortconv", "experts",
-                "mlp")
+_LAYER_KINDS = ("attention", "ssm", "mamba2", "shortconv", "mla",
+                "experts", "mlp")
 # the mixers whose decode state has no per-position entries
 _RECURRENT = ("ssm", "mamba2", "shortconv")
 
@@ -299,8 +299,8 @@ def _canon_layer_kinds(layer_kinds, num_layers):
     """layer_kinds as a per-layer tuple, or None where the stack is
     spelled the old way (block_type: a mixer and an FFN in every
     layer). Each entry is ONE sublayer: a mixer ("attention" | "ssm" |
-    "mamba2" | "shortconv"), a routed expert layer ("experts") or a
-    dense FFN ("mlp")."""
+    "mamba2" | "shortconv" | "mla"), a routed expert layer ("experts")
+    or a dense FFN ("mlp")."""
     if layer_kinds is None:
         return None
     kinds = tuple(layer_kinds)
@@ -597,6 +597,49 @@ def _decode_shortconv_block(x, prefix, max_len, pos, d_conv):
         name=prefix + "shortconv")
 
 
+def _canon_mla(mla, btypes):
+    """The "mla" layers' sizes as a plain dict with every key of
+    ops.mla.MLA_SIZES (each a positive int: none has a default), or
+    None when no layer is of that kind."""
+    from ..ops.mla import MLA_SIZES
+    if "mla" not in btypes:
+        if mla:
+            raise ValueError("mla sizes given but no layer_kinds entry "
+                             "is 'mla'")
+        return None
+    sizes = dict(mla or {})
+    if set(sizes) != set(MLA_SIZES) or \
+            any(int(v) < 1 for v in sizes.values()) or \
+            int(sizes["qk_rope_head_dim"]) % 2 or \
+            int(sizes["index_head_dim"]) < int(sizes["qk_rope_head_dim"]):
+        raise ValueError(
+            "'mla' layers need mla=dict(%s) with positive sizes, an "
+            "even qk_rope_head_dim and index_head_dim >= "
+            "qk_rope_head_dim, got %r" % ("=, ".join(MLA_SIZES) + "=",
+                                          mla))
+    return {k: int(sizes[k]) for k in MLA_SIZES}
+
+
+def _decode_mla_block(x, num_heads, prefix, max_len, pos, positions,
+                      sizes, rope_base=None, eps=1e-5):
+    """Latent attention over a learned selection of keys
+    (ops/mla.py) on the decode path, the whole mixer in one node: its
+    twelve weights are the operator's own inputs, "<prefix>mla_
+    q_a_weight" (q_lora_rank, dim) ... "<prefix>mla_index_head_weight"
+    (index_heads, dim), each (out, in); quantized= passes them by. Two
+    per-layer aux states with a length axis and widths of their own:
+    "<prefix>mla_latent_cache" (B, max_len, kv_lora_rank +
+    qk_rope_head_dim) and "<prefix>mla_index_cache" (B, max_len,
+    index_head_dim), in the served dtype. Returns (out, counts): the
+    mixer's output and its (2,) int32 [keys visible, keys selected]."""
+    kw = {} if rope_base is None else {"rope_base": float(rope_base)}
+    out = sym.contrib.LatentSelectAttention(
+        x, positions, pos=pos, max_len=max_len,
+        num_heads=int(num_heads), eps=float(eps),
+        name=prefix + "mla", **sizes, **kw)
+    return out[0], out[1]
+
+
 def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
                       dim=128, ffn_hidden=None, num_experts=0,
                       quantized=False, compute_dtype=None,
@@ -616,7 +659,8 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
                       routed_scaling_factor=1.0, expert_latent=0,
                       shared_expert_hidden=0, experts_held=None,
                       shortconv_kernel=3, norm_topk_eps=None,
-                      attention_layers=None, parallel_block=False):
+                      attention_layers=None, parallel_block=False,
+                      mla=None):
     """Autoregressive-decode twin of get_symbol.
 
     Inputs: data (B, Tnew) token ids for the tokens being appended
@@ -669,7 +713,17 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
     sublayers is two entries, so a stack whose FFN differs by layer
     (dense of ffn_hidden in the leading layers, experts of
     expert_hidden after) is spelled as it is: ("shortconv", "mlp",
-    "attention", "experts", ...). block_type then stays at its
+    "attention", "experts", ...). "mla" (under this spelling only;
+    pos_encoding "rope") is latent attention whose keys a learned
+    indexer selects, sized by `mla`, a dict with every key of
+    ops.mla.MLA_SIZES (q_lora_rank, kv_lora_rank, qk_nope_head_dim,
+    qk_rope_head_dim, v_head_dim, index_heads, index_head_dim,
+    index_topk) beside num_heads: two aux states a layer with a length
+    axis and widths of their own, latent rows (B, max_len,
+    kv_lora_rank + qk_rope_head_dim) and index-key rows (B, max_len,
+    index_head_dim); the rotation (half-split pairs, rope_base) turns
+    qk_rope_head_dim channels only: the last of a head, the first of
+    an index head. block_type then stays at its
     default: it is the other spelling, a mixer AND an FFN in every
     layer, and builds the symbol it always built.
 
@@ -709,7 +763,10 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
     the other experts would add is left to their chips. moe_stats=True
     adds a second output, (expert layers, 3) int32: each layer's pairs
     routed, distinct held experts hit and largest expert batch, and
-    with experts_held a fourth column, the pairs computed here.
+    with experts_held a fourth column, the pairs computed here; a
+    stack with "mla" layers adds an output after it (alone where no
+    layer routes), (mla layers, 2) int32: each layer's keys visible
+    and keys selected, summed over rows and positions.
     head_dim: a head size other than dim /
     num_heads (the q block and the out-projection's input are then
     num_heads * head_dim wide; the cache rows Hkv * head_dim).
@@ -731,8 +788,10 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
     if not head_dim and dim % num_heads:
         raise ValueError("dim (%d) must be divisible by num_heads (%d)"
                          % (dim, num_heads))
-    if moe_stats and not num_experts:
-        raise ValueError("moe_stats needs num_experts > 0")
+    if moe_stats and not num_experts and \
+            "mla" not in (layer_kinds or ()):
+        raise ValueError("moe_stats needs num_experts > 0 or an 'mla' "
+                         "layer")
     if head_rows and not per_row_pos:
         raise ValueError("head_rows needs per_row_pos (head_pos is "
                          "one offset a row)")
@@ -757,6 +816,11 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
                              % (num_experts, experts_held))
         experts_held = (first, count)
     mamba2 = _canon_mamba2(mamba2, btypes)
+    mla = _canon_mla(mla, btypes)
+    if mla and pos_encoding != "rope":
+        raise ValueError("'mla' layers rotate a slice of each head: "
+                         "pos_encoding must be 'rope', got %r"
+                         % (pos_encoding,))
     has_ssm = bool(set(_RECURRENT) & set(btypes))
     has_attn = "attention" in btypes
     no_bias = not use_bias
@@ -889,6 +953,12 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
         if kind == "shortconv":
             return _decode_shortconv_block(a, prefix, max_len,
                                            cache_pos, shortconv_kernel)
+        if kind == "mla":
+            out, counts = _decode_mla_block(
+                a, num_heads, prefix, max_len, cache_pos,
+                rope_positions, mla, rope_base=rope_base, eps=norm_eps)
+            key_stats.append(counts)
+            return out
         return attention(a, prefix)
 
     def experts(f, prefix):
@@ -911,7 +981,7 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
         return _ffn_block(f, dim, ffn_hidden, prefix,
                           quantized=quantized, kind=ffn, no_bias=no_bias)
 
-    layer_stats = []
+    layer_stats, key_stats = [], []
     for i in range(num_layers):
         prefix = "layer%d_" % i
         a = _norm(x, prefix + "ln1", norm, norm_eps)
@@ -942,8 +1012,9 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
         logits = sym.contrib.ScaleF32(
             logits, scalar=1.0 / float(logits_scaling))
     if moe_stats:
-        return sym.Group([logits, sym.stack(
-            *layer_stats, axis=0, num_args=len(layer_stats))])
+        return sym.Group([logits] + [
+            sym.stack(*stats, axis=0, num_args=len(stats))
+            for stats in (layer_stats, key_stats) if stats])
     return logits
 
 

@@ -27,5 +27,6 @@ from . import attention     # noqa: F401
 from . import ssm           # noqa: F401
 from . import mamba2        # noqa: F401
 from . import shortconv     # noqa: F401
+from . import mla           # noqa: F401
 from . import custom        # noqa: F401
 from . import shape_hooks   # noqa: F401  (must come after all registrations)

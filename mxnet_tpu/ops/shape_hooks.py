@@ -410,6 +410,33 @@ def _short_conv_shapes(shapes, attrs):
 set_param_shapes("_contrib_ShortConvCached", _short_conv_shapes)
 
 
+def _latent_select_shapes(shapes, attrs):
+    """Everything is sized from data (B, T, D) and the attrs
+    (ops/mla.py): the twelve weights as FullyConnected holds them, the
+    latent rows and the index-key rows of max_len positions, pos."""
+    data = shapes[0]
+    if data is None:
+        return shapes
+    B, T, D = data
+    a = {k: int(attrs.get(k, 0)) for k in (
+        "num_heads", "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+        "qk_rope_head_dim", "v_head_dim", "index_heads",
+        "index_head_dim", "max_len")}
+    H, Lq, L, rd = (a["num_heads"], a["q_lora_rank"], a["kv_lora_rank"],
+                    a["qk_rope_head_dim"])
+    J, Di, C = a["index_heads"], a["index_head_dim"], a["max_len"]
+    want = [data, (T,), (Lq, D), (Lq,),
+            (H * (a["qk_nope_head_dim"] + rd), Lq), (L + rd, D), (L,),
+            (H * (a["qk_nope_head_dim"] + a["v_head_dim"]), L),
+            (D, H * a["v_head_dim"]), (J * Di, Lq), (Di, D), (Di,),
+            (Di,), (J, D), (B, C, L + rd), (B, C, Di), (1,)]
+    return [w if s is None else s for s, w in zip(shapes, want)]
+
+
+set_param_shapes("_contrib_LatentSelectAttention",
+                 _latent_select_shapes)
+
+
 def _rms_shapes(shapes, attrs):
     data = shapes[0]
     if data is None:

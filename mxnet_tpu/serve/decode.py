@@ -734,9 +734,11 @@ class ContinuousDecoder:
             # the block step's head reads the open block's positions
             # alone
             opts["head_rows"] = self._diff["block_length"]
-        # a step reports what its expert layers did (a second output
-        # of counts, read back with the step's other results)
-        opts["moe_stats"] = bool(opts["num_experts"])
+        # a step reports what its expert layers did, and what the
+        # selection of its latent-attention layers did (one more
+        # output of counts each, read back with the step's other
+        # results)
+        opts["moe_stats"] = bool(opts["num_experts"] or opts["mla"])
         sym_p = transformer.get_decode_symbol(**opts)
         if [a for a in sym_p.list_arguments() if a != "head_pos"] != \
                 generator._sym.list_arguments():
@@ -811,6 +813,7 @@ class ContinuousDecoder:
         self._draft = draft
         self._gamma = max(1, int(lookahead)) if lookahead else 4
         if draft is not None:
+            generator._refuse_latent(draft)
             if getattr(generator, "_has_ssm", False) or \
                     getattr(draft, "_has_ssm", False):
                 # the env path (MXNET_SPEC_DRAFT -> truncated_draft)
@@ -918,6 +921,11 @@ class ContinuousDecoder:
         self._moe_pairs_here = 0   # pairs whose expert this chip holds
         self._moe_experts_hit = 0
         self._moe_max_load = 0.0   # largest expert batch over the mean
+        # what the latent-attention layers' selection did, summed over
+        # rows (idle ones too), layers and steps: keys a query could
+        # see, and keys it attended
+        self._dsa_keys_visible = 0
+        self._dsa_keys_selected = 0
         self._imported = 0
         self._resumed = 0
         self._evacuated = 0
@@ -1081,6 +1089,18 @@ class ContinuousDecoder:
                         r, gen._cache_shape[2], w) for r, w in rings),
                     jnp.dtype(gen._cache_dtype), len(gen._rings),
                     by_kind["kv_window"]))
+        for kind, suffix, what in (
+                ("latent_rows", "_latent_cache", "latent rows"),
+                ("index_rows", "_index_cache", "index-key rows")):
+            if kind in by_kind:
+                kinds.append("%s %dx%d (%s, shared by every head) in "
+                             "%d layer(s), %d bytes" % (
+                                 what, gen.max_len,
+                                 gen._row_widths[suffix],
+                                 jnp.dtype(gen._cache_dtype),
+                                 sum(n.endswith(suffix)
+                                     for n in self._aux),
+                                 by_kind[kind]))
         if "ssm_state" in by_kind:
             kinds.append("ssm state %s (float32, O(1) in max_len), "
                          "%d bytes" % (dims(gen._state_shape),
@@ -1125,7 +1145,7 @@ class ContinuousDecoder:
                 "device in place (donated)" % (aliased, held))
         rows = [a.sharding.shard_shape(a.shape) + (a.dtype.itemsize,)
                 for n, a in self._aux.items()
-                if gen._aux_kind(n) == "kv_rows"]
+                if gen._aux_kind(n).endswith("_rows")]
         if rows:
             # (B, C, Hkv*hd) token rows: what one step writes, and how
             lines.append(
@@ -2130,7 +2150,7 @@ class ContinuousDecoder:
                     # the expert layers' counts ride the step's read
                     self._inflight = {
                         "rows": rows, "logits": outs[0], "last": None,
-                        "stats": outs[1] if len(outs) > 1 else None}
+                        "stats": tuple(outs[1:]) or None}
                     self._steps_ahead += last is not None
             finally:
                 # a dispatch that raises loses no token of the step
@@ -2166,7 +2186,13 @@ class ContinuousDecoder:
                     np.ones((self._B,), bool))
             last, stats = jax.device_get((last, stats))
             if stats is not None:
-                self._count_experts(stats)
+                stats = list(stats)
+                if self._gen._decode_opts["num_experts"]:
+                    self._count_experts(stats.pop(0))
+                for keys in stats:
+                    # (mla layers, 2): keys visible, keys selected
+                    self._dsa_keys_visible += int(keys[:, 0].sum())
+                    self._dsa_keys_selected += int(keys[:, 1].sum())
         with _trace.phase("step.emit"):
             mine = [(i, req) for i, req in rows.items()
                     if self._slots[i] is req]
@@ -2792,6 +2818,8 @@ class ContinuousDecoder:
                 "moe_pairs_here": self._moe_pairs_here,
                 "moe_experts_hit": self._moe_experts_hit,
                 "moe_max_load": self._moe_max_load,
+                "dsa_keys_visible": self._dsa_keys_visible,
+                "dsa_keys_selected": self._dsa_keys_selected,
                 "merge_programs": sum(
                     fn._cache_size() for fn in
                     (self._merge_fn, self._dmerge_fn)

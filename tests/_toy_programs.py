@@ -1,7 +1,7 @@
 """The compiled programs of the benchmark's other language-model
 families at toy size, by the sha256 of their StableHLO text (no debug
-information): OPT, Granite, Nemotron and LFM2 `generator_step` and the
-slot pool's `decode_step`, SDAR's `block_step`. A PR that adds a family and
+information): OPT, Granite, Nemotron, LFM2 and Cohere2 `generator_step`
+and the slot pool's `decode_step`, SDAR's `block_step`. A PR that adds a family and
 must leave these programs as they are recomputes them on its parent
 (`cd <parent checkout> && PYTHONPATH=. python <this file>`) and holds
 its own tree to them in a test. The toy configurations are the ones
@@ -20,14 +20,17 @@ def _sha(lowered):
 
 def _generators():
     from mxnet_tpu.generation import Generator
-    from cellbench.models import granite, lfm2_moe, nemotron_h, sdar
+    from cellbench.models import (cohere2_moe, granite, lfm2_moe,
+                                  nemotron_h, sdar)
+    from cellbench.reference import cohere2_moe as cohere2_ref
     from cellbench.reference import granite as granite_ref
     from cellbench.reference import lfm2_moe as lfm2_ref
     from cellbench.reference import nemotron_h as nemotron_ref
     from cellbench.reference import opt as opt_ref
     from cellbench.reference import sdar as sdar_ref
-    from cellbench.tests import (test_granite, test_lfm2_moe,
-                                 test_nemotron_h, test_sdar, toy)
+    from cellbench.tests import (test_cohere2_moe, test_granite,
+                                 test_lfm2_moe, test_nemotron_h,
+                                 test_sdar, toy)
 
     def make(ref, cfg, args):
         cfg = dict(cfg, compute_dtype="float32")
@@ -47,6 +50,8 @@ def _generators():
                            nemotron_h.generator_args)
     yield "lfm2", make(lfm2_ref, test_lfm2_moe.SMALL,
                        lfm2_moe.generator_args)
+    yield "cohere2", make(cohere2_ref, test_cohere2_moe.SMALL,
+                          cohere2_moe.generator_args)
     yield "sdar", make(sdar_ref, test_sdar.SMALL,
                        lambda cfg: sdar.generator_args(
                            cfg, test_sdar.DECK))
