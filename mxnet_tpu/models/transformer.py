@@ -631,7 +631,8 @@ def _decode_mla_block(x, num_heads, prefix, max_len, pos, positions,
     "<prefix>mla_latent_cache" (B, max_len, kv_lora_rank +
     qk_rope_head_dim) and "<prefix>mla_index_cache" (B, max_len,
     index_head_dim), in the served dtype. Returns (out, counts): the
-    mixer's output and its (2,) int32 [keys visible, keys selected]."""
+    mixer's output and its (3,) int32 [keys visible, keys selected,
+    keys computed]."""
     kw = {} if rope_base is None else {"rope_base": float(rope_base)}
     out = sym.contrib.LatentSelectAttention(
         x, positions, pos=pos, max_len=max_len,
@@ -765,8 +766,9 @@ def get_decode_symbol(vocab_size, max_len, num_layers=2, num_heads=4,
     routed, distinct held experts hit and largest expert batch, and
     with experts_held a fourth column, the pairs computed here; a
     stack with "mla" layers adds an output after it (alone where no
-    layer routes), (mla layers, 2) int32: each layer's keys visible
-    and keys selected, summed over rows and positions.
+    layer routes), (mla layers, 3) int32: each layer's keys visible,
+    keys selected and keys computed (columns run x queries), summed
+    over rows and positions.
     head_dim: a head size other than dim /
     num_heads (the q block and the out-projection's input are then
     num_heads * head_dim wide; the cache rows Hkv * head_dim).

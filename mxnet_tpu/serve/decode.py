@@ -926,6 +926,7 @@ class ContinuousDecoder:
         # see, and keys it attended
         self._dsa_keys_visible = 0
         self._dsa_keys_selected = 0
+        self._dsa_keys_computed = 0
         self._imported = 0
         self._resumed = 0
         self._evacuated = 0
@@ -2190,9 +2191,10 @@ class ContinuousDecoder:
                 if self._gen._decode_opts["num_experts"]:
                     self._count_experts(stats.pop(0))
                 for keys in stats:
-                    # (mla layers, 2): keys visible, keys selected
+                    # (mla layers, 3): keys visible, selected, computed
                     self._dsa_keys_visible += int(keys[:, 0].sum())
                     self._dsa_keys_selected += int(keys[:, 1].sum())
+                    self._dsa_keys_computed += int(keys[:, 2].sum())
         with _trace.phase("step.emit"):
             mine = [(i, req) for i, req in rows.items()
                     if self._slots[i] is req]
@@ -2820,6 +2822,7 @@ class ContinuousDecoder:
                 "moe_max_load": self._moe_max_load,
                 "dsa_keys_visible": self._dsa_keys_visible,
                 "dsa_keys_selected": self._dsa_keys_selected,
+                "dsa_keys_computed": self._dsa_keys_computed,
                 "merge_programs": sum(
                     fn._cache_size() for fn in
                     (self._merge_fn, self._dmerge_fn)

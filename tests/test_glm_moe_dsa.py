@@ -216,9 +216,7 @@ def _program_sets(qi, ki, w, pos, k):
     tn = t - pos
     scores = mla.index_scores(jnp.asarray(qi[:, pos:]),
                               jnp.asarray(w[:, pos:]), jnp.asarray(ki))
-    valid = jnp.arange(t)[None, None, :] <= \
-        (pos + jnp.arange(tn))[None, :, None]
-    sel = np.asarray(mla.select_keys(scores, valid, k))
+    sel = np.asarray(mla.select_keys(scores, jnp.int32(pos), k))
     return {(r, pos + q): frozenset(np.flatnonzero(sel[r, q]).tolist())
             for r in range(b) for q in range(tn)}
 
@@ -265,17 +263,87 @@ def test_equal_scores_go_to_the_lower_position():
     row = np.full((1, 1, 24), -1.0, np.float32)
     row[0, 0, 10:17] = np.arange(7) + 2.0
     row[0, 0, [3, 20]] = 1.0
-    sel = mla.select_keys(jnp.asarray(row), jnp.ones((1, 1, 24), bool),
-                          TOPK)
+    sel = mla.select_keys(jnp.asarray(row), jnp.int32(23), TOPK)
     assert np.flatnonzero(np.asarray(sel)[0, 0]).tolist() == \
         [3] + list(range(10, 17))
     # -0.0 ties with +0.0, as a comparison of floats has it
     row[0, 0, 3], row[0, 0, 20] = 0.0, -0.0
     row[0, 0, 10:17], row[0, 0, 21:] = np.arange(7) + 1.0, -0.5
-    sel = mla.select_keys(jnp.asarray(row), jnp.ones((1, 1, 24), bool),
-                          TOPK)
+    sel = mla.select_keys(jnp.asarray(row), jnp.int32(23), TOPK)
     assert np.flatnonzero(np.asarray(sel)[0, 0]).tolist() == \
         [3] + list(range(10, 17))
+
+
+# -- the part over cached rows, a block of columns at a time -------------------
+
+_OP = dict(num_heads=4, qk_nope_head_dim=12, qk_rope_head_dim=4,
+           v_head_dim=8, index_heads=3)
+
+def _mixer(width, depth, topk=TOPK, rows=2, seed=0):
+    """The op alone on a chunk of 8 new positions at `depth` of a
+    buffer `width` columns long, every cached row random (those past
+    the depth too: nothing may read them): (out, stats)."""
+    rng = np.random.default_rng(seed)
+    layer = ref._program_layer(ref.base_key(seed), 0, "mla",
+                               ref.sizes(TOY), jnp.float32)
+    w = {k[4:]: v for k, v in layer.items() if k.startswith("mla_")}
+    x = jnp.asarray(rng.standard_normal((rows, CHUNK, 32)), jnp.float32)
+    latent = rng.standard_normal((rows, 256, 20)).astype(np.float32)
+    index = rng.standard_normal((rows, 256, 8)).astype(np.float32)
+    out, stats, _, _ = jax.jit(
+        lambda *a: mla.latent_select_attention(*a, index_topk=topk, **_OP))(
+        x, depth + jnp.arange(CHUNK, dtype=jnp.float32), w,
+        jnp.asarray(latent[:, :width]), jnp.asarray(index[:, :width]),
+        jnp.full((1,), depth, jnp.int32))
+    return np.asarray(out), np.asarray(stats).tolist()
+
+
+@pytest.mark.parametrize("depth", [0, 8, 24, 40, 56])
+def test_a_chunk_computes_the_columns_to_its_own_depth(depth, monkeypatch):
+    """Blocks of 16 columns (a quarter of the short buffer; in the
+    long one the budget holds a row's 4 x 8 float32 scores of as
+    many): a chunk that ends at `depth + 8` runs the blocks up to
+    there and no others, and the columns past them change nothing,
+    bit for bit: a buffer four times as long gives the same output
+    and counts."""
+    monkeypatch.setattr(mla, "_SCORE_BYTES", 4 * CHUNK * 16 * 4)
+    assert mla._block_width(CHUNK, 4, 64) == \
+        mla._block_width(CHUNK, 4, 256) == 16
+    out, (visible, selected, computed) = _mixer(64, depth)
+    assert computed == 2 * CHUNK * -(-(depth + CHUNK) // 16) * 16
+    assert visible == 2 * sum(depth + r + 1 for r in range(CHUNK))
+    assert selected == 2 * sum(min(depth + r + 1, TOPK)
+                               for r in range(CHUNK))
+    longer, counts = _mixer(256, depth)
+    np.testing.assert_array_equal(longer, out)
+    assert counts == [visible, selected, computed]
+
+
+def test_a_forward_no_deeper_than_the_keys_kept_selects_nothing():
+    """With `deepest <= index_topk` every visible row is kept by
+    definition: the forward gives what the configuration that keeps
+    every key (`index_topk = max_len`: no indexer at all) gives, bit
+    for bit; one position deeper it does not. (The branch it takes
+    holds no indexer and no selection: the lowered programs' test.)"""
+    out, (visible, selected, _) = _mixer(64, 8, topk=16)
+    assert selected == visible
+    every, counts = _mixer(64, 8, topk=64)
+    np.testing.assert_array_equal(out, every)
+    assert counts[:2] == [visible, visible]
+    out, (visible, selected, _) = _mixer(64, 9, topk=16)
+    assert selected == visible - 2 * 1
+    assert np.abs(out - _mixer(64, 9, topk=64)[0]).max() > 1e-3
+
+def test_the_pool_counts_the_keys_its_steps_computed(pool):
+    """`dsa_keys_computed`: columns run x queries over the steps' three
+    mixers, both rows of the pool: the blocks of 16 (a quarter of the
+    64 columns) up to the step's deepest row, 27 to 57 deep here."""
+    *_, stats, _ = pool
+    queries = stats["steps"] * 2 * 3
+    assert mla._block_width(1, 4, T) == 16
+    assert stats["dsa_keys_computed"] % (16 * 2 * 3) == 0
+    assert stats["dsa_keys_visible"] <= stats["dsa_keys_computed"] < \
+        T * queries
 
 
 # -- (c) the latent-space form, the rotary slice, the loader's permutation --------
@@ -547,15 +615,24 @@ def test_the_lowered_programs_carry_the_four_scopes():
                       "/mla.keys/mla.attend/", "/dsa.index/",
                       "/dsa.select/", "/mla.attend/", "/moe.experts/"):
             assert scope in text, scope
-        # what runs over the cached rows sits in a branch of the
-        # extents' switch, its scope still a whole part of the stack
-        assert "/mla.keys/cond/branch_3_fun/dsa.select/" in text
+        # what runs over the cached rows sits in loops over column
+        # blocks, each scope still a whole part of the stack
+        for loop in ("/mla.keys/cond/branch_0_fun/dsa.index/while/body",
+                     "/mla.keys/cond/branch_0_fun/dsa.select/while/body",
+                     "/mla.keys/mla.attend/while/body"):
+            assert loop in text, loop
+        # the branch of a forward no deeper than the keys kept holds
+        # no indexer and no selection
+        kept_all = [line for line in text.splitlines()
+                    if "/mla.keys/cond/branch_1_fun/" in line]
+        assert kept_all and not [x for x in kept_all if "dsa." in x]
         # exact: a threshold found bit by bit, never an approximate
         # top-k (the experts' router sorts; the selection does not)
         assert "approx" not in text.lower()
         assert not [line for line in text.splitlines()
                     if "sort" in line.lower() and "dsa." in line]
-        assert "stablehlo.case" in text       # one program, four extents
+        # one program: no branch by column count, one body a loop
+        assert "branch_2_fun" not in text
 
 
 def test_the_symbol_binds_one_dict_and_two_states_a_mixer():

@@ -319,6 +319,56 @@ def test_the_gated_short_convolution_compiles_for_v5e(
     assert stats.temp_size_in_bytes < 8 * B * tokens * 3 * D * 2 + 2 ** 20
 
 
+@pytest.mark.parametrize("rows,tokens", [(1, 512), (4, 1)],
+                         ids=["chunk", "step"])
+def test_the_latent_mixer_over_column_blocks_compiles_for_v5e(
+        one_chip, no_compile_cache, rows, tokens):
+    """The GLM-5 cell's mixer (64 heads, latents of 2 048 and 512, 32
+    index heads of 128, 2 048 keys kept of a buffer of 16 896) at its
+    two shapes: a chunk of 512 queries runs blocks of 512 columns in
+    loops, the step blocks of a quarter of the buffer; both update the
+    two caches in place, and the chunk's temporaries are the three (1,
+    512, 16 896) arrays of the selection (scores, their ordered form,
+    the mask) and little else: a block's float32 scores and the 32
+    heads' accumulator stay in fast memory; the step keeps no copy of
+    its 77.9 MB of latent rows (one block of the whole buffer kept
+    one, in another layout, and wrote it back)."""
+    from mxnet_tpu.ops import mla, shape_hooks
+    D, H, C = 6144, 64, 16896
+    sizes = dict(q_lora_rank=2048, kv_lora_rank=512, qk_nope_head_dim=192,
+                 qk_rope_head_dim=64, v_head_dim=256, index_heads=32,
+                 index_head_dim=128)
+    assert mla._block_width(tokens, H, C) == (512 if tokens > 1 else C // 4)
+    shapes = shape_hooks._latent_select_shapes(
+        [(rows, tokens, D)] + [None] * 16,
+        dict(sizes, num_heads=H, max_len=C))
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def mixer(x, weights, latent, index, pos):
+        return mla.latent_select_attention(
+            x, pos[:, None] + jnp.arange(tokens, dtype=jnp.float32),
+            dict(zip(mla._WEIGHTS, weights)), latent, index, pos,
+            num_heads=H, index_topk=2048, rope_base=1e6,
+            **{k: sizes[k] for k in (
+                "qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
+                "index_heads")})
+
+    compiled = jax.jit(mixer, donate_argnums=(2, 3)).lower(
+        spec(shapes[0]), [spec(s) for s in shapes[2:14]],
+        spec(shapes[14]), spec(shapes[15]),
+        spec((rows,), jnp.int32)).compile()
+    stats = compiled.memory_analysis()
+    caches = rows * C * (576 + 128) * 2
+    assert stats.alias_size_in_bytes >= caches
+    # read here: 90.0 MB for the chunk (the three arrays are 77.9) and
+    # 1.8 MB for the step
+    selection = 512 * C * (4 + 4 + 1)
+    assert stats.temp_size_in_bytes < (
+        selection + (32 << 20) if tokens > 1 else 16 << 20)
+
+
 @pytest.mark.parametrize("rule", ["sequential", "low_confidence_static",
                                   "low_confidence_dynamic"])
 def test_block_step_with_the_rows_state_compiles_for_v5e(
