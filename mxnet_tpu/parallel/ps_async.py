@@ -103,18 +103,25 @@ def _loads(data):
     return _NoImportUnpickler(_io.BytesIO(data)).load()
 
 
-def _send_msg(sock, obj, fault_point=None):
-    """Frame + send. ``fault_point`` names this call site for the
-    deterministic FaultInjector (resilience.py, MXNET_FAULT_SPEC);
-    None exempts the call (handshakes, heartbeat replies) so injection
-    counts stay reproducible."""
+def _frame_msg(sock, obj, fault_point=None):
+    """The bytes ``obj`` goes over ``sock`` as, for a caller that
+    writes them itself. ``fault_point`` names this call site for the
+    deterministic FaultInjector (resilience.py, MXNET_FAULT_SPEC),
+    which may sever ``sock`` and raise here, before anything is
+    written; None exempts the call (handshakes, heartbeat replies) so
+    injection counts stay reproducible."""
     payload = pickle.dumps(obj, protocol=4)
     frame = struct.pack(">I", len(payload)) + payload
     if fault_point is not None:
         inj = active_injector()
         if inj is not None:
             inj.on_send(fault_point, sock, frame)
-    sock.sendall(frame)
+    return frame
+
+
+def _send_msg(sock, obj, fault_point=None):
+    """Frame + send (:func:`_frame_msg`, then all of it)."""
+    sock.sendall(_frame_msg(sock, obj, fault_point))
 
 
 def _recv_msg(sock, fault_point=None):

@@ -130,6 +130,20 @@ the step before unread: ``steps - 1`` in a kept-full greedy pool) and
 ``idle_forwards``; ``on_logits`` hands each step's rows to whoever
 asks.
 
+Streamed generates (docs/serving.md §the streamed path's threads): an
+emission wakes nobody. A streamed request's sink (:meth:`_note`) puts
+the token on its stream's list of the turn; after each emitting part
+of a turn (an admission round, a chunk's last forward, a step or
+round) the loop hands what it noted to the decoder's ONE relay thread
+in one piece (:meth:`_hand_off`; ``stats()["stream_handoffs"]``), and
+the relay writes each stream's frame onto its connection without
+blocking (:meth:`_relay_to`). A stream's handler thread
+(:meth:`handle_generate_stream`) sleeps until the relay says the
+sequence has settled with every frame out; a connection that does not
+take a write whole becomes its handler's until the handler has caught
+up (``stats()["stream_frames_late"]``), so a client that stops reading
+delays no other stream and never the loop.
+
 Generation by diffusion over blocks (``Generator(diffusion=...)``):
 the pool's one compiled program is then ``block_step``, and a step no
 longer yields one token a row. A row's state LIVES ON THE DEVICE,
@@ -188,7 +202,9 @@ export refuse such a generator.
 """
 from __future__ import annotations
 
+import functools
 import logging
+import queue
 import signal
 import threading
 from collections import OrderedDict, deque
@@ -376,7 +392,8 @@ class DecodeFuture:
         settles (result or error). Delivery holds the emission lock,
         so a sink sees the stream exactly once, in order, with no gap
         between the prefix replay and live emissions — sinks must be
-        cheap and non-blocking (a queue put)."""
+        cheap and non-blocking (a list append: the streamed path's
+        :meth:`ContinuousDecoder._note`)."""
         with self._slock:
             for t in self.emitted:
                 sink(t)
@@ -427,6 +444,37 @@ class DecodeFuture:
         if self._exc is not None:
             raise self._exc
         return self._value
+
+
+class _Stream:
+    """One streamed generate between the three threads that serve it
+    (:meth:`ContinuousDecoder.handle_generate_stream`): the decode
+    loop notes its tokens, the decoder's relay writes its frames, its
+    handler sleeps until the sequence settles. The condition guards
+    every field, and only the relay and the handler ever take it.
+
+    ``late`` says whose the connection is: the handler's while it
+    sends the replayed prefix and, ``slow``, after a write that the
+    socket did not take whole, until it has sent all of ``backlog``
+    (calls, each sending what is left of one frame or one whole
+    frame); the relay's otherwise."""
+
+    __slots__ = ("emit", "nowait", "offset", "cv", "late", "slow",
+                 "backlog", "settled", "error", "closed")
+
+    def __init__(self, emit):
+        self.emit = emit
+        # an emit with no socket under it (an in-process caller's) is
+        # its own nowait: like a sink, it may not block
+        self.nowait = getattr(emit, "nowait", emit)
+        self.offset = 0            # emission index of the next frame
+        self.cv = threading.Condition(threading.Lock())
+        self.late = True
+        self.slow = False
+        self.backlog = []
+        self.settled = False       # every frame before this is out
+        self.error = None          # what a write to the connection raised
+        self.closed = False        # the handler has left
 
 
 def _merge_program(generator):
@@ -933,6 +981,16 @@ class ContinuousDecoder:
         self._deduped = 0
         self._streams = 0
         self._streams_inflight = 0
+        # the streamed path (handle_generate_stream): what the loop has
+        # noted since its last hand-off, {stream: [tokens, then None
+        # where the sequence settled]}, and the relay's queue of such
+        # hand-offs. The lock is the dict's; the loop takes it once a
+        # token and a handler once a stream
+        self._noted = {}
+        self._noted_lock = threading.Lock()
+        self._handoffs = queue.SimpleQueue()
+        self._stream_handoffs = 0
+        self._stream_frames_late = 0
         self._g_active = _telemetry.gauge("serve.decode.active_slots")
         # pool-measured twin of the Generator's static sizing gauge:
         # actual device-array bytes of the live cache pytree per slot.
@@ -985,6 +1043,10 @@ class ContinuousDecoder:
         self._c_streams = _telemetry.counter("serve.decode.streams")
         self._g_streams = _telemetry.gauge(
             "serve.decode.streams_active")
+        self._c_handoffs = _telemetry.counter(
+            "serve.decode.stream_handoffs")
+        self._c_frames_late = _telemetry.counter(
+            "serve.decode.stream_frames_late")
         self._c_chunks = _telemetry.counter(
             "serve.decode.prefill_chunks")
 
@@ -1057,6 +1119,9 @@ class ContinuousDecoder:
             self._log.info("decode slot sizing\n%s",
                            self.describe(hbm_budget=budget))
 
+        self._relay_thread = threading.Thread(
+            target=self._relay, name="mxnet-serve-relay", daemon=True)
+        self._relay_thread.start()
         self._thread = threading.Thread(
             target=self._loop, name="mxnet-serve-decode", daemon=True)
         self._thread.start()
@@ -1585,16 +1650,31 @@ class ContinuousDecoder:
     def handle_generate_stream(self, payload, emit):
         """The streamed twin of :meth:`handle_generate`
         (serve/net.py's ``generate`` frame with ``stream: True``):
-        submit the sequence, then relay every emitted token to
-        ``emit(tokens, offset)`` ON THIS handler thread as the decode
-        loop picks it — ``offset`` is the emission index of the
-        chunk's first token, so a deduped replay (whose subscription
-        replays the already-emitted prefix from offset 0) lets the
-        client resume token-exact with no duplicated or missing
-        frames. Returns the same final value as the one-shot path
-        (the full id row, or the ``evacuated`` state dict) — the
-        terminal frame carries it for bitwise comparison."""
-        import queue as _qmod
+        submit the sequence and have every emitted token reach
+        ``emit(tokens, offset)``, a step's tokens of this sequence in
+        one call, in order, all of them before this returns.
+        ``offset`` is the emission index of the call's first token, so
+        a deduped replay (whose subscription replays the
+        already-emitted prefix from offset 0) lets the client resume
+        token-exact with no duplicated or missing frames. Returns the
+        same final value as the one-shot path (the full id row, or the
+        ``evacuated`` state dict): the terminal frame carries it for
+        bitwise comparison.
+
+        Three threads serve a stream (docs/serving.md §streaming). The
+        decode loop only NOTES a token (:meth:`_note`, the sink
+        subscribed here) and, when a step's rows are done, hands all
+        it has noted to the decoder's one relay thread in one piece
+        (:meth:`_hand_off`): one thread woken a step, however many
+        rows stream. The relay calls ``emit.nowait(tokens, offset)``
+        for each stream of the step (:meth:`_relay_to`), which writes
+        what the connection takes at once. THIS handler thread sends
+        the replayed prefix, then sleeps until the sequence settles;
+        it is woken earlier only where a write fell short (a client
+        that has stopped reading: the connection is then this
+        thread's, to block on, until it has caught up, and nobody
+        else's frames wait) or raised (the error is raised here, and
+        ends this stream alone)."""
         fut = self.submit(
             payload["prompt"], payload["max_new_tokens"],
             eos_id=payload.get("eos_id"),
@@ -1605,11 +1685,16 @@ class ContinuousDecoder:
             admit_id=payload.get("admit_id"),
             resume=payload.get("resume"),
             speculative=bool(payload.get("speculative")))
-        q = _qmod.Queue()
-        sink = q.put
+        stream = _Stream(emit)
+        sink = functools.partial(self._note, stream)
         timeout = payload.get("timeout")
         deadline = None if timeout is None else \
             _telemetry.now_ms() + float(timeout) * 1000.0
+
+        def owed():
+            return stream.backlog or stream.settled or \
+                stream.error is not None
+
         with self._lock:
             self._streams += 1
             self._streams_inflight += 1
@@ -1617,32 +1702,36 @@ class ContinuousDecoder:
         self._c_streams.inc()
         fut.subscribe(sink)
         try:
-            offset = 0
-            settled = False
-            while not settled:
-                wait = None if deadline is None else max(
-                    0.0, (deadline - _telemetry.now_ms()) / 1000.0)
-                try:
-                    item = q.get(timeout=wait)
-                except _qmod.Empty:
-                    raise RequestTimeout(
-                        "sequence still decoding after %.3fs"
-                        % float(timeout))
-                toks = []
-                while True:
-                    if item is None:       # settle sentinel
-                        settled = True
-                        break
-                    toks.append(int(item))
-                    try:
-                        item = q.get_nowait()
-                    except _qmod.Empty:
-                        break
-                if toks:
-                    emit(toks, offset)
-                    offset += len(toks)
+            # the replayed prefix (and the settle, where the sequence
+            # had ended) was noted on this thread: it is this thread's
+            # to send, unless the loop has handed it over since. Under
+            # the lock, so that the next hand-off's frames of this
+            # stream line up behind it
+            with self._noted_lock:
+                self._relay_to(stream, self._noted.pop(stream, ()))
+            while True:
+                with stream.cv:
+                    if not owed():
+                        # the connection is the relay's from here on
+                        stream.late = stream.slow = False
+                        wait = None if deadline is None else max(
+                            0.0,
+                            (deadline - _telemetry.now_ms()) / 1000.0)
+                        if not stream.cv.wait_for(owed, wait):
+                            raise RequestTimeout(
+                                "sequence still decoding after %.3fs"
+                                % float(timeout))
+                    if stream.error is not None:
+                        raise stream.error
+                    sends, stream.backlog = stream.backlog, []
+                if not sends:              # settled, every frame out
+                    break
+                for send in sends:
+                    send()
         finally:
             fut.unsubscribe(sink)
+            with stream.cv:
+                stream.closed = True
             with self._lock:
                 self._streams_inflight -= 1
                 self._g_streams.set(self._streams_inflight)
@@ -1650,6 +1739,77 @@ class ContinuousDecoder:
             return fut.result(0)
         except SessionEvacuated as exc:
             return {"evacuated": exc.state}
+
+    def _note(self, stream, tok):
+        """A stream's sink (``DecodeFuture.subscribe``): the token, or
+        the None that settles the sequence, goes onto the stream's
+        list of the step. No queue, no condition, nobody woken."""
+        with self._noted_lock:
+            self._noted.setdefault(stream, []).append(tok)
+
+    def _hand_off(self):
+        """Everything noted since the last call goes to the relay in
+        one piece (decode loop thread only, after each of its turn's
+        emitting parts: an admission round, a chunk's last forward, a
+        step or round). Nothing noted, nothing done."""
+        if not self._noted:
+            return
+        with self._noted_lock:
+            noted, self._noted = self._noted, {}
+        if noted:
+            self._stream_handoffs += 1
+            self._c_handoffs.inc()
+            self._handoffs.put(noted)
+
+    def _relay(self):
+        """The relay thread: each hand-off's frames onto their
+        connections, in the order the loop handed them over, until the
+        loop's last (None)."""
+        while True:
+            noted = self._handoffs.get()
+            if noted is None:
+                return
+            for stream, toks in noted.items():
+                self._relay_to(stream, toks)
+
+    def _relay_to(self, stream, toks):
+        """One stream's tokens of one hand-off (a None last where the
+        sequence settled with them) as one frame at the stream's
+        offset: written now, as far as the connection takes it at
+        once, or queued for the stream's handler where the connection
+        is the handler's (``_Stream.late``). Never blocks. The handler
+        is woken where there is something for it: the backlog, the
+        settle, a write's error."""
+        settled = bool(toks) and toks[-1] is None
+        if settled:
+            toks = toks[:-1]
+        with stream.cv:
+            if stream.closed or stream.error is not None:
+                return
+            if toks:
+                offset = stream.offset
+                stream.offset += len(toks)
+                if stream.late:
+                    stream.backlog.append(
+                        functools.partial(stream.emit, toks, offset))
+                else:
+                    try:
+                        rest = stream.nowait(toks, offset)
+                    except Exception as exc:   # noqa: BLE001 — raised
+                        # again on the stream's own thread, which ends
+                        # as it did when that thread made the write
+                        stream.error = exc
+                    else:
+                        if rest is not None:
+                            stream.late = stream.slow = True
+                            stream.backlog.append(rest)
+                if stream.slow:
+                    self._stream_frames_late += 1
+                    self._c_frames_late.inc()
+            stream.settled = stream.settled or settled
+            if stream.late or stream.settled or \
+                    stream.error is not None:
+                stream.cv.notify()
 
     def generate_many(self, prompts, max_new_tokens, eos_id=None,
                       timeout=None, **kwargs):
@@ -2634,9 +2794,12 @@ class ContinuousDecoder:
                     break
             if self._evac_waiters or self._evac_flag:
                 self._do_evacuate()
+                self._hand_off()
                 continue
             self._admit()
+            self._hand_off()
             self._chunk_step()
+            self._hand_off()
             try:
                 if self._speculating():
                     # a round's inputs are the host's: the step in
@@ -2655,6 +2818,8 @@ class ContinuousDecoder:
             except Exception as exc:      # noqa: BLE001 — the loop
                 # serves every later request; see _step_failed
                 self._step_failed(exc)
+            self._hand_off()
+        self._handoffs.put(None)
         self._g_active.set(0)
         _telemetry.journal_event("serve.decode.stop")
 
@@ -2778,6 +2943,9 @@ class ContinuousDecoder:
             _telemetry.journal_event("serve.decode.drain",
                                      pending=pending)
         self._thread.join(timeout)
+        if not self._thread.is_alive():
+            # the loop's last hand-off is the relay's last
+            self._relay_thread.join(timeout)
         self._closed = True
         if self._shutdown is not None:
             self._shutdown.uninstall()
@@ -2831,6 +2999,11 @@ class ContinuousDecoder:
                 "evacuated": self._evacuated,
                 "deduped": self._deduped,
                 "streams": self._streams,
+                # times the loop handed noted tokens to the relay, and
+                # frames the relay left to a stream's own thread
+                # because its connection did not take a write whole
+                "stream_handoffs": self._stream_handoffs,
+                "stream_frames_late": self._stream_frames_late,
                 "spec_rounds": self._spec_rounds,
                 "draft_steps": self._draft_steps,
                 "spec_proposed": self._spec_proposed,

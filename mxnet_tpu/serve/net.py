@@ -42,6 +42,7 @@ explicit operator decision, never the default.
 """
 from __future__ import annotations
 
+import functools
 import logging
 import socket
 import threading
@@ -51,7 +52,7 @@ import numpy as np
 from .. import config as _config
 from .. import telemetry as _telemetry
 from .. import trace as _trace
-from ..parallel.ps_async import _recv_msg, _send_msg
+from ..parallel.ps_async import _frame_msg, _recv_msg, _send_msg
 from ..parallel.resilience import RetryPolicy
 from . import engine as _engine
 
@@ -77,6 +78,51 @@ def stream_idle_timeout():
             "bound would either fail every stream instantly or wedge "
             "on a hung replica forever)" % (t,))
     return t
+
+
+class _FrameWriter:
+    """A streamed generate's ("frame", {seq, offset, tokens}) frames
+    on its connection: the ``emit`` a stream handler is given. One
+    thread at a time owns it, and ``seq`` counts in the order the
+    frames are formed, which is the order they are written in.
+
+    Called, it sends one frame and returns when the socket has taken
+    all of it: the handler thread's way. :meth:`nowait` is for a
+    thread that serves many connections and may wait for none (a
+    decoder's relay, serve/decode.py): it writes what the socket takes
+    at once and returns None, or, where the client has stopped reading
+    and the socket's buffer is full, a call that sends the rest and
+    blocks, for the stream's own thread to make before anything else
+    goes onto this connection. Both pass the ``serve_srv_send`` point
+    once a frame, before a byte of it is written."""
+
+    def __init__(self, conn, frames):
+        self._conn = conn
+        self._c_frames = frames
+        self._seq = 0
+
+    def _frame(self, tokens, offset):
+        frame = _frame_msg(
+            self._conn, ("frame", {"seq": self._seq,
+                                   "offset": int(offset),
+                                   "tokens": [int(t) for t in tokens]}),
+            "serve_srv_send")
+        self._seq += 1
+        self._c_frames.inc()
+        return frame
+
+    def __call__(self, tokens, offset):
+        self._conn.sendall(self._frame(tokens, offset))
+
+    def nowait(self, tokens, offset):
+        frame = self._frame(tokens, offset)
+        try:
+            sent = self._conn.send(frame, socket.MSG_DONTWAIT)
+        except BlockingIOError:
+            sent = 0
+        if sent == len(frame):
+            return None
+        return functools.partial(self._conn.sendall, frame[sent:])
 
 
 class ServeServer:
@@ -269,20 +315,9 @@ class ServeServer:
                     # asked to stream against an engine without the
                     # handler simply gets the one-shot reply: zero
                     # frames is a valid stream.
-                    seq = [0]
-
-                    def emit(tokens, offset):
-                        _send_msg(conn, ("frame",
-                                         {"seq": seq[0],
-                                          "offset": int(offset),
-                                          "tokens": [int(t)
-                                                     for t in tokens]}),
-                                  "serve_srv_send")
-                        seq[0] += 1
-                        self._c_frames.inc()
-
                     self._c_streams.inc()
-                    return ("ok", sfn(kw, emit))
+                    return ("ok", sfn(kw, _FrameWriter(conn,
+                                                       self._c_frames)))
                 return ("ok", fn(kw))
             except _engine.ServeError as exc:
                 return ("err", type(exc).__name__, str(exc))

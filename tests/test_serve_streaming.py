@@ -18,6 +18,7 @@ Load-bearing acceptance gates:
   (MXNET_STREAM_IDLE_TIMEOUT) — never by the old whole-request
   deadline — and recovery delivers every token exactly once.
 """
+import socket
 import threading
 import time
 
@@ -30,6 +31,7 @@ from mxnet_tpu.generation import Generator
 from mxnet_tpu.initializer import Xavier
 from mxnet_tpu.models import transformer
 from mxnet_tpu.parallel import make_train_step
+from mxnet_tpu.parallel.ps_async import _recv_msg, _send_msg
 from mxnet_tpu.parallel.resilience import (FaultInjector, RetryPolicy,
                                            install_fault_injector)
 from mxnet_tpu.serve import (ContinuousDecoder, PrefillEngine,
@@ -37,6 +39,7 @@ from mxnet_tpu.serve import (ContinuousDecoder, PrefillEngine,
 from mxnet_tpu.serve.decode import prefill_chunk
 from mxnet_tpu.serve.net import ServeClient, stream_idle_timeout
 from test_block_diffusion import _in_one_round
+from test_serve_failover import _wait
 
 pytestmark = pytest.mark.serve
 
@@ -668,3 +671,299 @@ class TestIdleTimeout:
             stall.released.set()
             srv.close()
             dec.close()
+
+
+# -- (f) one relay a decoder ---------------------------------------------
+def _raw_stream(srv, payload, rcvbuf=None):
+    """A connection with one streamed generate sent on it and nothing
+    read yet: the client these tests have to be able to stall."""
+    sock = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
+    if rcvbuf:
+        sock.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, rcvbuf)
+    sock.settimeout(60.0)
+    sock.connect((srv.host, srv.port))
+    _send_msg(sock, ("generate", dict(payload, stream=True)))
+    return sock
+
+
+def _read_stream(sock):
+    """Every message of the stream in arrival order, up to and
+    including the terminal reply, and whether anything came after
+    it."""
+    msgs = []
+    while not msgs or msgs[-1][0] == "frame":
+        msgs.append(_recv_msg(sock))
+    sock.settimeout(0.2)
+    try:
+        after = _recv_msg(sock)
+    except socket.timeout:
+        after = None
+    return msgs, after
+
+
+def _tail(msgs):
+    """A stream's tokens as its frames carried them, the frames' seq
+    and offset held to the contract on the way."""
+    toks = []
+    for seq, (kind, fr) in enumerate(msgs[:-1]):
+        assert kind == "frame"
+        assert (fr["seq"], fr["offset"]) == (seq, len(toks))
+        toks.extend(fr["tokens"])
+    return toks
+
+
+class TestOneRelay:
+    """The streamed path's threads (docs/serving.md §streaming): the
+    loop notes, one relay a decoder writes, a stream's handler sleeps
+    until the settle."""
+
+    def test_a_step_of_streaming_rows_is_one_handoff(self, params):
+        """B rows admitted in one round and streamed to B clients for
+        N tokens: the admission's first tokens are one hand-off and
+        each of the N - 1 steps is one, of B frames each; no thread is
+        woken a row."""
+        B, N = 4, 6
+        rng = np.random.RandomState(3)
+        prompts = [rng.randint(1, V, (5,)) for _ in range(B)]
+        one = _gen(params, 1)
+        dec = ContinuousDecoder(_gen(params, B))
+        srv = ServeServer(dec)
+        got = [None] * B
+        toks = [[] for _ in range(B)]
+
+        def call(i):
+            with ServeClient(srv.host, srv.port) as cli:
+                got[i] = cli.generate(prompts[i], N,
+                                      on_token=toks[i].append)
+
+        try:
+            dec.submit(prompts[0], 2).result(120.0)   # programs built
+            gate = _in_one_round(dec)
+            before, f0 = dec.stats(), _cval("serve.net.stream_frames")
+            gate.clear()
+            threads = [threading.Thread(target=call, args=(i,))
+                       for i in range(B)]
+            for t in threads:
+                t.start()
+            # every stream subscribed before its row is admitted, so
+            # that no first token is a replayed prefix
+            _wait(lambda: len(dec._queue) == B and
+                  all(f._sinks for f in list(dec._queue)), 60,
+                  "the streams to subscribe")
+            gate.set()
+            for t in threads:
+                t.join(120.0)
+                assert not t.is_alive()
+            after = dec.stats()
+        finally:
+            srv.close()
+            dec.close()
+        for i in range(B):
+            want = one.generate(prompts[i][None], N)[0]
+            np.testing.assert_array_equal(got[i], want)
+            assert toks[i] == want[5:].tolist()
+        assert after["admit_rounds"] - before["admit_rounds"] == 1
+        assert after["steps"] - before["steps"] == N - 1
+        assert after["stream_handoffs"] - before["stream_handoffs"] == N
+        assert _cval("serve.net.stream_frames") - f0 == B * N
+        assert after["stream_frames_late"] == 0
+
+    def test_a_client_that_stops_reading_delays_nobody(self):
+        """One client asks for a long stream and reads nothing: its
+        socket fills, the relay leaves its frames to its own handler
+        (`stream_frames_late`), and meanwhile another client's streams
+        run at their own pace, each inside a wall-clock limit. When
+        the first client reads at last, its frames are all there, in
+        order, before its terminal reply."""
+        long_t, n_slow = 400, 380
+        sym = transformer.get_symbol(V, 12, num_layers=1, num_heads=H,
+                                     dim=DIM, max_len=long_t)
+        mx.random.seed(5)
+        wide = make_train_step(sym, optimizer="sgd").init_state(
+            Xavier(), {"data": (2, 12), "softmax_label": (2, 12)})[0]
+
+        def gen(batch):
+            return Generator(wide, V, long_t, num_layers=1, num_heads=H,
+                             dim=DIM, batch_size=batch)
+
+        p = np.arange(1, 6)
+        want_slow = gen(1).generate(p[None], n_slow)[0]
+        want_fast = gen(1).generate(p[None], 12)[0]
+        dec = ContinuousDecoder(gen(2))
+        srv = ServeServer(dec)
+        try:
+            dec.submit(p, 2).result(120.0)            # programs built
+            # small buffers on both ends (a connection inherits its
+            # listener's), so that a few hundred frames fill them
+            srv._sock.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF,
+                                 2048)
+            slow = _raw_stream(srv, {"prompt": p,
+                                     "max_new_tokens": n_slow},
+                               rcvbuf=2048)
+            _wait(lambda: dec.stats()["stream_frames_late"] > 0, 60,
+                  "the stalled client's socket to fill")
+            steps0 = dec.stats()["steps"]
+            with ServeClient(srv.host, srv.port) as cli:
+                for _ in range(3):
+                    toks = []
+                    t0 = time.monotonic()
+                    out = cli.generate(p, 12, on_token=toks.append)
+                    assert time.monotonic() - t0 < 20.0
+                    np.testing.assert_array_equal(out, want_fast)
+                    assert toks == want_fast[p.size:].tolist()
+            # the loop went on stepping the stalled row beside them
+            assert dec.stats()["steps"] - steps0 >= 3 * 11
+            assert dec.introspect()["streams_in_flight"] == 1
+            msgs, after = _read_stream(slow)
+            slow.close()
+        finally:
+            srv.close()
+            dec.close()
+        assert after is None
+        assert msgs[-1][0] == "ok"
+        np.testing.assert_array_equal(msgs[-1][1], want_slow)
+        assert _tail(msgs) == want_slow[p.size:].tolist()
+        late = dec.stats()["stream_frames_late"]
+        assert 0 < late < n_slow
+
+    def test_a_send_fault_ends_its_stream_alone(self, params):
+        """A sever injected at `serve_srv_send` under one of two
+        concurrent streams: that client reconnects and its replay
+        (the deduped admission's prefix from offset 0, then the live
+        tokens) delivers every token once; the other stream never
+        notices."""
+        N = 10
+        prompts = [np.arange(1, 5), np.arange(3, 9)]
+        one = _gen(params, 1)
+        dec = ContinuousDecoder(_gen(params, 2))
+        srv = ServeServer(dec)
+        got, toks = [None, None], [[], []]
+
+        def call(i):
+            with ServeClient(srv.host, srv.port) as cli:
+                got[i] = cli.generate(prompts[i], N, admit_id="a%d" % i,
+                                      on_token=toks[i].append)
+
+        try:
+            for p in prompts:
+                dec.submit(p, 2).result(120.0)        # programs built
+            before, r0 = dec.stats(), _cval("serve.net.retries")
+            # the fourth write at the point is a frame of one of the
+            # two (a terminal reply follows a stream's N frames)
+            install_fault_injector(FaultInjector(
+                "serve_srv_send:disconnect@4"))
+            threads = [threading.Thread(target=call, args=(i,))
+                       for i in range(2)]
+            try:
+                for t in threads:
+                    t.start()
+                for t in threads:
+                    t.join(120.0)
+                    assert not t.is_alive()
+            finally:
+                install_fault_injector(None)
+            after = dec.stats()
+        finally:
+            srv.close()
+            dec.close()
+        for i in range(2):
+            want = one.generate(prompts[i][None], N)[0]
+            np.testing.assert_array_equal(got[i], want)
+            assert toks[i] == want[prompts[i].size:].tolist()
+        assert _cval("serve.net.retries") - r0 == 1
+        assert after["deduped"] - before["deduped"] == 1
+        assert after["admitted"] - before["admitted"] == 2
+        assert after["streams"] - before["streams"] == 3
+        assert dec.introspect()["streams_in_flight"] == 0
+
+    def test_replays_beside_live_tokens_lose_and_repeat_nothing(
+            self, params):
+        """Sixteen connections, two for each of eight admissions (the
+        second attempt subscribes somewhere mid-sequence and is owed
+        the prefix by its own thread and the rest by the relay), on a
+        pool of four slots under a switch interval of 10 us: every
+        connection reads contiguous frames of exactly the one-shot
+        tokens before its terminal reply."""
+        import sys
+        N = 12
+        rng = np.random.RandomState(9)
+        prompts = [rng.randint(1, V, (4 + i % 3,)) for i in range(8)]
+        one = _gen(params, 1)
+        want = [one.generate(p[None], N)[0] for p in prompts]
+        dec = ContinuousDecoder(_gen(params, 4))
+        srv = ServeServer(dec)
+        read = [None] * 16
+
+        def call(k):
+            i = k // 2
+            time.sleep(0.004 * (k % 2) * (1 + i % 4))
+            sock = _raw_stream(srv, {"prompt": prompts[i],
+                                     "max_new_tokens": N,
+                                     "admit_id": "r%d" % i})
+            try:
+                read[k] = _read_stream(sock)
+            finally:
+                sock.close()
+
+        interval = sys.getswitchinterval()
+        try:
+            for p in prompts[:3]:
+                dec.submit(p, 2).result(120.0)        # programs built
+            before = dec.stats()
+            sys.setswitchinterval(1e-5)
+            threads = [threading.Thread(target=call, args=(k,))
+                       for k in range(16)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(120.0)
+                assert not t.is_alive()
+        finally:
+            sys.setswitchinterval(interval)
+            after = dec.stats()
+            srv.close()
+            dec.close()
+        for k, (msgs, tail) in enumerate(read):
+            assert tail is None
+            assert msgs[-1][0] == "ok"
+            np.testing.assert_array_equal(msgs[-1][1], want[k // 2])
+            assert _tail(msgs) == \
+                want[k // 2][prompts[k // 2].size:].tolist()
+        assert after["admitted"] - before["admitted"] == 8
+        assert after["deduped"] - before["deduped"] == 8
+        assert after["stream_frames_late"] == 0
+
+    @pytest.mark.parametrize("pool", ["autoregressive", "diffusion"])
+    def test_every_frame_precedes_the_terminal_reply(self, params,
+                                                     pool):
+        """What arrives on a stream's connection is its frames, in
+        order and whole, then the terminal reply, then nothing; a
+        diffusion row's step is one frame however many tokens it
+        unmasked."""
+        if pool == "diffusion":
+            import test_block_diffusion as bd
+            gen = bd._gen(bd.ref.make_params(bd.TOY, bd.SEED, "float32"),
+                          2)
+            prompts, n = bd._prompts([9, 6], seed=2), 11
+        else:
+            gen = _gen(params, 2)
+            prompts, n = [np.arange(1, 5), np.arange(2, 9)], 9
+        with gen.serving_decoder() as dec:
+            want = [dec.submit(p, n).result(120.0) for p in prompts]
+            with ServeServer(dec) as srv:
+                socks = [_raw_stream(srv, {"prompt": p,
+                                           "max_new_tokens": n})
+                         for p in prompts]
+                read = [_read_stream(s) for s in socks]
+                for s in socks:
+                    s.close()
+        for p, row, (msgs, after) in zip(prompts, want, read):
+            assert after is None
+            assert msgs[-1][0] == "ok"
+            np.testing.assert_array_equal(msgs[-1][1], row)
+            assert _tail(msgs) == np.asarray(row)[len(p):].tolist()
+            sizes = [len(fr["tokens"]) for _kind, fr in msgs[:-1]]
+            if pool == "diffusion":
+                assert max(sizes) > 1 and len(sizes) < n
+            else:
+                assert sizes == [1] * n

@@ -489,9 +489,11 @@ def _scn_streaming():
     prompt under MXNET_PREFILL_CHUNK admits in a deterministic chunk
     count with the same bits, and the (B, 1) decode step stays ONE
     compiled executable across streamed + chunked turnover. Stream/
-    chunk counters are exact; frame counts are noisy (the handler
-    coalesces emissions per wire frame, which is scheduling-
-    dependent)."""
+    chunk counters are exact and no frame is late; frame and hand-off
+    counts are noisy (a frame is a loop turn's tokens of one stream
+    and a hand-off a turn's frames, but a token emitted before its
+    stream subscribed is the handler's replayed prefix, not the
+    relay's, and that is a race between two threads)."""
     import os as _os
 
     import numpy as np
@@ -787,10 +789,11 @@ SCENARIOS = {
                 "+ chunked prefill, one decode replica on the wire",
         "gauges": ("serve.decode.jit_cache_size",
                    "serve.decode.kv_bytes_per_slot"),
-        # emissions coalesce into wire frames per handler wakeup —
-        # the frame count is scheduling-dependent, the token
-        # sequence is not
-        "noisy_counters": ("serve.net.stream_frames",),
+        # a first token emitted before its stream subscribed rides
+        # the replayed prefix — frame and hand-off counts are
+        # scheduling-dependent, the token sequence is not
+        "noisy_counters": ("serve.net.stream_frames",
+                           "serve.decode.stream_handoffs"),
         "noisy_events": (),
     },
     "spec_decode": {
